@@ -1028,7 +1028,7 @@ class ClusterEncoding:
         Row uploads are ONE fused jitted scatter per row-group (nodes,
         pods) with the dirty-index length padded to capacity buckets —
         stable shapes avoid per-sync XLA recompiles, and fusing avoids one
-        dispatch round-trip per array (24 of them) on tunneled devices.
+        dispatch per array (24 of them).
 
         CONTRACT: the scatter donates the previous device buffers, so
         arrays from an earlier device_state() call are INVALID once any

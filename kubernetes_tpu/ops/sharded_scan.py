@@ -60,7 +60,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..parallel.partition import (
     SESSION_PARTITION_RULES,
     session_specs,
-    shard_map_compat,
     shard_tree,
 )
 from ..parallel.sharded import NODE_AXIS
@@ -469,6 +468,7 @@ def _commit_fn(cfg, statics, tables, carry, x, t, best, oki):
         # block; kcnt accumulates the PER-SHARD key-presence totals
         # (nonzero only on the winner's shard — global totals psum at
         # read), mirroring the kernel's _apply_updates
+        ucnt, kcnt = carry["ucnt"], carry["kcnt"]
         pi = statics["prow_ipa"].astype(f32)              # (SUB, Npl)
         zb_i = psum(_doth(pi, hotf, (((1,), (1,)), ((), ()))))  # (SUB,1)
         m_i = ((pi == zb_i)
@@ -638,10 +638,10 @@ def _sharded_scan(cfg, mesh, statics, tables, carry, xs, k: int = 1):
         step = functools.partial(_step_fn, cfg, statics, tables)
         return jax.lax.scan(step, carry, xs)
 
-    carry, ys = shard_map_compat(
-        body, mesh,
+    carry, ys = jax.shard_map(
+        body, mesh=mesh,
         in_specs=(statics_spec, tables_spec, carry_spec, xs_spec),
-        out_specs=(carry_spec, ys_spec),
+        out_specs=(carry_spec, ys_spec), check_vma=False,
     )(statics, tables, carry, xs)
     if k > 1:
         ys = {kk: v.reshape((-1,) + v.shape[2:]) for kk, v in ys.items()}
@@ -697,10 +697,11 @@ class ShardedPallasSession:
         assert mesh is not None, "ShardedPallasSession needs a mesh"
         if len(mesh.devices.ravel()) < 1:
             raise PallasUnsupported("empty mesh", reason="other")
-        inner = PallasSession(cluster, template_arrays_list, weights)
+        inner = PallasSession(cluster, template_arrays_list, weights,
+                              launches_kernel=False)
         # multi-pod steps (conflict-SUFFIX contract: flagged pods are
         # uncommitted; the backend replays them through the live session)
-        self.multipod_k = K_ops.multipod_k(multipod_k)
+        self.multipod_k = K_ops.multipod_k(multipod_k, suffix_replay=True)
         self.mesh = mesh
         self.weights = inner.weights
         self._fps = inner._fps

@@ -25,11 +25,6 @@ Two rule tables live here:
   and carries split along their node axis, score tables and batch rows
   replicated. The specs reproduce the old `_NODE_DIM` placements
   exactly (pinned by tests/test_mesh_partition.py).
-
-`shard_map` moved out of `jax.experimental` upstream; `shard_map_compat`
-resolves whichever home this jax has and maps the replication-check
-kwarg (`check_vma` on new jax, `check_rep` on 0.4.x) so the sharded
-session runs on both.
 """
 
 from __future__ import annotations
@@ -178,17 +173,3 @@ def session_specs(group: str, tree: Dict) -> Dict:
     return match_partition_rules(SESSION_PARTITION_RULES,
                                  {group: tree})[group]
 
-
-# ---------------------------------------------------------------------------
-# shard_map compat (jax moved it out of experimental; the replication
-# check kwarg was renamed check_rep -> check_vma along the way)
-# ---------------------------------------------------------------------------
-
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False)

@@ -386,6 +386,40 @@ class Result:
     device_time: Optional[Dict[str, float]] = None
     recompiles: int = 0
     devtime_level: int = 0
+    # the device the run used, as jax reports it (utils/device.py) —
+    # `backend` above only names the scheduler backend that was ASKED
+    # for ("tpu" rides the CPU in tests), never what it ran on
+    platform: str = ""
+    device_kind: str = ""
+    device_count: int = 0
+    # health of the device path over the WHOLE run (init phase and the
+    # after_window hook included), as counter deltas: faults by kind,
+    # re-driven dispatches, ladder demotions and the rung at the end,
+    # supervised-worker restarts, and the text of every pallas AOT
+    # executable that failed to compile or was retired
+    # ("bucket/mode" -> error). All zero/empty on a clean run.
+    device_faults: Optional[Dict[str, int]] = None
+    dispatch_retries: int = 0
+    ladder_demotions: int = 0
+    backend_mode: str = ""
+    worker_restarts: int = 0
+    # the pallas session's executable cache: "bucket/mode" -> "aot" (the
+    # AOT-compiled program served) | "jit" (AOT failed or was retired)
+    executables: Optional[Dict[str, str]] = None
+    exec_errors: Optional[Dict[str, str]] = None
+    # executable builds (utils/device.CompileMeter): requests/cache_hits/
+    # seconds before the measured window (set-up) and inside it — a
+    # steady-state window compiles nothing
+    compile_setup: Optional[Dict[str, float]] = None
+    compile_window: Optional[Dict[str, float]] = None
+    # whatever the caller's after_window hook returned
+    after_window: Optional[dict] = None
+    # why this row is NOT a clean measurement of the path it names: a
+    # device fault, retry or demotion nobody injected, a failed AOT
+    # compile, a crashed worker, unbound pods on a workload where all
+    # can bind, a kernel-direct phase that raised. Bench entry points
+    # exit non-zero when any row has one; empty on a clean run.
+    failures: List[str] = field(default_factory=list)
 
     def to_dict(self) -> dict:
         return dict(self.__dict__)
@@ -494,42 +528,99 @@ def _kernel_direct_rate(sched, w: "Workload", reps: int = 3) -> float:
     the host encoding never sees the phantom pods, so a later real
     dispatch rebuilds clean. Callers freeze every in-window counter
     BEFORE calling this (the teardown/build pair is accounting noise).
-    Failures (PVC templates the raw encoder cannot resolve, demoted
-    backends) report 0.0 — the ratio is then omitted, never
-    fabricated."""
+    PVC templates the raw encoder cannot resolve (the phantom pods own
+    no claims) report 0.0 — the ratio is then omitted, never
+    fabricated; any other error is the caller's to report."""
+    from ..scheduler.volume_device import VolumeResolutionChanged
+
     tpu = sched.tpu
     if tpu is None or not w.kernel_direct:
         return 0.0
     nb = max(1, min(w.max_batch, w.num_pods or 1, 512))
     pods = [w.template.build(f"kdirect-{i}") for i in range(nb)]
-    try:
-        with tpu._lock:
-            tpu._flush_pending()
-            arrays = []
-            for p in pods:
-                enc = tpu.pe.encode(p)
-                arrays.append(
-                    {k: v for k, v in enc.items() if not k.startswith("_")}
-                )
-            tpu._invalidate_session("kernel-direct")
+    with tpu._lock:
+        tpu._flush_pending()
+        arrays = []
+        for p in pods:
             try:
-                tpu._session_schedule(arrays)  # build + bucket compile
-                t0 = time.perf_counter()
-                for _ in range(reps):
-                    tpu._session_schedule(arrays)
-                dt = time.perf_counter() - t0
-            finally:
-                tpu._invalidate_session("kernel-direct")
-        return nb * reps / dt if dt > 0 else 0.0
-    except Exception:  # noqa: BLE001 — report the loop numbers regardless
-        return 0.0
+                enc = tpu.pe.encode(p)
+            except VolumeResolutionChanged:
+                return 0.0
+            arrays.append(
+                {k: v for k, v in enc.items() if not k.startswith("_")}
+            )
+        tpu._invalidate_session("kernel-direct")
+        try:
+            tpu._session_schedule(arrays)  # build + bucket compile
+            t0 = time.perf_counter()
+            for _ in range(reps):
+                tpu._session_schedule(arrays)
+            dt = time.perf_counter() - t0
+        finally:
+            tpu._invalidate_session("kernel-direct")
+    return nb * reps / dt if dt > 0 else 0.0
 
 
-def run_workload(w: Workload, quiet: bool = True) -> Result:
+def _health_counts() -> Dict:
+    """Process-wide device-path health counters (run_workload diffs two
+    reads around a run)."""
+    from ..scheduler.metrics import (
+        device_faults,
+        dispatch_retries,
+        worker_restarts,
+    )
+
+    return {
+        "faults": _label_counts(device_faults),
+        "retries": _counter_total(dispatch_retries),
+        "restarts": _counter_total(worker_restarts),
+    }
+
+
+def _session_executables(tpu) -> tuple:
+    """({"bucket/mode": "aot" | "jit"}, {"bucket/mode": error text}) of
+    the live pallas session's executable cache — "jit" marks an entry
+    whose AOT compile failed or that was retired. Empty for sessions
+    without one (hoisted, sharded, none)."""
+    sess = tpu._session if tpu is not None else None
+    execs = {
+        f"{k[0]}/{k[1]}": "aot" if v is not None else "jit"
+        for k, v in dict(getattr(sess, "_exec", {})).items()
+    }
+    errors = {
+        f"{k[0]}/{k[1]}": v
+        for k, v in dict(getattr(sess, "exec_errors", {})).items()
+    }
+    return execs, errors
+
+
+def _meter_window(now: Dict, base: Dict) -> Dict:
+    return {
+        "requests": now["requests"] - base["requests"],
+        "cache_hits": now["cache_hits"] - base["cache_hits"],
+        "seconds": round(now["seconds"] - base["seconds"], 3),
+    }
+
+
+def run_workload(w: Workload, quiet: bool = True,
+                 after_window=None, tpu_backend=None) -> Result:
+    """Run one workload end to end. `after_window(cs, sched, stage)`, when
+    given, runs after the measured window's numbers are frozen and before
+    teardown, on the live cluster (stage(n, create_one) creates n pods
+    with the scheduler paused and resumes it); what it returns rides
+    Result.after_window, and faults it causes count against the row.
+    `tpu_backend` substitutes a pre-built TPUBackend (tests and CPU dry
+    runs of the chip path pass one that interprets the Pallas kernel)."""
+    from ..utils.device import compile_meter, device_info, row_fields
+
     if not w.columnar:
         os.environ["KTPU_COLUMNAR_CACHE"] = "0"
     else:
         os.environ.pop("KTPU_COLUMNAR_CACHE", None)
+    dev = device_info()
+    meter = compile_meter()
+    meter0 = meter.read()
+    health0 = _health_counts()
     api = APIServer()
     http_srv = None
     if w.wire:
@@ -579,8 +670,7 @@ def run_workload(w: Workload, quiet: bool = True) -> Result:
                 disruptions_allowed=w.pdb_disruptions_allowed),
         ))
     factory = SharedInformerFactory(cs)
-    tpu_backend = None
-    if w.backend == "tpu" and w.mesh_devices:
+    if tpu_backend is None and w.backend == "tpu" and w.mesh_devices:
         import jax
 
         from ..parallel.sharded import make_mesh
@@ -759,6 +849,11 @@ def run_workload(w: Workload, quiet: bool = True) -> Result:
                 }
             cs.pods.create(pod)
 
+        if sched.tpu is not None:
+            # every bucket executable the window can dispatch is built
+            # (or loaded) before it opens: background compiles would
+            # share the host with the run being measured
+            sched.tpu.wait_warm()
         _stage(w.num_pods, _create_measured)
         from ..scheduler import metrics as sched_metrics
 
@@ -823,6 +918,7 @@ def run_workload(w: Workload, quiet: bool = True) -> Result:
         trace_mark = tracing.RECORDER.mark() if tracing.enabled() else 0
         dt_mark = devtime.TIMELINE.mark() if devtime.enabled() else 0
         compiles0 = devtime.TIMELINE.compiles
+        meter_w0 = meter.read()
         t0 = time.perf_counter()
         t0_mono = time.monotonic()  # bind_timestamps' clock
         last_bound = 0
@@ -854,6 +950,7 @@ def run_workload(w: Workload, quiet: bool = True) -> Result:
         sched.pause()  # no fresh dispatches while results are read
         sched._drain_pipeline(timeout=30.0)  # land in-flight tail binds
         dt = time.perf_counter() - t0
+        meter_w1 = meter.read()
         # exact measured-phase bind timestamps (monotonic, bind-sent
         # time; binder threads may land batches slightly out of order)
         bind_ts = sorted(
@@ -951,6 +1048,7 @@ def run_workload(w: Workload, quiet: bool = True) -> Result:
             if sched.tpu is not None and sched.tpu._session is not None
             else ""
         )
+        executables, exec_errors = _session_executables(sched.tpu)
         # per-stage latency attribution, scoped to the measured window
         # (the mark() anchor above) and frozen BEFORE the kernel-direct
         # measurement, whose throwaway dispatches must not pollute the
@@ -984,7 +1082,41 @@ def run_workload(w: Workload, quiet: bool = True) -> Result:
                   "overlapped_s")}
             )
             n_recompiles = devtime.TIMELINE.compiles - compiles0
-        kd_rate = round(_kernel_direct_rate(sched, w), 2)
+        failures: List[str] = []
+        if bound_measured < w.num_pods and not (w.saturating
+                                                or w.stall_stop):
+            failures.append(
+                f"bound {bound_measured} of {w.num_pods} measured pods")
+        try:
+            kd_rate = round(_kernel_direct_rate(sched, w), 2)
+        except Exception as e:  # noqa: BLE001 — the loop numbers are still reported, with the failure
+            kd_rate = 0.0
+            failures.append(f"kernel-direct: {type(e).__name__}: {e}")
+        hook_out = None
+        if after_window is not None:
+            sched.resume()
+            hook_out = after_window(cs, sched, _stage)
+        tpu = sched.tpu
+        health = _health_counts()
+        faults = _counter_window(health["faults"], health0["faults"])
+        n_retries = health["retries"] - health0["retries"]
+        n_restarts = health["restarts"] - health0["restarts"]
+        execs_end, errors_end = _session_executables(tpu)
+        executables.update(execs_end)
+        exec_errors.update(errors_end)
+        if faults:
+            failures.append(f"device faults: {faults}")
+        if n_retries:
+            failures.append(f"{n_retries} dispatch retries")
+        if tpu is not None and (tpu.ladder.demotions
+                                or tpu.ladder.rung() < tpu.ladder.top):
+            failures.append(
+                f"backend demoted {tpu.ladder.demotions}x, ended at "
+                f"{tpu.ladder.mode()}")
+        if n_restarts:
+            failures.append(f"{n_restarts} worker restarts")
+        if exec_errors:
+            failures.append(f"pallas executables failed: {exec_errors}")
         return Result(
             name=w.name,
             backend=w.backend,
@@ -1047,12 +1179,43 @@ def run_workload(w: Workload, quiet: bool = True) -> Result:
             device_time=device_time,
             recompiles=n_recompiles,
             devtime_level=devtime.level(),
+            **row_fields(dev),
+            device_faults=faults,
+            dispatch_retries=n_retries,
+            ladder_demotions=tpu.ladder.demotions if tpu is not None else 0,
+            backend_mode=tpu.ladder.mode() if tpu is not None else "",
+            worker_restarts=n_restarts,
+            executables=executables,
+            exec_errors=exec_errors,
+            compile_setup=_meter_window(meter_w0, meter0),
+            compile_window=_meter_window(meter_w1, meter_w0),
+            after_window=hook_out,
+            failures=failures,
         )
     finally:
         sched.stop()
         factory.stop()
         if http_srv is not None:
             http_srv.stop()
+
+
+def bind_more(cs: Clientset, sched, stage, template: PodTemplate, n: int,
+              prefix: str, timeout: float) -> Dict[str, str]:
+    """For after_window hooks: create n more pods of `template` named
+    `<prefix>-i` on the live cluster, let the scheduler bind them, land
+    the pipeline, and return {pod name: node} for those that bound."""
+    stage(n, lambda i: cs.pods.create(template.build(f"{prefix}-{i}")))
+    got: Dict[str, str] = {}
+    deadline = time.monotonic() + timeout
+    while time.monotonic() < deadline and len(got) < n:
+        pods, _ = cs.pods.list(namespace="default")
+        got = {p.metadata.name: p.spec.node_name for p in pods
+               if p.metadata.name.startswith(f"{prefix}-")
+               and p.spec.node_name}
+        time.sleep(0.1)
+    sched.pause()
+    sched._drain_pipeline(timeout=30.0)
+    return got
 
 
 def _wait_all_bound(cs: Clientset, n: int, timeout: float) -> bool:
@@ -1064,6 +1227,21 @@ def _wait_all_bound(cs: Clientset, n: int, timeout: float) -> bool:
         time.sleep(0.2)
     return False
 
+
+# BASELINE.json's "5000 nodes / 10000 pods, default plugin profile": the
+# north-star cluster and backlog. 5000 nodes of the reference's synthetic
+# shape (4 CPU / 32Gi / 110 pods, 3 zones), zone-spread pods; the init
+# pods share the template so every kernel shape compiles before the
+# measured window. Batch 2048 beats 4096 here since the r3 host-loop
+# batching: same device amortization, steadier bind stream
+# (throughput_p50 > 0). One definition for scripts/bench_configs.py's
+# "default5000" row and chip_smoke.py.
+DEFAULT_5000N_10K = Workload(
+    "Default-5000n-10k", num_nodes=5000, num_init_pods=6144,
+    num_pods=10000, init_template=PodTemplate(spread_zone=True),
+    template=PodTemplate(spread_zone=True), max_batch=2048,
+    timeout=900.0,
+)
 
 # the reference's benchmark suite shapes (performance-config.yaml)
 STANDARD_WORKLOADS = {

@@ -45,12 +45,18 @@ _lib_lock = threading.Lock()
 
 
 def _build_library() -> None:
-    subprocess.run(
+    """Build libkvstore.so in place (native/build/ is not committed); a
+    toolchain that cannot is an error with the compiler's own text."""
+    proc = subprocess.run(
         ["make", "-s", "build/libkvstore.so"],
         cwd=os.path.abspath(_NATIVE_DIR),
-        check=True,
         capture_output=True,
+        text=True,
     )
+    if proc.returncode:
+        raise RuntimeError(
+            f"building native/build/libkvstore.so failed "
+            f"(make exited {proc.returncode}):\n{proc.stderr[-4000:]}")
 
 
 def load_library() -> ctypes.CDLL:

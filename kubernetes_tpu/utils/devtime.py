@@ -59,7 +59,7 @@ DEVTIME_OFF = 0
 DEVTIME_LAUNCHES = 1
 DEVTIME_PROFILE = 2
 
-# record kinds (the attribution taxonomy; README "Device-timeline
+# record kinds (the attribution scheme; README "Device-timeline
 # attribution" documents each)
 KINDS = (
     "kernel",    # scheduling scans: dispatch_many / schedule_many /

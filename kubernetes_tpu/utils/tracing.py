@@ -60,7 +60,7 @@ TRACE_OFF = 0
 TRACE_STAGES = 1
 TRACE_PODS = 2
 
-# canonical pipeline stage names (the span taxonomy; README
+# canonical pipeline stage names (the span naming scheme; README
 # "Observability" documents the meaning of each)
 STAGES = (
     "pop",          # scheduler thread: queue pop + batch gather

@@ -44,6 +44,7 @@ from kubernetes_tpu.utils.compilation_cache import (  # noqa: E402
 enable_persistent_cache()
 
 from kubernetes_tpu.perf.harness import (  # noqa: E402
+    DEFAULT_5000N_10K,
     PodTemplate,
     Workload,
     run_workload,
@@ -57,16 +58,8 @@ CONFIGS = {
         "SchedulingBasic-500", num_nodes=500, num_init_pods=1000,
         num_pods=1000, max_batch=1024,
     ),
-    # 5000 nodes / 10k pods, default profile (init pods share the
-    # template so every kernel shape compiles before the measured window)
-    # batch 2048 beats 4096 here since the r3 host-loop batching: same
-    # device amortization, steadier bind stream (throughput_p50 > 0)
-    "default5000": Workload(
-        "Default-5000n-10k", num_nodes=5000, num_init_pods=6144,
-        num_pods=10000, init_template=PodTemplate(spread_zone=True),
-        template=PodTemplate(spread_zone=True), max_batch=2048,
-        timeout=900.0,
-    ),
+    # 5000 nodes / 10k pods, default profile (perf/harness.py)
+    "default5000": DEFAULT_5000N_10K,
     # PodTopologySpread-heavy: 5000 nodes, 3 zones, maxSkew=1, 20k pods
     "pts20k": Workload(
         "PTS-heavy-5000n-20k", num_nodes=5000, num_init_pods=4096,
@@ -328,6 +321,10 @@ def main() -> None:
     the row carries the MEDIAN run's full detail plus per-rep
     throughput min/median/max. Heavy 5000-node configs halve the reps.
     Set BENCH_WIRE=1 to run the matrix over the real HTTP socket."""
+    from kubernetes_tpu.utils.device import require_device
+
+    dev = require_device()
+    failed = []
     names = sys.argv[1:] or list(CONFIGS)
     reps_default = int(os.environ.get("BENCH_REPS", "3"))
     wire = os.environ.get("BENCH_WIRE", "0") == "1"
@@ -359,7 +356,7 @@ def main() -> None:
             if w.num_nodes >= 5000 else reps_default
         print(f"=== {w.name}: {w.num_nodes} nodes, {w.num_pods} pods "
               f"(batch {w.max_batch}, reps {reps}, wire {wire}) on "
-              f"{jax.devices()[0].platform}",
+              f"{dev['platform']} ({dev['kind']} x{dev['count']})",
               file=sys.stderr, flush=True)
         runs = []
         for rep in range(reps):
@@ -372,10 +369,17 @@ def main() -> None:
             print(f"  rep {rep}: {line['throughput_avg']} pods/s "
                   f"({line['attempts_per_sec']} attempts/s)",
                   file=sys.stderr, flush=True)
+            for why in line["failures"]:
+                failed.append(f"{w.name} rep {rep}: {why}")
+                print(f"  rep {rep} FAILED: {why}", file=sys.stderr,
+                      flush=True)
         key = "attempts_per_sec" if w.saturating else "throughput_avg"
         vals = [r[key] for r in runs]
         line = next(r for r in runs if r[key] == _median(vals))
         line["reps"] = reps
+        # every rep's failures, not just the median rep's: a fault in
+        # one rep must not hide behind a clean median
+        line["failures_runs"] = [r["failures"] for r in runs]
         line["throughput_avg_runs"] = [r["throughput_avg"] for r in runs]
         line["attempts_per_sec_runs"] = [r["attempts_per_sec"] for r in runs]
         # per-rep session accounting: the rebuild storm was invisible
@@ -499,6 +503,9 @@ def main() -> None:
         with open(out_path, mode) as f:
             f.write(json.dumps(line) + "\n")
         mode = "a"
+    if failed:
+        sys.exit("bench_configs: rows are not clean measurements:\n  "
+                 + "\n  ".join(failed))
 
 
 if __name__ == "__main__":

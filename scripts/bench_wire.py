@@ -104,7 +104,7 @@ def _wiretax_rows(n_nodes: int, n_pods: int, reps: int) -> list:
         line["reps"] = reps
         for key in ("throughput_avg", "pod_scheduling_p99",
                     "serializations_per_event", "wire_encode_bytes",
-                    "watch_evictions"):
+                    "watch_evictions", "failures"):
             line[f"{key}_runs"] = [r[key] for r in runs]
         rows.append(line)
         print(json.dumps(line), flush=True)
@@ -142,6 +142,9 @@ def _fanout_rows(reps: int) -> list:
 
 
 def main() -> None:
+    from kubernetes_tpu.utils.device import require_device, row_fields
+
+    device = row_fields(require_device())
     n_nodes = int(sys.argv[1]) if len(sys.argv) > 1 else 1000
     n_pods = int(sys.argv[2]) if len(sys.argv) > 2 else 4096
     reps = int(os.environ.get("BENCH_REPS", "3"))
@@ -150,9 +153,11 @@ def main() -> None:
     lines = _wiretax_rows(n_nodes, n_pods, reps)
     inproc = next(ln for ln in lines if not ln["wire"])
     http = next(ln for ln in lines if ln["wire"])
-    lines += _fanout_rows(reps)
+    # the fan-out rows touch no device; they still say which host ran
+    lines += [dict(ln, **device) for ln in _fanout_rows(reps)]
     summary = {
         "name": "WireTaxSummary",
+        **device,
         "inproc_pods_per_sec": inproc["throughput_avg"],
         "http_pods_per_sec": http["throughput_avg"],
         "wire_tax_pct": round(
@@ -170,6 +175,11 @@ def main() -> None:
     with open(out_path, "w") as f:
         for ln in lines + [summary]:
             f.write(json.dumps(ln) + "\n")
+    failed = [f"{ln['name']}: {why}" for ln in lines
+              for rep in ln.get("failures_runs", ()) for why in rep]
+    if failed:
+        sys.exit("bench_wire: rows are not clean measurements:\n  "
+                 + "\n  ".join(failed))
 
 
 if __name__ == "__main__":

@@ -21,9 +21,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax  # noqa: E402
 
 jax.config.update("jax_enable_x64", True)
-# honor JAX_PLATFORMS=cpu even where a TPU plugin force-prepends itself
-if os.environ.get("JAX_PLATFORMS"):
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
 
 from kubernetes_tpu.perf import Workload, run_workload  # noqa: E402
 from kubernetes_tpu.perf.harness import PodTemplate  # noqa: E402

@@ -9,9 +9,7 @@ import numpy as np
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from jax._src.config import enable_x64 as x64ctx
 
-np.asarray(jnp.arange(4) + 1)  # sync mode
 Np, VZ, TCp, LANE, SUB, Bp = 5248, 128, 32, 128, 8, 1024
 
 def kernel(breal, tmpl, sc, mf, ms,
@@ -45,7 +43,7 @@ statics = [jnp.zeros((16, Np), jnp.int32), jnp.zeros((32, Np), jnp.int32),
 
 @jax.jit
 def run(carry, breal, tmpl, mf, ms):
-    with x64ctx(False):
+    with jax.enable_x64(False):
         return pl.pallas_call(
             kernel, out_shape=out_shape,
             in_specs=[sm, sm, sm, vm, vm] + [vm] * 14 + [vm] * 4,

@@ -7,7 +7,7 @@ process), builds a TPUBackend over a mesh of that many devices, and
 drives schedule_many over a synthetic cluster:
 
   * parity prefix: the first PROBE_PARITY pods are also scheduled
-    through a single-device (hoisted) backend over the same cluster —
+    through a single-device backend over the same cluster —
     decisions must be BIT-IDENTICAL before any number is recorded
     (the scale-out contract: sharding is a performance property);
   * throughput: pods/s over the measured schedule_many batches on the
@@ -15,9 +15,13 @@ drives schedule_many over a synthetic cluster:
   * memory: ru_maxrss after the run, plus the session's per-host node
     rows (Npl = Nps/nsh) — the bound that makes 100k nodes fit.
 
-CPU-runnable: the devices are simulated
-(XLA_FLAGS=--xla_force_host_platform_device_count, set below before
-jax imports). On a real pod slice the same probe measures ICI.
+The children use whatever device JAX finds, and each row names it: on a
+multi-chip TPU host the shards are real chips and the collectives ride
+ICI (the single-device reference is then the compiled PallasSession on
+device 0); with JAX_PLATFORMS=cpu the devices are simulated
+(XLA_FLAGS=--xla_force_host_platform_device_count, set below before jax
+imports; the flag only multiplies the HOST platform). This parent never
+imports jax, so it never holds a chip a child needs.
 
 Usage: python scripts/probe_mesh_scaling.py
 Env: PROBE_NODES (20000), PROBE_PODS (512), PROBE_PARITY (32),
@@ -57,7 +61,6 @@ def _vmrss_mb() -> float:
 def _child(nsh: int) -> None:
     """One measurement in THIS process (spawned by main): mesh backend
     at nsh shards, single-device parity prefix, one JSON row."""
-    os.environ["JAX_PLATFORMS"] = "cpu"
     os.environ["JAX_ENABLE_X64"] = "1"
     if "--xla_force_host_platform_device_count" not in os.environ.get(
             "XLA_FLAGS", ""):
@@ -70,8 +73,11 @@ def _child(nsh: int) -> None:
 
     import jax
 
-    jax.config.update("jax_platforms", "cpu")
     jax.config.update("jax_enable_x64", True)
+
+    from kubernetes_tpu.utils.device import require_device, row_fields
+
+    device = row_fields(require_device())
 
     from kubernetes_tpu.api import types as v1
     from kubernetes_tpu.parallel.sharded import make_mesh
@@ -117,6 +123,7 @@ def _child(nsh: int) -> None:
     dt = time.perf_counter() - t0
 
     row = {
+        **device,
         "nsh": nsh,
         "nodes": NODES,
         "pods": PODS,

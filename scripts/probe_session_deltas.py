@@ -92,7 +92,7 @@ def anti_pod(name, node=""):
 def classify_and_queue(be, event, payload) -> str:
     """Replay one trace event against the backend and report which class
     the classifier gave it (reading the queue/session state around the
-    listener call — the probe's whole point is showing the taxonomy)."""
+    listener call — the probe's whole point is showing the classification)."""
     sess = be._session
     n_deltas = len(be._deltas)
     event(payload)

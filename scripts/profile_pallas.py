@@ -1,4 +1,4 @@
-"""PallasSession on the real chip at bench scale: compile + honest timing
+"""PallasSession on the real chip at bench scale: compile + blocking timing
 + decision parity vs the jnp HoistedSession."""
 import os, sys, time
 os.environ.setdefault("JAX_ENABLE_X64", "1")
@@ -43,7 +43,7 @@ ps = PallasSession(enc.device_state(), templates, multipod_k=1)
 print(f"session build (prologue + remap): {time.perf_counter()-t0:.1f}s")
 t0 = time.perf_counter()
 ys = ps.schedule(arrays[:B])
-d0 = PallasSession.decisions(ys)   # also flips to honest sync mode
+d0 = PallasSession.decisions(ys)
 print(f"first schedule (compile): {time.perf_counter()-t0:.1f}s")
 ts = []
 outs = [d0]

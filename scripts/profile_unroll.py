@@ -30,8 +30,6 @@ templates, seen = [], set()
 for a in arrays:
     fp = template_fingerprint(a)
     if fp not in seen: seen.add(fp); templates.append(a)
-# honest mode
-poison = jax.numpy.arange(4) + 1; jax.block_until_ready(poison); np.asarray(poison)
 for unroll in (1, 8, 32):
     os.environ["KTPU_SCAN_UNROLL"] = str(unroll)
     H._session_scan._clear_cache()

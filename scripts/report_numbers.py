@@ -3,15 +3,17 @@
 Every figure quoted in README.md / PERF_NOTES.md must be reproducible by
 running this script — prose that contradicts it is a bug (VERDICT r4
 weak #3: claims diverging from artifacts). Reads BENCH_CONFIGS.json,
-BENCH_WIRE_CONFIGS.json, BENCH_SHARDED.json and the newest BENCH_r*.json.
+BENCH_WIRE_CONFIGS.json and BENCH_SHARDED.json, whichever exist. Rows
+written before PR 21 name no device: they are CPU runs of the build box
+(their session_build_reasons say "platform is not tpu", or they are the
+8-virtual-device mesh rows). Chip measurements are the driver's, in
+PERF_LEDGER.jsonl.
 """
 
 from __future__ import annotations
 
-import glob
 import json
 import os
-import re
 
 ROOT = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
 
@@ -39,20 +41,11 @@ def _newest_round(rows):
 
 
 def main() -> None:
-    benches = sorted(glob.glob(os.path.join(ROOT, "BENCH_r*.json")),
-                     key=lambda p: int(re.findall(r"(\d+)", p)[-1]))
-    if benches:
-        with open(benches[-1]) as f:
-            b = json.load(f)
-        p = b.get("parsed", b)
-        print(f"kernel-direct ({os.path.basename(benches[-1])}): "
-              f"{p.get('value')} pods/s median of {p.get('reps', 1)} reps "
-              f"{p.get('rep_pods_per_sec', '')}, warmup {p.get('warmup_compile_s')}s, "
-              f"vs 1-core-same-algorithm {p.get('vs_cpu_1core_same_algorithm')}x "
-              f"(cpu 1-core {p.get('baseline_cpu_1core_pods_per_sec')} pods/s)")
     for path, label in (("BENCH_CONFIGS.json", "in-proc"),
                         ("BENCH_WIRE_CONFIGS.json", "wire")):
         rows = _rows(path)
+        if not rows:
+            continue
         rnd, by_name = _newest_round(rows)
         print(f"\n-- {label} full-loop matrix ({path}, round {rnd}, "
               f"{len(by_name)} configs) --")
@@ -60,7 +53,8 @@ def main() -> None:
             r = by_name[name]
             key = "attempts_per_sec" if r.get("headline_metric") == \
                 "attempts_per_sec" or r.get("saturating") else "throughput_avg"
-            print(f"  {name}: {r['throughput_avg']} pods/s avg "
+            print(f"  {name} [{r.get('platform') or 'cpu, unrecorded'}]: "
+                  f"{r['throughput_avg']} pods/s avg "
                   f"(p50 {r['throughput_p50']}, attempts/s "
                   f"{r.get('attempts_per_sec')}, attempt_p50 "
                   f"{r.get('attempt_p50')}, reps {r.get('reps')}, "

@@ -30,7 +30,6 @@ from kubernetes_tpu.parallel.partition import (
     SESSION_PARTITION_RULES,
     match_partition_rules,
     session_specs,
-    shard_map_compat,
     tree_path_to_string,
 )
 from kubernetes_tpu.parallel.sharded import (
@@ -309,18 +308,18 @@ class TestPaddingExclusion:
 # ------------------------------------------------------ shard_map smoke
 
 
-class TestShardMapCompat:
+class TestShardMap:
     def test_psum_over_node_axis(self, sim_mesh):
-        """shard_map_compat papers over the jax.shard_map /
-        jax.experimental.shard_map split; a psum over the node axis is
-        the canonical collective every kernel reduction builds on."""
+        """A psum over the node axis is the canonical collective every
+        kernel reduction builds on."""
         x = jnp.arange(16.0)
 
         def f(xs):
             return jax.lax.psum(jnp.sum(xs), NODE_AXIS)
 
-        f_sharded = shard_map_compat(
-            f, sim_mesh, in_specs=(P(NODE_AXIS),), out_specs=P())
+        f_sharded = jax.shard_map(
+            f, mesh=sim_mesh, in_specs=(P(NODE_AXIS),), out_specs=P(),
+            check_vma=False)
         assert float(f_sharded(x)) == float(jnp.sum(x))
 
     def test_shard_cluster_places_on_mesh(self, sim_mesh):

@@ -472,6 +472,13 @@ class TestMultipodHostHalves:
         monkeypatch.delenv("KTPU_MULTIPOD_K")
         assert multipod_k(platform="tpu") == 4
         assert multipod_k(platform="cpu") == 1
+        # conflict-suffix sessions (pallas, sharded) stay at 1 on every
+        # platform unless asked: one template's pods all pick the same
+        # node, so k > 1 commits one pod per launch there
+        assert multipod_k(platform="tpu", suffix_replay=True) == 1
+        monkeypatch.setenv("KTPU_MULTIPOD_K", "8")
+        assert multipod_k(platform="tpu", suffix_replay=True) == 8
+        assert multipod_k(2, suffix_replay=True) == 2
 
     def test_pallas_conflict_stats_decodes_suffix(self):
         import numpy as np
