@@ -32,7 +32,7 @@ from typing import Any, Callable, Dict, Iterator, List, Optional, Tuple, Type
 from ..api import types as v1
 from ..api.labels import Selector
 from ..store import kv
-from ..utils import serde
+from ..utils import serde, tracing
 
 
 class APIError(Exception):
@@ -194,6 +194,15 @@ FINALIZER_FOREGROUND = "foregroundDeletion"
 FINALIZER_ORPHAN = "orphan"
 
 
+def _verb_span(verb: str, resource: str, namespace: str, name: str):
+    """The `apiserver` span of one write: "<verb> <resource>", keyed by
+    the object's namespace/name. Nothing is built with tracing off."""
+    if not tracing.enabled():
+        return tracing.NOOP_SPAN
+    return tracing.span(f"{verb} {resource}", "apiserver",
+                        key=f"{namespace}/{name}" if namespace else name)
+
+
 class APIServer:
     def __init__(
         self,
@@ -307,56 +316,64 @@ class APIServer:
         meta = obj.metadata
         if not meta.name:
             raise Invalid("metadata.name is required")
-        if resource == "certificatesigningrequests":
-            # stamp the requester identity server-side (certificates
-            # types.go:89-99: Username/Groups are set by the apiserver
-            # from the authenticated request, never trusted from the
-            # body) — otherwise any CSR-creating identity could assert a
-            # bootstrap identity and mint auto-approved node credentials.
-            # In-proc callers with no request context are the trusted
-            # local path (same trust level as writing the store directly).
-            from ..api.certificates import CertificateSigningRequestStatus
-            from .requestcontext import current_user
+        with _verb_span("create", resource, meta.namespace, meta.name) as sp:
+            if resource == "certificatesigningrequests":
+                # stamp the requester identity server-side (certificates
+                # types.go:89-99: Username/Groups are set by the apiserver
+                # from the authenticated request, never trusted from the
+                # body) — otherwise any CSR-creating identity could assert a
+                # bootstrap identity and mint auto-approved node credentials.
+                # In-proc callers with no request context are the trusted
+                # local path (same trust level as writing the store directly).
+                from ..api.certificates import CertificateSigningRequestStatus
+                from .requestcontext import current_user
 
-            user = current_user()
-            if user is not None:
-                obj.spec.username = user.name
-                obj.spec.groups = list(user.groups or ())
-            # a CREATE never carries status: a caller-supplied Approved
-            # condition would let the signer mint credentials without
-            # any approver having acted (create.go drops status for
-            # every resource with a status subresource)
-            obj.status = CertificateSigningRequestStatus()
-        # non-atomic admission runs OUTSIDE the lock — webhook plugins do
-        # blocking HTTP here and may re-enter the server; only hooks
-        # flagged `atomic` (quota: usage check must not race the write
-        # past the hard limit) run under the lock with the store write
-        for admit in self._mutating:
-            admit(resource, "CREATE", obj)
-        for admit in self._validating:
-            if not getattr(admit, "atomic", False):
+                user = current_user()
+                if user is not None:
+                    obj.spec.username = user.name
+                    obj.spec.groups = list(user.groups or ())
+                # a CREATE never carries status: a caller-supplied Approved
+                # condition would let the signer mint credentials without
+                # any approver having acted (create.go drops status for
+                # every resource with a status subresource)
+                obj.status = CertificateSigningRequestStatus()
+            # non-atomic admission runs OUTSIDE the lock — webhook plugins do
+            # blocking HTTP here and may re-enter the server; only hooks
+            # flagged `atomic` (quota: usage check must not race the write
+            # past the hard limit) run under the lock with the store write
+            for admit in self._mutating:
                 admit(resource, "CREATE", obj)
-        with self._lock:
             for admit in self._validating:
-                if getattr(admit, "atomic", False):
+                if not getattr(admit, "atomic", False):
                     admit(resource, "CREATE", obj)
-            meta.uid = meta.uid or str(uuid.uuid4())
-            meta.creation_timestamp = meta.creation_timestamp or time.time()
-            if resource == "namespaces" and "kubernetes" not in (meta.finalizers or []):
-                # stamped server-side at create (pkg/registry/core/namespace/
-                # strategy.go PrepareForCreate) so a delete racing the
-                # namespace controller can never skip the content drain
-                meta.finalizers = (meta.finalizers or []) + ["kubernetes"]
-            key = self._key(info, meta.namespace, meta.name)
-            body = serde.to_dict(obj)
-            try:
-                rev = self.store.create(key, body)
-            except kv.KeyExists:
-                raise AlreadyExists(key)
-        created = self._stamp(info, body, rev)
-        for hook in self._post_write:
-            hook(resource, "CREATE", created)
-        return created
+            sp.step("admission")
+            with self._lock:
+                sp.step("lock")
+                for admit in self._validating:
+                    if getattr(admit, "atomic", False):
+                        admit(resource, "CREATE", obj)
+                meta.uid = meta.uid or str(uuid.uuid4())
+                meta.creation_timestamp = meta.creation_timestamp or time.time()
+                if resource == "namespaces" and "kubernetes" not in (meta.finalizers or []):
+                    # stamped server-side at create (pkg/registry/core/namespace/
+                    # strategy.go PrepareForCreate) so a delete racing the
+                    # namespace controller can never skip the content drain
+                    meta.finalizers = (meta.finalizers or []) + ["kubernetes"]
+                key = self._key(info, meta.namespace, meta.name)
+                sp.step("stamp")  # uuid4 reads the kernel's random pool
+                body = serde.to_dict(obj)
+                sp.step("encode")
+                try:
+                    rev = self.store.create(key, body)
+                except kv.KeyExists:
+                    raise AlreadyExists(key)
+                sp.step("store")  # the watch emit included
+            created = self._stamp(info, body, rev)
+            sp.step("decode")
+            for hook in self._post_write:
+                hook(resource, "CREATE", created)
+            sp.step("hooks")
+            return created
 
     def get(self, resource: str, name: str, namespace: str = "") -> Any:
         info = self._info(resource)
@@ -374,35 +391,41 @@ class APIServer:
         meta = obj.metadata
         key = self._key(info, meta.namespace, meta.name)
         op = "UPDATE"
-        if resource == "certificatesigningrequests":
-            # CSR spec is immutable after create for authenticated
-            # callers (the reference's strategy.PrepareForUpdate copies
-            # the old spec): rewriting spec.username post-create would
-            # defeat the requester stamping above
-            from .requestcontext import current_user
+        with _verb_span("update", resource, meta.namespace, meta.name) as sp:
+            if resource == "certificatesigningrequests":
+                # CSR spec is immutable after create for authenticated
+                # callers (the reference's strategy.PrepareForUpdate copies
+                # the old spec): rewriting spec.username post-create would
+                # defeat the requester stamping above
+                from .requestcontext import current_user
 
-            if current_user() is not None:
-                try:
-                    old = self.get(resource, meta.name, meta.namespace)
-                    obj.spec = old.spec
-                except NotFound:
-                    pass
-        for admit in self._mutating:
-            admit(resource, op, obj)
-        for admit in self._validating:
-            admit(resource, op, obj)
-        expected = int(meta.resource_version) if meta.resource_version else None
-        body = serde.to_dict(obj)
-        try:
-            rev = self.store.update(key, body, expected_mod_revision=expected)
-        except kv.KeyNotFound as e:
-            raise NotFound(str(e))
-        except kv.Conflict as e:
-            raise Conflict(str(e))
-        updated = self._stamp(info, body, rev)
-        for hook in self._post_write:
-            hook(resource, op, updated)
-        return updated
+                if current_user() is not None:
+                    try:
+                        old = self.get(resource, meta.name, meta.namespace)
+                        obj.spec = old.spec
+                    except NotFound:
+                        pass
+            for admit in self._mutating:
+                admit(resource, op, obj)
+            for admit in self._validating:
+                admit(resource, op, obj)
+            expected = int(meta.resource_version) if meta.resource_version else None
+            sp.step("admission")
+            body = serde.to_dict(obj)
+            sp.step("encode")
+            try:
+                rev = self.store.update(key, body, expected_mod_revision=expected)
+            except kv.KeyNotFound as e:
+                raise NotFound(str(e))
+            except kv.Conflict as e:
+                raise Conflict(str(e))
+            sp.step("store")
+            updated = self._stamp(info, body, rev)
+            sp.step("decode")
+            for hook in self._post_write:
+                hook(resource, op, updated)
+            sp.step("hooks")
+            return updated
 
     def delete(self, resource: str, name: str, namespace: str = "",
                propagation_policy: Optional[str] = None, fence=None) -> None:
@@ -416,6 +439,12 @@ class APIServer:
         propagation_policy: None/"Background" (default), "Foreground"
         (block on dependents: the GC deletes blocking dependents first),
         or "Orphan" (the GC strips ownerReferences from dependents)."""
+        with _verb_span("delete", resource, namespace, name):
+            self._delete(resource, name, namespace, propagation_policy,
+                         fence)
+
+    def _delete(self, resource: str, name: str, namespace: str,
+                propagation_policy: Optional[str], fence) -> None:
         info = self._info(resource)
         key = self._key(info, namespace, name)
         fence_check = self._fence_precondition(fence, "delete")

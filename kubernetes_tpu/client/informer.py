@@ -17,6 +17,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from ..api import types as v1
 from ..store import kv
+from ..utils import tracing
 from .clientset import _ResourceClient
 
 
@@ -47,6 +48,7 @@ class Informer:
 
     def __init__(self, client: _ResourceClient, namespace: Optional[str] = None):
         self._client = client
+        self._resource = getattr(client, "_resource", "objects")
         self._namespace = namespace
         self._lock = threading.RLock()
         self._cache: Dict[str, Any] = {}
@@ -105,7 +107,9 @@ class Informer:
     def start(self) -> None:
         if self._thread is not None:
             return
-        self._thread = threading.Thread(target=self._run, daemon=True)
+        self._thread = threading.Thread(
+            target=self._run, daemon=True,
+            name=f"informer-{self._resource}")
         self._thread.start()
 
     def stop(self) -> None:
@@ -174,7 +178,17 @@ class Informer:
                     return
                 continue
             key = meta_namespace_key(ev.object)
-            with self._lock:
+            # one span per delivered ADDED event, from poll returning to
+            # the last handler returning (the time poll sat empty is no
+            # span): its end is the instant a new pod is in the queue.
+            # Updates and deletes are not spanned: the confirm of every
+            # bind would double what a pod costs the recorder
+            sp = tracing.NOOP_SPAN
+            if ev.type == kv.ADDED and tracing.enabled():
+                sp = tracing.span(f"ADDED {self._resource}", "informer",
+                                  key=key)
+            with sp, self._lock:
+                sp.step("lock")
                 handlers = list(self._handlers)
                 if ev.type == kv.DELETED:
                     prev = self._cache.pop(key, None)
@@ -190,6 +204,7 @@ class Informer:
                                 h.on_add(ev.object)
                         elif h.on_update:
                             h.on_update(prev, ev.object)
+                sp.step("handlers")
 
 
 class SharedInformerFactory:

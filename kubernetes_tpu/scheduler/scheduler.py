@@ -917,9 +917,10 @@ class Scheduler:
             self._check_kill("scheduler")
             try:
                 if self._paused.is_set():
-                    if self.backend == "tpu":
-                        self._drain_pipeline()
-                    time.sleep(0.02)
+                    with tracing.span("paused", "paused"):
+                        if self.backend == "tpu":
+                            self._drain_pipeline()
+                        time.sleep(0.02)
                     continue
                 self.schedule_one(timeout=0.2)
                 now = time.monotonic()
@@ -940,10 +941,12 @@ class Scheduler:
         """One scheduling cycle; returns False on queue timeout. In TPU
         mode, drains up to max_batch pods and schedules them in batched
         dispatches with sequential assume semantics."""
-        info = self.queue.pop(timeout=timeout)
-        if info is None:
-            if self.backend == "tpu":
+        with tracing.span("queue-empty", "queue-empty") as qsp:
+            info = self.queue.pop(timeout=timeout)
+            qsp.set(got=info is not None)
+            if info is None and self.backend == "tpu":
                 self._drain_pipeline()  # idle: land the tail batches
+        if info is None:
             return False
         if self._paused.is_set():
             # pause() landed while this thread was already blocked in
@@ -953,32 +956,43 @@ class Scheduler:
             self.queue.add(info.pod)
             return False
         info.pop_timestamp = _time.monotonic()
-        with self._inflight_lock:
-            self._inflight += 1
-        t0 = _time.perf_counter()
-        n_scheduled = 1
-        try:
-            if self.backend == "tpu":
-                infos = [info]
-                with tracing.span("pop", "pop") as sp:
-                    while len(infos) < self.max_batch:
-                        nxt = self.queue.pop(timeout=0)
-                        if nxt is None:
-                            break
-                        nxt.pop_timestamp = info.pop_timestamp
-                        infos.append(nxt)
-                    sp.set(n=len(infos))
-                n_scheduled = len(infos)
-                metrics.batch_size.observe(n_scheduled)
-                self._schedule_batch_tpu(infos)
-            else:
-                self._schedule_one_oracle(info)
-        finally:
-            dt = _time.perf_counter() - t0
-            for _ in range(n_scheduled):
-                metrics.scheduling_algorithm_duration.observe(dt / n_scheduled)
+        # the umbrella of one cycle on this thread: what no inner span
+        # names (the wait for the backend's lock, the hand-over to the
+        # completion FIFO, the metrics) is still time inside `cycle`
+        with tracing.span("cycle", "cycle") as csp:
             with self._inflight_lock:
-                self._inflight -= 1
+                self._inflight += 1
+            t0 = _time.perf_counter()
+            n_scheduled = 1
+            try:
+                if self.backend == "tpu":
+                    infos = [info]
+                    with tracing.span("pop", "pop") as sp:
+                        while len(infos) < self.max_batch:
+                            nxt = self.queue.pop(timeout=0)
+                            if nxt is None:
+                                break
+                            nxt.pop_timestamp = info.pop_timestamp
+                            infos.append(nxt)
+                        # the batch id: the scheduling cycle read once
+                        # after the gather (every pop counts it, so no
+                        # two batches share one); it rides every span
+                        # of this batch
+                        cycle = self.queue.scheduling_cycle
+                        sp.set(n=len(infos), batch=cycle)
+                    csp.set(n=len(infos), batch=cycle)
+                    n_scheduled = len(infos)
+                    metrics.batch_size.observe(n_scheduled)
+                    self._schedule_batch_tpu(infos, cycle)
+                else:
+                    self._schedule_one_oracle(info)
+            finally:
+                dt = _time.perf_counter() - t0
+                for _ in range(n_scheduled):
+                    metrics.scheduling_algorithm_duration.observe(
+                        dt / n_scheduled)
+                with self._inflight_lock:
+                    self._inflight -= 1
         return True
 
     def _skip(self, pod: v1.Pod) -> bool:
@@ -1010,18 +1024,30 @@ class Scheduler:
             return False
         return self.tpu is None or not self.tpu.volume_kernel_safe(pod)
 
-    def _schedule_batch_tpu(self, infos: List) -> None:
-        cycle = self.queue.scheduling_cycle
+    def _schedule_batch_tpu(self, infos: List,
+                            cycle: Optional[int] = None) -> None:
+        if cycle is None:
+            cycle = self.queue.scheduling_cycle
+        with tracing.span("prep", "prep", n=len(infos), batch=cycle):
+            todo = self._kernel_pods(infos)
+        if not todo:
+            return
+        self._dispatch_batch(todo, cycle)
+
+    def _kernel_pods(self, infos: List) -> List:
+        """The pods of a popped batch that ride the kernel: deleted and
+        assumed pods dropped, oracle-only and nominated pods scheduled
+        here and now. [] when nothing is left to dispatch."""
         todo = [i for i in infos if not self._skip(i.pod)]
         if todo and self.tpu.ladder.rung() <= RUNG_ORACLE:
             # degradation ladder fully demoted: no device dispatch at
             # all — every pod rides the oracle until the background
             # probe re-promotes the backend (degradation.py)
             if not self._drain_or_requeue(todo):
-                return
+                return []
             for info in todo:
                 self._schedule_one_oracle(info)
-            return
+            return []
         if self.framework is not None:
             # one partition pass: _needs_oracle runs a resolver pass for
             # PVC pods, and pending pods SHARING a claim within this
@@ -1046,7 +1072,7 @@ class Scheduler:
                 # the oracle schedules against the cache snapshot: every
                 # pipelined batch's assumes must land first
                 if not self._drain_or_requeue(oracle_infos + todo):
-                    return
+                    return []
                 for info in oracle_infos:
                     self._schedule_one_oracle(info)
             # nominated-node short-circuit (generic_scheduler.go:235
@@ -1063,12 +1089,13 @@ class Scheduler:
                 # feasibility runs on the cache snapshot — same drain
                 # requirement as the oracle path
                 if not self._drain_or_requeue(todo):
-                    return
+                    return []
                 placed = self._place_nominated(nominated)
                 if placed:
                     todo = [i for i in todo if id(i) not in placed]
-        if not todo:
-            return
+        return todo
+
+    def _dispatch_batch(self, todo: List, cycle: int) -> None:
         # pipelined dispatch: enqueue this batch's scan (async on the
         # live session — it chains on the previous batch's carry), hand
         # the completion (harvest -> assume -> bind -> failures) to the
@@ -1083,7 +1110,8 @@ class Scheduler:
         basis_gen = (self.cache.foreign_mutations(),
                      self._dropped_decisions)
         try:
-            handle = self.tpu.dispatch_many([i.pod for i in todo])
+            handle = self.tpu.dispatch_many([i.pod for i in todo],
+                                            batch=cycle)
         except Exception:  # noqa: BLE001 — the backend recovers its own
             # faults internally; an escape here is defensive: the pods
             # were never handed to the pipeline, so requeue exactly once
@@ -1112,11 +1140,14 @@ class Scheduler:
             # backpressure: the assume/bind lag stays bounded by the
             # pipeline depth (an unbounded queue would let the cache
             # trail arbitrarily far behind the device carry)
-            while (
-                len(self._completions) > self.pipeline_depth
-                and not self._stop.is_set()
-            ):
-                self._completion_cv.wait(0.2)
+            if len(self._completions) > self.pipeline_depth:
+                with tracing.span("backpressure", "backpressure",
+                                  batch=cycle):
+                    while (
+                        len(self._completions) > self.pipeline_depth
+                        and not self._stop.is_set()
+                    ):
+                        self._completion_cv.wait(0.2)
 
     def _completion_loop(self) -> None:
         """The async bind queue: completes dispatched batches strictly in
@@ -1127,16 +1158,26 @@ class Scheduler:
         unassigned — the reference's assume -> async bind ->
         confirm/forget contract (scheduler.go:359,:540)."""
         while True:
-            with self._completion_cv:
-                while not self._completions and not self._stop.is_set():
+            # one span per turn: to the item in hand, or one empty poll.
+            # The wait for the FIFO's lock (the scheduler thread holds
+            # it while it hands a batch over) is part of the wait
+            with tracing.span("worker-idle", "worker-idle"), \
+                    self._completion_cv:
+                if not self._completions and not self._stop.is_set():
                     self._completion_cv.wait(0.2)
                 if not self._completions:
-                    return  # stopped and fully drained
+                    if self._stop.is_set():
+                        return  # stopped and fully drained
+                    continue
                 item = self._completions[0]
             # kill seam OUTSIDE the per-batch isolation: the worker dies
             # at a batch boundary (nothing harvested, nothing assumed)
             # and the supervision wrapper recovers + restarts it
             self._check_kill("completion")
+            # the umbrella of one batch on this thread (see `cycle`)
+            sp = tracing.span("complete", "complete", n=len(item[0]),
+                              batch=item[2])
+            sp.__enter__()
             try:
                 self._complete_batch(*item)
             except Exception:  # the worker must outlive batch bugs:
@@ -1151,6 +1192,7 @@ class Scheduler:
                     if self._completions and self._completions[0] is item:
                         self._completions.popleft()
                     self._completion_cv.notify_all()
+                sp.__exit__(None, None, None)
 
     def _recover_completions(self) -> None:
         """Completion-worker crash recovery: restore the invariant
@@ -1347,7 +1389,7 @@ class Scheduler:
             else:
                 bound.append((info, node))
         if bound:
-            self._assume_and_bind_batch(bound)
+            self._assume_and_bind_batch(bound, cycle)
         if failed:
             self._handle_failure_wave(failed, cycle)
 
@@ -1978,7 +2020,8 @@ class Scheduler:
             self._assume_and_bind_batch(bound)
         return placed
 
-    def _assume_and_bind_batch(self, bound: List[Tuple]) -> None:
+    def _assume_and_bind_batch(self, bound: List[Tuple],
+                               batch: Optional[int] = None) -> None:
         """Batched assume + binding-cycle kickoff. Per-pod semantics match
         _assume_and_bind exactly; the batching removes the host costs the
         full-loop profile blamed: per-pod serde deep copies, cache-lock
@@ -1997,7 +2040,8 @@ class Scheduler:
             assumed.spec = copy.copy(info.pod.spec)
             assumed.spec.node_name = node
             assumed_list.append(assumed)
-        with tracing.span("assume", "assume", n=len(assumed_list)):
+        with tracing.span("assume", "assume", n=len(assumed_list),
+                          batch=batch):
             ok = self.cache.assume_pods(assumed_list)
         batch_items: List[Tuple] = []  # (assumed, node, state, info)
         # one check per harvest, not per pod: with no Reserve and no
@@ -2009,7 +2053,7 @@ class Scheduler:
         plugins_engaged = fwk is not None and (
             fwk.reserve_plugins or fwk.permit_plugins)
         with tracing.span("reserve-permit", "reserve-permit",
-                          n=len(assumed_list)):
+                          n=len(assumed_list), batch=batch):
             for (info, node), assumed, assumed_ok in zip(
                     bound, assumed_list, ok):
                 if not assumed_ok:
@@ -2026,12 +2070,14 @@ class Scheduler:
             with self._inflight_lock:
                 self._inflight += 1
             try:
-                self._binders.submit(self._bind_batch, batch_items)
+                self._binders.submit(
+                    self._bind_batch, batch_items, batch,
+                    _time.perf_counter() if tracing.enabled() else None)
             except RuntimeError:
                 # pool shut down (stop() raced a lagging completion):
                 # bind inline — we're already off the scheduler thread,
                 # and stranding the batch assumed-in-cache is worse
-                self._bind_batch(batch_items)
+                self._bind_batch(batch_items, batch)
 
     def _reserve_and_permit(
         self, state: CycleState, assumed: v1.Pod, node_name: str, info
@@ -2190,7 +2236,8 @@ class Scheduler:
                     except Exception:  # noqa: BLE001
                         traceback.print_exc()
 
-    def _bind_batch(self, items: List[Tuple]) -> None:
+    def _bind_batch(self, items: List[Tuple], batch: Optional[int] = None,
+                    t_submit: Optional[float] = None) -> None:
         """Binding cycle for a whole batch in one worker: PreBind per pod,
         bulk bind application, single-lock finish_binding, batched metrics,
         async events. `unsettled` tracks pods whose outcome is not yet
@@ -2199,10 +2246,17 @@ class Scheduler:
         (cleanup_expired_assumed_pods only expires pods whose binding
         FINISHED — an assumed pod that never reaches finish_binding has
         no expiry)."""
+        if t_submit is not None:
+            # the wait for a binder thread: it starts on the completion
+            # worker and ends here, so it carries no cpu_s
+            tracing.RECORDER.record(
+                "binder-queue", "binder-queue", t_submit,
+                _time.perf_counter() - t_submit, {"batch": batch})
         unsettled = {id(assumed): assumed for assumed, _, _, _ in items}
         bind_t0 = _time.monotonic()
-        bind_sp = tracing.span("bind", "bind", n=len(items))
+        bind_sp = tracing.span("bind", "bind", n=len(items), batch=batch)
         bind_sp.__enter__()
+        done: List[Tuple] = []
         try:
             fwk = self.framework
             ready: List[Tuple] = []
@@ -2222,8 +2276,8 @@ class Scheduler:
                  for a, node, _, _ in ready],
                 fence=self._fence,
             )
+            bind_sp.step("posted")
             now = _time.monotonic()
-            done: List[Tuple] = []
             for (assumed, node, state, info), err in zip(ready, outcomes):
                 unsettled.pop(id(assumed), None)
                 if isinstance(err, FenceExpired):
@@ -2272,6 +2326,11 @@ class Scheduler:
                     traceback.print_exc()
         finally:
             bind_sp.__exit__(None, None, None)
+            if done and tracing.enabled():
+                # joins the pipeline half of a pod's path (spans keyed
+                # by `batch`) to the control-plane half (keyed by `key`)
+                tracing.event("pod-path", "path", batch=batch,
+                              keys=[v1.pod_key(a) for a, _, _, _ in done])
             metrics.attempt_duration.observe(
                 _time.monotonic() - bind_t0, stage="bind")
             with self._inflight_lock:

@@ -92,10 +92,14 @@ class _BatchHandle:
 
     __slots__ = ("group", "ys", "decide", "node_names", "results",
                  "deadline", "bucket", "timed_out", "speculative",
-                 "conflicts", "prov", "explain", "basis_mutations", "dt")
+                 "conflicts", "prov", "explain", "basis_mutations", "dt",
+                 "batch")
 
-    def __init__(self, group: List[v1.Pod]):
+    def __init__(self, group: List[v1.Pod], batch: Optional[int] = None):
         self.group = group
+        # the scheduler loop's batch id (its scheduling cycle): the
+        # backend's spans carry the same number as the loop's
+        self.batch = batch
         self.ys = None
         self.decide = None
         # speculative dispatch: this scan was enqueued while EARLIER
@@ -1276,7 +1280,8 @@ class TPUBackend(CacheListener):
         })
         return True
 
-    def _apply_session_deltas_locked(self) -> None:
+    def _apply_session_deltas_locked(self,
+                                     batch: Optional[int] = None) -> None:
         """Flush the queued deltas into the live session in one fused
         launch — called right before a dispatch rides the session, so
         patches chain onto any in-flight scans as pure data
@@ -1293,7 +1298,7 @@ class TPUBackend(CacheListener):
 
         try:
             with tracing.span("queued-delta-apply", "delta-apply",
-                              n=len(deltas)):
+                              n=len(deltas), batch=batch):
                 if devtime.enabled():
                     # measured delta apply: the fused patch launch gets
                     # its own submit->ready interval via an explicit
@@ -1503,7 +1508,8 @@ class TPUBackend(CacheListener):
     # carry (utilization + PTS pair counts), so the prologue stays valid
     # and no host pod-table sync is needed between pipelined batches.
 
-    def dispatch_many(self, pods: List[v1.Pod]) -> "_BatchHandle":
+    def dispatch_many(self, pods: List[v1.Pod],
+                      batch: Optional[int] = None) -> "_BatchHandle":
         """Dispatch a batch; returns a handle for harvest(). Up to
         `max_pending` batches may be outstanding (the device double
         buffer) — a dispatch beyond that harvests the OLDEST first.
@@ -1511,7 +1517,7 @@ class TPUBackend(CacheListener):
         can't ride the live session (bound pods, mixed shapes, unknown
         templates or no session yet — the session builds on the
         synchronous path and subsequent batches pipeline)."""
-        h = _BatchHandle(list(pods))
+        h = _BatchHandle(list(pods), batch)
         with self._lock:
             while len(self._pending) >= max(1, self.max_pending):
                 if self.async_harvest_drain:
@@ -1534,7 +1540,8 @@ class TPUBackend(CacheListener):
                 not p.spec.node_name for p in pods
             ):
                 try:
-                    with tracing.span("encode", "encode", n=len(pods)):
+                    with tracing.span("encode", "encode", n=len(pods),
+                                      batch=batch):
                         clean = [
                             {k: v for k, v in self.pe.encode(p).items()
                              if not k.startswith("_")}
@@ -1557,7 +1564,7 @@ class TPUBackend(CacheListener):
                         # queued cluster-event deltas land first (one
                         # fused launch chained on the carry) so this
                         # scan evaluates the reconciled state
-                        self._apply_session_deltas_locked()
+                        self._apply_session_deltas_locked(batch)
                         if self._session is None:
                             # delta apply failed: structural fallback
                             h.results = self.schedule_many(pods)
@@ -1573,6 +1580,7 @@ class TPUBackend(CacheListener):
                             speculative=bool(self._pending),
                             pipelined=True,
                             group_pos=len(self._pending),
+                            batch=batch,
                         ) if tracing.enabled() else tracing.NOOP_SPAN
                         with sp, devtime.TIMELINE.maybe_profile(
                                 "dispatch"):
@@ -1630,7 +1638,8 @@ class TPUBackend(CacheListener):
             # handle timed out and the locked harvest runs recovery.
             with tracing.span("wait", "wait", n=len(handle.group),
                               bucket=handle.bucket,
-                              speculative=handle.speculative) as sp:
+                              speculative=handle.speculative,
+                              batch=handle.batch) as sp:
                 if not self._wait_ready(ys, self.watchdog_timeout):
                     handle.timed_out = True
                     sp.set(timed_out=True)
@@ -1739,7 +1748,8 @@ class TPUBackend(CacheListener):
         h = self._pending.popleft()
         self._pending_cv.notify_all()  # back-pressured dispatchers
         hsp = tracing.span("harvest", "harvest", n=len(h.group),
-                           bucket=h.bucket, speculative=h.speculative)
+                           bucket=h.bucket, speculative=h.speculative,
+                           batch=h.batch)
         try:
             with hsp:
                 if h.timed_out or not self._wait_ready(

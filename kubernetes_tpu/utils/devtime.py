@@ -51,7 +51,7 @@ import threading
 import time
 from typing import Dict, List, Optional, Tuple
 
-from . import knobs
+from . import knobs, tracing
 
 logger = logging.getLogger(__name__)
 
@@ -73,9 +73,13 @@ KINDS = (
 
 # host stages EXCLUDED from host_busy in overlap(): "wait" is the host
 # parked on the device (counting it as host work would make overlap
-# tautologically ~1.0), and the zero-duration marker stages carry no
-# wall-clock to overlap
-OVERLAP_EXCLUDE_STAGES = ("wait", "provenance", "fault")
+# tautologically ~1.0), the zero-duration marker stages carry no
+# wall-clock to overlap, and the named waits and umbrellas of the
+# scheduler's threads are no work of their own (an umbrella holds
+# "wait" itself). The control plane's spans stay out as well: host_busy
+# is the pipeline's stages, as it was before those spans existed
+OVERLAP_EXCLUDE_STAGES = ("wait", "provenance", "fault") \
+    + tracing.NOT_PIPELINE_WORK
 
 
 class _NoopLaunch:

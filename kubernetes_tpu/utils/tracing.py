@@ -1,13 +1,19 @@
 """Structured pipeline tracing: span recorder + bounded flight recorder.
 
-Extends the threshold tracer in utils/trace.py (which answers "was this
-ONE cycle slow?") with the causal record the pipeline needs: WHERE a
-pod's time went across the three-stage scheduling pipeline
-(pop -> encode -> queued-delta apply -> dispatch -> wait -> harvest ->
-validate -> assume -> reserve/permit -> bind), plus the failure seams'
-last-N-events dump. The loop-vs-kernel gap (~1600-2000 loop pods/s vs
-9353 kernel-direct) is argued from totals today; the per-stage span
-record turns it into a stage breakdown the chip rerun can adjudicate.
+The one span recorder of the program: WHERE a pod's time went, from
+`pods.create` in the API server through the informer and the queue
+(spans keyed by `key`, namespace/name) across the three-stage scheduling
+pipeline (pop -> encode -> queued-delta apply -> dispatch -> wait ->
+harvest -> validate -> assume -> reserve/permit -> bind; spans keyed by
+`batch`, the scheduling cycle read after the gather) to the bind, with
+one `pod-path` event per bound batch joining the two halves; the named
+waits of the scheduler's threads; plus the failure seams' last-N-events
+dump. A span records wall time and, one span in sixteen, the thread's
+CPU time (`cpu_s`): wall - cpu_s is what the thread stood waiting
+(interpreter lock, a lock, a condition). `Span.step(name)` splits one
+span into parts without a ring event each, and
+`Span.log_if_long(threshold)` is the reference's utiltrace threshold
+log (k8s.io/utils/trace).
 
 Levels (KTPU_TRACE):
 
@@ -17,8 +23,10 @@ Levels (KTPU_TRACE):
      allocates nothing per pod (span() returns a shared no-op
      singleton; tests pin this).
   1  per-stage spans — every pipeline stage records (name, stage, t0,
-     dur, tid, attrs) into the ring. Batch granularity: a few spans per
-     dispatched batch, bounded memory, safe to leave on in production.
+     dur, tid, attrs) into the ring. At most 20 events per dispatched
+     batch and 4 per pod (today 18 and 3: the pod's create, the
+     informer's ADDED, the Scheduled event's create), empty polls of
+     an idle thread aside; bounded memory.
   2  per-pod provenance — additionally, every decided pod records a
      provenance event: backend rung, session kind, last build/rebuild
      reason, pallas bucket, speculative chaining, replay/re-drive
@@ -48,6 +56,7 @@ import itertools
 import json
 import logging
 import os
+import sys
 import threading
 import time
 from typing import Dict, List, Optional, Tuple
@@ -73,6 +82,29 @@ STAGES = (
     "assume",       # cache.assume (completion worker)
     "reserve-permit",  # Reserve + Permit plugin pass
     "bind",         # batched bind POST
+    # the control plane, keyed by `key` (namespace/name)
+    "apiserver",    # one span per create/update/delete: "<verb>
+                    # <resource>", steps admission / lock / stamp /
+                    # encode / store / decode / hooks
+    "informer",     # one span per delivered ADDED event: "ADDED
+                    # <resource>", poll returning -> last handler
+                    # returning (queue.add is inside, step `handlers`)
+    # named waits and fill-ins of the scheduler's threads
+    "queue-empty",  # scheduler thread: the first, blocking queue.pop
+    "paused",       # scheduler thread held at the pause gate
+    "cycle",        # scheduler thread, umbrella of one batch: pop ->
+                    # handed to the pipeline; pop, prep, encode,
+                    # delta-apply, dispatch, backpressure nest inside,
+                    # and what none of them names is its own time
+    "complete",     # completion worker, umbrella of one batch: wait,
+                    # harvest, assume, reserve-permit nest inside
+    "prep",         # _schedule_batch_tpu entry -> dispatch_many call
+    "backpressure",  # scheduler thread waiting on a full completion FIFO
+    "worker-idle",  # completion worker waiting for a batch
+    "binder-queue",  # _binders.submit -> first line of _bind_batch
+                    # (starts on one thread, ends on another: no cpu_s)
+    "path",         # zero-duration `pod-path` event per bound batch:
+                    # batch + the bound pods' keys (joins the halves)
     "planner",      # preemption planner ladder: the per-WAVE plan span
     "whatif",       # per-pod fused what-if launches (nested inside a
                     # planner span — a separate stage so stage_stats
@@ -80,6 +112,16 @@ STAGES = (
     "session",      # session builds / teardowns
     "fault",        # fault + recovery markers (zero-duration events)
     "provenance",   # per-pod provenance records (level 2)
+)
+
+
+# stages that are not work of the pipeline's own: the named waits, the
+# umbrellas (their inner spans are counted themselves), the join marker,
+# and the control plane's threads. A reader that sums the pipeline's
+# host-busy time (devtime.overlap) leaves them out
+NOT_PIPELINE_WORK = (
+    "queue-empty", "paused", "backpressure", "worker-idle", "binder-queue",
+    "cycle", "complete", "path", "apiserver", "informer",
 )
 
 
@@ -99,12 +141,37 @@ class _NoopSpan:
     def set(self, **attrs) -> "_NoopSpan":
         return self
 
+    def step(self, name: str) -> "_NoopSpan":
+        return self
+
+    def log_if_long(self, threshold: float, out=None) -> bool:
+        return False
+
 
 NOOP_SPAN = _NoopSpan()
 
 
+# one span in CPU_EVERY also reads the thread's CPU clock. The read is a
+# system call, twice a span: 0.3 us on a workstation, 5.8 us on the
+# benchmark's host, where it was 11.5 of a span's 13.9 us and lifted the
+# arrivals' median bind from 14 to 25 ms (PERF.md, PR 25). That host's
+# thread CPU clock also ticks in steps of 10 ms, so one span's reading
+# says little and only sums over many spans of a stage mean something:
+# a sum over every sixteenth span says the same at a sixteenth of the
+# price.
+CPU_EVERY = 16
+
+
 class Span:
-    __slots__ = ("_rec", "name", "stage", "attrs", "t0")
+    """One timed span. Besides wall time it records, in its attributes,
+    `thread` (the recording thread's name: readers that keep only name,
+    stage, t0, dur and attrs, as the benchmark does, still tell threads
+    apart) and, for one span in CPU_EVERY, `cpu_s`: the thread's own
+    CPU seconds inside the span, spans nested in it included. Over the
+    spans of a stage that carry it, sum(cpu_s) / sum(dur) is the share
+    of the stage's wall time that was work and not waiting."""
+
+    __slots__ = ("_rec", "name", "stage", "attrs", "t0", "_cpu0", "_last")
 
     def __init__(self, rec: "FlightRecorder", name: str, stage: str,
                  attrs: Optional[dict]):
@@ -112,7 +179,8 @@ class Span:
         self.name = name
         self.stage = stage
         self.attrs = attrs
-        self.t0 = 0.0
+        self.t0 = self._last = 0.0
+        self._cpu0: Optional[float] = None
 
     def set(self, **attrs) -> "Span":
         if self.attrs is None:
@@ -121,15 +189,50 @@ class Span:
             self.attrs.update(attrs)
         return self
 
+    def step(self, name: str) -> "Span":
+        """Close one part of the span: `<name>_s` = seconds since the
+        previous step (or the span's start). One ring event for a call
+        with seven parts, not seven."""
+        now = time.perf_counter()
+        if self.attrs is None:
+            self.attrs = {}
+        self.attrs[name + "_s"] = now - self._last
+        self._last = now
+        return self
+
+    def log_if_long(self, threshold: float, out=None) -> bool:
+        """The reference's utiltrace.LogIfLong: print the span so far
+        with its step breakdown when it has run for `threshold` seconds
+        or more (generic_scheduler.go:96 logs cycles over 100 ms)."""
+        total = time.perf_counter() - self.t0
+        if total < threshold:
+            return False
+        out = out or sys.stderr
+        attrs = self.attrs or {}
+        fields = ",".join(f"{k}={v}" for k, v in attrs.items()
+                          if not k.endswith("_s"))
+        print(f'Trace "{self.name}" ({fields}): total {total * 1000:.1f}ms',
+              file=out)
+        for k, v in attrs.items():
+            if k.endswith("_s"):
+                print(f"  step {v * 1000:.1f}ms: {k[:-2]}", file=out)
+        return True
+
     def __enter__(self) -> "Span":
-        self.t0 = time.perf_counter()
+        if next(self._rec._cpu_turn) % CPU_EVERY == 0:
+            self._cpu0 = time.thread_time()
+        self.t0 = self._last = time.perf_counter()
         return self
 
     def __exit__(self, et, ev, tb) -> bool:
-        self._rec.record(
-            self.name, self.stage, self.t0,
-            time.perf_counter() - self.t0, self.attrs,
-        )
+        dur = time.perf_counter() - self.t0
+        attrs = self.attrs
+        if attrs is None:
+            attrs = self.attrs = {}
+        if self._cpu0 is not None:
+            attrs["cpu_s"] = time.thread_time() - self._cpu0
+        attrs["thread"] = threading.current_thread().name
+        self._rec.record(self.name, self.stage, self.t0, dur, attrs)
         return False
 
 
@@ -155,6 +258,7 @@ class FlightRecorder:
         self.level = max(0, int(level))
         self._buf: List[Optional[Event]] = [None] * self.capacity
         self._seq = itertools.count()
+        self._cpu_turn = itertools.count()  # see CPU_EVERY
         # dump bookkeeping (tests + drills read these; the dump itself
         # is the observable for the fault-seam acceptance contract)
         self._dump_lock = threading.Lock()
@@ -294,8 +398,7 @@ def set_level(n: int) -> int:
     return old
 
 
-def span(name: str, stage: str, **attrs):
-    return RECORDER.span(name, stage, **attrs)
+span = RECORDER.span  # no second call, no second packing of the attrs
 
 
 def event(name: str, stage: str, **attrs) -> None:

@@ -300,12 +300,12 @@ def _drive_with_faults(seed, arm_plan, n=32, watchdog=0.5):
                 orig = type(sched.tpu).dispatch_many
                 count = {"batches": 0}
 
-                def arming(self, pods, _orig=orig, _c=count, _inj=inj):
+                def arming(self, pods, _orig=orig, _c=count, _inj=inj, **kw):
                     kind = arm_plan.get(_c["batches"])
                     if kind is not None:
                         _inj.arm(kind, shots=1)
                     _c["batches"] += 1
-                    return _orig(self, pods)
+                    return _orig(self, pods, **kw)
 
                 sched.tpu.dispatch_many = arming.__get__(sched.tpu)
             pods = _pod_stream(random.Random(seed), n)
@@ -380,11 +380,11 @@ class TestSupervisedWorkers:
                     orig = type(sched.tpu).dispatch_many
                     count = {"batches": 0}
 
-                    def arming(self, pods, _orig=orig, _c=count, _inj=inj):
+                    def arming(self, pods, _orig=orig, _c=count, _inj=inj, **kw):
                         if _c["batches"] == 2:
                             _inj.arm("kill-completion", shots=1)
                         _c["batches"] += 1
-                        return _orig(self, pods)
+                        return _orig(self, pods, **kw)
 
                     sched.tpu.dispatch_many = arming.__get__(sched.tpu)
                 pods = _pod_stream(random.Random(seed), 32)
@@ -764,8 +764,8 @@ class TestFlightRecorderDumpDrills:
         handles = []
         orig = type(sched.tpu).dispatch_many
 
-        def capture(self, pods, _orig=orig):
-            h = _orig(self, pods)
+        def capture(self, pods, _orig=orig, **kw):
+            h = _orig(self, pods, **kw)
             handles.append(h)
             return h
 

@@ -5,8 +5,10 @@ Reference models: component-base/metrics tests, client-go record/
 leaderelection tests (leaderelection_test.go — acquire, renew, lose on
 expiry, second elector takes over); the flight-recorder half covers
 utils/tracing.py (ring wrap-around under concurrent writers, chrome
-export, stage stats), the backend-health k8s Events, the /configz
-KTPU_* knob surface, and the perf harness's per-stage latency fields."""
+export, stage stats, thread CPU and steps inside a span, one pod
+followed from pods.create to its bind), the backend-health k8s Events,
+the /configz KTPU_* knob surface, and the perf harness's per-stage
+latency fields."""
 
 from __future__ import annotations
 
@@ -23,7 +25,6 @@ from kubernetes_tpu.client.events import EventRecorder
 from kubernetes_tpu.client.leaderelection import LeaderElectionConfig, LeaderElector
 from kubernetes_tpu.utils import configz, tracing
 from kubernetes_tpu.utils.metrics import Counter, Gauge, Histogram, Registry
-from kubernetes_tpu.utils.trace import Trace
 
 
 def test_metrics_collect_and_expose():
@@ -92,16 +93,24 @@ def test_leader_election_failover():
 
 
 def test_trace_threshold():
-    tr = Trace("cycle", pod="default/p")
-    tr.step("filter")
-    assert not tr.log_if_long(10.0)
+    """utiltrace's LogIfLong, on the one span recorder: nothing below the
+    threshold, the step breakdown above it."""
     import io
 
+    rec = tracing.FlightRecorder(capacity=16, level=tracing.TRACE_STAGES)
     buf = io.StringIO()
-    time.sleep(0.02)
-    tr.step("score")
-    assert tr.log_if_long(0.01, out=buf)
-    assert "cycle" in buf.getvalue() and "score" in buf.getvalue()
+    with rec.span("cycle", "pop", pod="default/p") as tr:
+        tr.step("filter")
+        assert not tr.log_if_long(10.0, out=buf)
+        time.sleep(0.02)
+        tr.step("score")
+        assert tr.log_if_long(0.01, out=buf)
+    out = buf.getvalue()
+    assert 'Trace "cycle" (pod=default/p)' in out
+    assert "filter" in out and "score" in out and "cpu" not in out
+    # the same call on a disabled trace point costs nothing and logs nothing
+    assert not tracing.NOOP_SPAN.log_if_long(0.0, out=buf)
+    assert buf.getvalue() == out
 
 
 # -- flight recorder (utils/tracing.py) ------------------------------------
@@ -190,11 +199,66 @@ class TestFlightRecorder:
         rec = tracing.FlightRecorder(capacity=16, level=0)
         assert rec.span("a", "dispatch") is tracing.NOOP_SPAN
         assert rec.span("b", "harvest", n=1) is tracing.NOOP_SPAN
+        # every stage a trace point can name, the new ones with the old
+        for stage in tracing.STAGES:
+            assert rec.span(stage, stage, batch=7) is tracing.NOOP_SPAN
+        # and what a site calls on its span is a no-op on the singleton
+        sp = tracing.NOOP_SPAN
+        assert sp.step("store") is sp and sp.set(got=True) is sp
+        assert sp.log_if_long(0.0) is False
         rec.record("a", "dispatch", 0.0, 1.0)
         rec.provenance("default/p", rung="pallas")
         assert rec.snapshot() == []
         assert rec.dump("device-fault-timeout") == []
         assert rec.dump_history == []
+
+    def test_busy_span_records_thread_cpu_below_wall(self):
+        def first_span_of_a_recorder(stage, body):
+            # the first span of a recorder reads the CPU clock
+            rec = tracing.FlightRecorder(capacity=4, level=1)
+            with rec.span("s", stage):
+                body()
+            (ev,) = rec.snapshot()
+            return ev
+
+        def spin():
+            t_end = time.perf_counter() + 0.05
+            while time.perf_counter() < t_end:
+                sum(range(200))
+
+        busy = first_span_of_a_recorder("encode", spin)
+        idle = first_span_of_a_recorder("wait", lambda: time.sleep(0.05))
+        wall, cpu = busy[4], busy[6]["cpu_s"]
+        # the thread CPU clock ticks in steps of 10 ms on some kernels
+        assert 0.0 < cpu <= wall + 0.011
+        # a sleeping thread's wall is waiting, not work
+        assert idle[6]["cpu_s"] < 0.25 * idle[4]
+        assert busy[6]["thread"] == threading.current_thread().name
+        assert len(busy) == 7, "the ring tuple keeps its seven positions"
+
+    def test_one_span_in_sixteen_reads_the_cpu_clock(self, recorder):
+        """The read is a system call, twice a span: every span pays for
+        a sixteenth of it."""
+        for i in range(4 * tracing.CPU_EVERY):
+            with recorder.span(f"s{i}", "assume"):
+                pass
+        with_cpu = [e[1] for e in recorder.snapshot() if "cpu_s" in e[6]]
+        assert with_cpu == ["s0", "s16", "s32", "s48"]
+        assert all("thread" in e[6] for e in recorder.snapshot())
+
+    def test_steps_split_one_span_and_sum_to_it(self, recorder):
+        with recorder.span("create pods", "apiserver", key="default/p") as sp:
+            for part in ("admission", "encode", "store"):
+                time.sleep(0.002)
+                sp.step(part)
+        (ev,) = recorder.snapshot()  # one ring event, not one per part
+        attrs = ev[6]
+        parts = [attrs[f"{p}_s"] for p in ("admission", "encode", "store")]
+        assert all(v >= 0.002 for v in parts)
+        # the steps tile the span up to its last step: what is left is
+        # the exit of the with block
+        assert 0.0 <= ev[4] - sum(parts) < 1e-3
+        assert attrs["key"] == "default/p"
 
     def test_dump_writes_file_and_history(self, recorder, tmp_path):
         with recorder.span("batch", "dispatch", n=2):
@@ -231,27 +295,187 @@ class TestFlightRecorder:
         assert mix["planner"] == {"device": 1}
 
     def test_threshold_trace_mirrors_into_recorder(self, traced):
-        tr = Trace("cycle", pod="default/p")
-        tr.step("filter")
-        tr.step("score")
-        tr.record_spans()
-        names = [e[1] for e in traced.snapshot()]
-        assert "cycle/filter" in names and "cycle/score" in names
+        """What utils/trace.py's record_spans did with one ring event per
+        step, a span's steps do with one event in all."""
+        with tracing.span("cycle", "pop", pod="default/p") as tr:
+            tr.step("filter")
+            tr.step("score")
+        (ev,) = [e for e in traced.snapshot() if e[1] == "cycle"]
+        assert {"filter_s", "score_s", "pod"} <= set(ev[6])
+        assert "filter_s" in tracing.event_dict(ev)  # and into the export
+
+
+# -- one pod followed from pods.create to its bind ---------------------------
+
+N_ROUND_PODS = 48
+
+
+@pytest.fixture(scope="module")
+def traced_round():
+    """One create -> informer -> queue -> batch -> bind round of
+    N_ROUND_PODS pods on the CPU backend at level 1; returns (events,
+    keys of the pods, what a level-0 round left behind)."""
+    from tests.util import make_pod, wait_until
+
+    cs, factory, sched = _mini_scheduler(nodes=8, max_batch=8)
+    sched.start()
+
+    def round_of(prefix):
+        keys = []
+        for i in range(N_ROUND_PODS):
+            cs.pods.create(make_pod(f"{prefix}-{i:03d}", namespace="default",
+                                    cpu="10m"))
+            keys.append(f"default/{prefix}-{i:03d}")
+            if i % 6 == 5:
+                time.sleep(0.01)  # several small batches, some idle time
+        assert wait_until(lambda: all(
+            p.spec.node_name for p in cs.pods.list(namespace="default")[0]),
+            timeout=120)
+        assert sched.recorder.flush(timeout=10)
+        return keys
+
+    class CountedSpan(tracing.Span):
+        made = 0
+
+        def __init__(self, *a):
+            CountedSpan.made += 1
+            super().__init__(*a)
+
+    old = tracing.set_level(0)
+    real_span, tracing.Span = tracing.Span, CountedSpan
+    mark = on = 0
+    try:
+        round_of("warm")  # compiles; not traced
+        # the round with tracing off: no trace point may build a span,
+        # and the ring has to stay empty
+        tracing.RECORDER.clear()
+        CountedSpan.made = 0
+        round_of("off")
+        off = {"ring": len(tracing.RECORDER.snapshot()),
+               "spans_built": CountedSpan.made}
+        tracing.set_level(tracing.TRACE_STAGES)
+        mark = tracing.RECORDER.mark()
+        keys = round_of("on")
+        on = CountedSpan.made
+    finally:
+        sched.shutdown()  # closes the idle spans that are open
+        factory.stop()
+        events = tracing.RECORDER.snapshot(since=mark)
+        tracing.Span = real_span
+        tracing.set_level(old)
+        tracing.RECORDER.clear()
+    assert on >= len(events) - 2 * len(keys), "the counter saw the spans"
+    return events, keys, off
+
+
+def test_level_0_round_allocates_nothing_and_records_nothing(traced_round):
+    _, _, off = traced_round
+    assert off == {"ring": 0, "spans_built": 0}
+
+
+def test_a_pods_spans_join_from_create_to_bind(traced_round):
+    """The control-plane half is keyed by `key`, the pipeline half by
+    `batch`, and one pod-path event per bound batch joins them: for every
+    pod the chain create -> admitted -> popped -> harvested -> bound
+    exists and is in order."""
+    events, keys, _ = traced_round
+    created, admitted, batch_of = {}, {}, {}
+    by_batch = {}
+    for _, name, stage, t0, dur, _, attrs in events:
+        attrs = attrs or {}
+        if stage == "apiserver" and name == "create pods":
+            created[attrs["key"]] = (t0, t0 + dur)
+        elif stage == "informer" and name == "ADDED pods":
+            admitted[attrs["key"]] = t0 + dur
+        elif stage == "path":
+            for k in attrs["keys"]:
+                batch_of[k] = attrs["batch"]
+        elif "batch" in attrs:
+            by_batch.setdefault(attrs["batch"], {})[stage] = (t0, t0 + dur)
+    for key in keys:
+        assert key in created and key in admitted and key in batch_of, key
+        spans = by_batch[batch_of[key]]
+        # the loop's spans and the backend's carry the same number
+        assert {"cycle", "pop", "prep", "encode", "dispatch", "complete",
+                "wait", "harvest", "assume", "reserve-permit",
+                "binder-queue", "bind"} <= set(spans), (key, set(spans))
+        assert created[key][0] <= admitted[key]
+        assert spans["pop"][0] <= spans["harvest"][1] <= spans["bind"][1]
+        assert admitted[key] <= spans["harvest"][1]
+    create = [e for e in events if e[1] == "create pods"][0][6]
+    assert {"admission_s", "lock_s", "stamp_s", "encode_s", "store_s",
+            "decode_s", "hooks_s"} <= set(create)
+    timed = [e for e in events if e[6] and "thread" in e[6]]
+    with_cpu = [e for e in timed if "cpu_s" in e[6]]
+    assert len(timed) // 32 <= len(with_cpu) <= len(timed) // 8
+    bind = [e for e in events if e[2] == "bind"][0][6]
+    assert "posted_s" in bind
+    # a span that starts on one thread and ends on another has no cpu_s
+    waits = [e[6] for e in events if e[2] == "binder-queue"]
+    assert waits and all("cpu_s" not in a for a in waits)
+
+
+def test_ring_budget_per_pod_and_per_batch(traced_round):
+    """At most 4 ring events per pod and 20 per batch, empty polls of an
+    idle thread aside: run.py's 2^21 slots then hold a whole window."""
+    events, keys, _ = traced_round
+    per_pod, per_batch = {}, {}
+    for _, name, stage, _, _, _, attrs in events:
+        attrs = attrs or {}
+        if "batch" in attrs:
+            b = attrs["batch"]
+            per_batch[b] = per_batch.get(b, 0) + 1
+        elif "key" in attrs:
+            # a pod's Scheduled event is `default/<pod>.<suffix>`
+            k = attrs["key"].partition(".")[0]
+            per_pod[k] = per_pod.get(k, 0) + 1
+    assert set(keys) <= set(per_pod)
+    assert max(per_pod[k] for k in keys) <= 4, per_pod
+    batches = {a["batch"] for e in events if e[2] == "path"
+               for a in [e[6]]}
+    # the batch's own spans, its `bind pods` API call, and the polls
+    # (`queue-empty`, `worker-idle`) that found it
+    assert max(per_batch[b] for b in batches) + 3 <= 20, per_batch
+
+
+def test_scheduler_threads_are_inside_a_span(traced_round):
+    """The scheduler thread and the completion worker are inside some
+    span for all of a traced drain but the microseconds between spans."""
+    events, _, _ = traced_round
+    # the drain: first batch handed to the worker -> last bind done (the
+    # worker's idle span across the raise of the level was never opened)
+    t_a = min(e[3] for e in events if e[2] == "complete")
+    t_b = max(e[3] + e[4] for e in events if e[2] == "bind")
+    by_thread = {}
+    for e in events:
+        thread = (e[6] or {}).get("thread")
+        a, b = max(e[3], t_a), min(e[3] + e[4], t_b)
+        if thread in ("scheduler-loop", "batch-completions") and b > a:
+            by_thread.setdefault(thread, []).append((a, b))
+    assert set(by_thread) == {"scheduler-loop", "batch-completions"}
+    for thread, iv in by_thread.items():
+        covered, end = 0.0, t_a
+        for a, b in sorted(iv):
+            if b > end:
+                covered += b - max(a, end)
+                end = b
+        assert covered / (t_b - t_a) >= 0.97, (thread, covered, t_b - t_a)
 
 
 # -- backend health -> k8s Events + /configz knobs -------------------------
 
 
-def _mini_scheduler():
+def _mini_scheduler(nodes=1, **kw):
     from kubernetes_tpu.client import SharedInformerFactory
     from kubernetes_tpu.scheduler.scheduler import Scheduler
     from tests.util import make_node
 
     api = APIServer()
     cs = Clientset(api)
-    cs.nodes.create(make_node("node-0"))
+    for i in range(nodes):
+        cs.nodes.create(make_node(f"node-{i}"))
     factory = SharedInformerFactory(cs)
-    sched = Scheduler(cs, factory, backend="tpu", pipeline_depth=2)
+    sched = Scheduler(cs, factory, backend="tpu", pipeline_depth=2, **kw)
     factory.start()
     assert factory.wait_for_cache_sync()
     return cs, factory, sched

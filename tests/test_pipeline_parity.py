@@ -322,8 +322,8 @@ def test_speculation_kill_switch(monkeypatch):
             assert sched.tpu.speculation is False
             orig = type(sched.tpu).dispatch_many
 
-            def spy(self, pods, _orig=orig, _f=spec_flags):
-                h = _orig(self, pods)
+            def spy(self, pods, _orig=orig, _f=spec_flags, **kw):
+                h = _orig(self, pods, **kw)
                 _f.append(h.speculative)
                 return h
 
@@ -680,11 +680,11 @@ def test_speculation_miss_drill_through_loop(monkeypatch):
                 orig = type(sched.tpu).dispatch_many
                 count = {"batches": 0}
 
-                def arming(self, pods, _orig=orig, _c=count, _inj=inj):
+                def arming(self, pods, _orig=orig, _c=count, _inj=inj, **kw):
                     if _c["batches"] == 2:
                         _inj.arm("wedge-wait", shots=1)
                     _c["batches"] += 1
-                    return _orig(self, pods)
+                    return _orig(self, pods, **kw)
 
                 sched.tpu.dispatch_many = arming.__get__(sched.tpu)
             pods = _pod_stream(random.Random(seed), 32)
@@ -722,9 +722,9 @@ def test_backpressure_never_harvests_on_dispatch_thread():
     full_seen = []
     orig_d = type(sched.tpu).dispatch_many
 
-    def spy_d(self, pods, _orig=orig_d, _f=full_seen):
+    def spy_d(self, pods, _orig=orig_d, _f=full_seen, **kw):
         _f.append(len(self._pending))
-        return _orig(self, pods)
+        return _orig(self, pods, **kw)
 
     sched.tpu.dispatch_many = spy_d.__get__(sched.tpu)
     try:
@@ -758,8 +758,8 @@ def test_depth2_overlaps_dispatches():
     seen = []
     orig = type(sched.tpu).dispatch_many
 
-    def spy(self, pods):
-        h = orig(self, pods)
+    def spy(self, pods, **kw):
+        h = orig(self, pods, **kw)
         seen.append(len(self._pending))
         return h
 
