@@ -954,14 +954,10 @@ class _Handler(BaseHTTPRequestHandler):
             # per-item outcomes
             body = self._body()
             target = body.get("resource", "")
-            info = api._info(target)
-            n_ok = 0
-            for item in body.get("items") or []:
-                try:
-                    api.create(target, serde.from_dict(info.type, item))
-                    n_ok += 1
-                except APIError:
-                    pass
+            info = self.hub.api._info(target)
+            n_ok = api.create_bulk(target, [
+                serde.from_dict(info.type, item)
+                for item in body.get("items") or []])
             return self._send_json(200, {"created": n_ok})
         if resource == "pods" and sub == "exec":
             body = self._body()

@@ -23,6 +23,9 @@ callers can never alias stored state — the watch cache's copy discipline.
 
 from __future__ import annotations
 
+import contextlib
+import os
+import random
 import threading
 import time
 import uuid
@@ -194,6 +197,24 @@ FINALIZER_FOREGROUND = "foregroundDeletion"
 FINALIZER_ORPHAN = "orphan"
 
 
+# A uid is an identifier, not a secret: RFC 4122 version-4 shape from a
+# generator of this module's own (seeded from the kernel once, at import,
+# and again in a forked child; no caller's random.seed() reaches it).
+# uuid.uuid4() reads the kernel's pool per call, a system call that lets
+# go of the interpreter lock in the middle of every create (PERF.md, PR 26)
+_uid_rng = random.Random()
+os.register_at_fork(after_in_child=_uid_rng.seed)
+
+
+def _new_uid() -> str:
+    return str(uuid.UUID(int=_uid_rng.getrandbits(128), version=4))
+
+
+# what a create enters in place of APIServer._lock when no registered
+# validating hook is `atomic`
+_NO_LOCK = contextlib.nullcontext()
+
+
 def _verb_span(verb: str, resource: str, namespace: str, name: str):
     """The `apiserver` span of one write: "<verb> <resource>", keyed by
     the object's namespace/name. Nothing is built with tracing off."""
@@ -201,6 +222,22 @@ def _verb_span(verb: str, resource: str, namespace: str, name: str):
         return tracing.NOOP_SPAN
     return tracing.span(f"{verb} {resource}", "apiserver",
                         key=f"{namespace}/{name}" if namespace else name)
+
+
+def _bind_apply(namespace: str, pod_name: str, node_name: str):
+    """The read-modify-write of one pods/{name}/binding."""
+    def apply(body):
+        current = body.get("spec", {}).get("nodeName", "")
+        if current and current != node_name:
+            raise Conflict(
+                f"pod {namespace}/{pod_name} is already assigned to node {current}"
+            )
+        new_body = dict(body)
+        new_body["spec"] = dict(body.get("spec", {}))
+        new_body["spec"]["nodeName"] = node_name
+        return new_body
+
+    return apply
 
 
 class APIServer:
@@ -221,6 +258,21 @@ class APIServer:
         # (resource, op, obj) — serving-state side effects (e.g. CRD
         # registration) must not fire for writes the store rejects
         self._post_write: List[AdmissionFunc] = []
+        # The server-wide lock has ONE job on the write path: a create
+        # holds it around its `atomic` validating hooks and its store
+        # write, so that a hook which checks state the write changes
+        # cannot race another create past its limit. One hook in the tree
+        # is flagged so: admission.resource_quota (usage + this object
+        # <= hard; `admit.atomic = True`). A server with no atomic hook
+        # registered never takes it in create. What needs no lock here:
+        # every store's create (KVStore, DurableKV, the native one) makes
+        # existence check, revision, insert and watch emit one step under
+        # the store's own lock, and uid, timestamp, key and serde.to_dict
+        # touch only the caller's object; update, delete and bind never
+        # took it. Put nothing else under it: a thread that lets go of the
+        # interpreter while it holds this lock (a system call, a blocking
+        # hook) parks every other create behind it for a switch interval
+        # each (ROADMAP C11). It also guards _node_proxies, a dict lookup.
         self._lock = threading.Lock()
         # node-name -> kubelet node API (logs/exec proxying: the
         # reference's apiserver→kubelet connection behind
@@ -311,69 +363,145 @@ class APIServer:
 
     # -- verbs -------------------------------------------------------------
 
+    def _atomic_hooks(self) -> List[AdmissionFunc]:
+        """The registered validating hooks that must run under _lock
+        with the store write (read per call: hooks are appended late)."""
+        return [a for a in self._validating if getattr(a, "atomic", False)]
+
     def create(self, resource: str, obj: Any) -> Any:
-        info = self._info(resource)
+        return self._create(resource, obj, self._atomic_hooks(), want=True)
+
+    def _prepare_create(self, resource: str, info: ResourceInfo, obj: Any,
+                        sp) -> Tuple[str, Dict]:
+        """All of a create that touches only the caller's own object,
+        under NO lock: admission (the `atomic` hooks aside), uid,
+        timestamp, encode. Webhook plugins do blocking HTTP in here and
+        may re-enter the server. Returns the store's key and body."""
         meta = obj.metadata
         if not meta.name:
             raise Invalid("metadata.name is required")
-        with _verb_span("create", resource, meta.namespace, meta.name) as sp:
-            if resource == "certificatesigningrequests":
-                # stamp the requester identity server-side (certificates
-                # types.go:89-99: Username/Groups are set by the apiserver
-                # from the authenticated request, never trusted from the
-                # body) — otherwise any CSR-creating identity could assert a
-                # bootstrap identity and mint auto-approved node credentials.
-                # In-proc callers with no request context are the trusted
-                # local path (same trust level as writing the store directly).
-                from ..api.certificates import CertificateSigningRequestStatus
-                from .requestcontext import current_user
+        if resource == "certificatesigningrequests":
+            # stamp the requester identity server-side (certificates
+            # types.go:89-99: Username/Groups are set by the apiserver
+            # from the authenticated request, never trusted from the
+            # body) — otherwise any CSR-creating identity could assert a
+            # bootstrap identity and mint auto-approved node credentials.
+            # In-proc callers with no request context are the trusted
+            # local path (same trust level as writing the store directly).
+            from ..api.certificates import CertificateSigningRequestStatus
+            from .requestcontext import current_user
 
-                user = current_user()
-                if user is not None:
-                    obj.spec.username = user.name
-                    obj.spec.groups = list(user.groups or ())
-                # a CREATE never carries status: a caller-supplied Approved
-                # condition would let the signer mint credentials without
-                # any approver having acted (create.go drops status for
-                # every resource with a status subresource)
-                obj.status = CertificateSigningRequestStatus()
-            # non-atomic admission runs OUTSIDE the lock — webhook plugins do
-            # blocking HTTP here and may re-enter the server; only hooks
-            # flagged `atomic` (quota: usage check must not race the write
-            # past the hard limit) run under the lock with the store write
-            for admit in self._mutating:
+            user = current_user()
+            if user is not None:
+                obj.spec.username = user.name
+                obj.spec.groups = list(user.groups or ())
+            # a CREATE never carries status: a caller-supplied Approved
+            # condition would let the signer mint credentials without
+            # any approver having acted (create.go drops status for
+            # every resource with a status subresource)
+            obj.status = CertificateSigningRequestStatus()
+        for admit in self._mutating:
+            admit(resource, "CREATE", obj)
+        for admit in self._validating:
+            if not getattr(admit, "atomic", False):
                 admit(resource, "CREATE", obj)
-            for admit in self._validating:
-                if not getattr(admit, "atomic", False):
-                    admit(resource, "CREATE", obj)
-            sp.step("admission")
-            with self._lock:
-                sp.step("lock")
-                for admit in self._validating:
-                    if getattr(admit, "atomic", False):
+        sp.step("admission")
+        # stamped before the write (rest.BeforeCreate)
+        meta.uid = meta.uid or _new_uid()
+        meta.creation_timestamp = meta.creation_timestamp or time.time()
+        if resource == "namespaces" and "kubernetes" not in (meta.finalizers or []):
+            # stamped server-side at create (pkg/registry/core/namespace/
+            # strategy.go PrepareForCreate) so a delete racing the
+            # namespace controller can never skip the content drain
+            meta.finalizers = (meta.finalizers or []) + ["kubernetes"]
+        key = self._key(info, meta.namespace, meta.name)
+        sp.step("stamp")
+        body = serde.to_dict(obj)
+        sp.step("encode")
+        return key, body
+
+    def _create(self, resource: str, obj: Any, atomic: List[AdmissionFunc],
+                want: bool) -> Any:
+        """One create. `atomic` hooks run under _lock with the store
+        write (see __init__); with none, no lock of this server's is
+        taken. The stored object is decoded for the caller only if it is
+        `want`ed (or a post-write hook is registered)."""
+        info = self._info(resource)
+        meta = obj.metadata
+        with _verb_span("create", resource, meta.namespace, meta.name) as sp:
+            key, body = self._prepare_create(resource, info, obj, sp)
+            try:
+                with self._lock if atomic else _NO_LOCK:
+                    sp.step("lock")  # the wait for it; ~0 with none to take
+                    for admit in atomic:
                         admit(resource, "CREATE", obj)
-                meta.uid = meta.uid or str(uuid.uuid4())
-                meta.creation_timestamp = meta.creation_timestamp or time.time()
-                if resource == "namespaces" and "kubernetes" not in (meta.finalizers or []):
-                    # stamped server-side at create (pkg/registry/core/namespace/
-                    # strategy.go PrepareForCreate) so a delete racing the
-                    # namespace controller can never skip the content drain
-                    meta.finalizers = (meta.finalizers or []) + ["kubernetes"]
-                key = self._key(info, meta.namespace, meta.name)
-                sp.step("stamp")  # uuid4 reads the kernel's random pool
-                body = serde.to_dict(obj)
-                sp.step("encode")
-                try:
                     rev = self.store.create(key, body)
-                except kv.KeyExists:
-                    raise AlreadyExists(key)
-                sp.step("store")  # the watch emit included
-            created = self._stamp(info, body, rev)
+            except kv.KeyExists:
+                raise AlreadyExists(key)
+            sp.step("store")  # atomic hooks and the watch emit included
+            if atomic:
+                sp.set(locked=True)
+            created = None
+            if want or self._post_write:
+                created = self._stamp(info, body, rev)
             sp.step("decode")
             for hook in self._post_write:
                 hook(resource, "CREATE", created)
             sp.step("hooks")
             return created
+
+    def create_bulk(self, resource: str, objs) -> int:
+        """N creates of one resource in one call (the event firehose),
+        best-effort: each item takes the whole create path — admission,
+        stamp, encode, store write, watch emit, post-write hooks — and an
+        item the server refuses (AlreadyExists, an admission hook) is
+        skipped. Nothing is decoded for a return value nobody reads.
+        Returns the number created.
+
+        Every item is prepared first, under no lock, each in its own
+        "create <resource>" span (steps admission, stamp, encode); then
+        ONE store.create_many writes them, the store taking its own lock
+        once a run of items and not once an item (beside a create loop on
+        another thread every wait for it is a hand-over of the
+        interpreter both ways: PERF.md, PR 26). That write, the waits for
+        the store's lock included, is the step `store` of one
+        "create_bulk <resource>" span; the post-write hooks run after it,
+        outside every lock. Which way it goes is decided once a bulk."""
+        info = self._info(resource)
+        atomic = self._atomic_hooks()
+        n_ok = 0
+        if atomic:
+            # check + write are one step under _lock, item by item
+            for obj in objs:
+                try:
+                    self._create(resource, obj, atomic, want=False)
+                    n_ok += 1
+                except APIError:
+                    pass
+            return n_ok
+        ready: List[Tuple[str, Dict]] = []
+        for obj in objs:
+            meta = obj.metadata
+            try:
+                with _verb_span("create", resource, meta.namespace,
+                                meta.name) as sp:
+                    ready.append(self._prepare_create(resource, info, obj, sp))
+            except APIError:
+                pass
+        with tracing.span(f"create_bulk {resource}", "apiserver",
+                          n=len(ready)) as sp:
+            revs = self.store.create_many(ready)
+            sp.step("store")
+            for (_, body), rev in zip(ready, revs):
+                if rev is None:  # AlreadyExists
+                    continue
+                n_ok += 1
+                if self._post_write:
+                    created = self._stamp(info, body, rev)
+                    for hook in self._post_write:
+                        hook(resource, "CREATE", created)
+            sp.step("hooks")
+        return n_ok
 
     def get(self, resource: str, name: str, namespace: str = "") -> Any:
         info = self._info(resource)
@@ -642,23 +770,11 @@ class APIServer:
         """pods/{name}/binding: set spec.nodeName exactly once (reference:
         pkg/registry/core/pod/storage/storage.go BindingREST.Create —
         'pod X is already assigned to node Y' conflict)."""
-        info = self._info("pods")
-        key = self._key(info, namespace, pod_name)
-
-        def apply(body):
-            current = body.get("spec", {}).get("nodeName", "")
-            if current and current != node_name:
-                raise Conflict(
-                    f"pod {namespace}/{pod_name} is already assigned to node {current}"
-                )
-            new_body = dict(body)
-            new_body["spec"] = dict(body.get("spec", {}))
-            new_body["spec"]["nodeName"] = node_name
-            return new_body
-
         try:
             self.store.guaranteed_update(
-                key, apply, precondition=self._fence_precondition(fence, "bind")
+                self._key(self._info("pods"), namespace, pod_name),
+                _bind_apply(namespace, pod_name, node_name),
+                precondition=self._fence_precondition(fence, "bind"),
             )
         except kv.KeyNotFound as e:
             raise NotFound(str(e))
@@ -671,17 +787,24 @@ class APIServer:
         to N bind_pod calls; exists because the scheduler's batched cycle
         lands thousands of bindings at once and the per-call overhead
         (lock churn, method dispatch) was measurable in the full-loop
-        profile. The reference amortizes the same cost with 8 parallel
-        binder goroutines (pkg/scheduler/scheduler.go:540) — under a GIL,
-        batching is the equivalent lever."""
-        results: List[Optional[APIError]] = []
-        for namespace, pod_name, node_name in bindings:
-            try:
-                self.bind_pod(namespace, pod_name, node_name, fence=fence)
-                results.append(None)
-            except APIError as e:
-                results.append(e)
-        return results
+        profile: the store takes its lock once a run of bindings (each is
+        a read and a write under it), as for create_bulk. The reference
+        amortizes the same cost with 8 parallel binder goroutines
+        (pkg/scheduler/scheduler.go:540) — under a GIL, batching is the
+        equivalent lever."""
+        info = self._info("pods")
+        outcomes = self.store.guaranteed_update_many(
+            [(self._key(info, namespace, pod_name),
+              _bind_apply(namespace, pod_name, node_name))
+             for namespace, pod_name, node_name in bindings],
+            precondition=self._fence_precondition(fence, "bind"),
+            item_errors=(APIError,),
+        )
+        return [
+            NotFound(str(o)) if isinstance(o, kv.KeyNotFound)
+            else o if isinstance(o, APIError) else None
+            for o in outcomes
+        ]
 
     def update_status(self, resource: str, obj: Any, fence=None) -> Any:
         """status subresource: replaces only .status (handlers for

@@ -25,9 +25,11 @@ class _ResourceClient:
         return self._api.create(self._resource, obj)
 
     def create_many(self, objs) -> None:
-        """Best-effort bulk create (event firehose): ONE request on
-        wire-backed servers (create_bulk), a loop in-proc; individual
-        failures are swallowed (callers are fire-and-forget paths)."""
+        """Best-effort bulk create (event firehose): the server's
+        create_bulk — ONE request over the wire, one call in-process that
+        decodes no return value — or a loop of creates on a facade that
+        has none; individual failures are swallowed (callers are
+        fire-and-forget paths)."""
         bulk = getattr(self._api, "create_bulk", None)
         if bulk is not None:
             bulk(self._resource, list(objs))

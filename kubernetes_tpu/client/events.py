@@ -60,7 +60,11 @@ class EventRecorder:
         self._client = clientset.resource("events")
         self._component = component
         self._lock = threading.Lock()
-        self._known: Dict[tuple, str] = {}  # aggregation key -> event name
+        # aggregation key -> event name. The broadcaster thread's own:
+        # only _sink_batch and _sink, which run on it, read or write it,
+        # so it takes no lock (one taken per event stood in line with
+        # every binder thread's event() while a bind wave's events queued)
+        self._known: Dict[tuple, str] = {}
         # unbounded deque, bounded by hand in event(): the INCOMING event
         # is dropped when full (watch.NewBroadcaster's DropIfChannelFull
         # — a full channel never evicts already-queued events), counted
@@ -129,9 +133,7 @@ class EventRecorder:
                 dup.count += 1
                 dup.last_timestamp = now
                 continue
-            with self._lock:
-                known = key in self._known
-            if known:
+            if key in self._known:
                 self._sink(*item)
                 continue
             name = f"{ref.name}.{self._name_base}{next(self._seq):x}"
@@ -151,17 +153,15 @@ class EventRecorder:
             return
         try:
             self._client.create_many(list(fresh.values()))
-            with self._lock:
-                for key, ev in fresh.items():
-                    self._known[key] = ev.metadata.name
+            for key, ev in fresh.items():
+                self._known[key] = ev.metadata.name
         except Exception:  # noqa: BLE001 — events are best-effort
             pass
 
     def _sink(self, ref: ObjectReference, event_type: str, reason: str,
               message: str, now: float) -> None:
         key = (ref.kind, ref.namespace, ref.name, reason, message)
-        with self._lock:
-            existing_name = self._known.get(key)
+        existing_name = self._known.get(key)
         try:
             if existing_name:
                 try:
@@ -184,7 +184,6 @@ class EventRecorder:
                 source_component=self._component,
             )
             self._client.create(ev)
-            with self._lock:
-                self._known[key] = name
+            self._known[key] = name
         except Exception:
             pass  # events are best-effort

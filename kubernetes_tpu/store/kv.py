@@ -173,6 +173,13 @@ class KVStore:
 
     # -- writes ------------------------------------------------------------
 
+    def create_many(self, items) -> List[Optional[int]]:
+        """N creates under one wait for the store's lock a run of _RUN
+        of them, not one a create (the event firehose). Each (key, value)
+        is still its own create, with its own revision and watch event;
+        an item whose key exists reads None and the rest go on."""
+        return _in_runs(self._lock, create_many, self, items)
+
     def create(self, key: str, value: Any) -> int:
         with self._lock:
             if key in self._data:
@@ -235,6 +242,14 @@ class KVStore:
     def guaranteed_update(self, key: str, fn, max_retries: int = 16,
                           precondition=None) -> int:
         return guaranteed_update(self, key, fn, max_retries, precondition)
+
+    def guaranteed_update_many(self, updates, precondition=None,
+                               item_errors=()) -> list:
+        """guaranteed_update of every (key, fn), one wait for the
+        store's lock a run of _RUN of them (a bind wave): outcomes as
+        the module's function of this name gives them."""
+        return _in_runs(self._lock, guaranteed_update_many, self, updates,
+                        precondition, item_errors)
 
     # -- watch -------------------------------------------------------------
 
@@ -447,6 +462,11 @@ class DurableKVStore:
 
     # -- writes: apply, then log before acknowledging ----------------------
 
+    def create_many(self, items) -> List[Optional[int]]:
+        """KVStore.create_many: one wait for the writer lock a run,
+        every create applied and logged as its own record."""
+        return _in_runs(self._dlock, create_many, self, items)
+
     def create(self, key: str, value: Any) -> int:
         with self._dlock:
             rev = self._inner.create(key, value)
@@ -483,6 +503,11 @@ class DurableKVStore:
     def guaranteed_update(self, key: str, fn, max_retries: int = 16,
                           precondition=None) -> int:
         return guaranteed_update(self, key, fn, max_retries, precondition)
+
+    def guaranteed_update_many(self, updates, precondition=None,
+                               item_errors=()) -> list:
+        return _in_runs(self._dlock, guaranteed_update_many, self, updates,
+                        precondition, item_errors)
 
     def compact(self, revision: int) -> None:
         with self._dlock:
@@ -593,3 +618,55 @@ def guaranteed_update(store, key: str, fn, max_retries: int = 16,
         except Conflict:
             continue
     raise Conflict(f"{key}: too many conflicts in guaranteed_update")
+
+
+# Writes of one create_many / guaranteed_update_many made under one wait
+# for the store's lock. Beside a writer on another thread every wait is a
+# hand-over of the interpreter both ways, hundreds of microseconds on a
+# busy host, so a wave of thousands should wait tens of times and not
+# thousands; but readers, watches and other writers stand behind a run,
+# so it stays at a few milliseconds of writes (PERF.md, PR 26).
+_RUN = 64
+
+
+def _in_runs(lock, many, store, items, *args) -> list:
+    """many(store, <run of items>, *args) for run after run of `items`,
+    each under one hold of `lock`; the outcomes in order."""
+    items = list(items)
+    out: list = []
+    for i in range(0, len(items), _RUN):
+        with lock:
+            out += many(store, items[i:i + _RUN], *args)
+    return out
+
+
+def create_many(store, items) -> List[Optional[int]]:
+    """store.create of every (key, value), in order: the revision, or
+    None where the key exists. A store that has a lock of its own takes
+    it once around this loop (KVStore.create_many)."""
+    revs: List[Optional[int]] = []
+    for key, value in items:
+        try:
+            revs.append(store.create(key, value))
+        except KeyExists:
+            revs.append(None)
+    return revs
+
+
+def guaranteed_update_many(store, updates, precondition=None,
+                           item_errors=()) -> list:
+    """store.guaranteed_update of every (key, fn), in order, each with
+    its own outcome: the new revision, or the exception it raised where
+    that is a KeyNotFound or one of `item_errors` (what fn or the
+    precondition raise to refuse one item). Any other exception leaves at
+    once, with the items after it untouched, as it would leave a loop of
+    guaranteed_update calls."""
+    caught = (KeyNotFound,) + tuple(item_errors)
+    out: list = []
+    for key, fn in updates:
+        try:
+            out.append(store.guaranteed_update(key, fn,
+                                               precondition=precondition))
+        except caught as e:
+            out.append(e)
+    return out

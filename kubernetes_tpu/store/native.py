@@ -279,6 +279,20 @@ class NativeKVStore:
 
         return guaranteed_update(self, key, fn, max_retries, precondition)
 
+    def create_many(self, items) -> list:
+        """KVStore.create_many; the library locks per call, so this is
+        the plain loop."""
+        from .kv import create_many
+
+        return create_many(self, items)
+
+    def guaranteed_update_many(self, updates, precondition=None,
+                               item_errors=()) -> list:
+        from .kv import guaranteed_update_many
+
+        return guaranteed_update_many(self, updates, precondition,
+                                      item_errors)
+
     def compact(self, revision: int) -> None:
         """Drop history up to revision (etcd compaction)."""
         self._lib.kv_compact(self._h, revision)

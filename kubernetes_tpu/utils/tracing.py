@@ -84,8 +84,10 @@ STAGES = (
     "bind",         # batched bind POST
     # the control plane, keyed by `key` (namespace/name)
     "apiserver",    # one span per create/update/delete: "<verb>
-                    # <resource>", steps admission / lock / stamp /
-                    # encode / store / decode / hooks
+                    # <resource>", steps admission / stamp / encode /
+                    # lock / store / decode / hooks; a bulk create's
+                    # items end with `encode` and its one write is
+                    # "create_bulk <resource>", steps store / hooks
     "informer",     # one span per delivered ADDED event: "ADDED
                     # <resource>", poll returning -> last handler
                     # returning (queue.add is inside, step `handlers`)

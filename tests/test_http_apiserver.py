@@ -356,3 +356,54 @@ class TestBulkBindings:
         assert outcomes[2] is not None
         assert cs.pods.get("a", "default").spec.node_name == "n1"
         assert cs.pods.get("b", "default").spec.node_name == "n-other"
+
+
+class TestBulkCreate:
+    @staticmethod
+    def _events(n, base="ev"):
+        from kubernetes_tpu.client.events import Event
+
+        return [Event(metadata=v1.ObjectMeta(name=f"{base}-{i}",
+                                             namespace="default"),
+                      reason="Scheduled", message=f"m{i}") for i in range(n)]
+
+    def test_bulk_create_over_the_wire_is_best_effort(self, wire):
+        srv, remote = wire
+        evs = self._events(5)
+        remote.create("events", evs[2])  # one of the five already exists
+        remote.create_bulk("events", evs)
+        items, _ = remote.list("events", "default")
+        assert sorted(e.metadata.name for e in items) == [
+            f"ev-{i}" for i in range(5)]
+        assert all(e.metadata.uid and e.metadata.creation_timestamp
+                   for e in items)
+
+    def test_recorder_over_the_wire_takes_one_request_a_batch(self, wire):
+        from kubernetes_tpu.client.events import EventRecorder
+
+        srv, remote = wire
+        rec = EventRecorder(Clientset(remote), "wire-component")
+        for i in range(40):
+            rec.event(make_pod(f"p{i}"), "Normal", "Scheduled", f"to n{i}")
+        assert rec.flush(timeout=30.0)
+        assert rec.dropped_events == 0
+        items, _ = srv.api.list("events", "default")
+        assert len(items) == 40
+
+    def test_bulk_create_through_the_secured_chain(self):
+        secure = SecureAPIServer()
+        secure.authenticator.add_token("root-token", "admin",
+                                       ["system:masters"])
+        secure.authenticator.add_token("peon-token", "peon")
+        srv = HTTPAPIServer(secure).start()
+        try:
+            RemoteAPIServer(srv.address, token="peon-token").create_bulk(
+                "events", self._events(3, "denied"))
+            assert secure.api.list("events", "default")[0] == []
+            RemoteAPIServer(srv.address, token="root-token").create_bulk(
+                "events", self._events(3, "granted"))
+            assert sorted(e.metadata.name for e in
+                          secure.api.list("events", "default")[0]) == [
+                "granted-0", "granted-1", "granted-2"]
+        finally:
+            srv.stop()

@@ -63,6 +63,50 @@ def test_event_recorder_aggregates():
     assert len(events) == 2
 
 
+def test_event_firehose_sinks_every_event_through_the_bulk_route():
+    """One bind wave's worth of Scheduled events: every one an object in
+    the store, none dropped, none through a one-at-a-time create."""
+    api = APIServer()
+    cs = Clientset(api)
+    calls = {"create": 0, "create_bulk": 0, "bulk_items": 0}
+    real_create, real_bulk = api.create, api.create_bulk
+
+    def create(resource, obj):
+        calls["create"] += resource == "events"
+        return real_create(resource, obj)
+
+    def create_bulk(resource, objs):
+        calls["create_bulk"] += 1
+        calls["bulk_items"] += len(objs)
+        return real_bulk(resource, objs)
+
+    api.create, api.create_bulk = create, create_bulk
+    rec = EventRecorder(cs, "test-component")
+    watch = cs.resource("events").watch()
+    n = 2048
+    for i in range(n):
+        pod = v1.Pod(metadata=v1.ObjectMeta(name=f"p-{i:05d}",
+                                            namespace="default", uid=f"u{i}"))
+        rec.event(pod, "Normal", "Scheduled",
+                  f"Successfully assigned default/p-{i:05d} to n1")
+    assert rec.flush(timeout=60.0)
+    assert rec.dropped_events == 0
+    events, _ = cs.resource("events").list()
+    assert len(events) == n
+    assert {e.involved_object.name for e in events} == {
+        f"p-{i:05d}" for i in range(n)}
+    assert all(e.count == 1 and e.metadata.uid and e.metadata.creation_timestamp
+               and e.source_component == "test-component" for e in events)
+    assert calls["create"] == 0
+    assert calls["bulk_items"] == n and 1 <= calls["create_bulk"] <= n
+    seen = 0
+    while seen < n:
+        ev = watch.poll(timeout=5.0)
+        assert ev is not None and ev.type == "ADDED"
+        seen += 1
+    watch.stop()
+
+
 def test_leader_election_failover():
     api = APIServer()
     cs = Clientset(api)

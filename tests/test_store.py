@@ -168,6 +168,118 @@ class TestWatch:
         w.stop()
 
 
+class TestMany:
+    """create_many / guaranteed_update_many: N writes, each its own step,
+    the store's lock waited for once a run of them."""
+
+    def test_create_many_is_n_creates_with_their_own_outcomes(self, store):
+        store.create("/m/3", "there first")
+        w = store.watch("/m/")
+        n = 150  # more than two runs
+        revs = store.create_many([(f"/m/{i}", i) for i in range(n)])
+        assert revs[3] is None  # KeyExists: that item alone
+        made = [r for r in revs if r is not None]
+        assert len(made) == n - 1 and made == sorted(set(made))
+        assert store.get("/m/3").value == "there first"
+        seen = [w.poll(timeout=1) for _ in range(n - 1)]
+        assert [(e.type, e.key, e.value, e.revision) for e in seen] == [
+            ("ADDED", f"/m/{i}", i, revs[i]) for i in range(n) if i != 3]
+        assert w.poll(timeout=0.05) is None
+        w.stop()
+
+    def test_update_many_keeps_an_items_refusal_and_goes_on(self, store):
+        class Refused(Exception):
+            pass
+
+        def bump(value):
+            if value == 2:
+                raise Refused("not this one")
+            return value + 100
+
+        for i in range(4):
+            store.create(f"/u/{i}", i)
+        w = store.watch("/u/")
+        out = store.guaranteed_update_many(
+            [(f"/u/{i}", bump) for i in (0, 1, 2, 9, 3)],
+            item_errors=(Refused,))
+        assert isinstance(out[2], Refused)
+        assert isinstance(out[3], kv.KeyNotFound)
+        revs = [out[0], out[1], out[4]]
+        assert revs == sorted(set(revs))
+        assert [store.get(f"/u/{i}").value for i in range(4)] == [
+            100, 101, 2, 103]
+        seen = [w.poll(timeout=1) for _ in range(3)]
+        assert [(e.type, e.key, e.revision) for e in seen] == [
+            ("MODIFIED", "/u/0", revs[0]), ("MODIFIED", "/u/1", revs[1]),
+            ("MODIFIED", "/u/3", revs[2])]
+        w.stop()
+
+    def test_update_many_lets_any_other_error_out_at_once(self, store):
+        def bump(value):
+            if value == 1:
+                raise ZeroDivisionError("a bug, not a refusal")
+            return value + 100
+
+        for i in range(3):
+            store.create(f"/x/{i}", i)
+        with pytest.raises(ZeroDivisionError):
+            store.guaranteed_update_many([(f"/x/{i}", bump) for i in range(3)])
+        assert [store.get(f"/x/{i}").value for i in range(3)] == [100, 1, 2]
+
+    def test_update_many_checks_the_precondition_every_item(self, store):
+        class Stale(Exception):
+            pass
+
+        calls = []
+
+        def precondition():
+            calls.append(1)
+            if len(calls) == 2:
+                raise Stale("fence lost")
+
+        for i in range(3):
+            store.create(f"/p/{i}", i)
+        out = store.guaranteed_update_many(
+            [(f"/p/{i}", lambda v: v + 100) for i in range(3)],
+            precondition=precondition, item_errors=(Stale,))
+        assert isinstance(out[1], Stale) and len(calls) == 3
+        assert [store.get(f"/p/{i}").value for i in range(3)] == [100, 1, 102]
+
+    @pytest.mark.parametrize("backend", ["python", "durable"])
+    @pytest.mark.parametrize("many", ["create_many", "update_many"])
+    def test_another_writer_waits_for_the_run(self, backend, many, tmp_path):
+        s = (kv.KVStore() if backend == "python"
+             else kv.DurableKVStore(str(tmp_path / "db")))
+        th = threading.Thread(target=lambda: s.create("/other", 1),
+                              daemon=True)
+        s.create("/mine/0", 0)
+        inside = []
+
+        def second_write(*_):
+            # inside the run, after its first write: the other writer
+            # starts now and must still be waiting when the run goes on
+            th.start()
+            th.join(timeout=0.3)
+            inside.append(th.is_alive())
+            return 1
+
+        if many == "create_many":
+            real = s.create
+            s.create = lambda key, value: (
+                second_write() if key == "/mine/2" else None,
+                real(key, value))[1]
+            revs = s.create_many([("/mine/1", 1), ("/mine/2", 2)])
+            s.create = real
+        else:
+            revs = s.guaranteed_update_many(
+                [("/mine/0", lambda v: v + 1), ("/mine/0", second_write)])
+        th.join(timeout=10.0)
+        assert inside == [True], "a write got in between two of a run"
+        assert not th.is_alive()
+        assert revs[1] == revs[0] + 1
+        assert s.get("/other").mod_revision == revs[1] + 1
+
+
 class TestNativeBackedAPIServer:
     def test_cluster_on_native_store(self):
         """The whole apiserver + informer stack over the C++ store."""

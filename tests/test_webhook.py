@@ -135,6 +135,55 @@ class TestValidatingWebhook:
         cs.pods.create(make_pod("p"))  # unreachable hook now ignored
 
 
+class TestWebhookOnTheBulkRoute:
+    def test_a_backend_that_reads_this_server_is_answered(
+            self, cluster, webhook_server):
+        """The event firehose's bulk create with a validating webhook on
+        `events` whose backend reads and writes this very API server
+        before it answers: the hook runs with no lock of the server's or
+        the store's held, so the backend is served, every item is
+        admitted, and none waits for the hook's timeout."""
+        from kubernetes_tpu.client.events import Event
+
+        api, cs = cluster
+        url, handler = webhook_server
+        cs.resource("validatingwebhookconfigurations").create(
+            ValidatingWebhookConfiguration(
+                metadata=v1.ObjectMeta(name="events-hook"),
+                webhooks=[Webhook(
+                    name="events.example.com",
+                    client_config=WebhookClientConfig(url=url),
+                    rules=[RuleWithOperations(operations=["CREATE"],
+                                              resources=["events"])],
+                    failure_policy="Fail",
+                    timeout_seconds=3,
+                )],
+            )
+        )
+        served = []
+
+        def behavior(review):
+            name = review["request"]["object"]["metadata"]["name"]
+            api.list("events", "default")
+            api.create("configmaps", v1.ConfigMap(metadata=v1.ObjectMeta(
+                name=f"saw-{name}", namespace="default")))
+            served.append(name)
+            return {"allowed": name != "ev-2",
+                    "status": {"message": "not ev-2"}}
+
+        handler.behavior = staticmethod(behavior)
+        events = [Event(metadata=v1.ObjectMeta(name=f"ev-{i}",
+                                               namespace="default"),
+                        reason="Scheduled", message=f"m{i}")
+                  for i in range(5)]
+        assert api.create_bulk("events", events) == 4
+        assert served == [f"ev-{i}" for i in range(5)]
+        assert sorted(e.metadata.name for e in
+                      api.list("events", "default")[0]) == [
+            "ev-0", "ev-1", "ev-3", "ev-4"]
+        assert len(api.list("configmaps", "default")[0]) == 5
+
+
 class TestMutatingWebhook:
     def test_jsonpatch_applied(self, cluster, webhook_server):
         api, cs = cluster
