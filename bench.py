@@ -251,11 +251,7 @@ def main() -> None:
             # name. Interpreted only on a CPU asked for by name.
             from kubernetes_tpu.ops.pallas_scan import PallasSession
 
-            # multipod_k=1: the harvest below treats decisions() as
-            # final (no conflict-suffix replay loop), and the headline
-            # must stay comparable across rounds — one-pod-per-step.
             sess = PallasSession(enc.device_state(), templates,
-                                 multipod_k=1,
                                  interpret=dev["platform"] != "tpu")
             log("scan kernel: pallas single-launch")
         else:

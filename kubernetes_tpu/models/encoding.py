@@ -1068,6 +1068,18 @@ class ClusterEncoding:
             self._dirty_meta = False
         return dev
 
+    def host_state(self) -> dict:
+        """The cluster dict of device_state(), as the live HOST arrays:
+        no copy and no upload. The arrays mutate in place under the
+        owner's lock; a reader holds that lock for as long as it reads
+        (host_snapshot() copies for readers that cannot)."""
+        if self._rebuild_needed or self._caps_grew():
+            self.rebuild()
+        host = dict(self._arrays)
+        host.update(self._term_arrays())
+        host["n_nodes"] = np.array(self.n_nodes, np.int32)
+        return host
+
     def host_snapshot(self) -> dict:
         """Numpy COPIES of the current host arrays (rebuilding first if
         pending) — a consistent point-in-time view a caller can carry

@@ -76,7 +76,9 @@ _FP_MEMO = None  # id(anchor array) -> fingerprint; finalizer-evicted
 def template_fingerprint(pod_arrays: Dict) -> Tuple:
     """Identity of the scheduling-relevant template: every encoded array
     except the per-pod node-name fields (which must be absent/false for
-    batchable pending pods anyway).
+    batchable pending pods anyway). Equal fingerprints mean equal
+    scheduling semantics, NOT equal array shapes: callers that stack
+    arrays compare ops/batch.py shape_signature as well.
 
     Memoized on the identity of the self_ppair buffer: the pod encoder
     caches encodings by spec fingerprint and hands out shallow copies, so
@@ -103,6 +105,12 @@ def template_fingerprint(pod_arrays: Dict) -> Tuple:
         if k.startswith("_") or k in TEMPLATE_KEYS_EXCLUDED:
             continue
         a = np.asarray(pod_arrays[k])
+        if a.ndim == 1 and a.dtype == np.bool_:
+            # a bitmap over a vocabulary (labels, tolerations): its
+            # width is the vocabulary's capacity bucket when the pod was
+            # encoded, and ids are permanent, so the same spec encoded
+            # after the vocabulary grew differs in trailing zeros only
+            a = np.trim_zeros(a, "b")
         items.append((k, a.shape, a.dtype.str, a.tobytes()))
     fp = tuple(items)
     if anchor is not None:

@@ -1,52 +1,88 @@
-"""Pallas mega-kernel for the hoisted scheduling session: the WHOLE batch
-scan runs as ONE kernel launch with the carry held in registers.
+"""Pallas mega-kernel for the scheduling session: the WHOLE batch scan
+runs as ONE kernel launch, and a pod spec is a ROW of device-resident
+tables that a live session admits without a rebuild and without a compile.
 
-Why: the lax.scan step compiles to dozens of fusions, each launched
-afresh on every scan iteration. Inside one pallas kernel the per-op cost
-is VPU cycles, so a fori_loop over pods turns 1024 steps x ~25 launches
-into ONE launch.
+Why one launch: the lax.scan step compiles to dozens of fusions, each
+launched afresh on every scan iteration. Inside one pallas kernel the
+per-op cost is VPU cycles, so a fori_loop over pods turns 1024 steps x
+~25 launches into ONE launch.
+
+Why a table: a cluster holds hundreds to thousands of Deployments, each
+its own labels, selector and requests, so every Deployment is a spec of
+its own. Nothing in the compiled program depends on how many specs are
+live or which a launch carries; the shapes are capacities:
+
+- `spec` (SMEM, [Tcap * W] int32): one record of W scalars per spec —
+  rescaled requests, the ids of its static rows, per-constraint flags
+  and row ids, and (offset, length) of its lists in `pool`. A pod names
+  its record (`tmpl[b]`), the kernel reads scalars at `t * W + field`.
+- `pool` (SMEM, int32): the variable-length lists, append-only. A
+  spec's TOUCH list says which count rows a pod of that spec counts
+  toward — entries (count row, pair-id row, weight, eligibility row):
+  "which rows this pod counts toward" is data, not an identity assumed
+  from the deployment (a pod's labels may match another spec's selector).
+- `srow` (VMEM, [SRcap, Np] int32): every per-node static row (feasibility
+  mask, static scores, topology pair ids, key-on-node flags, static term
+  counts), interned by content: 512 Deployments on one node shape share a
+  handful of rows. `zrow` ([ZRcap, 128]) likewise for per-zone rows.
+- `cnt` (VMEM carry, [RCcap, Np] int32): the count rows. Every row is
+  owned by its READER — (spec, spread constraint) or (spec, affinity
+  term / repel key / score) — starts at what the prologue counted in the
+  cluster snapshot of the reader's admission (spread) or at zero (terms:
+  the snapshot part lives in the reader's static rows), and is
+  incremented by the pods on its writers' touch lists. Reader-owned rows
+  are what makes late admission exact: no row is shared between readers
+  admitted against different snapshots, so nothing is counted twice.
+
+The kernel reads and writes single rows at dynamic offsets
+(`ref[pl.ds(row, 1), :]`), loops over a spec's lists with dynamic trip
+counts, and skips whole sections (`lax.cond`) for specs that have no such
+constraint. Admission (PallasSession.admit) runs the hoisted prologue on
+the new specs only — on the host, against the encoding's host arrays —
+interns their rows, appends touch entries to every writer, and writes the
+changed rows/records to the device: the session object, its executables
+and its carry stay.
 
 Design notes (vs ops/hoisted.py _step, whose semantics this mirrors):
 
-- **int64-free**: Mosaic has no 64-bit types. Resource quantities
-  (milli-CPU, memory bytes, ...) are rescaled per dimension by the GCD
-  of every value in the session. This is EXACT, not approximate: the
-  fit comparisons, least-allocated's `(cap-req)*100 // cap`, and
-  balanced's fractions are invariant under a common rescale (floors of
-  equal rationals are equal). Falls back (PallasUnsupported) if the
-  rescaled magnitudes overflow the int32 headroom.
-- **gather-free PTS counts**: pair-count tables [C, Vnp] (Vnp ~ 11k,
-  dominated by per-node hostname pairs) become (a) per-node count rows
-  for constraints whose pairs are node-distinct (hostname), and (b)
-  compact Vz<=128-lane tables for shared-value keys (zone, ...), with a
-  static one-hot [N, Vz] so count-to-node expansion and scored-set
-  registration are MXU matvecs instead of gathers (unsupported in
-  Mosaic).
-- float64 score math (PTS topology weights, IPA/balanced normalization)
-  runs in float32 in-kernel. Decision parity with the f64 path is pinned
-  by tests on every workload shape we ship; divergence is only possible
-  where two nodes' scores straddle an f32 rounding boundary, in which
-  case either choice is a max-score node.
-- jnp.argmax tie semantics (first max) are reproduced manually (min
-  index among maxima) — Mosaic's argmax lane order is unspecified.
+- **int64-free**: Mosaic has no 64-bit types. Resource quantities are
+  rescaled per dimension by the GCD of every value in the session —
+  EXACT: fit comparisons, least-allocated's `(cap-req)*100 // cap` and
+  balanced's fractions are invariant under a common rescale. A spec
+  whose requests the live GCD does not divide refines it in place (one
+  elementwise launch multiplies the utilization rows).
+- **BalancedAllocation is exact**: the reference computes
+  `int((1 - |c/C - m/M|) * 100)` in float64. With irregular requests an
+  f32 evaluation lands on the other side of an integer a few times in
+  ten thousand node states. The kernel takes the exact rational floor in
+  int32 and then reproduces float64's own rounding at the only states
+  where the two can differ — the states whose exact value IS an integer
+  — from a short host-enumerated list (see _balanced_quirks).
+- **gather-free spread counts**: per-node count rows; zone expansion and
+  scored-set registration are MXU matvecs against a static one-hot.
+- float64 score math that remains (PTS topology weights, IPA
+  normalization) runs in float32; parity is pinned by tests.
+- first-max tie-break (min index among maxima) is explicit — Mosaic's
+  argmax lane order is unspecified.
+
+The mesh path (ops/sharded_scan.py) still runs the dense per-template
+layout (ops/dense_remap.py): it admits nothing and rebuilds on a new
+spec, as the jnp HoistedSession does.
 
 Reference frame: same as ops/hoisted.py — this replaces
 findNodesThatPassFilters + RunScorePlugins (generic_scheduler.go:235,
-framework.go:723) for template-stamped batchable pods, restructured as a
-single accelerator program.
+framework.go:723) for batchable pending pods of any mix of specs,
+restructured as a single accelerator program.
 """
 
 from __future__ import annotations
 
 import functools
-import math
-import os as _os
-import time as _time
 import logging
+import math
 import threading
-from typing import Dict, List, NamedTuple, Optional
-
-from ..utils import knobs
+import time as _time
+from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -54,10 +90,12 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..utils import knobs
 from .hoisted import (
+    _eval_reqs_batch_np,
     _session_prologue,
     _stack_templates,
-    match_matrices_np,
+    batch_bucket,
     template_fingerprint,
     templates_have_ports,
     templates_have_terms,
@@ -70,7 +108,11 @@ SUB = 8
 POS_BIG = 2 ** 30
 NEG_BIG = -(2 ** 30)
 
-CARRY_KEYS = ("requested", "nzpc", "cnt_fn", "cnt_sn")
+CARRY_KEYS = ("requested", "nzpc", "cnt")
+ADMIT_CHUNK = 8     # specs per prologue launch (one compiled shape)
+WRITE_CHUNK = 64    # rows per table write (one compiled shape per table)
+MAX_QUIRKS = 1024   # balanced float64-quirk states the kernel can list
+TOUCH_W = 4         # words per touch entry: count row, pair row, weight, src row
 
 _MISSING = object()  # exec-cache sentinel (None = AOT failed, use jit)
 
@@ -78,8 +120,8 @@ logger = logging.getLogger(__name__)
 
 
 class PallasUnsupported(Exception):
-    """This cluster/template shape can't ride the pallas path; callers
-    fall back to the jnp HoistedSession.
+    """This cluster/spec shape can't ride the pallas path; callers fall
+    back to the jnp HoistedSession.
 
     `reason` is a FIXED slug per raise site (no interpolated shape
     numbers) — it feeds the scheduler_tpu_session_builds_total metric's
@@ -90,15 +132,14 @@ class PallasUnsupported(Exception):
         self.reason = reason
 
 
+class TableFull(PallasUnsupported):
+    """A live session cannot admit this spec (a capacity is used up, or
+    the spec's arrays have another shape than the table's): the backend
+    rebuilds, counted under `reason`."""
+
+
 def _ceil(n: int, m: int) -> int:
     return ((n + m - 1) // m) * m
-
-
-def _pad_tc(a: np.ndarray, t_n: int) -> np.ndarray:
-    """[T, X<=8] -> [T, 8] zero-padded (per-term scalar tables)."""
-    out = np.zeros((t_n, SUB), a.dtype)
-    out[:, : a.shape[1]] = a
-    return out
 
 
 def _pad2(a: np.ndarray, rows: int = SUB, lanes: int = LANE) -> np.ndarray:
@@ -119,1872 +160,539 @@ def _gcd_all(*arrays) -> int:
     return max(g, 1)
 
 
-@functools.partial(jax.jit, static_argnames=("n",))
-def _pack_group(n: int, *arrs):
-    return jnp.concatenate([a.ravel() for a in arrs])
+def _host_prologue(cluster: Dict, arrays: List[Dict], dyn_ipa: bool) -> Dict:
+    """The hoisted prologue (ops/hoisted.py _prologue) for a chunk of
+    specs, run on the HOST's own XLA device against the encoding's host
+    arrays, outputs as numpy.
+
+    The prologue is an int64/float64 program over every pod row of the
+    encoding. The chip has neither type: there it compiled for minutes
+    cold (emulated 64-bit) and wanted the whole encoding uploaded
+    (~160 MB at 120 000 pod rows) before each use. The host has both
+    types and already holds the arrays; a chunk of 8 specs takes ~0.2 s
+    and an admission inside a window moves nothing to the chip but the
+    rows it wrote."""
+    try:
+        cpu = jax.devices("cpu")[0]
+    except RuntimeError:   # a process held to one platform: use that one
+        cpu = jax.devices()[0]
+    with jax.default_device(cpu):
+        S = _session_prologue(
+            {k: np.asarray(v) for k, v in cluster.items()},
+            _stack_templates(arrays), dyn_ipa=dyn_ipa)
+        return {k: np.asarray(v) for k, v in S.items()}
 
 
-def _fetch_packed(tree: Dict) -> Dict:
-    """Device->host fetch of a dict of device arrays in ONE transfer per
-    dtype group, instead of ~80 prologue outputs one np.asarray (one
-    blocking transfer) at a time."""
-    items = [(k, v) for k, v in tree.items()]
-    by_dtype: Dict = {}
-    for k, v in items:
-        by_dtype.setdefault(jnp.asarray(v).dtype, []).append(k)
-    out: Dict = {}
-    for dtype, keys in by_dtype.items():
-        arrs = [jnp.asarray(tree[k]) for k in keys]
-        packed = np.asarray(_pack_group(len(arrs), *arrs))
-        off = 0
-        for k, a in zip(keys, arrs):
-            size = int(np.prod(a.shape)) if a.shape else 1
-            out[k] = packed[off:off + size].reshape(a.shape)
-            off += size
+# ---------------------------------------------------------------------------
+# BalancedAllocation: where float64 and the exact rational floor part ways
+
+_QUIRK_CACHE: Dict[Tuple[int, int], np.ndarray] = {}
+
+
+def _balanced_quirks(cap_c: int, cap_m: int) -> np.ndarray:
+    """[(c, m)] of the node states (non-zero requested cpu c < cap_c and
+    memory m < cap_m, pod included, in rescaled units) at which the
+    reference's float64 `int((1 - |c/C - m/M|) * 100)` reads ONE LESS than
+    the exact rational floor.
+
+    float64 carries ~1e-16 of relative error and the exact value's
+    distance to the next integer is at least 1/(C*M) unless it IS an
+    integer, so the two can differ only at exact-integer states (checked
+    over whole grids in tests/test_pallas_table.py). Those states solve a
+    linear congruence and are few; float64 is evaluated there, here, the
+    way the reference evaluates it."""
+    key = (int(cap_c), int(cap_m))
+    hit = _QUIRK_CACHE.get(key)
+    if hit is not None:
+        return hit
+    C, M = key
+    out = np.zeros((0, 2), np.int64)
+    if C > 0 and M > 0:
+        den = C * M
+        c = np.arange(C, dtype=np.int64)[:, None]
+        pts = []
+        # rows of the grid in slabs: the whole grid is at most 2^31 / 100
+        step = max(1, (1 << 22) // max(M, 1))
+        m = np.arange(M, dtype=np.int64)[None, :]
+        for lo in range(0, C, step):
+            cs = c[lo:lo + step]
+            num = MAX_NODE_SCORE * (den - np.abs(cs * M - m * C))
+            ci, mi = np.nonzero(num % den == 0)
+            if len(ci):
+                pts.append(np.stack([cs[ci, 0], m[0, mi]], axis=1))
+        if pts:
+            p = np.concatenate(pts)
+            cf = p[:, 0] / np.float64(C)
+            mf = p[:, 1] / np.float64(M)
+            f64 = ((1.0 - np.abs(cf - mf)) * MAX_NODE_SCORE).astype(np.int64)
+            exact = (MAX_NODE_SCORE
+                     * (den - np.abs(p[:, 0] * M - p[:, 1] * C))) // den
+            if (np.abs(f64 - exact) > 1).any() or (f64 > exact).any():
+                raise PallasUnsupported(
+                    "float64 balanced score strays from the exact floor "
+                    "by more than its last unit", reason="balanced-float64")
+            out = p[f64 != exact]
+    _QUIRK_CACHE[key] = out
     return out
 
 
-def batch_prologue(fps: Dict, tp_np: Dict, pod_arrays_list: List[Dict],
-                   minimum: int, require_unbound: bool = True):
-    """Shared host-side batch prep for the session schedule paths
-    (PallasSession.schedule, _dispatch_mode, ShardedPallasSession):
-    pow2 length bucket (each distinct Bp is a fresh compile; production
-    batches are ragged), template ids, and the match matrices — computed
-    on HOST (match_matrices_np): an on-device compute + readback here
-    would wait out the previous batch's scan and kill the
-    dispatch/harvest overlap. Returns (Bp, tmpl[Bp], mfa, msa)."""
-    from .hoisted import batch_bucket
-
-    B = len(pod_arrays_list)
-    Bp = batch_bucket(B, minimum=minimum)
-    tmpl = np.zeros(Bp, np.int32)
-    for i, pa in enumerate(pod_arrays_list):
-        if require_unbound and bool(np.asarray(pa["has_node_name"])):
-            raise ValueError("session pods must be unbound")
-        tmpl[i] = fps[template_fingerprint(pa)]
-    mfa, msa = match_matrices_np(tp_np, pod_arrays_list)
-    return Bp, tmpl, mfa, msa
+# ---------------------------------------------------------------------------
+# the spec record
 
 
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _carry_delta_scan(carry, prow_f, prow_s, src_rows, perno_rows, xs):
-    """Apply a batch of cluster-event deltas to a pallas-layout carry in
-    ONE fused launch (shared by PallasSession and the sharded mirror —
-    the math is layout-identical, only Np differs). Each event is the
-    jnp twin of the kernel's _apply_updates with `best := node` and a
-    sign folded into the payload: utilization columns plus the same-pair
-    count masks (prow == prow[:, node], -1 lanes never update, exactly
-    the kernel's gating), with cnt_sn's perno/src factor reproduced
-    verbatim. lax.scan keeps the launch count at ONE regardless of the
-    event count; padding rows are node 0 with all-zero payloads."""
+class _Layout(NamedTuple):
+    """Field offsets inside one spec record (W int32 words)."""
 
-    def step(c, x):
-        c = dict(c)
-        n = x["node"]
-        c["requested"] = c["requested"].at[:, n].add(x["dres"])
-        c["nzpc"] = c["nzpc"].at[:, n].add(x["dnzpc"])
-        pf_b = jax.lax.dynamic_index_in_dim(prow_f, n, axis=1)  # [TCp, 1]
-        same_f = (prow_f == pf_b) & (prow_f >= 0)
-        c["cnt_fn"] = c["cnt_fn"] + x["mf"][:, None] * same_f
-        ps_b = jax.lax.dynamic_index_in_dim(prow_s, n, axis=1)
-        same_s = (prow_s == ps_b) & (prow_s >= 0)
-        src_b = jax.lax.dynamic_index_in_dim(src_rows, n, axis=1)
-        factor = perno_rows + (1 - perno_rows) * src_b       # [TCp, 1]
-        c["cnt_sn"] = c["cnt_sn"] + x["ms"][:, None] * factor * same_s
-        return c, None
+    REQ: int
+    CHK: int
+    HAS: int
+    NZ: int
+    IPAP: int
+    STAT: int
+    PF: int
+    PS: int
+    FS: int
+    SS: int
+    TCH: int
+    IPA: int
+    W: int
 
-    carry, _ = jax.lax.scan(step, carry, xs)
-    return carry
+
+PF_W = 6   # valid, skew, self_match, count row, registered row, key-on-node row
+PS_W = 9   # valid, skew, first, key, perno, count row, zone-valid row, key-on-node row, zrow
+IPA_W = 13  # has_aff, self_match_all, aff_total, fail row, all-keys row,
+#             repel (off, n), anti terms (off, n), aff terms (off, n),
+#             score row, presence row
+(ST_MASK, ST_RAW_IPA, ST_TAINT, ST_NODEAFF, ST_IMAGE, ST_AVOID, ST_HAS_ALL,
+ ST_SRC) = range(8)
+
+
+def _layout(R: int, C: int, ipa: bool) -> _Layout:
+    stat = 2 * R + 4
+    pf = stat + 8
+    ps = pf + PF_W * C
+    fs = ps + PS_W * C
+    ss = fs + C * C
+    tch = ss + C * C
+    ipa_o = tch + 2
+    return _Layout(REQ=0, CHK=R, HAS=2 * R, NZ=2 * R + 1, IPAP=2 * R + 3,
+                   STAT=stat, PF=pf, PS=ps, FS=fs, SS=ss, TCH=tch,
+                   IPA=ipa_o, W=ipa_o + (IPA_W if ipa else 0))
 
 
 class _Cfg(NamedTuple):
     """Value-hashable kernel configuration — the ONLY static jit input.
-    Sessions with equal shapes/weights share one compiled program; the
-    cluster statics flow in as dynamic args (see _dispatch)."""
+    Sessions with equal capacities/weights share one compiled program;
+    everything a spec brings flows in as dynamic args (see _dispatch)."""
 
-    shapes: tuple
+    shapes: tuple   # (Tcap, PC, Np, R, C, RC, SRc, ZRc, K, LB)
     weights: tuple
-    ur: int
-    carry_keys: tuple
+    ipa: bool
+    bal_int: bool   # exact int32 balanced (else f32: caps too large)
     interpret: bool
-    mode: str = "full"  # full | eval | apply (see _build_kernel)
-    mk: int = 1  # multi-pod step width (full mode only; pow2, <= 64)
-
-
-class PallasSession:
-    """HoistedSession-compatible API over the single-launch kernel.
-
-    Semantics: identical to ops/hoisted.py HoistedSession (same
-    prologue, same carry discipline) — parity pinned by
-    tests/test_pallas_scan.py. Raises PallasUnsupported when the cluster
-    shape needs a fallback (e.g. a shared-value topology key with more
-    than 128 distinct values).
-    """
-
-    # KTPU_EXPLAIN: the Mosaic kernel's scan does not surface per-plugin
-    # mask/score sections — explain mode rides the jnp hoisted session
-    # (TPUBackend demotes with session_builds{reason="explain"})
-    supports_explain = False
-
-    @staticmethod
-    def explain_payload(ys):
-        return None
-
-    def __init__(self, cluster: Dict, template_arrays_list: List[Dict],
-                 weights: Optional[Dict[str, int]] = None,
-                 interpret: bool = False,
-                 multipod_k: Optional[int] = None,
-                 launches_kernel: bool = True):
-        """launches_kernel=False builds the host-side remap only (the
-        sharded session reuses it and runs jnp under shard_map): the
-        Mosaic kernel's VMEM budget does not apply to it."""
-        from .kernel import multipod_k as _resolve_mk
-
-        # multi-pod scan steps (conflict-SUFFIX contract: the kernel
-        # defers commits within a group, detects conflicts with the
-        # shared algebra, and leaves the conflicted suffix uncommitted
-        # + flagged in out row 3 for the backend's host replay).
-        # Opt-in (KTPU_MULTIPOD_K or the argument): a suffix costs a
-        # relaunch, see kernel.multipod_k.
-        self.multipod_k = _resolve_mk(multipod_k, suffix_replay=True)
-        if templates_have_ports(template_arrays_list):
-            # the jnp HoistedSession carries host-port tables; the pallas
-            # kernel does not (yet) — signal a fallback, not an error
-            raise PallasUnsupported(
-                "templates with host ports ride the jnp hoisted session",
-                reason="host-ports",
-            )
-        # affinity-term templates ARE supported: the D1-D5 deltas
-        # (ops/hoisted.py term-machinery block) ride per-(template, key)
-        # per-node count carries updated with the same same-pair-mask
-        # trick as the PTS counts — see _build_ipa below
-        self.dyn_ipa = templates_have_terms(template_arrays_list)
-        self.weights = dict(weights or DEFAULT_WEIGHTS)
-        self.interpret = interpret
-        self._fps = {
-            template_fingerprint(t): i for i, t in enumerate(template_arrays_list)
-        }
-        # pad the template axis to a pow2 bucket (min 2) with inert
-        # copies of template 0 (never referenced by a pod's tmpl index):
-        # a workload introducing its 2nd..Nth template then reuses the
-        # compiled program instead of paying a mid-window recompile —
-        # the unschedulable-churn bench lost 21s of its 23s window to
-        # exactly that rebuild
-        from ..models.vocab import bucket_capacity
-
-        Tb = bucket_capacity(len(template_arrays_list), minimum=2)
-        template_arrays_list = list(template_arrays_list) + [
-            template_arrays_list[0]
-        ] * (Tb - len(template_arrays_list))
-        # first-max tie-break + score output rely on f32-exact totals:
-        # every plugin score is <= MAX_NODE_SCORE after normalization
-        if sum(abs(int(v)) for v in self.weights.values()) \
-                * (MAX_NODE_SCORE + 1) >= 2 ** 24:
-            raise PallasUnsupported("weights too large for exact f32 totals",
-                                    reason="weights-exceed-f32")
-        tp = _stack_templates(template_arrays_list)
-        self._tp = tp
-        # numpy copies of the selector tables schedule() evaluates on
-        # HOST per batch (match_matrices_np) — the jnp path would block
-        # the dispatch behind the previous batch's scan (device stream
-        # ordering), serializing the scheduler's 1-deep pipeline
-        self._tp_np = {
-            k: np.asarray(tp[k])
-            for k in ("ptsf_op", "ptsf_rkey", "ptsf_pairs",
-                      "ptss_op", "ptss_rkey", "ptss_pairs", "self_ns")
-        }
-        from .hoisted import TERM_NP_KEYS
-
-        # delta classifier input (tpu_backend): a foreign pod matching a
-        # template's own IPA terms perturbs the prologue statics, so its
-        # event cannot ride the carry-delta path
-        self._term_np = (
-            {k: np.asarray(tp[k]) for k in TERM_NP_KEYS}
-            if self.dyn_ipa else None
-        )
-        S = _fetch_packed(
-            _session_prologue(cluster, tp, dyn_ipa=self.dyn_ipa)
-        )
-        c = _fetch_packed(cluster)
-        self._build(c, S)
-        self._ipa = self._build_ipa(c, S, tp) if self.dyn_ipa else None
-        if self._ipa is not None:
-            # SMEM scalar extension: [T,3] has_aff/self_match_all/aff_total,
-            # then anti_valid/aff_valid [T,8] each, then the w45 GCD
-            # scale (offsets in _build_kernel). The scale rides SMEM, not
-            # the static config: sessions whose weights differ only by a
-            # common factor share one compiled program.
-            extra = np.concatenate([
-                np.stack([
-                    self._ipa["has_aff"], self._ipa["self_match_all"],
-                    self._ipa["aff_total"],
-                ], axis=1).reshape(-1),
-                self._ipa["anti_valid"].reshape(-1),
-                self._ipa["aff_valid"].reshape(-1),
-                np.array([self._ipa["w45_scale"]]),
-            ]).astype(np.int32)
-            self._scalars = np.concatenate([self._scalars, extra])
-        self._carry = None
-        self._bundle = None
-        # (Bp, mode) -> AOT-compiled executable (None = AOT unavailable,
-        # dispatch through jit). Shared between the serving path and the
-        # warm_buckets daemon thread; plain dict ops are GIL-atomic and a
-        # rare duplicate compile is absorbed by the persistent cache.
-        self._exec: Dict = {}
-        # (Bp, mode) -> text of the error that pinned that entry to None
-        # (AOT compile rejected, or the executable refused its arguments).
-        # The jit path still serves — and fails the same way one frame
-        # later if the kernel itself is at fault — but the compiler's
-        # message is kept: logged where it happened, and read by
-        # chip_smoke.py / the bench rows, which fail on any entry here.
-        self.exec_errors: Dict = {}
-        self._warm_stop = threading.Event()
-        if launches_kernel and jax.default_backend() == "tpu":
-            # the grid-less kernel holds every operand whole in VMEM: a
-            # cluster too wide for the core is a clean downgrade here,
-            # not a compiler error at the first dispatch (off-TPU the
-            # session only interprets, or is built for its host half)
-            need = _kernel_vmem_bytes(
-                *self._get_bundle()[1:], self._carry_struct(), 2048)
-            if _vmem_request(need) > _vmem_cap():
-                raise PallasUnsupported(
-                    f"kernel operands ({need >> 20} MiB) exceed the "
-                    f"core's VMEM", reason="vmem-budget")
-
-    # -- host-side prologue remap ------------------------------------------
-
-    def _build(self, c: Dict, S: Dict) -> None:
-        T, N = S["static_mask"].shape
-        C = S["f_valid"].shape[1]
-        self.T, self.C, self.N = T, C, N
-        Np = _ceil(N, LANE)
-        self.Np = Np
-        CP = SUB  # constraint rows padded to 8 per template: dynamic
-        # (CP, Np) block reads at t*CP are provably 8-aligned for Mosaic
-        if C > CP:
-            raise PallasUnsupported(f"{C} constraints > {CP} per template",
-                                    reason="too-many-constraints")
-        TC = T * C
-        TCp = T * CP
-        self.CP = CP
-        self.TCp = TCp
-        R = c["alloc"].shape[1]
-        self.R = R
-        tp = self._tp
-
-        # ---- exact per-dimension GCD rescale to int32 ----
-        alloc = c["alloc"].astype(np.int64).T.copy()            # [R, N]
-        requested = c["requested"].astype(np.int64).T.copy()
-        req = np.asarray(tp["req"]).astype(np.int64)            # [T, R]
-        nz_requested = c["nz_requested"].astype(np.int64).T.copy()  # [2, N]
-        nz_req = np.asarray(tp["nz_req"]).astype(np.int64)      # [T, 2]
-        # per-dimension rescale factors survive the build: incoming
-        # session deltas (tpu_backend carry patches) must divide by the
-        # SAME gcd to stay exact — an indivisible delta is classified
-        # structural instead (delta_compatible)
-        self._gcd = np.ones(R, np.int64)
-        for r in range(R):
-            extra = [nz_requested[r], nz_req[:, r]] if r < 2 else []
-            g = _gcd_all(alloc[r], requested[r], req[:, r], *extra)
-            self._gcd[r] = g
-            alloc[r] //= g
-            requested[r] //= g
-            req[:, r] //= g
-            if r < 2:
-                nz_requested[r] //= g
-                nz_req[:, r] //= g
-        hi = max((int(a.max(initial=0)) for a in
-                  (alloc, requested, req, nz_requested, nz_req)), default=0)
-        if hi * (MAX_NODE_SCORE + 1) >= 2 ** 31:
-            raise PallasUnsupported(
-                f"rescaled resource magnitude {hi} too large for int32",
-                reason="resource-magnitude")
-
-        self._alloc = _pad2(alloc.astype(np.int32))             # [Rp, Np]
-        self._requested0 = _pad2(requested.astype(np.int32))
-        nzpc = np.zeros((SUB, N), np.int64)
-        nzpc[0] = nz_requested[0]
-        nzpc[1] = nz_requested[1]
-        nzpc[2] = c["pod_count"].astype(np.int64)
-        nzpc[3] = c["allowed_pods"].astype(np.int64)
-        self._nzpc0 = _pad2(nzpc.astype(np.int32))              # [8, Np]
-        self._req_s = req.astype(np.int32)
-        self._nz_req_s = nz_req.astype(np.int32)
-        self._req_check_s = np.asarray(tp["req_check"]).astype(np.int32)
-        self._req_has_any_s = np.asarray(tp["req_has_any"]).astype(np.int32)
-
-        # ---- per-template [T, N] statics: row t*SR+i ----
-        stat_rows = [
-            S["static_mask"], S["raw_ipa"], S["cnt_taint"],
-            S["cnt_nodeaff"], S["sc_image"], S["sc_avoid"],
-            np.zeros_like(S["static_mask"]), S["s_src"],
-        ]
-        if any(np.abs(a.astype(np.int64)).max(initial=0) >= POS_BIG
-               for a in stat_rows):
-            # POS_BIG (2^30), not 2^31: the kernel's min/max sentinels must
-            # stay strictly above any genuine value
-            raise PallasUnsupported("static score magnitude exceeds sentinel",
-                                    reason="score-magnitude")
-        SR = len(stat_rows)  # == 8
-        self.SR = SR
-        stat = np.stack([a.astype(np.int32) for a in stat_rows], axis=1)
-        self._stat = _pad2(stat.reshape(T * SR, N))             # [T*SR, Np]
-
-        # ---- PTS: per-constraint representation ----
-        valid_nodes = c["valid"].astype(bool)
-
-        def col(side, t, cc):
-            return S[f"{side}_pair_cn"][t, :, cc]
-
-        def node_distinct(column):
-            real = column[valid_nodes]
-            return len(real) == 0 or len(np.unique(real)) == len(real)
-
-        uid_of: Dict[bytes, int] = {}
-        uids: List[np.ndarray] = []
-
-        def classify(side, force_host=None, intern=True):
-            """-> (keyid [T,C], perno [T,C] bool): perno = per-node count
-            representation; otherwise compact key `keyid`. With
-            intern=False only perno is computed (the filter path works
-            entirely per-node and must not consume the key/value budgets
-            that exist for score-side registration)."""
-            keyid = np.full((T, C), -1, np.int32)
-            perno = np.zeros((T, C), bool)
-            for t in range(T):
-                for cc in range(C):
-                    if not S[f"{side}_valid"][t, cc]:
-                        continue
-                    column = col(side, t, cc)
-                    is_host = (force_host[t, cc] if force_host is not None
-                               else node_distinct(column))
-                    if is_host:
-                        perno[t, cc] = True
-                        continue
-                    if not intern:
-                        continue
-                    key = column.tobytes()
-                    u = uid_of.get(key)
-                    if u is None:
-                        u = len(uids)
-                        uid_of[key] = u
-                        uids.append(column.copy())
-                    keyid[t, cc] = u
-            return keyid, perno
-
-        # score side MUST follow the prologue's hostname flag (it selects
-        # the log(n_scored) weight semantics, not just a representation)
-        s_hostflag = S["s_hostname"].astype(bool)
-        fk, fh = classify("f", intern=False)
-        sk, sh = classify("s", force_host=s_hostflag)
-        # a non-hostname score constraint whose pairs are node-distinct
-        # would blow the 128-lane vocab — unsupported
-        self._f_keyid, self._f_perno = fk, fh
-        self._s_keyid, self._s_perno = sk, sh
-
-        K = max(len(uids), 1)
-        if len(uids) > 4:
-            raise PallasUnsupported(f"{len(uids)} distinct shared-value keys",
-                                    reason="too-many-topology-keys")
-        self.K = K
-        onehot = np.zeros((K, Np, VZ), np.float32)
-        zof: List[Dict[int, int]] = []
-        for u, column in enumerate(uids):
-            vals = np.unique(column[valid_nodes])
-            vals = vals[vals > 0]
-            if len(vals) > VZ:
-                raise PallasUnsupported(
-                    f"topology key {u} has {len(vals)} values > {VZ}",
-                    reason="too-many-topology-values")
-            m = {int(v): z for z, v in enumerate(vals)}
-            zof.append(m)
-            zid = np.array([m.get(int(v), -1) for v in column], np.int32)
-            ok = (zid >= 0) & valid_nodes
-            onehot[u, np.arange(N)[ok], zid[ok]] = 1.0
-        self._onehot = onehot
-
-        def gather_rows(side, cnt_tcv, perno, perno_src=None):
-            """[T, C, Vnp] pair counts -> per-NODE count rows [TCp, Np]:
-            row (t*CP+c), lane n = count of the pair node n belongs to."""
-            out = np.zeros((TCp, Np), np.int32)
-            for t in range(T):
-                for cc in range(C):
-                    row = t * CP + cc
-                    if perno[t, cc] and perno_src is not None:
-                        out[row, :N] = perno_src[t, cc]
-                    else:
-                        out[row, :N] = cnt_tcv[t, cc][col(side, t, cc)]
-            return out
-
-        self._cnt_fn0 = gather_rows("f", S["f_cnt0"], fh)
-        self._cnt_sn0 = gather_rows(
-            "s", S["s_cnt0"], sh,
-            perno_src=S["h_cnt0"].astype(np.int64))
-
-        # static per-node structures
-        prow_f = np.full((TCp, Np), -1, np.int32)
-        prow_s = np.full((TCp, Np), -1, np.int32)
-        regrow_f = np.zeros((TCp, Np), np.int32)
-        zvalid_node_s = np.zeros((TCp, Np), np.int32)
-        zvalid_s = np.zeros((TCp, VZ), np.int32)
-        for t in range(T):
-            for cc in range(C):
-                row = t * CP + cc
-                if S["f_valid"][t, cc]:
-                    column = col("f", t, cc)
-                    prow_f[row, :N] = np.where(valid_nodes, column, -1)
-                    regrow_f[row, :N] = S["f_reg_real"][t, cc][column]
-                if S["s_valid"][t, cc]:
-                    column = col("s", t, cc)
-                    prow_s[row, :N] = np.where(valid_nodes, column, -1)
-                    if not sh[t, cc] and sk[t, cc] >= 0:
-                        zvalid_node_s[row, :N] = (column > 0) & valid_nodes
-                        for pair, zz in zof[sk[t, cc]].items():
-                            zvalid_s[row, zz] = 1
-        self._prow_f = prow_f
-        self._prow_s = prow_s
-        self._regrow_f = regrow_f
-        self._zvalid_node_s = zvalid_node_s
-        self._zvalid_s = zvalid_s
-        if max(prow_f.max(), prow_s.max()) >= 2 ** 24:
-            raise PallasUnsupported("pair ids exceed exact-f32 range",
-                                    reason="pair-ids-exceed-f32")
-
-        def tcn(a):  # [T, N, C] bool -> [TCp, Np] i32 (stride CP)
-            out = np.zeros((TCp, Np), np.int32)
-            for t in range(T):
-                for cc in range(C):
-                    out[t * CP + cc, :N] = a[t, :, cc]
-            return out
-
-        self._konn_f = tcn(S["f_key_on_node"])
-        self._konn_s = tcn(S["s_key_on_node"])
-        # session-delta statics: row-expanded s_src (score-count node
-        # eligibility per row's template) and the per-row perno flag —
-        # the jnp twin of the kernel's _apply_updates factor, used by
-        # apply_deltas to patch cnt_sn exactly as an in-scan assume would
-        src_rows = np.zeros((TCp, Np), np.int32)
-        perno_rows = np.zeros((TCp, 1), np.int32)
-        for t in range(T):
-            for cc in range(C):
-                src_rows[t * CP + cc, :N] = S["s_src"][t].astype(np.int32)
-                perno_rows[t * CP + cc, 0] = int(self._s_perno[t, cc])
-        self._src_rows = src_rows
-        self._perno_rows = perno_rows
-        self._delta_statics = None  # device copies, built on first apply
-        sha = np.zeros((_ceil(T, SUB), Np), np.int32)
-        sha[:T, :N] = S["s_has_all"].astype(np.int32)
-        self._shasall = sha
-        vn = np.zeros((SUB, Np), np.int32)
-        vn[:, :N] = c["valid"].astype(np.int32)[None, :]
-        self._valid_n = vn
-
-        # row -> template one-hot [T, TCp, VZ] and identity [TCp, LANE]
-        if TCp > LANE:
-            raise PallasUnsupported(f"T*CP={TCp} exceeds {LANE} match lanes",
-                                    reason="too-many-match-lanes")
-        rowt = np.zeros((T, TCp, VZ), np.int32)
-        for t in range(T):
-            rowt[t, t * CP:t * CP + C, :] = 1
-        self._rowt = rowt
-        # identity mapping match-lane (t*CP+cc) -> row (t*CP+cc)
-        eye = np.zeros((TCp, LANE), np.float32)
-        for i in range(TCp):
-            if i < LANE:
-                eye[i, i] = 1.0
-        self._eye = eye
-
-        # multipod IPA interference superset (filled by _build_ipa when
-        # the session carries term templates; zeros otherwise): row u,
-        # lane t != 0 means assuming a template-u pod can perturb a
-        # template-t evaluation through the D1-D5 term machinery — the
-        # multipod conflict test then replays instead of speculating
-        self._gmat = np.zeros((_ceil(T, SUB), LANE), np.float32)
-
-        # SMEM scalar table
-        self._scalars = self._pack_scalars(S)
-
-    # ktpu: allow-sync(session build: one-time host packing of affinity planes, runs before first dispatch)
-    def _build_ipa(self, c: Dict, S: Dict, tp: Dict) -> Dict:
-        """InterPodAffinity term machinery for the single-launch kernel.
-
-        The hoisted scan's D1-D5 deltas (ops/hoisted.py term-machinery
-        block) all reduce to per-(assumed-template u, topology key ki)
-        counts gathered at each node's (ki, value) group. The pallas port
-        keeps those counts PER NODE (the same representation trick as the
-        PTS cnt_fn/cnt_sn rows): carry row (u*8 + ki) of `ucnt` holds,
-        for every node n, the number of session-assumed u-pods in n's
-        ki-group — updated on assume with a same-pair mask from `prow_ipa`
-        (pair id per node per key; -1 where the node lacks the key, which
-        makes the nkey gating implicit: rows never accumulate on keyless
-        nodes). `kcnt` row (u*8+ki) carries the scalar total (lanes all
-        equal). Every D1-D5 read then becomes a STATIC gate/weight matrix
-        (template x term match booleans from _term_gates, resolved host-
-        side) times ucnt — one MXU dot each:
-          D1 fail-existing  : g1[t] . (ucnt > 0) > 0
-          D2 own-anti counts: wanti[t-block] @ ucnt  (+ static anti rows)
-          D3 own-aff counts : waff[t-block] @ ucnt   (+ static aff rows)
-          D4+D5 score       : w45[t] @ ucnt  (weights pre-folded)
-          presence flags    : gpres[t] . rowany(ucnt > 0)
-          aff_total delta   : w3tot[t] . kcnt[:, 0]
-        Exactness: counts are integers in f32 (exact < 2^24); the 0/1
-        dots are bounded by 8 * count; the score dot is guarded below.
-        """
-        T, N, Np = self.T, self.N, self.Np
-        aa_key = np.asarray(tp["ipaaa_key"])
-        aa_valid = np.asarray(tp["ipaaa_valid"]).astype(bool)
-        a_key = np.asarray(tp["ipaa_key"])
-        a_valid = np.asarray(tp["ipaa_valid"]).astype(bool)
-        p_key = np.asarray(tp["ipap_key"])
-        p_valid = np.asarray(tp["ipap_valid"]).astype(bool)
-        p_w = np.asarray(tp["ipap_weight"]).astype(np.int64)
-        if aa_key.shape[1] > SUB or a_key.shape[1] > SUB:
-            raise PallasUnsupported(
-                f"{max(aa_key.shape[1], a_key.shape[1])} required "
-                f"(anti-)affinity terms > {SUB} per template",
-                reason="too-many-ipa-terms")
-        # distinct topology keys across every template's valid terms
-        keys: set = set()
-        for k_tbl, v_tbl in ((aa_key, aa_valid), (a_key, a_valid),
-                             (p_key, p_valid)):
-            keys.update(int(x) for x in k_tbl[v_tbl])
-        ki_list = sorted(keys)
-        if len(ki_list) > SUB:
-            raise PallasUnsupported(
-                f"{len(ki_list)} IPA topology keys > {SUB}",
-                reason="too-many-ipa-keys")
-        ki_of = {k: i for i, k in enumerate(ki_list)}
-        UR = T * SUB  # ucnt rows: (u * 8 + ki)
-
-        pok = c["pair_of_key"].astype(np.int64)  # [N, K]
-        nkey = c["nkey"].astype(bool)
-        valid_nodes = c["valid"].astype(bool)
-        prow_ipa = np.full((SUB, Np), -1, np.int32)
-        for i, key in enumerate(ki_list):
-            ok = nkey[:, key] & valid_nodes
-            prow_ipa[i, :N] = np.where(ok, pok[:, key], -1)
-        if prow_ipa.max(initial=0) >= 2 ** 24:
-            raise PallasUnsupported("IPA pair ids exceed exact-f32 range",
-                                    reason="pair-ids-exceed-f32")
-
-        M_anti = np.asarray(S["M_anti"]).astype(bool)   # [T, TAA, T]
-        M_aff = np.asarray(S["M_aff"]).astype(bool)     # [T, TA, T]
-        M_pref = np.asarray(S["M_pref"]).astype(bool)   # [T, TP, T]
-        match_all = np.asarray(S["match_all"]).astype(bool)  # [T, T]
-        hard_w = int(np.asarray(c["hard_pod_affinity_weight"]))
-
-        # multipod template-interference superset (the host twin of the
-        # hoisted prologue's G_ipa; symmetrized — a false positive only
-        # costs a replay, never a wrong decision)
-        a1 = M_anti.any(axis=1)
-        a2 = M_aff.any(axis=1)
-        a3 = M_pref.any(axis=1)
-        g = (a1 | a1.T | a2 | a2.T | a3 | a3.T | match_all | match_all.T)
-        self._gmat[:T, :T] = g.astype(np.float32)
-
-        t_pad = _ceil(T, SUB)  # per-template matrices: row t (T can be >8)
-        g1 = np.zeros((t_pad, UR), np.float32)
-        wanti = np.zeros((T * SUB, UR), np.float32)
-        waff = np.zeros((T * SUB, UR), np.float32)
-        w3tot = np.zeros((t_pad, UR), np.float32)
-        w45_i = np.zeros((t_pad, UR), np.int64)
-        gpres = np.zeros((t_pad, UR), np.float32)
-
-        def cx(u, key):
-            return u * SUB + ki_of[int(key)]
-
-        for t in range(T):
-            # D1: assumed u-pods' anti terms repel t where t matches them
-            for u in range(T):
-                for tau in range(aa_key.shape[1]):
-                    if aa_valid[u, tau] and M_anti[u, tau, t]:
-                        g1[t, cx(u, aa_key[u, tau])] = 1.0
-            # D2: assumed pods counting toward t's own anti terms
-            for tau in range(aa_key.shape[1]):
-                if not aa_valid[t, tau]:
-                    continue
-                for u in range(T):
-                    if M_anti[t, tau, u]:
-                        wanti[t * SUB + tau, cx(u, aa_key[t, tau])] = 1.0
-            # D3: assumed pods matching ALL of t's affinity terms
-            for tau in range(a_key.shape[1]):
-                if not a_valid[t, tau]:
-                    continue
-                for u in range(T):
-                    if match_all[t, u]:
-                        waff[t * SUB + tau, cx(u, a_key[t, tau])] = 1.0
-                        w3tot[t, cx(u, a_key[t, tau])] += 1.0
-            # D4: assumed pods' score terms vs t (required-aff at
-            # hardPodAffinityWeight; preferred at signed weight) and
-            # D5: t's own preferred terms vs assumed pods
-            for u in range(T):
-                for tau in range(a_key.shape[1]):
-                    if a_valid[u, tau] and M_aff[u, tau, t] and hard_w > 0:
-                        w45_i[t, cx(u, a_key[u, tau])] += hard_w
-                        gpres[t, cx(u, a_key[u, tau])] = 1.0
-                for tau in range(p_key.shape[1]):
-                    if p_valid[u, tau] and M_pref[u, tau, t]:
-                        w45_i[t, cx(u, p_key[u, tau])] += int(p_w[u, tau])
-                        gpres[t, cx(u, p_key[u, tau])] = 1.0
-                for tau in range(p_key.shape[1]):
-                    if p_valid[t, tau] and M_pref[t, tau, u]:
-                        w45_i[t, cx(u, p_key[t, tau])] += int(p_w[t, tau])
-                        gpres[t, cx(u, p_key[t, tau])] = 1.0
-        # score-dot exactness: |w|.sum * count must stay < 2^24 in f32.
-        # Weights first shed their common GCD (the kernel multiplies the
-        # int32 dot result back by w45_scale): the harness's weight-100
-        # preferred-affinity templates (sum|w| 300) ride the kernel as
-        # sum|w/g| 3 instead of downgrading to the hoisted session —
-        # the Preferred-affinity configs' silent ~4x slow path.
-        w45_scale = _gcd_all(w45_i)
-        w45_i //= w45_scale
-        # with the scaled dot cast to int32 BEFORE the multiply, only
-        # the dot itself must be exact: cap session assumed counts at
-        # 2^16 (far above any bench window) -> sum|w/g| < 2^8
-        scaled_sum = int(np.abs(w45_i).sum(axis=1).max(initial=0))
-        if scaled_sum >= 256:
-            raise PallasUnsupported(
-                "IPA score weights too large for exact f32 dot",
-                reason="ipa-score-weights")
-        # ... and the RESTORED magnitude must keep int32 headroom: the
-        # multiply-back delta (scale * scaled-sum * count) has to stay
-        # clear of the 2^30 score sentinel at the same 2^16 count cap,
-        # or raw_ipa's int32 add could wrap for extreme weight mixes
-        # (e.g. {100, 25400}: gcd 100, scaled sum 255) that the
-        # pre-scale guard used to reject outright
-        if w45_scale * scaled_sum >= 2 ** 14:
-            raise PallasUnsupported(
-                "IPA score weights too large for int32 score headroom",
-                reason="ipa-score-weights")
-
-        # static per-term per-node blocks (rows t*8+term)
-        anti_static = np.zeros((T * SUB, Np), np.int32)
-        anti_konn = np.zeros((T * SUB, Np), np.int32)
-        aff_static = np.zeros((T * SUB, Np), np.int32)
-        anti_cnt_n = np.asarray(S["ipa_anti_cnt_n"])    # [T, N, TAA]
-        anti_kon = np.asarray(S["ipa_anti_key_on_node"])
-        aff_cnt_n = np.asarray(S["ipa_aff_cnt_n"])      # [T, N, TA]
-        for t in range(T):
-            for tau in range(aa_key.shape[1]):
-                anti_static[t * SUB + tau, :N] = anti_cnt_n[t, :, tau]
-                anti_konn[t * SUB + tau, :N] = anti_kon[t, :, tau]
-            for tau in range(a_key.shape[1]):
-                aff_static[t * SUB + tau, :N] = aff_cnt_n[t, :, tau]
-        # per-template per-node statics (rows t*2 / t*2+1)
-        ipa_stat = np.zeros((_ceil(2 * T, SUB), Np), np.int32)
-        fe = np.asarray(S["ipa_fail_existing"])         # [T, N]
-        aak = np.asarray(S["ipa_aff_all_keys"])
-        for t in range(T):
-            ipa_stat[2 * t, :N] = fe[t]
-            ipa_stat[2 * t + 1, :N] = aak[t]
-        if max(int(anti_static.max(initial=0)),
-               int(aff_static.max(initial=0))) >= POS_BIG:
-            raise PallasUnsupported("IPA static counts exceed sentinel",
-                                    reason="score-magnitude")
-        return dict(
-            UR=UR,
-            prow_ipa=prow_ipa, ipa_stat=ipa_stat,
-            anti_static=anti_static, anti_konn=anti_konn,
-            aff_static=aff_static,
-            g1=g1, wanti=wanti, waff=waff, w3tot=w3tot,
-            w45=w45_i.astype(np.float32), w45_scale=w45_scale, gpres=gpres,
-            # SMEM scalar extension: per-t has_aff/self_match_all/
-            # aff_total + per-term valid flags
-            has_aff=np.asarray(S["ipa_has_aff"]).astype(np.int32),
-            self_match_all=np.asarray(
-                S["ipa_self_match_all"]).astype(np.int32),
-            aff_total=np.asarray(S["ipa_aff_total"]).astype(np.int32),
-            anti_valid=_pad_tc(aa_valid.astype(np.int32), T),
-            aff_valid=_pad_tc(a_valid.astype(np.int32), T),
-        )
-
-    # ktpu: allow-sync(session build: packs static scalar rows on host before upload)
-    def _pack_scalars(self, S) -> np.ndarray:
-        T, C, R = self.T, self.C, self.R
-        # the sharded two-phase session (ops/sharded_scan.py) reads these
-        # as structured tables instead of SMEM offsets
-        self._sc_tables = {
-            k: np.asarray(S[k]).copy()
-            for k in ("f_valid", "s_valid", "f_skew", "s_skew",
-                      "f_self_match", "s_first", "f_same_key", "s_same_key",
-                      "ipa_present")
-        }
-        per_t = np.concatenate([
-            self._req_s, self._req_check_s,
-            self._req_has_any_s[:, None], self._nz_req_s,
-            S["ipa_present"].astype(np.int32)[:, None]], axis=1)  # [T, 2R+4]
-        tc = np.stack([
-            S["f_valid"].astype(np.int32), S["s_valid"].astype(np.int32),
-            S["f_skew"].astype(np.int32), S["s_skew"].astype(np.int32),
-            S["f_self_match"].astype(np.int32), S["s_first"].astype(np.int32),
-            self._f_keyid, self._s_keyid,
-            self._f_perno.astype(np.int32), self._s_perno.astype(np.int32),
-        ], axis=0)  # [10, T, C]
-        return np.concatenate([
-            per_t.reshape(-1), tc.reshape(-1),
-            S["f_same_key"].astype(np.int32).reshape(-1),
-            S["s_same_key"].astype(np.int32).reshape(-1),
-        ]).astype(np.int32)
-
-    # -- scheduling --------------------------------------------------------
-
-    def _initial_carry(self):
-        z = jnp.asarray
-        carry = {
-            "requested": z(self._requested0), "nzpc": z(self._nzpc0),
-            "cnt_fn": z(self._cnt_fn0), "cnt_sn": z(self._cnt_sn0),
-        }
-        if self._ipa is not None:
-            # session starts with zero ASSUMED pods (existing pods live in
-            # the static tables) — mirrors _init_dynamic_carries
-            carry["ucnt"] = jnp.zeros((self._ipa["UR"], self.Np), jnp.int32)
-            carry["kcnt"] = jnp.zeros((self._ipa["UR"], LANE), jnp.int32)
-        return carry
-
-    def _get_bundle(self):
-        """(cfg, statics, ipa) for _dispatch: cfg is the value-hashed
-        static config; statics/ipa are device-resident dynamic args."""
-        if self._bundle is None:
-            z = jnp.asarray
-            ipa = None
-            carry_keys = CARRY_KEYS
-            if self._ipa is not None:
-                ipa = {
-                    k: z(self._ipa[k])
-                    for k in ("ipa_stat", "anti_static", "anti_konn",
-                              "aff_static", "prow_ipa", "g1", "wanti",
-                              "waff", "w3tot", "w45", "gpres")
-                }
-                carry_keys = CARRY_KEYS + ("ucnt", "kcnt")
-            statics = {
-                "alloc": z(self._alloc), "stat": z(self._stat),
-                "onehot": z(self._onehot), "regrow_f": z(self._regrow_f),
-                "zvalid_node_s": z(self._zvalid_node_s),
-                "zvalid_s": z(self._zvalid_s),
-                "konn_f": z(self._konn_f), "konn_s": z(self._konn_s),
-                "shasall": z(self._shasall), "valid_n": z(self._valid_n),
-                "rowt": z(self._rowt), "eye": z(self._eye),
-                "prow_f": z(self._prow_f), "prow_s": z(self._prow_s),
-                "gmat": z(self._gmat),
-                "scalars": z(self._scalars),
-            }
-            cfg = _Cfg(
-                shapes=(self.T, self.C, self.Np, self.R, self.SR,
-                        self.TCp, self.K, self.CP),
-                weights=tuple(sorted(self.weights.items())),
-                ur=(self._ipa["UR"] if self._ipa else 0),
-                carry_keys=carry_keys,
-                interpret=self.interpret,
-                mk=self.multipod_k,
-            )
-            self._bundle = (cfg, statics, ipa)
-        return self._bundle
-
-    def _pack_batch(self, B, Bp, tmpl, mfa, msa):
-        """Per-batch host->device payload as TWO arrays instead of four
-        (B_real, tmpl, mfT, msT): each transfer carries a fixed cost.
-        meta = [B_real | tmpl]; match lanes
-        (t*CP+c) = that constraint row per pod, filter block then score
-        block — int8 on the wire (weights are 0/1), widened on-device."""
-        T, C, CP = self.T, self.C, self.CP
-        meta = np.empty(1 + Bp, np.int32)
-        meta[0] = B
-        meta[1:] = tmpl
-        match = np.zeros((Bp, 2 * LANE), np.int8)
-        for t in range(T):
-            match[:B, t * CP:t * CP + C] = mfa[t].reshape(B, C)
-            match[:B, LANE + t * CP:LANE + t * CP + C] = msa[t].reshape(B, C)
-        return meta, match
-
-    def schedule(self, pod_arrays_list: List[Dict]):
-        """Enqueue one batch; returns the (8, Bp) device result rows —
-        row 0 best / row 1 score / row 2 n_feasible. decisions() blocks."""
-        B = len(pod_arrays_list)
-        Bp, tmpl, mfa, msa = batch_prologue(
-            self._fps, self._tp_np, pod_arrays_list, minimum=LANE)
-        meta, match = self._pack_batch(B, Bp, tmpl, mfa, msa)
-        out = self._run_dispatch(meta, match)
-        # bucket rides the result so a harvest-side device fault can
-        # retire exactly the executable that produced the bad payload
-        # (tpu_backend.py retry path)
-        return {"rows": out, "n": B, "bucket": Bp, "mk": self.multipod_k}
-
-    @staticmethod
-    # ktpu: allow-sync(harvest decode: host consumes batch verdicts after the launch completes)
-    def decisions(ys) -> List[int]:
-        return [int(v) for v in np.asarray(ys["rows"])[0, :ys["n"]]]
-
-    @staticmethod
-    # ktpu: allow-sync(harvest decode: host reads conflict planes after the launch completes)
-    def conflict_stats(ys):
-        """(n_conflicts, replay_suffix_start) from out row 3: the kernel
-        leaves the conflicted suffix UNCOMMITTED (flag 1) — the backend
-        replays exactly those pods through the session, whose carry
-        holds the committed prefix. n_conflicts is 1 — ONE detection
-        headed the suffix; the flags after it are collateral (the
-        kernel cannot know which of them would conflict against the
-        replayed carry), and any genuine later conflict is re-detected
-        — and re-counted — when the replayed suffix runs. (0, None)
-        when the batch ran one-pod-per-step (row 3 is the -1 init
-        then)."""
-        if ys.get("mk", 1) <= 1:
-            return 0, None
-        flags = np.asarray(ys["rows"])[3, :ys["n"]] > 0
-        if not flags.any():
-            return 0, None
-        return 1, int(np.argmax(flags))
-
-    def retire_exec(self, bucket: Optional[int] = None,
-                    mode: Optional[str] = None) -> int:
-        """Retire AOT executables after a device fault: a dispatch that
-        raised, wedged, or harvested garbage leaves its compiled program
-        suspect. Entries are pinned to None (= dispatch through jit), the
-        same retired state the arg-mismatch path uses — warm_buckets
-        never resurrects a retired entry, and _run_dispatch never
-        recompiles one. With `bucket` given, absent entries are pinned
-        too: the backend quarantines a suspect bucket on every REBUILT
-        session (the _exec cache dies with its session, but the fault
-        does not), and lifts it only after the bucket harvests cleanly
-        through jit. bucket/mode both None retires every existing
-        entry. Returns the number of entries pinned."""
-        n = 0
-        modes = (mode,) if mode is not None else ("full", "eval", "apply")
-        if bucket is not None:
-            for m in modes:
-                if self._exec.get((bucket, m), _MISSING) is not None:
-                    self._exec[(bucket, m)] = None
-                    n += 1
-            return n
-        for key in list(self._exec):
-            if mode is not None and key[1] != mode:
-                continue
-            if self._exec.get(key) is not None:
-                self._exec[key] = None
-                n += 1
-        return n
-
-    # -- incremental device-state deltas -----------------------------------
-
-    def delta_compatible(self, dres, dnz) -> bool:
-        """A utilization delta rides this session's int32 carry only when
-        the build-time per-dimension GCD rescale stays exact on it and
-        the rescaled magnitudes keep the int32 headroom the build
-        guaranteed."""
-        dres = np.asarray(dres, np.int64)
-        if dres.shape[0] != self._gcd.shape[0]:
-            return False
-        if (dres % self._gcd != 0).any():
-            return False
-        dnz = np.asarray(dnz, np.int64)
-        if (dnz % self._gcd[:2] != 0).any():
-            return False
-        hi = max(
-            int(np.abs(dres // self._gcd).max(initial=0)),
-            int(np.abs(dnz // self._gcd[:2]).max(initial=0)),
-        )
-        return hi * (MAX_NODE_SCORE + 1) < 2 ** 31
-
-    def _delta_rows(self, d) -> tuple:
-        """One backend delta dict -> (node, dres[Rp] scaled, dnzpc[8],
-        mf[TCp], ms[TCp]) in this session's carry layout."""
-        rp = self._requested0.shape[0]
-        dres = np.zeros(rp, np.int32)
-        dnzpc = np.zeros(SUB, np.int32)
-        mf_rows = np.zeros(self.TCp, np.int32)
-        ms_rows = np.zeros(self.TCp, np.int32)
-        if d["kind"] == "node-alloc":
-            dnzpc[3] = d["dallowed"]
-        else:
-            dres[: self.R] = (
-                np.asarray(d["dres"], np.int64) // self._gcd
-            ).astype(np.int32)
-            dnzpc[0] = int(d["dnz"][0]) // int(self._gcd[0])
-            dnzpc[1] = int(d["dnz"][1]) // int(self._gcd[1])
-            dnzpc[2] = d["dcount"]
-            for t in range(self.T):
-                mf_rows[t * self.CP: t * self.CP + self.C] = d["mf"][t]
-                ms_rows[t * self.CP: t * self.CP + self.C] = d["ms"][t]
-        return d["node"], dres, dnzpc, mf_rows, ms_rows
-
-    def _patch_alloc_static(self, d) -> None:
-        """node-alloc prologue patch: the static alloc columns move (the
-        prologue never reads alloc, so nothing else needs recompute).
-        The CUMULATIVE rescaled magnitude must keep the int32 headroom
-        the build guaranteed — delta_compatible bounds one delta, not
-        the sum of many capacity bumps — so the patched column is
-        re-checked and an overflow raises (the backend's apply wrapper
-        downgrades to a rebuild, whose own envelope then decides)."""
-        scaled = (np.asarray(d["dalloc"], np.int64) // self._gcd).astype(
-            np.int32)
-        n = d["node"]
-        col = self._alloc[: self.R, n].astype(np.int64) + scaled
-        if int(np.abs(col).max(initial=0)) * (MAX_NODE_SCORE + 1) >= 2 ** 31:
-            raise ValueError(
-                "cumulative alloc patches exceed the int32 score headroom")
-        self._alloc[: self.R, n] += scaled
-        if self._bundle is not None:
-            cfg, statics, ipa = self._bundle
-            statics = dict(statics)
-            statics["alloc"] = statics["alloc"].at[:self.R, n].add(
-                jnp.asarray(scaled))
-            self._bundle = (cfg, statics, ipa)
-
-    def apply_deltas(self, deltas: List[Dict]) -> None:
-        """Absorb batched cluster-event deltas into the carry (and the
-        alloc statics) without a session rebuild — the pallas face of
-        the session-delta contract (see HoistedSession.apply_deltas).
-        With no dispatch yet (carry unmaterialized) the numpy seed
-        arrays are patched host-side; otherwise one fused
-        _carry_delta_scan launch chains onto the in-flight carry."""
-        for d in deltas:
-            if d["kind"] == "node-alloc":
-                self._patch_alloc_static(d)
-        rows = [self._delta_rows(d) for d in deltas]
-        if self._carry is None:
-            for n, dres, dnzpc, mf_rows, ms_rows in rows:
-                self._requested0[:, n] += dres
-                self._nzpc0[:, n] += dnzpc
-                same_f = (
-                    (self._prow_f == self._prow_f[:, n][:, None])
-                    & (self._prow_f >= 0)
-                )
-                self._cnt_fn0 += mf_rows[:, None] * same_f
-                same_s = (
-                    (self._prow_s == self._prow_s[:, n][:, None])
-                    & (self._prow_s >= 0)
-                )
-                factor = (
-                    self._perno_rows
-                    + (1 - self._perno_rows) * self._src_rows[:, n][:, None]
-                )
-                self._cnt_sn0 += ms_rows[:, None] * factor * same_s
-            return
-        e = len(rows)
-        from .hoisted import batch_bucket
-
-        ep = batch_bucket(e, minimum=8)  # pow2: one compile per bucket
-        xs = {
-            "node": np.zeros(ep, np.int32),
-            "dres": np.zeros((ep, self._requested0.shape[0]), np.int32),
-            "dnzpc": np.zeros((ep, SUB), np.int32),
-            "mf": np.zeros((ep, self.TCp), np.int32),
-            "ms": np.zeros((ep, self.TCp), np.int32),
-        }
-        for i, (n, dres, dnzpc, mf_rows, ms_rows) in enumerate(rows):
-            xs["node"][i] = n
-            xs["dres"][i] = dres
-            xs["dnzpc"][i] = dnzpc
-            xs["mf"][i] = mf_rows
-            xs["ms"][i] = ms_rows
-        if self._delta_statics is None:
-            self._delta_statics = {
-                "prow_f": jnp.asarray(self._prow_f),
-                "prow_s": jnp.asarray(self._prow_s),
-                "src_rows": jnp.asarray(self._src_rows),
-                "perno_rows": jnp.asarray(self._perno_rows),
-            }
-        ds = self._delta_statics
-        self._carry = _carry_delta_scan(
-            self._carry, ds["prow_f"], ds["prow_s"], ds["src_rows"],
-            ds["perno_rows"], {k: jnp.asarray(v) for k, v in xs.items()},
-        )
-
-    # -- dispatch plumbing: persistent executables ------------------------
-
-    def _carry_struct(self) -> Dict:
-        """ShapeDtypeStructs of the carry, WITHOUT touching self._carry:
-        warm_buckets runs on a daemon thread concurrently with
-        schedule() — a warm-thread write of self._carry would silently
-        zero the assumes of any batch dispatched in between."""
-        structs = {
-            "requested": jax.ShapeDtypeStruct(
-                self._requested0.shape, jnp.int32),
-            "nzpc": jax.ShapeDtypeStruct(self._nzpc0.shape, jnp.int32),
-            "cnt_fn": jax.ShapeDtypeStruct(self._cnt_fn0.shape, jnp.int32),
-            "cnt_sn": jax.ShapeDtypeStruct(self._cnt_sn0.shape, jnp.int32),
-        }
-        if self._ipa is not None:
-            structs["ucnt"] = jax.ShapeDtypeStruct(
-                (self._ipa["UR"], self.Np), jnp.int32)
-            structs["kcnt"] = jax.ShapeDtypeStruct(
-                (self._ipa["UR"], LANE), jnp.int32)
-        return structs
-
-    def _compile_exec(self, Bp: int, mode: str = "full"):
-        """AOT lower+compile the dispatch for one (batch bucket, mode).
-        The compiled executable is invoked DIRECTLY on the serving path
-        (persistent executable reuse): every dispatch then runs the same
-        loaded program object — no jit-dispatch signature hashing, and no
-        per-launch program re-resolution for the runtime to pay."""
-        cfg, statics, ipa = self._get_bundle()
-        if mode != "full":
-            cfg = cfg._replace(mode=mode)
-
-        def st(x):
-            return jax.ShapeDtypeStruct(jnp.shape(x), jnp.asarray(x).dtype)
-
-        statics_s = {k: st(v) for k, v in statics.items()}
-        ipa_s = {k: st(v) for k, v in ipa.items()} if ipa else None
-        args = [
-            cfg, statics_s, ipa_s,
-            jax.ShapeDtypeStruct((1 + Bp,), jnp.int32),
-            self._carry_struct(),
-            jax.ShapeDtypeStruct((Bp, 2 * LANE), jnp.int8),
-        ]
-        if mode == "apply":
-            args.append(jax.ShapeDtypeStruct((2 * Bp,), jnp.int32))
-        return _dispatch.lower(*args).compile()
-
-    def _run_dispatch(self, meta: np.ndarray, match: np.ndarray,
-                      mode: str = "full", forced=None):
-        """Execute one dispatch through the persistent-executable cache
-        (fallback: the plain jit path). Owns the carry swap — the carry
-        buffers are donated to the launch and replaced by its outputs."""
-        if self._carry is None:
-            self._carry = self._initial_carry()
-        Bp = int(meta.shape[0]) - 1
-        meta = jnp.asarray(meta)
-        match = jnp.asarray(match)
-        key = (Bp, mode)
-        fn = self._exec.get(key, _MISSING)
-        if not knobs.get_bool("KTPU_PALLAS_AOT"):
-            fn = None  # kill switch wins even over warm-installed execs
-        elif fn is _MISSING:
-            # Counted miss path: a dispatch-time compile is a stall the
-            # device timeline must attribute (warm_buckets prefills are
-            # deliberate and uncounted).
-            from ..utils import devtime
-            t0 = _time.perf_counter()
-            try:
-                fn = self._compile_exec(Bp, mode)
-            except Exception as e:  # noqa: BLE001 — the jit path serves; the error is kept
-                fn = None
-                self._exec_failed(key, "AOT compile failed", e)
-            self._exec[key] = fn
-            if devtime.enabled():
-                devtime.TIMELINE.compile_event(
-                    "pallas-bucket", t0, _time.perf_counter() - t0,
-                    bucket=Bp, mode=mode, ok=fn is not None)
-        if fn is not None:
-            args = [meta, self._carry, match]
-            if mode == "apply":
-                args.append(jnp.asarray(forced, jnp.int32))
-            try:
-                out, self._carry = fn(self._get_bundle()[1],
-                                      self._get_bundle()[2], *args)
-                return out
-            except (TypeError, ValueError) as e:
-                # arg-structure/layout mismatch is raised BEFORE
-                # execution (carry buffers untouched): retire this
-                # executable and serve through jit from now on
-                self._exec[key] = None
-                self._exec_failed(key, "AOT executable refused its args", e)
-        cfg, statics, ipa = self._get_bundle()
-        if mode != "full":
-            cfg = cfg._replace(mode=mode)
-        fv = None if forced is None else jnp.asarray(forced, jnp.int32)
-        out, self._carry = _dispatch(
-            cfg, statics, ipa, meta, self._carry, match, forced=fv)
-        return out
-
-    def _exec_failed(self, key, what: str, e: BaseException) -> None:
-        self.exec_errors[key] = f"{what}: {type(e).__name__}: {e}"
-        logger.error("pallas bucket %s mode %s: %s", key[0], key[1], what,
-                     exc_info=e)
-
-    def stop_warm(self) -> None:
-        """Ask a running warm_buckets to stop after the bucket it is
-        compiling (backend close: no compile may outlive the process's
-        orderly exit)."""
-        self._warm_stop.set()
-
-    def warm_buckets(self, sizes=(LANE, 256, 512, 1024, 2048)) -> None:
-        """AOT-compile the dispatch for the ragged-tail batch buckets
-        WITHOUT dispatching: .lower().compile() populates jax's caches
-        including the persistent one, so a mid-window first-tail-bucket
-        batch pays a cache hit instead of a fresh ~30s Mosaic compile (a
-        gang rep that drained into a never-seen bucket measured 160
-        pods/s against its siblings' 1300). Compiled executables land in
-        self._exec, so the serving path reuses the very same loaded
-        program. Runs on a daemon thread: it must NEVER write
-        self._carry (a mid-warm schedule() would have its batch's
-        assumes silently zeroed by the overwrite) — all shapes come from
-        _carry_struct. A failure stops the warming (the lazy path would
-        hit the same compiler error) and is recorded in exec_errors —
-        without pinning the entry, so the serving path still makes its
-        own attempt."""
-        aot = knobs.get_bool("KTPU_PALLAS_AOT")
-        for Bp in sizes:
-            if self._warm_stop.is_set():
-                return
-            if (Bp, "full") in self._exec:
-                # present entries stand: a None means the serving
-                # path RETIRED this executable — do not resurrect it
-                continue
-            try:
-                compiled = self._compile_exec(Bp)
-            except Exception as e:  # noqa: BLE001 — warming is off the serving path; the error is kept
-                self._exec_failed((Bp, "full"), "AOT warm compile failed", e)
-                return
-            # with the AOT kill switch set, warming still fills the
-            # (persistent) compile caches, but the serving path must
-            # keep dispatching through jit — don't install
-            if aot:
-                self._exec.setdefault((Bp, "full"), compiled)
-
-    # -- split eval/apply (the sharded session's building blocks) ----------
-    # A multi-chip session cannot let each shard apply its own local
-    # best: the winner is a cross-shard argmax. These run the SAME
-    # kernel in mode="eval" (masks/scores/local best, carries untouched)
-    # and mode="apply" (commit externally-decided placements; off-shard
-    # lanes no-op), so eval -> global argmax -> apply replays the full
-    # kernel exactly (pinned by tests/test_pallas_scan.py
-    # TestEvalApplySplit).
-
-    def _dispatch_mode(self, pod_arrays_list, mode, forced=None):
-        B = len(pod_arrays_list)
-        Bp, tmpl, mfa, msa = batch_prologue(
-            self._fps, self._tp_np, pod_arrays_list, minimum=LANE,
-            require_unbound=False)
-        meta, match = self._pack_batch(B, Bp, tmpl, mfa, msa)
-        fvec = None
-        if mode == "apply":
-            fvec = np.zeros(2 * Bp, np.int32)
-            for i, (lane, ok) in enumerate(forced):
-                fvec[2 * i] = lane
-                fvec[2 * i + 1] = ok
-        out = self._run_dispatch(meta, match, mode=mode, forced=fvec)
-        return {"rows": out, "n": B}
-
-    def evaluate(self, pod_arrays_list: List[Dict]):
-        """Local (best, score) per pod WITHOUT carry updates — every pod
-        evaluated against the same carry state."""
-        ys = self._dispatch_mode(pod_arrays_list, "eval")
-        rows = np.asarray(ys["rows"])
-        return [
-            (int(rows[0, i]), int(rows[1, i])) for i in range(ys["n"])
-        ]
-
-    def apply_decisions(
-        self, pod_arrays_list: List[Dict], decisions: List[int]
-    ) -> None:
-        """Commit placements (node lane or -1 = unplaced / off-shard)
-        to the session carry."""
-        forced = [(d if d >= 0 else -1, 1 if d >= 0 else 0)
-                  for d in decisions]
-        self._dispatch_mode(pod_arrays_list, "apply", forced=forced)
 
 
 # ---------------------------------------------------------------------------
 # kernel
 
 
-def _build_kernel(shapes, weights, Bp: int, ur: int = 0,
-                  mode: str = "full", mk: int = 1):
-    """mode: "full" = eval + select + apply own decision (single-device
-    session); "eval" = masks/scores/local-best only, carries untouched;
-    "apply" = apply an externally-decided (cross-shard) placement to the
-    carries. The sharded session alternates eval/apply around an ICI
-    argmax (ShardedPallasSession).
-
-    mk > 1 (full mode): multi-pod steps with exact conflict detection —
-    mk pods are evaluated against the GROUP-START carry (their evals
-    share no data dependency), then committed in order; a pod whose
-    evaluation an earlier commit could have perturbed (same node, PTS
-    match-gate, IPA template gate, or the fit/balanced/least recheck —
-    the same algebra as ops/hoisted.py _step_multi) starts the CONFLICT
-    SUFFIX: it and every later pod of the batch stay UNCOMMITTED, out
-    row 3 flags them, and the host replays exactly that suffix through
-    the session (tpu_backend._harvest_locked) — bit-identical to
-    one-pod-per-step either way."""
-    from ..utils import knobs as _knobs
-
+def _build_kernel(cfg: _Cfg, Bp: int):
+    """One launch decides Bp pods one after another: filter + score every
+    node lane for pod b against the carry, take the first of the maxima,
+    commit it to the carry (utilization rows and the count rows on the
+    spec's touch list), next pod."""
     skip = frozenset(
-        _knobs.get_str("KTPU_PALLAS_SKIP").split(","))  # profiling only
-    T, C, Np, R, SR, TCp, K, CP = shapes
-    W = dict(weights)
-    dyn_ipa = ur > 0 and "ipa" not in skip
-    row_len = 2 * R + 4
-    off_tc = T * row_len
-    off_fsame = off_tc + 10 * T * C
-    off_ssame = off_fsame + T * C * C
-    # IPA scalar extension (appended when the session has term templates)
-    off_ipa_t = off_ssame + T * C * C
-    off_av = off_ipa_t + 3 * T
-    off_w45s = off_av + 2 * T * SUB  # w45 GCD scale (one scalar)
-    (W_F_VALID, W_S_VALID, W_F_SKEW, W_S_SKEW, W_F_SELF, W_S_FIRST,
-     W_F_KEY, W_S_KEY, W_F_PERNO, W_S_PERNO) = range(10)
+        knobs.get_str("KTPU_PALLAS_SKIP").split(","))  # profiling only
+    (Tcap, PC, Np, R, C, RC, SRc, ZRc, K, LB) = cfg.shapes
+    L = _layout(R, C, cfg.ipa)
+    Wt = dict(cfg.weights)
+    W = L.W
+    dyn_ipa = cfg.ipa and "ipa" not in skip
+    f32 = jnp.float32
+    i32 = jnp.int32
 
-    def kernel(*refs):
-        forced_ref = None
-        if mode == "apply":
-            forced_ref = refs[0]  # SMEM [2*Bp]: (local lane | -1, ok)
-            refs = refs[1:]
-        (breal_ref, tmpl_ref, sc_ref, mf_ref, ms_ref,
-         alloc_ref, stat_ref, onehot_ref, regrowf_ref, zvnode_ref,
-         zvalid_ref, konnf_ref, konns_ref, shasall_ref, validn_ref,
-         rowt_ref, eye_ref, prowf_ref, prows_ref, gmat_ref) = refs[:20]
-        i = 20
-        if ur > 0:
-            (ipastat_ref, antic_ref, antik_ref, affc_ref, prowipa_ref,
-             g1_ref, wanti_ref, waff_ref, w3tot_ref, w45_ref,
-             gpres_ref) = refs[i:i + 11]
-            i += 11
-        ncarry = 6 if ur > 0 else 4
-        carry_in = refs[i:i + ncarry]
-        i += ncarry
-        out_ref = refs[i]
-        carry_refs = refs[i + 1:]
-        requested_in, nzpc_in = carry_in[0], carry_in[1]
-        requested_ref, nzpc_ref, cntfn_ref, cntsn_ref = carry_refs[:4]
-        if ur > 0:
-            ucnt_ref, kcnt_ref = carry_refs[4], carry_refs[5]
-        # carries live in the OUTPUT refs (initialized from the inputs);
-        # refs — unlike loop-carried values — support dynamic row reads
-        for cin, cref in zip(carry_in, carry_refs):
-            cref[:] = cin[:]
-        out_ref[:] = jnp.full((SUB, Bp), -1, jnp.int32)
+    def kernel(breal_ref, tmpl_ref, spec_ref, pool_ref, bad_ref,
+               alloc_ref, validn_ref, balgrp_ref, srow_ref, zrow_ref,
+               onehot_ref, requested_in, nzpc_in, cnt_in,
+               out_ref, requested_ref, nzpc_ref, cnt_ref):
+        # carries live in the OUTPUT refs (initialized from the inputs)
+        requested_ref[:] = requested_in[:]
+        nzpc_ref[:] = nzpc_in[:]
+        cnt_ref[:] = cnt_in[:]
+        out_ref[:] = jnp.full((SUB, Bp), -1, i32)
 
-        sc = sc_ref
-        f32 = jnp.float32
+        def srow(i):
+            return srow_ref[pl.ds(i, 1), :]
 
-        def sm_t(t, i):
-            return sc[t * row_len + i]
+        def crow(i):
+            return cnt_ref[pl.ds(i, 1), :]
 
-        def sm_tc(which, t, cc):
-            return sc[off_tc + which * T * C + t * C + cc]
+        def at_lane(row, lane_n, best):
+            """row[best] as a scalar (NEG_BIG when best is no lane; a max
+            and not a sum: Mosaic widens integer sums to 64 bits)."""
+            return jnp.max(jnp.where(lane_n == best, row, i32(NEG_BIG)))
 
-        def sm_fsame(t, ci, cj):
-            return sc[off_fsame + (t * C + ci) * C + cj]
+        def any_lane(flags_i32):
+            return jnp.max(flags_i32) > 0
 
-        def sm_ssame(t, ci, cj):
-            return sc[off_ssame + (t * C + ci) * C + cj]
-
-        def dotz(mat_1v, k):
-            """(1, VZ) . onehot[k]^T -> (1, Np)."""
-            return jax.lax.dot_general(
-                mat_1v, onehot_ref[k], (((1,), (1,)), ((), ())),
-                preferred_element_type=f32)
-
-        def dotn(mat_1n, k):
-            """(1, Np) . onehot[k] -> (1, VZ)."""
-            return jax.lax.dot_general(
-                mat_1n, onehot_ref[k], (((1,), (0,)), ((), ())),
-                preferred_element_type=f32)
-
-        def doth(a, b, dims):
-            """Exact-f32 dot (counts/ids above 2^8 need HIGHEST)."""
-            return jax.lax.dot_general(
-                a, b, dims, preferred_element_type=f32,
-                precision=jax.lax.Precision.HIGHEST)
-
-        def sm_ipa_t(t, i):
-            return sc[off_ipa_t + t * 3 + i]
-
-        def sm_av(which, t, tau):
-            return sc[off_av + which * T * SUB + t * SUB + tau]
-
-        def _col_av(which, t):
-            """(SUB, 1) f32 column of per-(t, term) valid flags."""
-            i0 = jax.lax.broadcasted_iota(jnp.int32, (SUB, 1), 0)
-            out = jnp.zeros((SUB, 1), f32)
-            for tau in range(SUB):
-                e = (i0 == tau).astype(f32)
-                out = out + sm_av(which, t, tau).astype(f32) * e
-            return out
-
-        def _apply_updates(b, t, lane_n, best, oki, okf):
-            """Carry updates for pod b landing on node lane `best` (all
-            no-ops when best is off this kernel's node range — `hot` is
-            then all-zero, which is exactly how the sharded session's
-            non-owning shards stay consistent)."""
-            hot = (lane_n == best).astype(jnp.int32) * oki   # (1, Np)
-            hotf = hot.astype(f32)
-            for r in range(R):
-                requested_ref[r:r + 1, :] = (
-                    requested_ref[r:r + 1, :] + hot * sm_t(t, r))
-            nzpc_ref[0:1, :] = nzpc_ref[0:1, :] + hot * sm_t(t, 2 * R + 1)
-            nzpc_ref[1:2, :] = nzpc_ref[1:2, :] + hot * sm_t(t, 2 * R + 2)
-            nzpc_ref[2:3, :] = nzpc_ref[2:3, :] + hot
-
-            # per-row match weights: column b of mf/ms via identity-dot
-            mf_vec = mf_ref[pl.ds(b, 1), :].astype(f32)      # (1, LANE)
-            ms_vec = ms_ref[pl.ds(b, 1), :].astype(f32)
-            mf_col = jax.lax.dot_general(
-                eye_ref[:], mf_vec, (((1,), (1,)), ((), ())),
-                preferred_element_type=f32)                  # (TCp, 1)
-            ms_col = jax.lax.dot_general(
-                eye_ref[:], ms_vec, (((1,), (1,)), ((), ())),
-                preferred_element_type=f32)
-
-            # pair id at best, per row (one matvec each side); same-pair
-            # lanes get the count delta — hostname rows degenerate to
-            # same-NODE exactly like the pair-space update they mirror
-            pf = prowf_ref[:].astype(f32)
-            zb_f = jax.lax.dot_general(
-                pf, hotf, (((1,), (1,)), ((), ())),
-                preferred_element_type=f32,
-                precision=jax.lax.Precision.HIGHEST)         # (TCp, 1)
-            m_f = ((pf == zb_f) & (prowf_ref[:] >= 0)).astype(f32) * okf
-            ps_ = prows_ref[:].astype(f32)
-            zb_s = jax.lax.dot_general(
-                ps_, hotf, (((1,), (1,)), ((), ())),
-                preferred_element_type=f32,
-                precision=jax.lax.Precision.HIGHEST)
-            m_s = ((ps_ == zb_s) & (prows_ref[:] >= 0)).astype(f32) * okf
-
-            # s_src factor at best per row's template (zone rows only; the
-            # per-node/hostname update has no src gate, mirroring _step)
-            srcrow = jnp.zeros((TCp, 1), f32)
-            for tt in range(T):
-                srow = stat_ref[pl.ds(tt * SR + 7, 1), :]
-                v = jnp.sum(
-                    jnp.where(lane_n == best, srow, jnp.int32(0)).astype(f32))
-                srcrow = srcrow + rowt_ref[tt][:, 0:1].astype(f32) * v
-            pernosel = _stack_tc(sm_tc, W_S_PERNO, T, C, TCp)             # (TCp, 1)
-            factor = pernosel + (f32(1.0) - pernosel) * srcrow
-
-            cntfn_ref[:] = (cntfn_ref[:].astype(f32)
-                            + mf_col * m_f).astype(jnp.int32)
-            cntsn_ref[:] = (cntsn_ref[:].astype(f32)
-                            + ms_col * factor * m_s).astype(jnp.int32)
-
-            if dyn_ipa:
-                # the assumed pod joins its node's topology groups for
-                # every IPA key the node carries: same-pair mask from
-                # prow_ipa (-1 rows = node lacks key -> no-op), written
-                # into template t's own 8-row ucnt block
-                pi = prowipa_ref[:].astype(f32)                # (SUB, Np)
-                zb_i = doth(pi, hotf, (((1,), (1,)), ((), ())))  # (SUB, 1)
-                m_i = ((pi == zb_i)
-                       & (prowipa_ref[:] >= 0)).astype(f32) * okf
-                base_u = pl.multiple_of(t * SUB, SUB)
-                ucnt_ref[pl.ds(base_u, SUB), :] = (
-                    ucnt_ref[pl.ds(base_u, SUB), :].astype(f32) + m_i
-                ).astype(jnp.int32)
-                hask = doth((pi >= 0).astype(f32), hotf,
-                            (((1,), (1,)), ((), ())))          # (SUB, 1)
-                kcnt_ref[pl.ds(base_u, SUB), :] = (
-                    kcnt_ref[pl.ds(base_u, SUB), :].astype(f32)
-                    + hask * okf
-                ).astype(jnp.int32)
-
-        def fit_row(t):
-            """NodeResourcesFit row against the CURRENT carry refs —
-            shared by the eval and the multipod conflict recheck (the
-            fit leg of kernel.multipod_utilization_conflicts)."""
+        def fit_row(sp):
+            """NodeResourcesFit row against the CURRENT carry refs."""
             over = jnp.zeros((1, Np), jnp.bool_)
             for r in range(R):
                 free = alloc_ref[r:r + 1, :] - requested_ref[r:r + 1, :]
-                over = over | ((sm_t(t, r) > free) & (sm_t(t, R + r) != 0))
-            fail_dims = (sm_t(t, 2 * R) != 0) & over
-            fail_count = (nzpc_ref[2:3, :] + jnp.int32(1)) > nzpc_in[3:4, :]
+                over = over | ((sp(L.REQ + r) > free) & (sp(L.CHK + r) != 0))
+            fail_dims = (sp(L.HAS) != 0) & over
+            fail_count = (nzpc_ref[2:3, :] + i32(1)) > nzpc_in[3:4, :]
             return jnp.logical_not(fail_count | fail_dims)
 
-        def resource_rows(t):
-            """(balanced, least) rows against the CURRENT carry refs —
-            shared by the eval and the multipod wbl recheck."""
-            nz_cpu = (nzpc_ref[0:1, :] + sm_t(t, 2 * R + 1)).astype(f32)
-            nz_mem = (nzpc_ref[1:2, :] + sm_t(t, 2 * R + 2)).astype(f32)
-            cap_cpu = alloc_ref[0:1, :].astype(f32)
-            cap_mem = alloc_ref[1:2, :].astype(f32)
-            frac_c = jnp.where(cap_cpu == 0, f32(1.0), nz_cpu / cap_cpu)
-            frac_m = jnp.where(cap_mem == 0, f32(1.0), nz_mem / cap_mem)
-            balanced = ((f32(1.0) - jnp.abs(frac_c - frac_m))
-                        * MAX_NODE_SCORE).astype(jnp.int32)
-            balanced = jnp.where((frac_c >= 1) | (frac_m >= 1),
-                                 jnp.int32(0), balanced)
+        def resource_rows(sp):
+            """(balanced, exact-integer flags, key, least) against the
+            CURRENT carry refs."""
+            nzc = nzpc_ref[0:1, :] + sp(L.NZ)
+            nzm = nzpc_ref[1:2, :] + sp(L.NZ + 1)
+            cap_c = alloc_ref[0:1, :]
+            cap_m = alloc_ref[1:2, :]
+            full = ((cap_c == 0) | (cap_m == 0)
+                    | (nzc >= cap_c) | (nzm >= cap_m))
+            if cfg.bal_int:
+                # exact floor of (1 - |c/C - m/M|) * 100; the build
+                # guarantees 100 * C * M < 2^31
+                den = jnp.where(full, i32(1), cap_c * cap_m)
+                num = MAX_NODE_SCORE * (
+                    den - jnp.abs(nzc * cap_m - nzm * cap_c))
+                num = jnp.where(full, i32(0), num)
+                balanced = num // den
+                whole = jnp.logical_not(full) & (num - balanced * den == 0)
+                key = nzc * (cap_m + i32(1)) + nzm
+            else:
+                fc = jnp.where(cap_c == 0, f32(1.0),
+                               nzc.astype(f32) / cap_c.astype(f32))
+                fm = jnp.where(cap_m == 0, f32(1.0),
+                               nzm.astype(f32) / cap_m.astype(f32))
+                balanced = ((f32(1.0) - jnp.abs(fc - fm))
+                            * MAX_NODE_SCORE).astype(i32)
+                balanced = jnp.where(full, i32(0), balanced)
+                whole = jnp.zeros((1, Np), jnp.bool_)
+                key = jnp.zeros((1, Np), i32)
 
             def least_dim(cap, reqq):
                 d = ((cap - reqq) * MAX_NODE_SCORE
-                     // jnp.where(cap == 0, jnp.int32(1), cap))
-                return jnp.where((cap == 0) | (reqq > cap), jnp.int32(0), d)
+                     // jnp.where(cap == 0, i32(1), cap))
+                return jnp.where((cap == 0) | (reqq > cap), i32(0), d)
 
-            least = (least_dim(alloc_ref[0:1, :],
-                               nzpc_ref[0:1, :] + sm_t(t, 2 * R + 1))
-                     + least_dim(alloc_ref[1:2, :],
-                                 nzpc_ref[1:2, :] + sm_t(t, 2 * R + 2))
-                     ) // jnp.int32(2)
-            return balanced, least
+            least = (least_dim(cap_c, nzc) + least_dim(cap_m, nzm)) // i32(2)
+            return balanced, whole, key, least
 
-        def lane_gate(which, t):
-            """(1, LANE) gate over match lanes: 1.0 at lane (t*CP+c) for
-            template t's VALID constraint slots — counts written to
-            invalid slots are never read, so gating the multipod PTS
-            conflict test on them is what makes it exact."""
-            lanei1 = jax.lax.broadcasted_iota(jnp.int32, (1, LANE), 1)
-            out = jnp.zeros((1, LANE), f32)
-            for tt in range(T):
-                sel = (t == tt).astype(f32)
-                for cc in range(C):
-                    e = (lanei1 == (tt * CP + cc)).astype(f32)
-                    out = out + sel * sm_tc(which, tt, cc).astype(f32) * e
-            return out
+        def quirk_mask(key):
+            """Lanes at a state where float64 reads one less."""
+            grp = balgrp_ref[0:1, :]
+
+            def body(i, acc):
+                hit = (grp == bad_ref[1 + 2 * i]) & (key == bad_ref[2 + 2 * i])
+                return jnp.maximum(acc, hit.astype(i32))
+
+            return jax.lax.fori_loop(0, bad_ref[0], body,
+                                     jnp.zeros((1, Np), i32))
 
         def eval_pod(b):
-            """Filter + score pod b against the CURRENT carry refs
-            WITHOUT committing — the eval half of one_pod, reused by the
-            multipod group body (where all mk pods run it against the
-            group-start refs before any commit)."""
             t = tmpl_ref[b]
+            base = t * W
+
+            def sp(i):
+                return spec_ref[base + i]
+
             # NOTHING big is hoisted out of the loop: values live across
             # iterations spill out of vector registers and the
             # spill/restore swamps the step (measured; see PERF_NOTES)
-            lane_n = jax.lax.broadcasted_iota(jnp.int32, (1, Np), 1)
+            lane_n = jax.lax.broadcasted_iota(i32, (1, Np), 1)
             valid_n = validn_ref[0:1, :]
-
-            def trow(i):
-                return stat_ref[pl.ds(t * SR + i, 1), :]
-
-            static_mask = trow(0)
-            raw_ipa = trow(1)
-            cnt_taint = trow(2)
-            cnt_nodeaff = trow(3)
-            sc_image = trow(4)
-            sc_avoid = trow(5)
-            ipa_present = sm_t(t, 2 * R + 3)
-
+            static_mask = srow(sp(L.STAT + ST_MASK))
+            raw_ipa = srow(sp(L.STAT + ST_RAW_IPA))
+            cnt_taint = srow(sp(L.STAT + ST_TAINT))
+            cnt_nodeaff = srow(sp(L.STAT + ST_NODEAFF))
+            sc_image = srow(sp(L.STAT + ST_IMAGE))
+            sc_avoid = srow(sp(L.STAT + ST_AVOID))
+            shasall = srow(sp(L.STAT + ST_HAS_ALL))
+            ipa_present = sp(L.IPAP)
 
             # ---- NodeResourcesFit (exact int32 after GCD rescale) ----
-            mask_fit = fit_row(t)
+            mask_fit = fit_row(sp)
 
-            # ---- PTS filter (per-node counts; all C constraints as one
-            # (C, Np) block — fewer dynamic reads, wider VPU ops) ----
-            if "ptsf" in skip:
-                fail_pts = jnp.zeros((1, Np), jnp.bool_)
-            else:
-                base = pl.multiple_of(t * CP, SUB)
-                cntf = cntfn_ref[pl.ds(base, CP), :].astype(f32)   # (CP, Np)
-                sameM = _sq_from_smem(sm_fsame, t, C, CP)          # (CP, CP)
-                sh = jax.lax.dot_general(
-                    sameM, cntf, (((1,), (0,)), ((), ())),
-                    preferred_element_type=f32,
-                    precision=jax.lax.Precision.HIGHEST)           # (CP, Np)
-                reg = regrowf_ref[pl.ds(base, CP), :]
-                big = f32(POS_BIG)
-                min_c = jnp.min(jnp.where(reg != 0, sh, big),
-                                axis=1, keepdims=True)             # (C, 1)
-                min_c = jnp.where(min_c == big, f32(0.0), min_c)
-                cnt_n = jnp.where(reg != 0, sh, f32(0.0))
-                konn = konnf_ref[pl.ds(base, CP), :]
-                vld = _col_tc(sm_tc, W_F_VALID, t, C, CP)      # (CP, 1)
-                selfm = _col_tc(sm_tc, W_F_SELF, t, C, CP)
-                maxskew = _col_tc(sm_tc, W_F_SKEW, t, C, CP)
-                fail_missing = (vld != 0) & (konn == 0)
-                skew = cnt_n + selfm - min_c
-                fail_skew = (vld != 0) & (konn != 0) & (skew > maxskew)
-                # axis-0 reduction via ones-dot (Mosaic can't lower
-                # multi_reduction over the sublane axis here)
-                onesC = jnp.ones((1, CP), f32)
-                fail_pts = jax.lax.dot_general(
-                    onesC, (fail_missing | fail_skew).astype(f32),
-                    (((1,), (0,)), ((), ())),
-                    preferred_element_type=f32) > 0                # (1, Np)
+            # ---- PTS filter: per-node counts of the reader's own rows ----
+            fail_pts = jnp.zeros((1, Np), i32)
+            for c in range(C) if "ptsf" not in skip else ():
+                o = L.PF + PF_W * c
 
-            # ---- InterPodAffinity: static parts + assumed-pod counts
-            # (D1-D3 of the hoisted term machinery as gate-matrix dots
-            # over the per-node ucnt carry; see _build_ipa) ----
+                def hard(o=o, c=c):
+                    sh = jnp.zeros((1, Np), i32)
+                    for c2 in range(C):
+                        sh = sh + sp(L.FS + c * C + c2) * crow(
+                            sp(L.PF + PF_W * c2 + 3))
+                    reg = srow(sp(o + 4))
+                    konn = srow(sp(o + 5))
+                    min_c = jnp.min(jnp.where(reg != 0, sh, i32(POS_BIG)))
+                    min_c = jnp.where(min_c == POS_BIG, i32(0), min_c)
+                    skew = jnp.where(reg != 0, sh, i32(0)) + sp(o + 2) - min_c
+                    return ((konn == 0) | (skew > sp(o + 1))).astype(i32)
+
+                fail_pts = jnp.maximum(fail_pts, jax.lax.cond(
+                    sp(o) != 0, hard, lambda: jnp.zeros((1, Np), i32)))
+
+            # ---- InterPodAffinity: static rows + the reader's own count
+            # rows (D1-D3 of the hoisted term machinery) ----
             if dyn_ipa:
-                ucf = ucnt_ref[:].astype(f32)                  # (UR, Np)
-                pos = (ucnt_ref[:] > 0).astype(f32)
-                # D1: assumed pods' anti terms repel this pod
-                g1row = g1_ref[pl.ds(t, 1), :]                 # (1, UR)
-                fail1 = doth(g1row, pos, (((1,), (0,)), ((), ()))) > 0
-                fe_static = ipastat_ref[pl.ds(2 * t, 1), :]
-                aff_allk = ipastat_ref[pl.ds(2 * t + 1, 1), :]
-                base8 = pl.multiple_of(t * SUB, SUB)
-                # D2: assumed pods vs this pod's own anti terms
-                anti_dyn = doth(wanti_ref[pl.ds(base8, SUB), :], ucf,
-                                (((1,), (0,)), ((), ())))      # (SUB, Np)
-                a_stat = antic_ref[pl.ds(base8, SUB), :].astype(f32)
-                akonn = antik_ref[pl.ds(base8, SUB), :]
-                avld = _col_av(0, t)                           # (SUB, 1)
-                onesS = jnp.ones((1, SUB), f32)
-                fail_anti_rows = ((avld != 0) & (akonn != 0)
-                                  & ((a_stat + anti_dyn) > 0)).astype(f32)
-                fail_anti = doth(onesS, fail_anti_rows,
-                                 (((1,), (0,)), ((), ()))) > 0  # (1, Np)
-                # D3: assumed pods matching ALL of this pod's aff terms
-                aff_dyn = doth(waff_ref[pl.ds(base8, SUB), :], ucf,
-                               (((1,), (0,)), ((), ())))
-                f_stat = affc_ref[pl.ds(base8, SUB), :].astype(f32)
-                fvld = _col_av(1, t)
-                miss_rows = ((fvld != 0)
-                             & ((f_stat + aff_dyn) <= 0)).astype(f32)
-                pods_missing = doth(onesS, miss_rows,
-                                    (((1,), (0,)), ((), ()))) > 0
-                kc0 = kcnt_ref[:, 0:1].astype(f32)             # (UR, 1)
-                w3row = w3tot_ref[pl.ds(t, 1), :]
-                at_dyn = jnp.sum(doth(w3row, kc0, (((1,), (0,)), ((), ()))))
-                counts_empty = (sm_ipa_t(t, 2).astype(f32) + at_dyn) == 0
-                has_aff = sm_ipa_t(t, 0)
-                smatch = sm_ipa_t(t, 1)
-                aff_ok = ((has_aff == 0)
-                          | ((aff_allk != 0)
-                             & (jnp.logical_not(pods_missing)
-                                | (counts_empty & (smatch != 0)))))
-                mask_ipa = (jnp.logical_not((fe_static != 0) | fail1)
-                            & jnp.logical_not(fail_anti) & aff_ok)
+                io = L.IPA
+
+                def terms():
+                    def repel(i, acc):  # D1: assumed pods' anti terms
+                        return jnp.maximum(
+                            acc, (crow(pool_ref[sp(io + 5) + i]) > 0)
+                            .astype(i32))
+
+                    fail = jax.lax.fori_loop(
+                        0, sp(io + 6), repel,
+                        (srow(sp(io + 3)) != 0).astype(i32))
+
+                    def anti(i, acc):  # D2: own anti terms
+                        e = sp(io + 7) + 3 * i
+                        hit = ((srow(pool_ref[e + 1]) != 0)
+                               & ((srow(pool_ref[e]) + crow(pool_ref[e + 2]))
+                                  > 0))
+                        return jnp.maximum(acc, hit.astype(i32))
+
+                    fail = jax.lax.fori_loop(0, sp(io + 8), anti, fail)
+
+                    def aff(i, acc):  # D3: own affinity terms
+                        e = sp(io + 9) + 2 * i
+                        dyn = crow(pool_ref[e + 1])
+                        miss = ((srow(pool_ref[e]) + dyn) <= 0).astype(i32)
+                        return (jnp.maximum(acc[0], miss),
+                                jnp.maximum(acc[1], (dyn > 0).astype(i32)))
+
+                    z = jnp.zeros((1, Np), i32)
+                    missing, seen = jax.lax.fori_loop(
+                        0, sp(io + 10), aff, (z, z))
+                    counts_empty = ((sp(io + 2) == 0)
+                                    & jnp.logical_not(any_lane(seen)))
+                    aff_ok = ((sp(io) == 0)
+                              | ((srow(sp(io + 4)) != 0)
+                                 & ((missing == 0)
+                                    | (counts_empty & (sp(io + 1) != 0)))))
+                    return ((fail == 0) & aff_ok).astype(i32)
+
+                # specs no term reads or names keep the all-ones mask
+                touched = ((sp(io) != 0) | (sp(io + 6) > 0) | (sp(io + 8) > 0)
+                           | (sp(io + 3) != 0))
+                mask_ipa = jax.lax.cond(
+                    touched, terms, lambda: jnp.ones((1, Np), i32)) != 0
             else:
                 mask_ipa = jnp.ones((1, Np), jnp.bool_)
 
-            feasible = ((static_mask != 0) & mask_fit
-                        & jnp.logical_not(fail_pts) & mask_ipa
-                        & (valid_n != 0))
-            n_feasible = jnp.sum(feasible.astype(f32)).astype(jnp.int32)
+            feasible = ((static_mask != 0) & mask_fit & (fail_pts == 0)
+                        & mask_ipa & (valid_n != 0))
+            n_feasible = jnp.sum(feasible.astype(f32)).astype(i32)
 
             # ---- resource scores ----
-            balanced, least = resource_rows(t)
+            balanced, whole, key, least = resource_rows(sp)
 
             # ---- PTS score ----
-            shasall = shasall_ref[pl.ds(t, 1), :]
             scored = feasible & (shasall != 0)
             ignored = feasible & (shasall == 0)
             scored_f32 = scored.astype(f32)
             n_scored = jnp.sum(scored_f32)
-            # zone-presence among scored nodes, per key: (1, VZ) and its
-            # per-node expansion — the ONLY matvecs in the step
-            zp = []
-            zpn = []
-            for k in range(K) if "zp" not in skip else ():
-                p = (dotn(scored_f32, k) > 0).astype(f32)
-                zp.append(p)
-                zpn.append(dotz(p, k))
-            if "zp" in skip:
-                zp = [jnp.zeros((1, VZ), f32)] * K
-                zpn = [jnp.zeros((1, Np), f32)] * K
-            zval_l = None  # (set in the vectorized score block)
-            if "ptss" in skip:
-                raw = jnp.zeros((1, Np), f32)
-                have_s = jnp.int32(0)
-            else:
-                base = pl.multiple_of(t * CP, SUB)
-                cnts = cntsn_ref[pl.ds(base, CP), :].astype(f32)   # (CP, Np)
-                sameS = _sq_from_smem(sm_ssame, t, C, CP)
-                sh = jax.lax.dot_general(
-                    sameS, cnts, (((1,), (0,)), ((), ())),
-                    preferred_element_type=f32,
-                    precision=jax.lax.Precision.HIGHEST)           # (CP, Np)
-                vld = _col_tc(sm_tc, W_S_VALID, t, C, CP)      # (CP, 1)
-                perno = _col_tc(sm_tc, W_S_PERNO, t, C, CP)
-                key = _col_tc(sm_tc, W_S_KEY, t, C, CP)
-                first = _col_tc(sm_tc, W_S_FIRST, t, C, CP)
-                sskew = _col_tc(sm_tc, W_S_SKEW, t, C, CP)
-                have_s = (jnp.sum(
-                    jax.lax.dot_general(
-                        jnp.ones((1, CP), f32), vld,
-                        (((1,), (0,)), ((), ())),
-                        preferred_element_type=f32)) > 0).astype(jnp.int32)
-                zval_l = zvalid_ref[pl.ds(base, CP), :].astype(f32)  # (CP, VZ)
-                zval_n = zvnode_ref[pl.ds(base, CP), :]              # (CP, Np)
-                topo = jnp.zeros((CP, 1), f32)
-                regn = jnp.zeros((CP, Np), f32)
-                for k in range(K):
-                    use = (jnp.logical_not(perno != 0)
-                           & (key == k)).astype(f32)               # (C, 1)
-                    topo = topo + use * jnp.sum(zp[k] * zval_l, axis=1,
-                                                keepdims=True)
-                    regn = regn + use * zpn[k]
-                regn = regn * (zval_n != 0)
-                topo_size = jnp.where(first != 0, topo, f32(0.0))
-                weight = jnp.log(jnp.where(perno != 0, n_scored, topo_size)
-                                 + f32(2.0))                       # (C, 1)
-                cnt_n = jnp.where(perno != 0, sh,
-                                  jnp.where(regn > 0, sh, f32(0.0)))
-                konn = konns_ref[pl.ds(base, CP), :]
-                term = jnp.where(
-                    (vld != 0) & (konn != 0),
-                    cnt_n * weight + (sskew - f32(1.0)),
-                    f32(0.0))
-                raw = jax.lax.dot_general(
-                    jnp.ones((1, CP), f32), term,
-                    (((1,), (0,)), ((), ())),
-                    preferred_element_type=f32,
-                    precision=jax.lax.Precision.HIGHEST)           # (1, Np)
-            raw_i = raw.astype(jnp.int32)
-            min_r = jnp.min(jnp.where(scored, raw_i, jnp.int32(POS_BIG)))
-            max_r = jnp.max(jnp.where(scored, raw_i, jnp.int32(0)))
-            min_r = jnp.where(min_r == POS_BIG, jnp.int32(0), min_r)
-            norm = (MAX_NODE_SCORE * (max_r + min_r - raw_i)
-                    // jnp.where(max_r == 0, jnp.int32(1), max_r))
-            norm = jnp.where(max_r == 0, jnp.int32(MAX_NODE_SCORE), norm)
-            norm = jnp.where(ignored, jnp.int32(0), norm)
-            sc_pts = jnp.where(have_s != 0, norm, jnp.int32(0))
+            raw = jnp.zeros((1, Np), f32)
+            have_s = i32(0)
+            for c in range(C) if "ptss" not in skip else ():
+                o = L.PS + PS_W * c
 
-            # ---- IPA score: static raw + assumed-pod terms (D4+D5) ----
+                def soft(o=o, c=c):
+                    sh = jnp.zeros((1, Np), i32)
+                    for c2 in range(C):
+                        sh = sh + sp(L.SS + c * C + c2) * crow(
+                            sp(L.PS + PS_W * c2 + 5))
+                    shf = sh.astype(f32)
+                    perno = sp(o + 4) != 0
+
+                    def zone():
+                        # zone presence among scored nodes and its
+                        # per-node expansion — the ONLY matvecs in the step
+                        k = sp(o + 3)
+                        p = (jax.lax.dot_general(
+                            scored_f32, onehot_ref[k],
+                            (((1,), (0,)), ((), ())),
+                            preferred_element_type=f32) > 0).astype(f32)
+                        zpn = jax.lax.dot_general(
+                            p, onehot_ref[k], (((1,), (1,)), ((), ())),
+                            preferred_element_type=f32)
+                        zval_l = zrow_ref[pl.ds(sp(o + 8), 1), :].astype(f32)
+                        topo = jnp.sum(p * zval_l)
+                        regn = zpn * (srow(sp(o + 6)) != 0)
+                        topo = jnp.where(sp(o + 2) != 0, topo, f32(0.0))
+                        return jnp.where(regn > 0, shf, f32(0.0)), topo
+
+                    cnt_n, size = jax.lax.cond(
+                        perno, lambda: (shf, n_scored), zone)
+                    weight = jnp.max(jnp.log(
+                        jnp.full((1, LANE), size, f32) + f32(2.0)))
+                    return jnp.where(
+                        srow(sp(o + 7)) != 0,
+                        cnt_n * weight + (sp(o + 1) - 1).astype(f32),
+                        f32(0.0))
+
+                raw = raw + jax.lax.cond(
+                    sp(o) != 0, soft, lambda: jnp.zeros((1, Np), f32))
+                have_s = jnp.maximum(have_s, (sp(o) != 0).astype(i32))
+            raw_i = raw.astype(i32)
+            min_r = jnp.min(jnp.where(scored, raw_i, i32(POS_BIG)))
+            max_r = jnp.max(jnp.where(scored, raw_i, i32(0)))
+            min_r = jnp.where(min_r == POS_BIG, i32(0), min_r)
+            norm = (MAX_NODE_SCORE * (max_r + min_r - raw_i)
+                    // jnp.where(max_r == 0, i32(1), max_r))
+            norm = jnp.where(max_r == 0, i32(MAX_NODE_SCORE), norm)
+            norm = jnp.where(ignored, i32(0), norm)
+            sc_pts = jnp.where(have_s != 0, norm, i32(0))
+
+            # ---- IPA score: static raw + the reader's score row (D4+D5) --
+            present = ipa_present != 0
             if dyn_ipa:
-                w45row = w45_ref[pl.ds(t, 1), :]
-                dyn45 = doth(w45row, ucf, (((1,), (0,)), ((), ())))
-                # the f32 dot ran on GCD-scaled weights (exactness needs
-                # only sum|w/g| * count < 2^24); the int32 multiply
-                # restores real magnitudes exactly
-                raw_ipa = raw_ipa + dyn45.astype(jnp.int32) * sc[off_w45s]
-                rowany = jnp.max(pos, axis=1, keepdims=True)   # (UR, 1)
-                gp = gpres_ref[pl.ds(t, 1), :]
-                pres_dyn = jnp.sum(
-                    doth(gp, rowany, (((1,), (0,)), ((), ())))) > 0
-                present = (ipa_present != 0) | pres_dyn
-            else:
-                present = ipa_present != 0
+                raw_ipa = raw_ipa + crow(sp(L.IPA + 11))
+                present = present | any_lane(crow(sp(L.IPA + 12)))
 
             # ---- IPA normalize ----
-            min_i = jnp.min(jnp.where(feasible, raw_ipa, jnp.int32(POS_BIG)))
-            max_i = jnp.max(jnp.where(feasible, raw_ipa, jnp.int32(NEG_BIG)))
+            min_i = jnp.min(jnp.where(feasible, raw_ipa, i32(POS_BIG)))
+            max_i = jnp.max(jnp.where(feasible, raw_ipa, i32(NEG_BIG)))
             diff = (max_i - min_i).astype(f32)
             ipa = jnp.where(
                 diff > 0,
                 (MAX_NODE_SCORE * ((raw_ipa - min_i).astype(f32)
                                    / jnp.where(diff > 0, diff, f32(1.0))))
-                .astype(jnp.int32),
-                jnp.zeros((1, Np), jnp.int32))
-            ipa = jnp.where(present, ipa, jnp.zeros((1, Np), jnp.int32))
+                .astype(i32),
+                jnp.zeros((1, Np), i32))
+            ipa = jnp.where(present, ipa, jnp.zeros((1, Np), i32))
 
             # ---- default-normalized taint / node-affinity ----
             def norm_default(counts, reverse):
-                mx = jnp.max(jnp.where(feasible, counts, jnp.int32(0)))
+                mx = jnp.max(jnp.where(feasible, counts, i32(0)))
                 scaled = (MAX_NODE_SCORE * counts
-                          // jnp.where(mx == 0, jnp.int32(1), mx))
+                          // jnp.where(mx == 0, i32(1), mx))
                 if reverse:
-                    return jnp.where(mx == 0, jnp.int32(MAX_NODE_SCORE),
-                                     jnp.int32(MAX_NODE_SCORE) - scaled)
+                    return jnp.where(mx == 0, i32(MAX_NODE_SCORE),
+                                     i32(MAX_NODE_SCORE) - scaled)
                 return jnp.where(mx == 0, counts, scaled)
 
             sc_taint = norm_default(cnt_taint, True)
             sc_nodeaff = norm_default(cnt_nodeaff, False)
 
-            total = (balanced * W["balanced"] + sc_image * W["image"]
-                     + ipa * W["ipa"] + least * W["least"]
-                     + sc_nodeaff * W["node_affinity"]
-                     + sc_avoid * W["prefer_avoid"]
-                     + sc_pts * W["pts"] + sc_taint * W["taint"])
-            total = jnp.where(feasible, total, jnp.int32(-1))
+            total = (balanced * Wt["balanced"] + sc_image * Wt["image"]
+                     + ipa * Wt["ipa"] + least * Wt["least"]
+                     + sc_nodeaff * Wt["node_affinity"]
+                     + sc_avoid * Wt["prefer_avoid"]
+                     + sc_pts * Wt["pts"] + sc_taint * Wt["taint"])
+            total = jnp.where(feasible, total, i32(-1))
+            if cfg.bal_int and Wt["balanced"] != 0:
+                # float64 reads one less than the exact floor at a few
+                # exact-integer states; lowering a lane can only matter if
+                # that lane stands at the maximum now
+                m0 = jnp.max(total)
+                top_whole = any_lane(
+                    (feasible & whole & (total == m0)).astype(i32))
+                total = jax.lax.cond(
+                    top_whole & (bad_ref[0] > 0),
+                    lambda: jnp.where(
+                        feasible & whole,
+                        total - quirk_mask(key) * Wt["balanced"], total),
+                    lambda: total)
 
-            # first-max (jnp.argmax tie semantics; exact — scores < 2^24)
-            tf = total.astype(f32)
-            m = jnp.max(tf)
-            idx = jnp.where(tf >= m, lane_n, jnp.int32(POS_BIG))
-            best = jnp.min(idx).astype(jnp.int32)
+            # first-max (jnp.argmax tie semantics), exact in int32
+            m = jnp.max(total)
+            best = jnp.min(jnp.where(total >= m, lane_n, i32(POS_BIG)))
             ok = (m >= 0) & (b < breal_ref[0])
-            wbl = balanced * W["balanced"] + least * W["least"]
-            return t, lane_n, best, m, ok, n_feasible, total, wbl
+            return sp, lane_n, best, m, ok, n_feasible
+
+        def apply_pod(sp, lane_n, best, oki):
+            """Carry updates for a pod of this spec landing on `best`."""
+            hot = (lane_n == best).astype(i32) * oki   # (1, Np)
+            for r in range(R):
+                requested_ref[r:r + 1, :] = (
+                    requested_ref[r:r + 1, :] + hot * sp(L.REQ + r))
+            nzpc_ref[0:1, :] = nzpc_ref[0:1, :] + hot * sp(L.NZ)
+            nzpc_ref[1:2, :] = nzpc_ref[1:2, :] + hot * sp(L.NZ + 1)
+            nzpc_ref[2:3, :] = nzpc_ref[2:3, :] + hot
+
+            def touch(i, _):
+                # the pod joins its node's topology group on the row's
+                # key: same-pair lanes get the weight (-1 = the node lacks
+                # the key: no lane). Zone spread rows also ask that the
+                # landing node is one the reader counts on (src row).
+                e = sp(L.TCH) + TOUCH_W * i
+                row = pool_ref[e]
+                pair = srow(pool_ref[e + 1])
+                at = at_lane(pair, lane_n, best)
+                src = pool_ref[e + 3]
+                gate = jnp.where(
+                    src < 0, i32(1),
+                    at_lane(srow(jnp.maximum(src, 0)), lane_n, best))
+                w = pool_ref[e + 2] * oki * (gate > 0).astype(i32)
+                same = ((pair == at) & (pair >= 0)).astype(i32)
+                cnt_ref[pl.ds(row, 1), :] = crow(row) + same * w
+                return i32(0)
+
+            jax.lax.fori_loop(0, sp(L.TCH + 1), touch, i32(0))
 
         def one_pod(b):
-            if mode == "apply":
-                # forced decision (the cross-shard winner, mapped to this
-                # shard's local lanes or -1): updates only, no eval
-                t = tmpl_ref[b]
-                lane_n = jax.lax.broadcasted_iota(jnp.int32, (1, Np), 1)
-                best = forced_ref[2 * b]
-                oki = forced_ref[2 * b + 1]
-                okf = oki.astype(f32)
-                _apply_updates(b, t, lane_n, best, oki, okf)
-                return jnp.int32(0)
-            t, lane_n, best, m, ok, n_feasible, total, wbl = eval_pod(b)
-            oki = ok.astype(jnp.int32)
-            okf = oki.astype(f32)
-
-            if "updates" in skip or mode == "eval":
-                # eval-only: best/score/feasible out, carries untouched
-                # (the sharded session applies the GLOBAL decision in a
-                # separate "apply" launch after the cross-shard argmax)
-                subi0 = jax.lax.broadcasted_iota(jnp.int32, (SUB, Bp), 0)
-                lanei0 = jax.lax.broadcasted_iota(jnp.int32, (SUB, Bp), 1)
-                at_b0 = lanei0 == b
-                o = out_ref[:]
-                o = jnp.where(at_b0 & (subi0 == 0),
-                              jnp.where(ok, best, jnp.int32(-1)), o)
-                o = jnp.where(at_b0 & (subi0 == 1),
-                              jnp.where(ok, m.astype(jnp.int32),
-                                        jnp.int32(-1)), o)
-                o = jnp.where(at_b0 & (subi0 == 2), n_feasible, o)
-                out_ref[:] = o
-                return jnp.int32(0)
-            _apply_updates(b, t, lane_n, best, oki, okf)
-
-            subi = jax.lax.broadcasted_iota(jnp.int32, (SUB, Bp), 0)
-            lanei = jax.lax.broadcasted_iota(jnp.int32, (SUB, Bp), 1)
+            sp, lane_n, best, m, ok, n_feasible = eval_pod(b)
+            if "updates" not in skip:
+                apply_pod(sp, lane_n, best, ok.astype(i32))
+            subi = jax.lax.broadcasted_iota(i32, (SUB, Bp), 0)
+            lanei = jax.lax.broadcasted_iota(i32, (SUB, Bp), 1)
             at_b = lanei == b
             o = out_ref[:]
             o = jnp.where(at_b & (subi == 0),
-                          jnp.where(ok, best, jnp.int32(-1)), o)
-            o = jnp.where(at_b & (subi == 1),
-                          jnp.where(ok, m.astype(jnp.int32), jnp.int32(-1)),
-                          o)
+                          jnp.where(ok, best, i32(-1)), o)
+            o = jnp.where(at_b & (subi == 1), jnp.where(ok, m, i32(-1)), o)
             o = jnp.where(at_b & (subi == 2), n_feasible, o)
             out_ref[:] = o
 
-        def write_multi(b, best, score, nfeas, okc, flag):
-            """Out rows for one multipod-group pod: 0 best / 1 score /
-            2 n_feasible / 3 conflict-suffix flag (1 = NOT committed,
-            host must replay)."""
-            subi = jax.lax.broadcasted_iota(jnp.int32, (SUB, Bp), 0)
-            lanei = jax.lax.broadcasted_iota(jnp.int32, (SUB, Bp), 1)
-            at_b = lanei == b
-            placed = okc != 0
-            o = out_ref[:]
-            o = jnp.where(at_b & (subi == 0),
-                          jnp.where(placed, best, jnp.int32(-1)), o)
-            o = jnp.where(at_b & (subi == 1),
-                          jnp.where(placed, score, jnp.int32(-1)), o)
-            o = jnp.where(at_b & (subi == 2), nfeas, o)
-            o = jnp.where(at_b & (subi == 3), flag, o)
-            out_ref[:] = o
-
-        def multi_group(j, seen):
-            """mk pods per step: parallel-in-spirit evals against the
-            group-start carry refs (commits are DEFERRED, so nothing a
-            later eval reads has moved), then in-order commits gated by
-            the exact conflict test. `seen` carries the suffix flag
-            ACROSS groups: later groups' evals chained on a carry
-            missing suffix commits are invalid too."""
-            base = j.astype(jnp.int32) * jnp.int32(mk)
-            evs = [eval_pod(base + jnp.int32(i)) for i in range(mk)]
-            conf_seen = seen
-            committed = []  # (best, okc, tmpl) of this group's prefix
-            for i in range(mk):
-                b = base + jnp.int32(i)
-                t, lane_n, best, m, ok, nfeas, total, wbl = evs[i]
-                score_i = jnp.max(total)  # int32 twin of the f32 argmax m
-                conf = jnp.int32(0)
-                if i > 0:
-                    gate_f = lane_gate(W_F_VALID, t)
-                    gate_s = lane_gate(W_S_VALID, t)
-                for e, (be, oke, te) in enumerate(committed):
-                    same = oke * ((be == best)
-                                  & (m >= 0)).astype(jnp.int32)
-                    # PTS: pod e's Mf/Ms lanes of template t, valid-gated
-                    mf_e = mf_ref[pl.ds(base + jnp.int32(e), 1),
-                                  :].astype(f32)
-                    ms_e = ms_ref[pl.ds(base + jnp.int32(e), 1),
-                                  :].astype(f32)
-                    hit = (jnp.sum(mf_e * gate_f)
-                           + jnp.sum(ms_e * gate_s)) > 0
-                    conf = jnp.maximum(conf, jnp.maximum(
-                        same, oke * hit.astype(jnp.int32)))
-                    if ur > 0:
-                        # IPA template-interference superset (gmat)
-                        grow = gmat_ref[pl.ds(te, 1), :]
-                        lanei1 = jax.lax.broadcasted_iota(
-                            jnp.int32, (1, LANE), 1)
-                        gv = jnp.sum(jnp.where(lanei1 == t, grow,
-                                               f32(0.0)))
-                        conf = jnp.maximum(
-                            conf, oke * (gv > 0).astype(jnp.int32))
-                # utilization legs (kernel.multipod_utilization_conflicts
-                # mirrored in Mosaic): fit/balanced/least are the only
-                # carry-reading plugins left once the count gates are
-                # clean — recheck them against the CURRENT refs
-                fit_new = fit_row(t)
-                bal2, least2 = resource_rows(t)
-                new_tot = total - wbl + (bal2 * W["balanced"]
-                                         + least2 * W["least"])
-                feas_old = total >= 0
-                flip = jnp.max(jnp.where(
-                    feas_old & jnp.logical_not(fit_new),
-                    f32(1.0), f32(0.0))) > 0
-                over = jnp.max(jnp.where(
-                    feas_old & fit_new
-                    & ((new_tot > score_i)
-                       | ((new_tot == score_i) & (lane_n < best))),
-                    f32(1.0), f32(0.0))) > 0
-                util = (flip | (over & (m >= 0))).astype(jnp.int32)
-                conf = jnp.maximum(conf, util)
-                conf = conf * (b < breal_ref[0]).astype(jnp.int32)
-                conf_seen = jnp.maximum(conf_seen, conf)
-                okc = ok.astype(jnp.int32) * (jnp.int32(1) - conf_seen)
-                _apply_updates(b, t, lane_n, best, okc, okc.astype(f32))
-                committed.append((best, okc, t))
-                write_multi(b, best, score_i, nfeas, okc, conf_seen)
-            return conf_seen
-
-        if mode == "full" and mk > 1 and "updates" not in skip:
-            jax.lax.fori_loop(0, Bp // mk, multi_group, jnp.int32(0))
-            return
-
         # manual unroll: U pods per loop iteration amortizes Mosaic's
-        # per-iteration bookkeeping (the marginal-cost floor; partial
-        # `unroll=` is unsupported by the TPU lowering). b >= B_real
-        # iterations are no-ops via the ok gate.
-        U = int(_knobs.get_int("KTPU_PALLAS_GROUP"))
+        # per-iteration bookkeeping (partial `unroll=` is unsupported by
+        # the TPU lowering). b >= B_real iterations are no-ops via the ok
+        # gate.
+        U = int(knobs.get_int("KTPU_PALLAS_GROUP"))
         while Bp % U:
             U //= 2
 
         def body(j, _):
-            base = j.astype(jnp.int32) * jnp.int32(U)
+            base = j.astype(i32) * i32(U)
             for i in range(U):
-                one_pod(base + jnp.int32(i))
-            return jnp.int32(0)
+                one_pod(base + i32(i))
+            return i32(0)
 
-        jax.lax.fori_loop(0, Bp // U, body, jnp.int32(0))
+        jax.lax.fori_loop(0, Bp // U, body, i32(0))
 
     return kernel
 
 
-def _sq_from_smem(sm_pair, t, C, CP):
-    """(CP, CP) f32 same-key matrix from SMEM scalars.
-
-    Built as a sum of scalar x static-one-hot constants — Mosaic cannot
-    shape-cast stacked scalars into 2D."""
-    i0 = jax.lax.broadcasted_iota(jnp.int32, (CP, CP), 0)
-    i1 = jax.lax.broadcasted_iota(jnp.int32, (CP, CP), 1)
-    out = jnp.zeros((CP, CP), jnp.float32)
-    for ci in range(C):
-        for cj in range(C):
-            e = ((i0 == ci) & (i1 == cj)).astype(jnp.float32)
-            out = out + sm_pair(t, ci, cj).astype(jnp.float32) * e
-    return out
+# the kernel's VMEM statics, in operand order (after the SMEM operands)
+_VMEM_STATICS = ("alloc", "valid_n", "balgrp", "srow", "zrow", "onehot")
+_SMEM_STATICS = ("spec", "pool", "bad")
 
 
-def _col_tc(sm_tc, which, t, C, CP):
-    """(CP, 1) f32 column of per-(t, c) SMEM scalars (one-hot sums)."""
-    i0 = jax.lax.broadcasted_iota(jnp.int32, (CP, 1), 0)
-    out = jnp.zeros((CP, 1), jnp.float32)
-    for cc in range(C):
-        e = (i0 == cc).astype(jnp.float32)
-        out = out + sm_tc(which, t, cc).astype(jnp.float32) * e
-    return out
-
-
-def _stack_tc(sm_tc, which, T, C, TCp):
-    """(TCp, 1) f32 from per-(t,c) SMEM scalars (one-hot sums)."""
-    CP = TCp // T
-    i0 = jax.lax.broadcasted_iota(jnp.int32, (TCp, 1), 0)
-    out = jnp.zeros((TCp, 1), jnp.float32)
-    for t in range(T):
-        for cc in range(C):
-            e = (i0 == (t * CP + cc)).astype(jnp.float32)
-            out = out + (sm_tc(which, t, cc) != 0).astype(jnp.float32) * e
-    return out
-
-
-# the kernel's VMEM statics, in operand order (after the SMEM scalars)
-_VMEM_STATICS = (
-    "alloc", "stat", "onehot", "regrow_f", "zvalid_node_s", "zvalid_s",
-    "konn_f", "konn_s", "shasall", "valid_n", "rowt", "eye", "prow_f",
-    "prow_s", "gmat",
-)
-
-
-def _kernel_vmem_bytes(statics: Dict, ipa: Optional[Dict], carry: Dict,
-                       Bp: int) -> int:
+def _kernel_vmem_bytes(statics: Dict, carry: Dict, Bp: int) -> int:
     """Bytes one launch keeps in VMEM: the statics, the carries twice
-    (input refs and the aliased output refs both exist in the kernel),
-    the widened match rows and the result rows."""
+    (input refs and the aliased output refs both exist in the kernel) and
+    the result rows."""
     def nbytes(x):
         return math.prod(x.shape) * np.dtype(x.dtype).itemsize
 
     return (sum(nbytes(statics[k]) for k in _VMEM_STATICS)
-            + sum(nbytes(v) for v in (ipa or {}).values())
             + 2 * sum(nbytes(v) for v in carry.values())
-            + 2 * Bp * LANE * 4 + SUB * Bp * 4)
+            + SUB * Bp * 4)
 
 
 def _vmem_request(operand_bytes: int) -> int:
     """What the kernel asks the compiler for: its operands plus room for
-    the step's (CP, Np) temporaries, which grow with the node axis like
-    the operands do. At 5000 nodes ~10 MB of operands compile under the
-    default 16 MiB scope, so half again plus 16 MiB is generous."""
-    return operand_bytes + operand_bytes // 2 + (16 << 20)
+    the step's (1, Np) temporaries."""
+    return operand_bytes + operand_bytes // 8 + (16 << 20)
 
 
 def _vmem_cap() -> int:
@@ -2002,54 +710,29 @@ def _vmem_limit(operand_bytes: int) -> int:
 
 @functools.partial(jax.jit, static_argnames=("cfg",),
                    donate_argnames=("carry",))
-def _dispatch(cfg: "_Cfg", statics: Dict, ipa: Optional[Dict],
-              meta, carry: Dict, match, forced=None):
-    # meta = [B_real | tmpl] (int32), match = [mfT | msT] (int8): the
-    # whole per-batch payload in two transfers — the split happens here
-    # on-device. B_real stays a DYNAMIC (SMEM) scalar: variable batch
+def _dispatch(cfg: "_Cfg", statics: Dict, meta, carry: Dict):
+    # meta = [B_real | tmpl] (int32): the whole per-batch payload in one
+    # transfer. B_real stays a DYNAMIC (SMEM) scalar: variable batch
     # lengths must not recompile the kernel (only the padded width Bp is
-    # static). The cluster statics arrive as DYNAMIC pytree args, NOT
-    # via the static cfg: baking them in as trace constants made every
-    # session rebuild a fresh program (different constants -> jit cache
-    # miss AND persistent-cache miss) — the 20-30s "warm" rebuild the
-    # churn workload paid mid-window. cfg hashes by VALUE, so two
-    # sessions with the same shapes share one compiled program.
+    # static). The tables arrive as DYNAMIC pytree args, NOT via the
+    # static cfg: cfg hashes by VALUE, so two sessions with the same
+    # capacities share one compiled program, and an admitted spec is new
+    # data for the same program.
     Bp = int(meta.shape[0]) - 1
-    B_real = meta[:1]
-    tmpl = meta[1:]
-    kernel = _build_kernel(cfg.shapes, cfg.weights, Bp, cfg.ur,
-                           mode=cfg.mode, mk=cfg.mk)
-    # widen the int8 wire format on-device (i8 VMEM rows would need
-    # 32-sublane alignment in the kernel; one cheap convert avoids that)
-    mfT = match[:, :LANE].astype(jnp.int32)
-    msT = match[:, LANE:].astype(jnp.int32)
-    carry_keys = cfg.carry_keys
-    carry_in = [carry[k] for k in carry_keys]
-    ipa_in = []
-    if ipa is not None:
-        ipa_in = [ipa[k] for k in
-                  ("ipa_stat", "anti_static", "anti_konn", "aff_static",
-                   "prow_ipa", "g1", "wanti", "waff", "w3tot", "w45",
-                   "gpres")]
+    kernel = _build_kernel(cfg, Bp)
+    carry_in = [carry[k] for k in CARRY_KEYS]
     out_shape = (
         jax.ShapeDtypeStruct((SUB, Bp), jnp.int32),
         *[jax.ShapeDtypeStruct(x.shape, x.dtype) for x in carry_in],
     )
     vm = pl.BlockSpec(memory_space=pltpu.VMEM)
     sm = pl.BlockSpec(memory_space=pltpu.SMEM)
-    pre_args: tuple = ()
-    pre_specs: list = []
-    if cfg.mode == "apply":
-        pre_args = (forced.astype(jnp.int32),)
-        pre_specs = [sm]
-    n_pre = len(pre_specs) + 20 + len(ipa_in)  # inputs before the carries
-    vmem_args = (mfT, msT, *(statics[k] for k in _VMEM_STATICS), *ipa_in,
-                 *carry_in)
+    n_pre = 2 + len(_SMEM_STATICS) + len(_VMEM_STATICS)
     compiler_params = None
     if not cfg.interpret:
         compiler_params = pltpu.CompilerParams(
             vmem_limit_bytes=_vmem_limit(
-                _kernel_vmem_bytes(statics, ipa, carry, Bp)))
+                _kernel_vmem_bytes(statics, carry, Bp)))
     # trace the kernel with x64 OFF: every input is explicitly 32-bit,
     # and weak python literals must not widen ops to i64/f64 (Mosaic has
     # no 64-bit types)
@@ -2057,12 +740,1198 @@ def _dispatch(cfg: "_Cfg", statics: Dict, ipa: Optional[Dict],
         results = pl.pallas_call(
             kernel,
             out_shape=out_shape,
-            in_specs=(pre_specs + [sm, sm, sm, vm, vm] + [vm] * 15
-                      + [vm] * len(ipa_in) + [vm] * len(carry_in)),
+            in_specs=([sm] * (2 + len(_SMEM_STATICS))
+                      + [vm] * (len(_VMEM_STATICS) + len(carry_in))),
             out_specs=tuple([vm] * (1 + len(carry_in))),
             input_output_aliases={n_pre + i: 1 + i
                                   for i in range(len(carry_in))},
             interpret=cfg.interpret,
             compiler_params=compiler_params,
-        )(*pre_args, B_real, tmpl, statics["scalars"], *vmem_args)
-    return results[0], dict(zip(carry_keys, results[1:]))
+        )(meta[:1], meta[1:], *(statics[k] for k in _SMEM_STATICS),
+          *(statics[k] for k in _VMEM_STATICS), *carry_in)
+    return results[0], dict(zip(CARRY_KEYS, results[1:]))
+
+
+# ---------------------------------------------------------------------------
+# table writes: one compiled shape per table, warmed at the build
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _write_rows(table, idx, rows):
+    """table[idx[i]] = rows[i]; an index past the end is dropped (the
+    padding of a short chunk)."""
+    return table.at[idx].set(rows, mode="drop")
+
+
+@functools.partial(jax.jit, donate_argnums=(0, 1, 2))
+def _rescale(requested, nzpc, alloc, k_res, k_nz):
+    """A finer GCD: every utilization value in units of the new one."""
+    return (requested * k_res[:, None], nzpc * k_nz[:, None],
+            alloc * k_res[:, None])
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _delta_scan(carry, srow, xs):
+    """Apply cluster-event deltas to the carry in ONE fused launch: each
+    entry is the jnp twin of the kernel's apply_pod with `best := node`
+    and a sign folded into the payload — utilization columns, then one
+    touch (row, pair row, weight, src row). lax.scan keeps the launch
+    count at ONE regardless of the entry count; padding entries are node
+    0 with all-zero payloads."""
+
+    def step(c, x):
+        c = dict(c)
+        n = x["node"]
+        c["requested"] = c["requested"].at[:, n].add(x["dres"])
+        c["nzpc"] = c["nzpc"].at[:, n].add(x["dnzpc"])
+        pair = jax.lax.dynamic_index_in_dim(srow, x["pair"], 0)   # [1, Np]
+        at = jax.lax.dynamic_index_in_dim(pair, n, 1)
+        same = ((pair == at) & (pair >= 0)).astype(jnp.int32)
+        src = jax.lax.dynamic_index_in_dim(
+            srow, jnp.maximum(x["src"], 0), 0)
+        gate = jnp.where(x["src"] < 0, 1,
+                         (jax.lax.dynamic_index_in_dim(src, n, 1) != 0)
+                         .astype(jnp.int32)[0, 0])
+        c["cnt"] = c["cnt"].at[x["row"]].add((same * x["w"] * gate)[0])
+        return c, None
+
+    carry, _ = jax.lax.scan(step, carry, xs)
+    return carry
+
+
+# ---------------------------------------------------------------------------
+# the session
+
+# per-spec selector tables the host matches label sets against at an
+# admission (spread selectors, then the three term families)
+_PTS_KEYS = tuple(f"{p}_{s}" for p in ("ptsf", "ptss")
+                  for s in ("op", "rkey", "pairs"))
+_TERM_FAMILIES = ("ipaaa", "ipaa", "ipap")
+_TERM_KEYS = tuple(f"{p}_{s}" for p in _TERM_FAMILIES
+                   for s in ("op", "rkey", "pairs", "ns", "valid", "key"))
+_STACK_KEYS = _PTS_KEYS + _TERM_KEYS + ("self_ns", "ipap_weight")
+# the small part of the cluster dict the host half needs
+_CLUSTER_KEYS = ("alloc", "requested", "nz_requested", "pod_count",
+                 "allowed_pods", "valid", "pair_of_key", "nkey",
+                 "hard_pod_affinity_weight")
+
+
+def table_capacity(pod_reserve: int) -> int:
+    """Specs a session should have room for, from the one hint a caller
+    gives (ClusterEncoding.reserve(pods=...)): one spec per 128 pods of
+    the reserve — clusterloader2's load test fills a cluster with groups
+    of 5/30/250 pods, ~9 pods a Deployment, but a table that size would
+    not fit the core, and what does not fit rebuilds (counted) — between
+    64 and 1024, a power of two so capacities rarely move between runs."""
+    want = max(64, min(1024, pod_reserve // 128))
+    return 1 << (want - 1).bit_length()
+
+
+class PallasSession:
+    """HoistedSession-compatible API over the single-launch table kernel.
+
+    Semantics: identical to ops/hoisted.py HoistedSession (same prologue,
+    same carry discipline) — parity pinned by tests/test_pallas_scan.py
+    and tests/test_pallas_table.py. `admit` takes specs into the LIVE
+    session; it raises TableFull where only a rebuild can (a capacity is
+    used up), PallasUnsupported where the spec cannot ride this kernel at
+    all (e.g. a shared-value topology key with more than 128 values)."""
+
+    # KTPU_EXPLAIN: the Mosaic kernel's scan does not surface per-plugin
+    # mask/score sections — explain mode rides the jnp hoisted session
+    # (TPUBackend demotes with session_builds{reason="explain"})
+    supports_explain = False
+
+    @staticmethod
+    def explain_payload(ys):
+        return None
+
+    # ktpu: allow-sync(session build: the node-side arrays come to the host once, before the first dispatch)
+    def __init__(self, cluster: Dict, template_arrays_list: List[Dict],
+                 weights: Optional[Dict[str, int]] = None,
+                 interpret: bool = False, capacity: int = 64,
+                 terms: Optional[bool] = None):
+        """capacity: specs the table has room for (table_capacity).
+        terms: build the term machinery even though no template carries a
+        term yet (the caller expects some: enc.reserve(anti_terms=...));
+        a term spec met by a session built without it is a rebuild."""
+        if templates_have_ports(template_arrays_list):
+            # the jnp HoistedSession carries host-port tables; the pallas
+            # kernel does not (yet) — signal a fallback, not an error
+            raise PallasUnsupported(
+                "templates with host ports ride the jnp hoisted session",
+                reason="host-ports")
+        self.dyn_ipa = bool(terms) or templates_have_terms(
+            template_arrays_list)
+        self.weights = dict(weights or DEFAULT_WEIGHTS)
+        self.interpret = interpret
+        # totals stay int32: every plugin score is <= MAX_NODE_SCORE
+        if sum(abs(int(v)) for v in self.weights.values()) \
+                * (MAX_NODE_SCORE + 1) >= 2 ** 24:
+            raise PallasUnsupported("weights too large for exact totals",
+                                    reason="weights-exceed-f32")
+        c = {k: np.asarray(cluster[k]) for k in _CLUSTER_KEYS}
+        self._c = c
+        t0 = template_arrays_list[0]
+        self.R = int(np.asarray(t0["req"]).shape[0])
+        self.C = int(np.asarray(t0["ptsf_op"]).shape[0])
+        self.N = int(c["valid"].shape[0])
+        self.Np = _ceil(self.N, LANE)
+        self.Tcap = 1 << (max(capacity, 2 * len(template_arrays_list), 64)
+                          - 1).bit_length()
+        self.RC = self.Tcap             # count rows
+        self.SRc = max(64, self.Tcap // 2)   # interned static rows
+        self.ZRc = SUB                  # interned per-zone rows
+        self.K = 2                      # shared-value topology keys
+        self.PC = 16 * self.Tcap        # pool words
+        self._L = _layout(self.R, self.C, self.dyn_ipa)
+        if self.Tcap * self._L.W + self.PC > (1 << 17) + (1 << 16):
+            raise PallasUnsupported("spec records exceed the scalar memory",
+                                    reason="table-capacity")
+        self._sig = None       # array shapes every admitted spec shares
+        self._fps: Dict = {}   # fingerprint -> spec index
+        self.T = 0
+        self._arrays: List[Dict] = []
+        self._has_terms = np.zeros(self.Tcap, bool)
+        self._stack: Dict[str, np.ndarray] = {}
+        self._lab_pair = np.zeros((self.Tcap, 0), bool)
+        self._lab_key = np.zeros((self.Tcap, 0), bool)
+        # per spec, what the kernel loops over (python lists; the pool
+        # holds their flattened copies)
+        self._touch: List[List[Tuple[int, int, int, int]]] = []
+        self._repel: List[List[int]] = []        # D1 count rows
+        self._repel_key: List[Dict[int, int]] = []   # key -> D1 row
+        self._anti: List[List[Tuple[int, int, int]]] = []
+        self._aff: List[List[Tuple[int, int]]] = []
+        self._rows: List[Dict] = []   # per spec: its row ids by role
+        self._dirty: List[bool] = []  # per spec: lists changed, not in pool
+        self._spec = np.zeros(self.Tcap * self._L.W, np.int32)
+        self._pool = np.zeros(self.PC, np.int32)
+        self._pool_n = 0
+        self._srow_ids: Dict[bytes, int] = {}
+        self._zrow_ids: Dict[bytes, int] = {}
+        self._uids: Dict[bytes, int] = {}
+        self._zof: List[Dict[int, int]] = []
+        self._pair_rows: Dict[int, int] = {}   # topology key -> pair row
+        self._n_cnt = 1   # row 0: all zero, never written (absent reads)
+        self._pending: Dict[str, List] = {"srow": [], "zrow": [], "cnt": [],
+                                          "onehot": []}
+        self._build_base(c, template_arrays_list)
+        self._intern_srow(np.zeros(self.Np, np.int32))   # id 0
+        self._intern_zrow(np.zeros(VZ, np.int32))        # id 0
+        # the carry goes to the device with the first dispatch; until
+        # then admissions fill these host seeds
+        self._cnt0 = np.zeros((self.RC, self.Np), np.int32)
+        self._carry = None
+        self._statics = {
+            "alloc": jnp.asarray(self._alloc),
+            "valid_n": jnp.asarray(self._valid_n),
+            "balgrp": jnp.asarray(self._balgrp),
+            "srow": jnp.zeros((self.SRc, self.Np), jnp.int32),
+            "zrow": jnp.zeros((self.ZRc, VZ), jnp.int32),
+            "onehot": jnp.zeros((self.K, self.Np, VZ), jnp.float32),
+            "spec": None, "pool": None,
+            "bad": jnp.asarray(self._bad),
+        }
+        self._cfg = _Cfg(
+            shapes=(self.Tcap, self.PC, self.Np, self.R, self.C, self.RC,
+                    self.SRc, self.ZRc, self.K, MAX_QUIRKS),
+            weights=tuple(sorted(self.weights.items())),
+            ipa=self.dyn_ipa, bal_int=self._bal_int, interpret=interpret)
+        self.admits = 0   # admissions after the build
+        # (Bp, "full") -> AOT-compiled executable (None = AOT unavailable,
+        # dispatch through jit). Shared between the serving path and the
+        # warm_buckets daemon thread; plain dict ops are GIL-atomic and a
+        # rare duplicate compile is absorbed by the persistent cache.
+        self._exec: Dict = {}
+        # (Bp, mode) -> text of the error that pinned that entry to None
+        # (AOT compile rejected, or the executable refused its arguments).
+        # The jit path still serves — and fails the same way one frame
+        # later if the kernel itself is at fault — but the compiler's
+        # message is kept: logged where it happened, and read by
+        # chip_smoke.py / the bench rows, which fail on any entry here.
+        self.exec_errors: Dict = {}
+        self._warm_stop = threading.Event()
+        # every table write below runs the shape it will run in a window
+        self._warm_writers()
+        self._admit(cluster, template_arrays_list)
+        if not interpret and jax.default_backend() == "tpu":
+            # the grid-less kernel holds every operand whole in VMEM: a
+            # table too wide for the core is a clean downgrade here, not
+            # a compiler error at the first dispatch
+            need = _kernel_vmem_bytes(
+                self._statics, self._carry_struct(), 2048)
+            if _vmem_request(need) > _vmem_cap():
+                raise PallasUnsupported(
+                    f"kernel operands ({need >> 20} MiB) exceed the "
+                    f"core's VMEM", reason="vmem-budget")
+
+    # -- node-side state ----------------------------------------------------
+
+    # ktpu: allow-sync(session build: one-time host packing of the node rows, runs before first dispatch)
+    def _build_base(self, c: Dict, templates: List[Dict]) -> None:
+        """Utilization rows and the exact per-dimension GCD rescale to
+        int32."""
+        N, Np, R = self.N, self.Np, self.R
+        alloc = c["alloc"].astype(np.int64).T.copy()            # [R, N]
+        requested = c["requested"].astype(np.int64).T.copy()
+        nz_requested = c["nz_requested"].astype(np.int64).T.copy()  # [2, N]
+        req = np.stack([np.asarray(t["req"]).astype(np.int64)
+                        for t in templates])                    # [T, R]
+        nz_req = np.stack([np.asarray(t["nz_req"]).astype(np.int64)
+                           for t in templates])
+        # the rescale factors survive the build: incoming deltas and
+        # later specs must divide by the SAME gcd to stay exact — a spec
+        # that does not refines it (_refine_gcd), an indivisible delta is
+        # structural (delta_compatible)
+        self._gcd = np.ones(R, np.int64)
+        for r in range(R):
+            extra = [nz_requested[r], nz_req[:, r]] if r < 2 else []
+            g = _gcd_all(alloc[r], requested[r], req[:, r], *extra)
+            self._gcd[r] = g
+            alloc[r] //= g
+            requested[r] //= g
+            if r < 2:
+                nz_requested[r] //= g
+        hi = max((int(a.max(initial=0)) for a in
+                  (alloc, requested, nz_requested)), default=0)
+        if hi * (MAX_NODE_SCORE + 1) >= 2 ** 31:
+            raise PallasUnsupported(
+                f"rescaled resource magnitude {hi} too large for int32",
+                reason="resource-magnitude")
+        self._alloc = _pad2(alloc.astype(np.int32))             # [Rp, Np]
+        self._requested0 = _pad2(requested.astype(np.int32))
+        nzpc = np.zeros((SUB, N), np.int64)
+        nzpc[0] = nz_requested[0]
+        nzpc[1] = nz_requested[1]
+        nzpc[2] = c["pod_count"].astype(np.int64)
+        nzpc[3] = c["allowed_pods"].astype(np.int64)
+        self._nzpc0 = _pad2(nzpc.astype(np.int32))              # [8, Np]
+        vn = np.zeros((SUB, Np), np.int32)
+        vn[:, :N] = c["valid"].astype(np.int32)[None, :]
+        self._valid_n = vn
+        self._bal_int = self._balanced_tables(build=True)
+
+    def _balanced_tables(self, build: bool = False) -> bool:
+        """Node groups by (cpu, memory) capacity and the float64-quirk
+        states of each, as the kernel lists them. False: the exact int32
+        form does not fit these capacities (the kernel then evaluates in
+        f32, as wide clusters always did)."""
+        N = self.N
+        valid = self._c["valid"].astype(bool)
+        cap = self._alloc[:2, :N].astype(np.int64)
+        pairs = np.unique(cap[:, valid].T, axis=0) if valid.any() \
+            else np.zeros((0, 2), np.int64)
+        grp = np.zeros((SUB, self.Np), np.int32)
+        bad = np.zeros(1 + 2 * MAX_QUIRKS, np.int32)
+        ok = len(pairs) <= 64 and all(
+            MAX_NODE_SCORE * int(cc) * int(cm) < 2 ** 31 for cc, cm in pairs)
+        n = 0
+        if ok:
+            for g, (cc, cm) in enumerate(pairs):
+                grp[0, :N][(cap[0] == cc) & (cap[1] == cm)] = g
+                q = _balanced_quirks(int(cc), int(cm))
+                if n + len(q) > MAX_QUIRKS:
+                    ok = False
+                    break
+                bad[1 + 2 * n:1 + 2 * (n + len(q)):2] = g
+                bad[2 + 2 * n:2 + 2 * (n + len(q)):2] = (
+                    q[:, 0] * (int(cm) + 1) + q[:, 1])
+                n += len(q)
+        if not ok:
+            grp[:] = 0
+            bad[:] = 0
+            n = 0
+        bad[0] = n
+        if not build and ok != self._bal_int:
+            raise ValueError("capacities left the exact balanced form")
+        self._balgrp, self._bad = grp, bad
+        return ok
+
+    # -- interned rows ------------------------------------------------------
+
+    def _intern(self, table: str, ids: Dict[bytes, int], cap: int,
+                row: np.ndarray) -> int:
+        """Id of `row` in a content-addressed static table (`srow`,
+        `zrow`); a row met for the first time is queued for the device.
+        Id 0 is the all-zero row every table starts with."""
+        row = np.ascontiguousarray(row, np.int32)
+        key = row.tobytes()
+        i = ids.get(key)
+        if i is None:
+            i = len(ids)
+            if i >= cap:
+                raise TableFull(f"{table} table is full",
+                                reason="table-static-rows")
+            ids[key] = i
+            if i:
+                self._pending[table].append((i, row))
+        return i
+
+    def _intern_srow(self, row: np.ndarray) -> int:
+        return self._intern("srow", self._srow_ids, self.SRc, row)
+
+    def _intern_zrow(self, row: np.ndarray) -> int:
+        return self._intern("zrow", self._zrow_ids, self.ZRc, row)
+
+    def _node_row(self, values, fill: int = 0) -> np.ndarray:
+        out = np.full(self.Np, fill, np.int32)
+        v = np.asarray(values)
+        if np.abs(v.astype(np.int64)).max(initial=0) >= POS_BIG:
+            # POS_BIG (2^30), not 2^31: the kernel's min/max sentinels
+            # must stay strictly above any genuine value
+            raise PallasUnsupported("static magnitude exceeds sentinel",
+                                    reason="score-magnitude")
+        out[: self.N] = v
+        return out
+
+    def _new_cnt(self, init=None) -> int:
+        i = self._n_cnt
+        if i >= self.RC:
+            raise TableFull("count row table is full", reason="table-rows")
+        self._n_cnt += 1
+        if init is not None and np.any(init):
+            self._pending["cnt"].append((i, self._node_row(init)))
+        return i
+
+    def _pair_row(self, key: int) -> int:
+        """Interned pair-id row of one topology key (-1: the node lacks
+        the key, so no lane ever matches it)."""
+        i = self._pair_rows.get(key)
+        if i is None:
+            c = self._c
+            ok = c["nkey"][:, key].astype(bool) & c["valid"].astype(bool)
+            i = self._intern_srow(self._node_row(
+                np.where(ok, c["pair_of_key"][:, key], -1), fill=-1))
+            self._pair_rows[key] = i
+        return i
+
+    def _zone_key(self, column: np.ndarray) -> Tuple[int, int]:
+        """(key id, zone-valid row) of a shared-value topology column."""
+        valid_nodes = self._c["valid"].astype(bool)
+        kb = column.tobytes()
+        u = self._uids.get(kb)
+        if u is None:
+            u = len(self._uids)
+            if u >= self.K:
+                raise TableFull("shared-value topology keys used up",
+                                reason="too-many-topology-keys")
+            vals = np.unique(column[valid_nodes])
+            vals = vals[vals > 0]
+            if len(vals) > VZ:
+                raise PallasUnsupported(
+                    f"topology key has {len(vals)} values > {VZ}",
+                    reason="too-many-topology-values")
+            m = {int(v): z for z, v in enumerate(vals)}
+            zid = np.array([m.get(int(v), -1) for v in column], np.int32)
+            ok = (zid >= 0) & valid_nodes
+            onehot = np.zeros((self.Np, VZ), np.float32)
+            onehot[np.arange(self.N)[ok], zid[ok]] = 1.0
+            self._uids[kb] = u
+            self._zof.append(m)
+            self._pending["onehot"].append((u, onehot))
+        zv = np.zeros(VZ, np.int32)
+        zv[list(self._zof[u].values())] = 1
+        return u, self._intern_zrow(zv)
+
+    # -- device writes ------------------------------------------------------
+
+    def _write(self, table, items, width):
+        for lo in range(0, len(items), WRITE_CHUNK):
+            part = items[lo:lo + WRITE_CHUNK]
+            idx = np.full(WRITE_CHUNK, table.shape[0], np.int32)  # dropped
+            rows = np.zeros((WRITE_CHUNK,) + tuple(width), table.dtype)
+            for j, (i, row) in enumerate(part):
+                idx[j] = i
+                rows[j] = row
+            table = _write_rows(table, jnp.asarray(idx), jnp.asarray(rows))
+        return table
+
+    def _warm_writers(self) -> None:
+        """Run every table write once with nothing to write, so that the
+        shapes an admission inside a window uses are compiled here."""
+        st = self._statics
+        st["srow"] = self._write(st["srow"], [(self.SRc, 0)], (self.Np,))
+        st["zrow"] = self._write(st["zrow"], [(self.ZRc, 0)], (VZ,))
+        self._write(jnp.zeros((self.RC, self.Np), jnp.int32),
+                    [(self.RC, 0)], (self.Np,))
+        oh = np.zeros((1, self.Np, VZ), np.float32)
+        st["onehot"] = _write_rows(
+            st["onehot"], jnp.asarray(np.array([self.K], np.int32)),
+            jnp.asarray(oh))
+        rp = self._requested0.shape[0]
+        _, _, st["alloc"] = _rescale(
+            jnp.asarray(self._requested0), jnp.asarray(self._nzpc0),
+            st["alloc"], jnp.ones(rp, jnp.int32), jnp.ones(SUB, jnp.int32))
+
+    def _flush_writes(self) -> int:
+        """Everything an admission changed, to the device. Returns the
+        number of rows written."""
+        st, p = self._statics, self._pending
+        n = len(p["srow"]) + len(p["zrow"]) + len(p["cnt"])
+        if p["srow"]:
+            st["srow"] = self._write(st["srow"], p["srow"], (self.Np,))
+        if p["zrow"]:
+            st["zrow"] = self._write(st["zrow"], p["zrow"], (VZ,))
+        if p["cnt"] and self._carry is None:
+            for i, row in p["cnt"]:
+                self._cnt0[i] = row
+        elif p["cnt"]:
+            self._carry["cnt"] = self._write(
+                self._carry["cnt"], p["cnt"], (self.Np,))
+        for u, onehot in p["onehot"]:
+            st["onehot"] = _write_rows(
+                st["onehot"], jnp.asarray(np.array([u], np.int32)),
+                jnp.asarray(onehot[None]))
+        for k in p:
+            p[k] = []
+        st["spec"] = jnp.asarray(self._spec)
+        st["pool"] = jnp.asarray(self._pool)
+        return n
+
+    # -- admission ----------------------------------------------------------
+
+    @property
+    def specs(self) -> int:
+        return self.T
+
+    @property
+    def count_rows(self) -> int:
+        return self._n_cnt
+
+    def has(self, fp) -> bool:
+        return fp in self._fps
+
+    def admit(self, cluster_fn: Callable[[], Dict],
+              template_arrays_list: List[Dict],
+              flush: Optional[Callable[[], None]] = None) -> Dict:
+        """Take new specs into the LIVE session: no rebuild, no compile.
+
+        `cluster_fn()` gives the cluster dict the prologue reads (host
+        arrays: ClusterEncoding.host_state); it is called after
+        `flush()`, which is called only if pods still in
+        flight could match a selector of a new spec (their decisions are
+        on the device alone: the snapshot would miss them). Raises
+        TableFull / PallasUnsupported where the specs do not fit: the
+        session is then half-written and DEAD, the caller rebuilds.
+        Returns {"n", "rows"}."""
+        new = self._unknown(template_arrays_list)
+        if not new:
+            return {"n": 0, "rows": 0}
+        if flush is not None and self._read_by_new(new):
+            flush()
+        out = self._admit(cluster_fn(), new)
+        self.admits += 1
+        return out
+
+    def _unknown(self, arrays_list: List[Dict]) -> List[Dict]:
+        seen, out = set(), []
+        for a in arrays_list:
+            fp = template_fingerprint(a)
+            if fp not in self._fps and fp not in seen:
+                seen.add(fp)
+                out.append(a)
+        return out
+
+    def _signature(self, a: Dict) -> tuple:
+        return tuple((k, np.asarray(a[k]).shape) for k in _STACK_KEYS) + (
+            ("req", np.asarray(a["req"]).shape),)
+
+    def _label_batch(self, arrays_list: List[Dict]):
+        """(pair bits, key bits, namespaces) of label sets, at the widest
+        vocabulary seen so far (ids are permanent: widening pads zeros)."""
+        wp = max([self._lab_pair.shape[1]]
+                 + [len(a["self_ppair"]) for a in arrays_list])
+        wk = max([self._lab_key.shape[1]]
+                 + [len(a["self_pkey"]) for a in arrays_list])
+        if wp > self._lab_pair.shape[1]:
+            self._lab_pair = np.pad(
+                self._lab_pair, ((0, 0), (0, wp - self._lab_pair.shape[1])))
+        if wk > self._lab_key.shape[1]:
+            self._lab_key = np.pad(
+                self._lab_key, ((0, 0), (0, wk - self._lab_key.shape[1])))
+        pp = np.zeros((len(arrays_list), wp), bool)
+        pk = np.zeros((len(arrays_list), wk), bool)
+        for i, a in enumerate(arrays_list):
+            pp[i, :len(a["self_ppair"])] = a["self_ppair"]
+            pk[i, :len(a["self_pkey"])] = a["self_pkey"]
+        ns = np.array([int(np.asarray(a["self_ns"])) for a in arrays_list],
+                      np.int64)
+        return pp, pk, ns
+
+    @staticmethod
+    def _match(tab: Dict, prefix: str, pp, pk, ns, owner_ns=None):
+        """[owners, slots, label sets]: does label set b satisfy selector
+        slot x of owner o? `tab[prefix_*]` are [owners, slots, ...]; a
+        spread selector holds in the owner's namespace (`owner_ns`), a
+        term in its own namespace list and only where it is valid."""
+        op = tab[f"{prefix}_op"]
+        o_n, x_n = op.shape[:2]
+        m = _eval_reqs_batch_np(
+            op.reshape((o_n * x_n,) + op.shape[2:]),
+            tab[f"{prefix}_rkey"].reshape((o_n * x_n,) + op.shape[2:]),
+            tab[f"{prefix}_pairs"].reshape(
+                (o_n * x_n,) + tab[f"{prefix}_pairs"].shape[2:]),
+            pp, pk).T.reshape(o_n, x_n, len(ns))
+        if owner_ns is not None:
+            return m & (owner_ns[:, None, None] == ns[None, None, :])
+        ns_tbl = tab[f"{prefix}_ns"]                      # [O, X, NS]
+        ns_ok = ((ns_tbl[:, :, :, None] == ns[None, None, None, :])
+                 & (ns_tbl[:, :, :, None] != 0)).any(axis=2)
+        return m & ns_ok & tab[f"{prefix}_valid"].astype(bool)[:, :, None]
+
+    def _tables_of(self, arrays_list: List[Dict]) -> Dict:
+        return {k: np.stack([np.asarray(a[k]) for a in arrays_list])
+                for k in _STACK_KEYS}
+
+    def _read_by_new(self, new: List[Dict]) -> bool:
+        """Would a pod of a LIVE spec count toward a row of a new spec?"""
+        if not self.T:
+            return False
+        try:
+            tab = self._tables_of(new)
+            self._label_batch(new)   # widens the live label store
+            pp, pk = self._lab_pair[: self.T], self._lab_key[: self.T]
+            ns = self._stack["self_ns"][: self.T].astype(np.int64)
+            own = tab["self_ns"].astype(np.int64)
+            hit = (self._match(tab, "ptsf", pp, pk, ns, own).any()
+                   or self._match(tab, "ptss", pp, pk, ns, own).any())
+            return bool(hit or any(
+                self._match(tab, f, pp, pk, ns).any()
+                for f in _TERM_FAMILIES))
+        except (ValueError, IndexError):
+            return True   # shapes moved: _admit will say so; be safe
+
+    def _admit(self, cluster: Dict, new: List[Dict]) -> Dict:
+        if self._sig is None:
+            self._sig = self._signature(new[0])
+            self._stack = {
+                k: np.zeros((self.Tcap,) + np.asarray(new[0][k]).shape,
+                            np.asarray(new[0][k]).dtype)
+                for k in _STACK_KEYS}
+        for a in new:
+            if self._signature(a) != self._sig:
+                raise TableFull("spec arrays of another shape",
+                                reason="shape-change")
+        if self.T + len(new) > self.Tcap:
+            raise TableFull("spec table is full", reason="table-full")
+        if not self.dyn_ipa and templates_have_terms(new):
+            raise TableFull("session was built without the term machinery",
+                            reason="terms-enabled")
+        if templates_have_ports(new):
+            raise PallasUnsupported(
+                "templates with host ports ride the jnp hoisted session",
+                reason="host-ports")
+        first = self.T
+        self._refine_gcd(new)
+        for lo in range(0, len(new), ADMIT_CHUNK):
+            part = new[lo:lo + ADMIT_CHUNK]
+            padded = part + [part[0]] * (ADMIT_CHUNK - len(part))
+            S = _host_prologue(cluster, padded, self.dyn_ipa)
+            for i, a in enumerate(part):
+                self._admit_one(S, i, a)
+        self._wire(first)
+        for t in range(self.T):
+            if self._dirty[t]:
+                self._write_lists(t)
+        return {"n": len(new), "rows": self._flush_writes()}
+
+    def _refine_gcd(self, new: List[Dict]) -> None:
+        """Requests the live GCD does not divide: a finer unit, every
+        utilization value multiplied up (exact), capacities re-checked."""
+        req = np.stack([np.asarray(a["req"]).astype(np.int64) for a in new])
+        nz = np.stack([np.asarray(a["nz_req"]).astype(np.int64)
+                       for a in new])
+        k = np.ones(self.R, np.int64)
+        for r in range(self.R):
+            vals = [req[:, r]] + ([nz[:, r]] if r < 2 else [])
+            g = math.gcd(int(self._gcd[r]), _gcd_all(*vals)) \
+                if any(v.any() for v in vals) else int(self._gcd[r])
+            k[r] = int(self._gcd[r]) // g
+        if (k == 1).all():
+            return
+        alloc = self._alloc.astype(np.int64)
+        alloc[: self.R] *= k[:, None]
+        L, Wd = self._L, self._L.W
+        rec = self._spec.reshape(self.Tcap, Wd).astype(np.int64)
+        rec[:, L.REQ:L.REQ + self.R] *= k[None, :]
+        rec[:, L.NZ:L.NZ + 2] *= k[None, :2]
+        # the carried utilization is bounded by the capacities
+        hi = max(int(alloc.max(initial=0)),
+                 int(rec[:, L.REQ:L.REQ + self.R].max(initial=0)))
+        if hi * (MAX_NODE_SCORE + 1) >= 2 ** 31:
+            raise TableFull("refined resource unit too fine for int32",
+                            reason="resource-magnitude")
+        old = (self._alloc, self._gcd, self._balgrp, self._bad)
+        self._alloc = alloc.astype(np.int32)
+        self._gcd = self._gcd // k
+        try:
+            self._balanced_tables()
+        except ValueError:
+            self._alloc, self._gcd, self._balgrp, self._bad = old
+            raise TableFull("refined unit leaves the exact balanced form",
+                            reason="resource-magnitude")
+        self._spec[:] = rec.astype(np.int32).reshape(-1)
+        rp = self._requested0.shape[0]
+        k_res = np.ones(rp, np.int32)
+        k_res[: self.R] = k
+        k_nz = np.ones(SUB, np.int32)
+        k_nz[:2] = k[:2]
+        st = self._statics
+        if self._carry is None:
+            self._requested0 *= k_res[:, None]
+            self._nzpc0 *= k_nz[:, None]
+            st["alloc"] = jnp.asarray(self._alloc)
+        else:
+            c = self._carry
+            c["requested"], c["nzpc"], st["alloc"] = _rescale(
+                c["requested"], c["nzpc"], st["alloc"],
+                jnp.asarray(k_res), jnp.asarray(k_nz))
+        st["balgrp"] = jnp.asarray(self._balgrp)
+        st["bad"] = jnp.asarray(self._bad)
+
+    # ktpu: allow-sync(admission: host packing of one spec's rows and record)
+    def _admit_one(self, S: Dict, i: int, a: Dict) -> None:
+        """Rows, record and lists of one spec from the prologue's outputs
+        (index i of the chunk). Its own count rows are created here; who
+        writes them is settled in _wire."""
+        L, C, N = self._L, self.C, self.N
+        t = self.T
+        rec = np.zeros(L.W, np.int32)
+        req = np.asarray(a["req"]).astype(np.int64) // self._gcd
+        nz = np.asarray(a["nz_req"]).astype(np.int64) // self._gcd[:2]
+        if max(int(req.max(initial=0)), int(nz.max(initial=0))) \
+                * (MAX_NODE_SCORE + 1) >= 2 ** 31:
+            raise PallasUnsupported("request too large for int32",
+                                    reason="resource-magnitude")
+        rec[L.REQ:L.REQ + self.R] = req
+        rec[L.CHK:L.CHK + self.R] = np.asarray(a["req_check"])
+        rec[L.HAS] = int(np.asarray(a["req_has_any"]))
+        rec[L.NZ:L.NZ + 2] = nz
+        rec[L.IPAP] = int(S["ipa_present"][i])
+        for j, k in ((ST_MASK, "static_mask"), (ST_RAW_IPA, "raw_ipa"),
+                     (ST_TAINT, "cnt_taint"), (ST_NODEAFF, "cnt_nodeaff"),
+                     (ST_IMAGE, "sc_image"), (ST_AVOID, "sc_avoid"),
+                     (ST_HAS_ALL, "s_has_all"), (ST_SRC, "s_src")):
+            rec[L.STAT + j] = self._intern_srow(self._node_row(S[k][i]))
+        valid_nodes = self._c["valid"].astype(bool)
+        rows = {"f": [0] * C, "s": [0] * C, "f_pair": [0] * C,
+                "s_pair": [0] * C, "s_src": [-1] * C,
+                "score": 0, "present": 0, "score_w": 0}
+        for c in range(C):
+            if S["f_valid"][i, c]:
+                column = S["f_pair_cn"][i][:, c]
+                o = L.PF + PF_W * c
+                rows["f_pair"][c] = self._intern_srow(self._node_row(
+                    np.where(valid_nodes, column, -1), fill=-1))
+                rows["f"][c] = self._new_cnt(S["f_cnt0"][i, c][column])
+                rec[o:o + PF_W] = (
+                    1, int(S["f_skew"][i, c]), int(S["f_self_match"][i, c]),
+                    rows["f"][c],
+                    self._intern_srow(self._node_row(
+                        S["f_reg_real"][i, c][column])),
+                    self._intern_srow(self._node_row(
+                        S["f_key_on_node"][i][:, c])))
+            if S["s_valid"][i, c]:
+                column = S["s_pair_cn"][i][:, c]
+                o = L.PS + PS_W * c
+                # the prologue's hostname flag selects the log(n_scored)
+                # weight semantics, not just a representation
+                perno = bool(S["s_hostname"][i, c])
+                rows["s_pair"][c] = self._intern_srow(self._node_row(
+                    np.where(valid_nodes, column, -1), fill=-1))
+                key = zrow = zvn = 0
+                if perno:
+                    rows["s"][c] = self._new_cnt(S["h_cnt0"][i, c])
+                else:
+                    key, zrow = self._zone_key(column)
+                    zvn = self._intern_srow(self._node_row(
+                        (column > 0) & valid_nodes))
+                    rows["s"][c] = self._new_cnt(S["s_cnt0"][i, c][column])
+                    rows["s_src"][c] = int(rec[L.STAT + ST_SRC])
+                rec[o:o + PS_W] = (
+                    1, int(S["s_skew"][i, c]), int(S["s_first"][i, c]), key,
+                    int(perno), rows["s"][c], zvn,
+                    self._intern_srow(self._node_row(
+                        S["s_key_on_node"][i][:, c])), zrow)
+        rec[L.FS:L.FS + C * C] = S["f_same_key"][i].astype(np.int32).ravel()
+        rec[L.SS:L.SS + C * C] = S["s_same_key"][i].astype(np.int32).ravel()
+        anti: List[Tuple[int, int, int]] = []
+        aff: List[Tuple[int, int]] = []
+        rows["anti"], rows["aff"] = {}, {}
+        has_terms = False
+        if self.dyn_ipa:
+            io = L.IPA
+            rec[io] = int(S["ipa_has_aff"][i])
+            rec[io + 1] = int(S["ipa_self_match_all"][i])
+            rec[io + 2] = min(int(S["ipa_aff_total"][i]), POS_BIG - 1)
+            rec[io + 3] = self._intern_srow(
+                self._node_row(S["ipa_fail_existing"][i]))
+            rec[io + 4] = self._intern_srow(
+                self._node_row(S["ipa_aff_all_keys"][i]))
+            for tau in np.flatnonzero(np.asarray(a["ipaaa_valid"])):
+                rows["anti"][int(tau)] = self._new_cnt()
+                anti.append((
+                    self._intern_srow(self._node_row(
+                        S["ipa_anti_cnt_n"][i][:, tau])),
+                    self._intern_srow(self._node_row(
+                        S["ipa_anti_key_on_node"][i][:, tau])),
+                    rows["anti"][int(tau)]))
+            for tau in np.flatnonzero(np.asarray(a["ipaa_valid"])):
+                rows["aff"][int(tau)] = self._new_cnt()
+                aff.append((
+                    self._intern_srow(self._node_row(
+                        S["ipa_aff_cnt_n"][i][:, tau])),
+                    rows["aff"][int(tau)]))
+            has_terms = bool(anti or aff
+                             or np.asarray(a["ipap_valid"]).any())
+        self._spec[t * L.W:(t + 1) * L.W] = rec
+        for k in _STACK_KEYS:
+            self._stack[k][t] = np.asarray(a[k])
+        pp, pk, _ = self._label_batch([a])
+        self._lab_pair[t], self._lab_key[t] = pp[0], pk[0]
+        self._fps[template_fingerprint(a)] = t
+        self._arrays.append(a)
+        self._has_terms[t] = has_terms
+        self._touch.append([])
+        self._repel.append([])
+        self._repel_key.append({})
+        self._anti.append(anti)
+        self._aff.append(aff)
+        self._rows.append(rows)
+        self._dirty.append(True)
+        self.T = t + 1
+
+    def _add_touch(self, writer: int, row: int, pair: int, w: int,
+                   src: int = -1) -> None:
+        self._touch[writer].append((row, pair, int(w), src))
+        self._dirty[writer] = True
+
+    def _add_score(self, writer: int, reader: int, pair: int,
+                   w: int) -> None:
+        """D4/D5: a pod of `writer` moves `reader`'s raw IPA score by w
+        in its topology group, and makes the score present. The reader's
+        score and presence rows are created with their first writer."""
+        rows = self._rows[reader]
+        if not rows["score"]:
+            rows["score"] = self._new_cnt()
+            rows["present"] = self._new_cnt()
+            base = reader * self._L.W + self._L.IPA
+            self._spec[base + 11] = rows["score"]
+            self._spec[base + 12] = rows["present"]
+        rows["score_w"] += abs(w)
+        if rows["score_w"] >= 2 ** 14:
+            # the int32 score row must keep clear of the 2^30 sentinel
+            # at 2^16 assumed pods
+            raise PallasUnsupported(
+                "IPA score weights too large for int32 score headroom",
+                reason="ipa-score-weights")
+        self._add_touch(writer, rows["score"], pair, w)
+        self._add_touch(writer, rows["present"], pair, 1)
+
+    def _wire(self, first: int) -> None:
+        """Who counts toward whose rows, for every (reader, writer) pair
+        with a spec admitted now (index >= first) on either side: the
+        match booleans of ops/hoisted.py _match_matrices / _term_gates,
+        evaluated on the host, turned into touch entries of the writer."""
+        T = self.T
+        live = {k: v[:T] for k, v in self._stack.items()}
+        pp, pk = self._lab_pair[:T], self._lab_key[:T]
+        ns = live["self_ns"].astype(np.int64)
+
+        def blocks(prefix, spread=False):
+            """The match booleans that have a new spec on a side, as
+            [(m [owners, slots, entities], first owner, first entity)]:
+            new owners against every label set, old owners against the
+            new label sets — O(new x live), not O(live^2)."""
+            out = []
+            for o0, o1, e0 in ((first, T, 0), (0, first, first)):
+                if o1 > o0:
+                    tab = {k: v[o0:o1] for k, v in live.items()}
+                    out.append((self._match(
+                        tab, prefix, pp[e0:], pk[e0:], ns[e0:],
+                        ns[o0:o1] if spread else None), o0, e0))
+            return out
+
+        def pairs_of(prefix, spread=False):
+            """(owner, slot, entity) triples with a new spec on a side."""
+            for m, o0, e0 in blocks(prefix, spread):
+                o, x, e = np.nonzero(m)
+                yield from zip((o + o0).tolist(), x.tolist(),
+                               (e + e0).tolist())
+
+        for side in ("f", "s"):
+            for o, c, e in pairs_of(f"pts{side}", spread=True):
+                row = self._rows[o][side][c]
+                if row:
+                    self._add_touch(e, row, self._rows[o][f"{side}_pair"][c],
+                                    1, self._rows[o]["s_src"][c]
+                                    if side == "s" else -1)
+        if not self.dyn_ipa:
+            return
+        hard_w = int(np.asarray(self._c["hard_pod_affinity_weight"]))
+        for o, tau, e in pairs_of("ipaaa"):
+            pair = self._pair_row(int(live["ipaaa_key"][o, tau]))
+            # D2: e counts toward o's own anti term
+            self._add_touch(e, self._rows[o]["anti"][tau], pair, 1)
+            # D1: o's pods repel e wherever they sit, by the term's key
+            key = int(live["ipaaa_key"][o, tau])
+            row = self._repel_key[e].get(key)
+            if row is None:
+                row = self._repel_key[e][key] = self._new_cnt()
+                self._repel[e].append(row)
+                self._dirty[e] = True
+            self._add_touch(o, row, pair, 1)
+        a_valid = live["ipaa_valid"].astype(bool)
+        for m_aff, o0, e0 in blocks("ipaa"):
+            av = a_valid[o0:o0 + m_aff.shape[0]]
+            match_all = (np.where(av[:, :, None], m_aff, True).all(axis=1)
+                         & av.any(axis=1)[:, None])           # [O, E]
+            for o, e in zip(*np.nonzero(match_all)):
+                o, e = int(o) + o0, int(e) + e0
+                for tau in np.flatnonzero(a_valid[o]):        # D3
+                    self._add_touch(
+                        e, self._rows[o]["aff"][int(tau)],
+                        self._pair_row(int(live["ipaa_key"][o, tau])), 1)
+            if hard_w > 0:                                    # D4, required
+                for o, tau, e in zip(*np.nonzero(m_aff)):
+                    o, e = int(o) + o0, int(e) + e0
+                    self._add_score(o, e, self._pair_row(
+                        int(live["ipaa_key"][o, tau])), hard_w)
+        for o, tau, e in pairs_of("ipap"):
+            w = int(live["ipap_weight"][o, tau])
+            pair = self._pair_row(int(live["ipap_key"][o, tau]))
+            self._add_score(o, e, pair, w)                    # D4, preferred
+            self._add_score(e, o, pair, w)                    # D5
+
+    def _write_lists(self, t: int) -> None:
+        """Append spec t's lists to the pool (the pool is append-only: a
+        list that grew is written anew, its old copy is dead until the
+        next rebuild) and point the record at them."""
+        L = self._L
+        words: List[int] = []
+
+        def put(items) -> int:
+            off = self._pool_n + len(words)
+            for it in items:
+                words.extend(it if isinstance(it, tuple) else (it,))
+            return off
+
+        base = t * L.W
+        self._spec[base + L.TCH] = put(self._touch[t])
+        self._spec[base + L.TCH + 1] = len(self._touch[t])
+        if self.dyn_ipa:
+            io = base + L.IPA
+            self._spec[io + 5] = put(self._repel[t])
+            self._spec[io + 6] = len(self._repel[t])
+            self._spec[io + 7] = put(self._anti[t])
+            self._spec[io + 8] = len(self._anti[t])
+            self._spec[io + 9] = put(self._aff[t])
+            self._spec[io + 10] = len(self._aff[t])
+        if self._pool_n + len(words) > self.PC:
+            raise TableFull("list pool is full", reason="table-pool")
+        self._pool[self._pool_n:self._pool_n + len(words)] = words
+        self._pool_n += len(words)
+        self._dirty[t] = False
+
+    # -- scheduling --------------------------------------------------------
+
+    def schedule(self, pod_arrays_list: List[Dict]):
+        """Enqueue one batch; returns the (8, Bp) device result rows —
+        row 0 best / row 1 score / row 2 n_feasible. decisions() blocks.
+        Host work is one dictionary lookup a pod: what a spec matches was
+        settled when it was admitted."""
+        B = len(pod_arrays_list)
+        # pow2 length bucket: each distinct Bp is a fresh compile, and
+        # production batches are ragged
+        Bp = batch_bucket(B, minimum=LANE)
+        meta = np.zeros(1 + Bp, np.int32)
+        meta[0] = B
+        fps = self._fps
+        for i, pa in enumerate(pod_arrays_list):
+            if bool(np.asarray(pa["has_node_name"])):
+                raise ValueError("session pods must be unbound")
+            meta[1 + i] = fps[template_fingerprint(pa)]
+        out = self._run_dispatch(meta)
+        tm = meta[1:1 + B]
+        # bucket rides the result so a harvest-side device fault can
+        # retire exactly the executable that produced the bad payload
+        # (tpu_backend.py retry path)
+        specs = np.unique(tm)
+        return {"rows": out, "n": B, "bucket": Bp,
+                # what the launch carried, for its dispatch span
+                "templates": int(specs.size),
+                "term_pods": int(self._has_terms[tm].sum()),
+                "count_rows": len({e[0] for t in specs
+                                   for e in self._touch[t]})}
+
+    @staticmethod
+    # ktpu: allow-sync(harvest decode: host consumes batch verdicts after the launch completes)
+    def decisions(ys) -> List[int]:
+        return [int(v) for v in np.asarray(ys["rows"])[0, :ys["n"]]]
+
+    def retire_exec(self, bucket: Optional[int] = None) -> int:
+        """Retire AOT executables after a device fault: a dispatch that
+        raised, wedged, or harvested garbage leaves its compiled program
+        suspect. Entries are pinned to None (= dispatch through jit), the
+        same retired state the arg-mismatch path uses — warm_buckets
+        never resurrects a retired entry, and _run_dispatch never
+        recompiles one. With `bucket` given, an absent entry is pinned
+        too: the backend quarantines a suspect bucket on every REBUILT
+        session (the _exec cache dies with its session, but the fault
+        does not), and lifts it only after the bucket harvests cleanly
+        through jit. bucket None retires every existing entry. Returns
+        the number of entries pinned."""
+        if bucket is not None:
+            if self._exec.get((bucket, "full"), _MISSING) is not None:
+                self._exec[(bucket, "full")] = None
+                return 1
+            return 0
+        n = 0
+        for key in list(self._exec):
+            if self._exec.get(key) is not None:
+                self._exec[key] = None
+                n += 1
+        return n
+
+    # -- incremental device-state deltas -----------------------------------
+
+    # what the backend's delta classifier matches a foreign pod against:
+    # the live specs' selector tables, [T, ...]
+    @property
+    def _tp_np(self) -> Dict:
+        return {k: self._stack[k][: self.T]
+                for k in _PTS_KEYS + ("self_ns",)}
+
+    @property
+    def _term_np(self) -> Optional[Dict]:
+        if not self.dyn_ipa:
+            return None
+        return {k: self._stack[k][: self.T] for k in _TERM_KEYS
+                if not k.endswith("_key")}
+
+    def delta_compatible(self, dres, dnz) -> bool:
+        """A utilization delta rides this session's int32 carry only when
+        the per-dimension GCD rescale stays exact on it and the rescaled
+        magnitudes keep the int32 headroom the build guaranteed."""
+        dres = np.asarray(dres, np.int64)
+        if dres.shape[0] != self._gcd.shape[0]:
+            return False
+        if (dres % self._gcd != 0).any():
+            return False
+        dnz = np.asarray(dnz, np.int64)
+        if (dnz % self._gcd[:2] != 0).any():
+            return False
+        hi = max(
+            int(np.abs(dres // self._gcd).max(initial=0)),
+            int(np.abs(dnz // self._gcd[:2]).max(initial=0)),
+        )
+        return hi * (MAX_NODE_SCORE + 1) < 2 ** 31
+
+    def _delta_entries(self, d) -> List[tuple]:
+        """One backend delta dict -> [(node, dres[Rp], dnzpc[8], row,
+        pair, w, src)]: the utilization move on the first entry, then one
+        entry per count row the pod's labels are counted on (d["mf"] /
+        d["ms"] are [specs at the time of the event, C])."""
+        rp = self._requested0.shape[0]
+        dres = np.zeros(rp, np.int32)
+        dnzpc = np.zeros(SUB, np.int32)
+        touches: List[tuple] = []
+        if d["kind"] == "node-alloc":
+            dnzpc[3] = d["dallowed"]
+        else:
+            dres[: self.R] = (
+                np.asarray(d["dres"], np.int64) // self._gcd
+            ).astype(np.int32)
+            dnzpc[0] = int(d["dnz"][0]) // int(self._gcd[0])
+            dnzpc[1] = int(d["dnz"][1]) // int(self._gcd[1])
+            dnzpc[2] = d["dcount"]
+            for side, m in (("f", d["mf"]), ("s", d["ms"])):
+                for t, c in zip(*np.nonzero(np.asarray(m)[: self.T])):
+                    rows = self._rows[t]
+                    if rows[side][c]:
+                        touches.append((
+                            rows[side][c], rows[f"{side}_pair"][c],
+                            int(m[t][c]),
+                            rows["s_src"][c] if side == "s" else -1))
+        touches = touches or [(0, 0, 0, -1)]
+        zero_r, zero_n = np.zeros(rp, np.int32), np.zeros(SUB, np.int32)
+        return [(d["node"], dres if i == 0 else zero_r,
+                 dnzpc if i == 0 else zero_n) + tc
+                for i, tc in enumerate(touches)]
+
+    def _patch_alloc_static(self, d) -> None:
+        """node-alloc patch: the static alloc columns move (the prologue
+        never reads alloc, so nothing else needs recompute). The
+        CUMULATIVE rescaled magnitude must keep the int32 headroom the
+        build guaranteed, and the capacities must stay inside the exact
+        balanced form — else this raises (the backend's apply wrapper
+        downgrades to a rebuild, whose own envelope then decides)."""
+        scaled = (np.asarray(d["dalloc"], np.int64) // self._gcd).astype(
+            np.int32)
+        n = d["node"]
+        col = self._alloc[: self.R, n].astype(np.int64) + scaled
+        if int(np.abs(col).max(initial=0)) * (MAX_NODE_SCORE + 1) >= 2 ** 31:
+            raise ValueError(
+                "cumulative alloc patches exceed the int32 score headroom")
+        self._alloc[: self.R, n] += scaled
+        self._balanced_tables()
+        st = self._statics
+        st["alloc"] = st["alloc"].at[:self.R, n].add(jnp.asarray(scaled))
+        st["balgrp"] = jnp.asarray(self._balgrp)
+        st["bad"] = jnp.asarray(self._bad)
+
+    def apply_deltas(self, deltas: List[Dict]) -> None:
+        """Absorb batched cluster-event deltas into the carry (and the
+        alloc statics) without a session rebuild — the pallas face of
+        the session-delta contract (see HoistedSession.apply_deltas): one
+        fused _delta_scan launch chains onto the in-flight carry."""
+        for d in deltas:
+            if d["kind"] == "node-alloc":
+                self._patch_alloc_static(d)
+        entries = [e for d in deltas for e in self._delta_entries(d)]
+        ep = batch_bucket(len(entries), minimum=8)  # pow2: one compile each
+        rp = self._requested0.shape[0]
+        xs = {
+            "node": np.zeros(ep, np.int32),
+            "dres": np.zeros((ep, rp), np.int32),
+            "dnzpc": np.zeros((ep, SUB), np.int32),
+            "row": np.zeros(ep, np.int32), "pair": np.zeros(ep, np.int32),
+            "w": np.zeros(ep, np.int32), "src": np.full(ep, -1, np.int32),
+        }
+        for i, (n, dres, dnzpc, row, pair, w, src) in enumerate(entries):
+            xs["node"][i] = n
+            xs["dres"][i] = dres
+            xs["dnzpc"][i] = dnzpc
+            xs["row"][i], xs["pair"][i] = row, pair
+            xs["w"][i], xs["src"][i] = w, src
+        self._carry = _delta_scan(
+            self._initial_carry(), self._statics["srow"],
+            {k: jnp.asarray(v) for k, v in xs.items()})
+
+    def _initial_carry(self) -> Dict:
+        """The live carry; from the host seeds if no launch has run."""
+        if self._carry is None:
+            self._carry = {
+                "requested": jnp.asarray(self._requested0),
+                "nzpc": jnp.asarray(self._nzpc0),
+                "cnt": jnp.asarray(self._cnt0),
+            }
+        return self._carry
+
+    # ktpu: allow-sync(tests and probes: one count row, to the host)
+    def count_row(self, fp, side: str, c: int) -> np.ndarray:
+        """The carried count row of spec `fp`'s spread constraint c
+        ("f" filter / "s" score), per node lane."""
+        row = self._rows[self._fps[fp]][side][c]
+        return np.asarray(self._initial_carry()["cnt"][row])[: self.N]
+
+    # -- dispatch plumbing: persistent executables ------------------------
+
+    def _carry_struct(self) -> Dict:
+        """ShapeDtypeStructs of the carry, WITHOUT touching self._carry:
+        warm_buckets runs on a daemon thread concurrently with
+        schedule()."""
+        return {
+            "requested": jax.ShapeDtypeStruct(
+                self._requested0.shape, jnp.int32),
+            "nzpc": jax.ShapeDtypeStruct(self._nzpc0.shape, jnp.int32),
+            "cnt": jax.ShapeDtypeStruct((self.RC, self.Np), jnp.int32),
+        }
+
+    def _compile_exec(self, Bp: int):
+        """AOT lower+compile the dispatch for one batch bucket. The
+        compiled executable is invoked DIRECTLY on the serving path
+        (persistent executable reuse): every dispatch then runs the same
+        loaded program object — no jit-dispatch signature hashing, and no
+        per-launch program re-resolution for the runtime to pay. Its
+        argument shapes are capacities: no admission changes them."""
+        def st(x):
+            return jax.ShapeDtypeStruct(jnp.shape(x), jnp.asarray(x).dtype)
+
+        statics_s = {k: st(v) for k, v in self._statics.items()}
+        return _dispatch.lower(
+            self._cfg, statics_s,
+            jax.ShapeDtypeStruct((1 + Bp,), jnp.int32),
+            self._carry_struct()).compile()
+
+    def _run_dispatch(self, meta: np.ndarray):
+        """Execute one dispatch through the persistent-executable cache
+        (fallback: the plain jit path). Owns the carry swap — the carry
+        buffers are donated to the launch and replaced by its outputs."""
+        self._initial_carry()
+        Bp = int(meta.shape[0]) - 1
+        meta = jnp.asarray(meta)
+        key = (Bp, "full")
+        fn = self._exec.get(key, _MISSING)
+        if not knobs.get_bool("KTPU_PALLAS_AOT"):
+            fn = None  # kill switch wins even over warm-installed execs
+        elif fn is _MISSING:
+            # Counted miss path: a dispatch-time compile is a stall the
+            # device timeline must attribute (warm_buckets prefills are
+            # deliberate and uncounted).
+            from ..utils import devtime
+            t0 = _time.perf_counter()
+            try:
+                fn = self._compile_exec(Bp)
+            except Exception as e:  # noqa: BLE001 — the jit path serves; the error is kept
+                fn = None
+                self._exec_failed(Bp, "AOT compile failed", e)
+            self._exec[key] = fn
+            if devtime.enabled():
+                devtime.TIMELINE.compile_event(
+                    "pallas-bucket", t0, _time.perf_counter() - t0,
+                    bucket=Bp, mode="full", ok=fn is not None)
+        if fn is not None:
+            try:
+                out, self._carry = fn(self._statics, meta, self._carry)
+                return out
+            except (TypeError, ValueError) as e:
+                # arg-structure/layout mismatch is raised BEFORE
+                # execution (carry buffers untouched): retire this
+                # executable and serve through jit from now on
+                self._exec[key] = None
+                self._exec_failed(Bp, "AOT executable refused its args", e)
+        out, self._carry = _dispatch(self._cfg, self._statics, meta,
+                                     self._carry)
+        return out
+
+    def _exec_failed(self, bucket: int, what: str, e: BaseException) -> None:
+        self.exec_errors[(bucket, "full")] = f"{what}: {type(e).__name__}: {e}"
+        logger.error("pallas bucket %s: %s", bucket, what, exc_info=e)
+
+    def stop_warm(self) -> None:
+        """Ask a running warm_buckets to stop after the bucket it is
+        compiling (backend close: no compile may outlive the process's
+        orderly exit)."""
+        self._warm_stop.set()
+
+    def warm_buckets(self, sizes=(LANE, 256, 512, 1024, 2048)) -> None:
+        """AOT-compile the dispatch for the ragged-tail batch buckets
+        WITHOUT dispatching: .lower().compile() populates jax's caches
+        including the persistent one, so a mid-window first-tail-bucket
+        batch pays a cache hit instead of a fresh ~30s Mosaic compile.
+        Compiled executables land in self._exec, so the serving path
+        reuses the very same loaded program. Runs on a daemon thread: it
+        never touches self._carry. A failure stops the warming (the lazy
+        path would hit the same compiler error) and is recorded in
+        exec_errors — without pinning the entry, so the serving path
+        still makes its own attempt."""
+        aot = knobs.get_bool("KTPU_PALLAS_AOT")
+        for Bp in sizes:
+            if self._warm_stop.is_set():
+                return
+            if (Bp, "full") in self._exec:
+                # present entries stand: a None means the serving
+                # path RETIRED this executable — do not resurrect it
+                continue
+            try:
+                compiled = self._compile_exec(Bp)
+            except Exception as e:  # noqa: BLE001 — warming is off the serving path; the error is kept
+                self._exec_failed(Bp, "AOT warm compile failed", e)
+                return
+            # with the AOT kill switch set, warming still fills the
+            # (persistent) compile caches, but the serving path must
+            # keep dispatching through jit — don't install
+            if aot:
+                self._exec.setdefault((Bp, "full"), compiled)

@@ -33,7 +33,7 @@ int32 rescaled resources, f32 score math, first-max tie-break); parity is
 pinned by tests/test_sharded_scan.py over fuzzed clusters on a virtual
 8-device CPU mesh.
 
-Statics and envelope come from PallasSession's own prologue (the GCD
+Statics and envelope come from the dense host remap (ops/dense_remap.py: the GCD
 int32 rescale, per-template static rows, compact topology vocab): a shape
 the pallas kernel rejects is rejected here with the same PallasUnsupported
 reasons. Templates with affinity TERMS ride the sharded session too: the
@@ -66,15 +66,13 @@ from ..parallel.sharded import NODE_AXIS
 from . import kernel as K_ops
 from .hoisted import template_fingerprint
 from .kernel import MAX_NODE_SCORE
+from .dense_remap import DenseRemap, _carry_delta_scan, batch_prologue
 from .pallas_scan import (
     LANE,
     POS_BIG,
     SUB as SUB_IPA,
-    PallasSession,
     PallasUnsupported,
-    _carry_delta_scan,
     _ceil,
-    batch_prologue,
 )
 
 _CARRY_KEYS = ("requested", "nzpc", "cnt_fn", "cnt_sn")
@@ -670,7 +668,7 @@ def _node_col_apply(statics, delta, carry, lane, cols):
 class ShardedPallasSession:
     """Session API (schedule/decisions) over the two-phase sharded scan.
 
-    Construction derives every static from PallasSession's prologue (the
+    Construction derives every static from DenseRemap (the
     envelope gates — GCD int32 rescale bounds, <=8 constraints, <=128
     topology values, f32-exact weights, the IPA term/key budgets — apply
     identically), then splits the node axis over the mesh. Affinity-TERM
@@ -697,8 +695,7 @@ class ShardedPallasSession:
         assert mesh is not None, "ShardedPallasSession needs a mesh"
         if len(mesh.devices.ravel()) < 1:
             raise PallasUnsupported("empty mesh", reason="other")
-        inner = PallasSession(cluster, template_arrays_list, weights,
-                              launches_kernel=False)
+        inner = DenseRemap(cluster, template_arrays_list, weights)
         # multi-pod steps (conflict-SUFFIX contract: flagged pods are
         # uncommitted; the backend replays them through the live session)
         self.multipod_k = K_ops.multipod_k(multipod_k, suffix_replay=True)
@@ -935,7 +932,7 @@ class ShardedPallasSession:
 
     # same GCD-divisibility / int32-headroom envelope as the pallas carry
     # this mirrors (self._gcd is the inner session's)
-    delta_compatible = PallasSession.delta_compatible
+    delta_compatible = DenseRemap.delta_compatible
 
     def apply_deltas(self, deltas: List[Dict]) -> None:
         """Sharded face of the session-delta contract, extended with the
@@ -1051,7 +1048,7 @@ class ShardedPallasSession:
         """Column-write delta for a node ADD at `lane`, or None when the
         add falls outside the delta envelope (caller rebuilds).
 
-        The column comes from a 1-node PallasSession built on the node's
+        The column comes from a 1-node DenseRemap built on the node's
         own slice of the encoding (pod rows and term tables zeroed, see
         ClusterEncoding.node_slice_cluster). Inside the envelope —
         _node_delta_ok, fresh pair ids, a pod-free node — that slice's
@@ -1064,7 +1061,7 @@ class ShardedPallasSession:
         if not self._node_delta_ok or not (0 <= lane < self.Nps):
             return None
         try:
-            s1 = PallasSession(slice_cluster, self._templates, self.weights)
+            s1 = DenseRemap(slice_cluster, self._templates, self.weights)
         except (PallasUnsupported, KeyError):
             return None
         T, SR, TCp = self.T, self.SR, self.TCp

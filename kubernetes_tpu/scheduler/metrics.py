@@ -243,6 +243,27 @@ session_builds = legacy_registry.register(
         ("kind", "reason", "shards"),
     )
 )
+session_templates = legacy_registry.register(
+    Gauge(
+        "scheduler_session_templates",
+        "The live device session's table of pod specs (TPU-build "
+        "metric): what=specs the specs admitted, what=capacity the "
+        "room for them, what=rows / what=row_capacity the count rows "
+        "their constraints own. A spec that does not fit is a rebuild "
+        "(scheduler_session_rebuilds_total{reason=table-*}); watch "
+        "specs against capacity to see one coming.",
+        ("what",),
+    )
+)
+session_template_admits = legacy_registry.register(
+    Counter(
+        "scheduler_session_template_admits_total",
+        "Pod specs a LIVE device session took in without a rebuild and "
+        "without a compile (a row write each); the specs of a session's "
+        "own build are not counted.",
+        (),
+    )
+)
 mesh_shards = legacy_registry.register(
     Gauge(
         "scheduler_mesh_shards",

@@ -22,7 +22,7 @@ import os
 import random
 import threading
 import time as _time
-from collections import deque
+from collections import OrderedDict, deque
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -151,6 +151,9 @@ class TPUBackend(CacheListener):
     """Owns the dense encoding + kernel dispatch; registered as a cache
     listener so device state tracks the assume-cache at O(changed rows)."""
 
+    # templates a session that cannot admit one is (re)built with
+    DENSE_SESSION_TEMPLATES = 8
+
     def __init__(
         self,
         weights: Optional[Dict[str, int]] = None,
@@ -201,7 +204,16 @@ class TPUBackend(CacheListener):
         # the queue is worth, and the teardown path absorbs everything
         self.max_queued_deltas = knobs.get_int("KTPU_MAX_QUEUED_DELTAS")
         self._node_fps: Dict[str, tuple] = {}  # heartbeat-change gate
-        self._known_templates: Dict = {}  # fingerprint -> pod arrays
+        # fingerprint -> pod arrays of every spec met, least recently
+        # used first: what a (re)build takes into the session. A live
+        # table session (ops/pallas_scan.py) admits a spec it has not
+        # met; any other session kind is rebuilt with it.
+        self._known_templates: "OrderedDict" = OrderedDict()
+        # (distinct specs, pods that carry a term) of the last launch,
+        # for the synchronous dispatch span
+        self._launch_stats: Optional[Tuple[int, int]] = None
+        self._session_sig: Tuple = ()  # array shapes the session stacked
+        self._batch_specs = 0  # distinct specs of the batch in hand
         # in-flight batches, oldest first. Depth 2 double-buffers the
         # device: batch k+1's scan is enqueued (chained on k's carry as a
         # pure data dependency) while k still runs, so the device never
@@ -224,7 +236,6 @@ class TPUBackend(CacheListener):
         # harvested carry — dispatch_many flushes the pipeline first
         # (serializing; the A/B lever for the bench matrix)
         self.speculation = knobs.get_bool("KTPU_SPECULATION")
-        self.MAX_SESSION_TEMPLATES = 8
         self.volume_resolver = None  # scheduler/volume_device.py
         # pallas rides only on real TPUs: on CPU the interpreter is
         # pathologically slow and compile-heavy, so only a caller that
@@ -1514,9 +1525,10 @@ class TPUBackend(CacheListener):
         `max_pending` batches may be outstanding (the device double
         buffer) — a dispatch beyond that harvests the OLDEST first.
         Falls back to the synchronous path (ready handle) when the batch
-        can't ride the live session (bound pods, mixed shapes, unknown
-        templates or no session yet — the session builds on the
-        synchronous path and subsequent batches pipeline)."""
+        can't ride the live session (bound pods, mixed shapes, specs the
+        session can neither hold nor admit, or no session yet — the
+        session builds on the synchronous path and subsequent batches
+        pipeline)."""
         h = _BatchHandle(list(pods), batch)
         with self._lock:
             while len(self._pending) >= max(1, self.max_pending):
@@ -1553,13 +1565,21 @@ class TPUBackend(CacheListener):
                     h.results = self.schedule_many(pods)
                     return h
                 sig0 = shape_signature(clean[0])
-                if (
-                    all(shape_signature(a) == sig0 for a in clean[1:])
-                    and all(
-                        template_fingerprint(a) in self._session._fps
-                        for a in clean
-                    )
-                ):
+                rides = all(shape_signature(a) == sig0 for a in clean[1:])
+                if rides:
+                    uniq: Dict = {}
+                    for a in clean:
+                        uniq.setdefault(template_fingerprint(a), a)
+                    # specs this session has not met: a table takes
+                    # them in and the batch stays on the pipeline; what
+                    # cannot is torn down here and rebuilt on the
+                    # synchronous path below
+                    if any(fp not in getattr(self._session, "_fps", uniq)
+                           for fp in uniq):
+                        self._remember_templates(uniq)
+                    self._admit_templates_locked(uniq)
+                    rides = self._session is not None
+                if rides:
                     try:
                         # queued cluster-event deltas land first (one
                         # fused launch chained on the carry) so this
@@ -1585,6 +1605,10 @@ class TPUBackend(CacheListener):
                         with sp, devtime.TIMELINE.maybe_profile(
                                 "dispatch"):
                             ys = self._session.schedule(clean)  # async
+                            if isinstance(ys, dict) and "templates" in ys:
+                                sp.set(templates=ys["templates"],
+                                       term_pods=ys["term_pods"],
+                                       rows=ys["count_rows"])
                         if devtime.enabled():
                             # submit stamps at the enqueue; harvest
                             # stamps ready after the pipeline's own
@@ -1910,10 +1934,12 @@ class TPUBackend(CacheListener):
                     arrays.append(q)
                     j += 1
 
-                # pending pods: the template-hoisted SESSION — carry
-                # stays on-device across batches and scheduler cycles;
-                # prologue is paid only when the session is torn down
-                # by a foreign cluster mutation or a new template.
+                # pending pods: the hoisted SESSION — carry stays
+                # on-device across batches and scheduler cycles; the
+                # prologue is paid for the specs of a (re)build and for
+                # each spec a table session admits later. Only a foreign
+                # cluster mutation, or a spec the session can neither
+                # hold nor admit, tears it down.
                 # NOTE: no device_state() here — with dirty rows the
                 # fused scatter DONATES the old device arrays, which
                 # are exactly the live session's statics (the session
@@ -1929,6 +1955,9 @@ class TPUBackend(CacheListener):
                          if not k.startswith("_")}
                         for a in arrays
                     ])
+                    if self._launch_stats is not None:
+                        sp.set(templates=self._launch_stats[0],
+                               term_pods=self._launch_stats[1])
                 if decisions is None:
                     # retries exhausted (or fully demoted): the whole
                     # group re-gates via the queue exactly once; while
@@ -1953,56 +1982,14 @@ class TPUBackend(CacheListener):
 
     def _session_schedule(self, arrays: List[Dict]) -> List[int]:
         """Schedule a batchable pending group through the cross-cycle
-        session, (re)building it when torn down or when a new template
-        fingerprint appears."""
-        fps = [template_fingerprint(a) for a in arrays]
+        session: built when there is none, and torn down first when it
+        cannot take the batch's specs in (_admit_templates_locked)."""
         uniq: Dict = {}
-        for fp, a in zip(fps, arrays):
-            uniq.setdefault(fp, a)
-        if len(uniq) > self.MAX_SESSION_TEMPLATES:
-            # one batch alone exceeds the session template budget: a
-            # one-shot hoisted dispatch. The device_state() sync may
-            # donate buffers a live session still references, so tear
-            # the session down first
-            from ..ops.hoisted import schedule_batch_hoisted
-
-            self._invalidate_session("template-overflow")
-            cluster = self.enc.device_state()
-            if self.mesh is not None:
-                from ..parallel import sharded
-
-                cluster = sharded.shard_cluster(cluster, self.mesh)
-            decisions, _ = schedule_batch_hoisted(
-                cluster, arrays, self.weights
-            )
-            return decisions
-        # an encoding rebuild (vocab/table growth) changes array shapes;
-        # cached templates from before the rebuild can no longer stack
-        # with the incoming batch — evict them
-        sig = shape_signature(arrays[0])
-        stale = [
-            fp for fp, a in self._known_templates.items()
-            if shape_signature(a) != sig
-        ]
-        if stale:
-            for fp in stale:
-                del self._known_templates[fp]
-            self._invalidate_session("shape-change")
-        new = [fp for fp in uniq if fp not in self._known_templates]
-        if new:
-            for fp in new:
-                self._known_templates[fp] = uniq[fp]
-            # evict oldest templates NOT used by this batch (keeps the
-            # hot set; clearing everything would thrash a workload that
-            # alternates template sets)
-            while len(self._known_templates) > self.MAX_SESSION_TEMPLATES:
-                for old in list(self._known_templates):
-                    if old not in uniq:
-                        del self._known_templates[old]
-                        break
-                else:
-                    break
-            self._invalidate_session("new-template")
+        for a in arrays:
+            uniq.setdefault(template_fingerprint(a), a)
+        self._remember_templates(uniq)
+        if self._session is not None:
+            self._admit_templates_locked(uniq)
         if self._session is None:
             self._session = self._build_session()
         else:
@@ -2017,8 +2004,12 @@ class TPUBackend(CacheListener):
         from .metrics import conflict_replays, multipod_conflicts
 
         decisions: List[int] = []
+        self._launch_stats = None
         while arrays:
             ys = self._session.schedule(arrays)
+            if self._launch_stats is None and isinstance(ys, dict) \
+                    and "templates" in ys:
+                self._launch_stats = (ys["templates"], ys["term_pods"])
             # decisions() decodes through np.asarray, an UNBOUNDED device
             # wait — bound it with the watchdog first or the synchronous
             # re-decide path (fault recovery!) could hang on the very
@@ -2057,6 +2048,80 @@ class TPUBackend(CacheListener):
             arrays = arrays[suffix:]
         return decisions
 
+    def _remember_templates(self, uniq: Dict) -> None:
+        """Note the batch's specs as the most recently used. Specs whose
+        arrays have another shape than the batch's (the encoding grew a
+        vocabulary bucket since) cannot stack with it in a build: they
+        go, and are met again with their next pod."""
+        known = self._known_templates
+        sig = shape_signature(next(iter(uniq.values())))
+        for fp in [fp for fp, a in known.items()
+                   if fp not in uniq and shape_signature(a) != sig]:
+            del known[fp]
+        for fp, a in uniq.items():
+            known[fp] = a
+            known.move_to_end(fp)
+        self._batch_specs = len(uniq)
+
+    def _table_capacity(self) -> int:
+        from ..ops.pallas_scan import table_capacity
+
+        return table_capacity(self.enc._pod_reserve)
+
+    def _admit_templates_locked(self, uniq: Dict) -> None:
+        """Make the live session hold every spec of `uniq`, or tear it
+        down. A table session (ops/pallas_scan.py PallasSession.admit)
+        takes new specs in: no rebuild, no compile; batches still in
+        flight are landed first only if their pods could count toward a
+        new spec's rows. Every other session kind, and a table that
+        cannot fit them, is torn down under a reason of its own."""
+        sess = self._session
+        held = getattr(sess, "_fps", None)
+        if held is None:
+            return  # a session that does not say what it holds (tests)
+        new = [a for fp, a in uniq.items() if fp not in held]
+        if not hasattr(sess, "admit"):
+            # an encoding rebuild (vocab/table growth) changes array
+            # shapes: the session's stacked templates no longer stack
+            # with the batch's, whether it has met the specs or not
+            if shape_signature(
+                    next(iter(uniq.values()))) != self._session_sig:
+                self._invalidate_session("shape-change")
+            elif new:
+                self._invalidate_session("new-template")
+            return
+        if not new:
+            return
+        from ..ops.pallas_scan import PallasUnsupported
+        from .metrics import session_template_admits
+
+        try:
+            with tracing.span("template-admit", "template-admit",
+                              n=len(new)) as sp:
+                out = sess.admit(self.enc.host_state, new,
+                                 flush=self._flush_pending)
+                sp.set(rows=out["rows"])
+        except PallasUnsupported as e:
+            # the table is used up (table-*), the spec needs what this
+            # session was built without, or it cannot ride the kernel
+            # at all: the rebuild decides which
+            if self._session is sess:
+                self._invalidate_session(e.reason)
+            return
+        if self._session is not sess:
+            return  # a fault while landing the batches in flight
+        session_template_admits.inc(len(new))
+        self._export_table_gauges(sess)
+
+    @staticmethod
+    def _export_table_gauges(sess) -> None:
+        from .metrics import session_templates
+
+        session_templates.set(float(sess.specs), what="specs")
+        session_templates.set(float(sess.Tcap), what="capacity")
+        session_templates.set(float(sess.count_rows), what="rows")
+        session_templates.set(float(sess.RC), what="row_capacity")
+
     def _build_session(self):
         """Span-wrapped _build_session_impl: records the build as a
         "session" span (builds are the seconds-scale cost rebuild storms
@@ -2065,6 +2130,8 @@ class TPUBackend(CacheListener):
         with tracing.span("session-build", "session",
                           reason=self._last_invalidate) as sp:
             s = self._build_session_impl()
+            self._session_sig = shape_signature(
+                next(iter(self._known_templates.values())))
             self._last_build = (
                 f"{type(s).__name__}/{self._last_invalidate or 'initial'}"
             )
@@ -2087,23 +2154,49 @@ class TPUBackend(CacheListener):
         from .metrics import session_builds
 
         sh = self._shards_label()
+        # the specs met, most recent last. A table session has room for
+        # _table_capacity() of them, and half is left free for those to
+        # come; a session that is [T, ...] in its templates (the mesh's
+        # dense layout: 16 fit its 128 match lanes, tests/
+        # test_mesh_scaleout.py test_randomized_stream_parity; the jnp
+        # session: one compile per T) keeps the hot few. The least
+        # recently used wait for their next pod; the batch in hand
+        # always stays.
+        tabled = (self.use_pallas and not self.explain
+                  and self.ladder.rung() >= self.ladder.top)
+        keep = max(self._table_capacity() // 2 if tabled
+                   else self.DENSE_SESSION_TEMPLATES, self._batch_specs, 1)
+        while len(self._known_templates) > keep:
+            self._known_templates.popitem(last=False)
         templates = list(self._known_templates.values())
-        if devtime.enabled():
-            # the cluster upload is the H2D transfer the mesh rows care
-            # about: measured with an explicit block (decision-inert —
-            # the session constructor would synchronize on these arrays
-            # anyway), byte count from the uploaded leaves
-            import jax
+        uploaded: List[Dict] = []
 
-            lt = devtime.launch("transfer", "session-upload")
-            cluster = self.enc.device_state()
-            # ktpu: allow-sync(devtime fence: session upload timed at build, not on the dispatch path)
-            jax.block_until_ready(cluster)
-            lt.h2d_bytes = devtime.payload_bytes(cluster)
-            lt.done()
-            self._upload_seconds = _time.perf_counter() - lt.submit
-        else:
-            cluster = self.enc.device_state()
+        def device_cluster() -> Dict:
+            """The encoding on the device, uploaded when the first
+            session kind that reads it there asks (the table session
+            reads the host's arrays and never does)."""
+            if uploaded:
+                return uploaded[0]
+            if devtime.enabled():
+                # the cluster upload is the H2D transfer the mesh rows
+                # care about: measured with an explicit block
+                # (decision-inert — the session constructor would
+                # synchronize on these arrays anyway), byte count from
+                # the uploaded leaves
+                import jax
+
+                lt = devtime.launch("transfer", "session-upload")
+                cluster = self.enc.device_state()
+                # ktpu: allow-sync(devtime fence: session upload timed at build, not on the dispatch path)
+                jax.block_until_ready(cluster)
+                lt.h2d_bytes = devtime.payload_bytes(cluster)
+                lt.done()
+                self._upload_seconds = _time.perf_counter() - lt.submit
+            else:
+                cluster = self.enc.device_state()
+            uploaded.append(cluster)
+            return cluster
+
         # KTPU_EXPLAIN (or an armed shadow sentinel): per-plugin
         # attribution exists only on the hoisted session's scan outputs
         # — pallas/sharded builds demote, loudly, for as long as the
@@ -2116,7 +2209,7 @@ class TPUBackend(CacheListener):
 
                 session_builds.inc(kind="hoisted", reason="explain", shards=sh)
                 return HoistedSession(
-                    sharded.shard_cluster(cluster, self.mesh),
+                    sharded.shard_cluster(device_cluster(), self.mesh),
                     templates, self.weights, explain_k=explain_k,
                 )
             if self.use_pallas:
@@ -2124,7 +2217,8 @@ class TPUBackend(CacheListener):
                     "explain mode: hoisted session instead of pallas")
             session_builds.inc(kind="hoisted", reason="explain", shards=sh)
             return HoistedSession(
-                cluster, templates, self.weights, explain_k=explain_k)
+                device_cluster(), templates, self.weights,
+                explain_k=explain_k)
         # degradation ladder: a DEMOTED backend (rung below the
         # platform's top — NOT merely a platform whose top is hoisted)
         # builds the hoisted session even on a TPU; the probe loop
@@ -2136,7 +2230,7 @@ class TPUBackend(CacheListener):
             from ..parallel import sharded
 
             return HoistedSession(
-                sharded.shard_cluster(cluster, self.mesh),
+                sharded.shard_cluster(device_cluster(), self.mesh),
                 templates, self.weights,
             )
         if self.mesh is not None:
@@ -2149,7 +2243,8 @@ class TPUBackend(CacheListener):
 
             try:
                 s = ShardedPallasSession(
-                    cluster, templates, self.weights, mesh=self.mesh)
+                    device_cluster(), templates, self.weights,
+                    mesh=self.mesh)
                 session_builds.inc(kind="pallas", reason="mesh-sharded", shards=sh)
                 return s
             except PallasUnsupported as e:
@@ -2166,7 +2261,7 @@ class TPUBackend(CacheListener):
             from ..parallel import sharded
 
             return HoistedSession(
-                sharded.shard_cluster(cluster, self.mesh),
+                sharded.shard_cluster(device_cluster(), self.mesh),
                 templates, self.weights,
             )
         if self.use_pallas and demoted:
@@ -2179,8 +2274,17 @@ class TPUBackend(CacheListener):
             from ..ops.pallas_scan import PallasSession, PallasUnsupported
 
             try:
-                s = PallasSession(cluster, templates, self.weights,
-                                  interpret=self.pallas_interpret)
+                # the table session's prologue runs on the host, against
+                # the encoding's own arrays
+                s = PallasSession(
+                    self.enc.host_state(), templates, self.weights,
+                    interpret=self.pallas_interpret,
+                    capacity=self._table_capacity(),
+                    # reserve(anti_terms=...) says term pods will come:
+                    # a session built without the term machinery would
+                    # be rebuilt at the first of them
+                    terms=self.enc._anti_reserve > 0)
+                self._export_table_gauges(s)
                 # re-apply the fault quarantine: suspect buckets stay
                 # jit-only on the rebuilt session until they harvest
                 # cleanly again
@@ -2208,7 +2312,7 @@ class TPUBackend(CacheListener):
         else:
             session_builds.inc(kind="hoisted", reason="platform is not tpu",
                                shards=sh)
-        return HoistedSession(cluster, templates, self.weights)
+        return HoistedSession(device_cluster(), templates, self.weights)
 
     # -- helpers -----------------------------------------------------------
 

@@ -1,7 +1,8 @@
 """AOT-compile every Pallas dispatch variant the product can launch, once,
-without running it: batch buckets x {full, eval, apply} x {term-free
-zone-spread template, hostname anti-affinity template} at the
-Default-5000n cluster shape. On a TPU the kernels go through Mosaic; on
+without running it: batch buckets x {a table built without the term
+machinery (zone-spread spec), one built with it (hostname anti-affinity
+spec)} at the Default-5000n cluster shape and the table capacity a
+100 000-pod reserve asks for. On a TPU the kernels go through Mosaic; on
 CPU (asked for by name with JAX_PLATFORMS=cpu) they trace in interpret
 mode, which checks the harness of this script and nothing about Mosaic.
 
@@ -10,7 +11,7 @@ One JSON line per variant on stdout and in chiprun_out/compile_matrix.jsonl
 variant failed to compile.
 
     python scripts/compile_matrix.py [--nodes 5000] [--buckets 128 2048]
-        [--modes full eval apply] [--templates spread anti] [--mk 4 1]
+        [--templates spread anti] [--run]
 """
 
 from __future__ import annotations
@@ -39,12 +40,12 @@ TEMPLATES = {
     # the Default-5000n / PTS rows' shape: soft zone spread, no terms
     "spread": dict(spread_zone=True),
     # the IPA-churn rows' shape: required hostname anti-affinity — the
-    # term-template kernel (extra ipa operands, ucnt/kcnt carries)
+    # kernel with the term sections (repel / anti / affinity lists)
     "anti": dict(anti_affinity_hostname=True, labels={"app": "churn"}),
 }
 
 
-def build_session(n_nodes: int, template: str, mk, interpret: bool):
+def build_session(n_nodes: int, template: str, interpret: bool):
     """PallasSession over a synthetic cluster of the harness's node shape
     with a quarter of the nodes already holding one template pod each
     (counts and anti-affinity statics are then non-trivial)."""
@@ -66,22 +67,21 @@ def build_session(n_nodes: int, template: str, mk, interpret: bool):
     pe = PodEncoder(enc)
     pa = {k: v for k, v in pe.encode(tmpl.build("probe")).items()
           if not k.startswith("_")}
+    from kubernetes_tpu.ops.pallas_scan import table_capacity
+
     sess = PallasSession(enc.device_state(), [pa], interpret=interpret,
-                         multipod_k=mk)
+                         capacity=table_capacity(100_000))
     return sess, pa
 
 
 def _run_once(sess, pa, bucket: int) -> dict:
     """One blocking dispatch of `bucket` template pods on the compiled
-    executable: wall seconds, the committed prefix length (multipod
-    leaves a conflict suffix uncommitted) and the first decisions."""
+    executable: wall seconds and the first decisions."""
     t0 = time.perf_counter()
     ys = sess.schedule([pa] * bucket)
     decisions = type(sess).decisions(ys)
     dt = time.perf_counter() - t0
-    _, suffix = type(sess).conflict_stats(ys)
     return dict(run_s=round(dt, 4),
-                committed=bucket if suffix is None else suffix,
                 placed=sum(1 for d in decisions if d >= 0),
                 head=decisions[:16])
 
@@ -91,13 +91,10 @@ def main() -> int:
     ap.add_argument("--nodes", type=int, default=5000)
     ap.add_argument("--buckets", type=int, nargs="+",
                     default=[128, 256, 512, 1024, 2048])
-    ap.add_argument("--modes", nargs="+", default=["full", "eval", "apply"])
     ap.add_argument("--templates", nargs="+", default=list(TEMPLATES))
-    ap.add_argument("--mk", type=int, nargs="+", default=[1, 4],
-                    help="multipod step widths for full mode")
     ap.add_argument("--run", action="store_true",
                     help="also dispatch one full batch per compiled "
-                         "full-mode variant and report its decisions")
+                         "variant and report its decisions")
     args = ap.parse_args()
 
     dev = require_device()
@@ -110,38 +107,32 @@ def main() -> int:
     failed = 0
     with open(os.path.join(out_dir, "compile_matrix.jsonl"), "w") as f:
         for template in args.templates:
-            for mk in args.mk:
-                sess, pa = build_session(args.nodes, template, mk,
-                                         interpret)
-                for bucket in args.buckets:
-                    for mode in args.modes:
-                        if mode != "full" and mk != args.mk[0]:
-                            continue  # eval/apply never take the mk body
-                        row = dict(
-                            device=dev, template=template, bucket=bucket,
-                            mode=mode, mk=sess.multipod_k if mode == "full"
-                            else 1, nodes=args.nodes, np=sess.Np,
-                            interpret=interpret, cache_dir=cache_dir)
-                        t0 = time.perf_counter()
-                        try:
-                            fn = sess._compile_exec(bucket, mode)
-                            row["compile_s"] = round(
-                                time.perf_counter() - t0, 2)
-                            row["ok"] = True
-                            if args.run and mode == "full":
-                                sess._exec[(bucket, mode)] = fn
-                                row.update(_run_once(sess, pa, bucket))
-                        except Exception as e:  # noqa: BLE001 — the matrix reports every variant
-                            failed += 1
-                            row["ok"] = False
-                            row["error"] = f"{type(e).__name__}: {e}"
-                            row["traceback"] = traceback.format_exc()
-                        f.write(json.dumps(row) + "\n")
-                        f.flush()
-                        row.pop("traceback", None)
-                        if "error" in row:
-                            row["error"] = row["error"][:2000]
-                        print(json.dumps(row), flush=True)
+            sess, pa = build_session(args.nodes, template, interpret)
+            for bucket in args.buckets:
+                row = dict(
+                    device=dev, template=template, bucket=bucket,
+                    nodes=args.nodes, np=sess.Np, specs=sess.Tcap,
+                    terms=sess.dyn_ipa, interpret=interpret,
+                    cache_dir=cache_dir)
+                t0 = time.perf_counter()
+                try:
+                    fn = sess._compile_exec(bucket)
+                    row["compile_s"] = round(time.perf_counter() - t0, 2)
+                    row["ok"] = True
+                    if args.run:
+                        sess._exec[(bucket, "full")] = fn
+                        row.update(_run_once(sess, pa, bucket))
+                except Exception as e:  # noqa: BLE001 — the matrix reports every variant
+                    failed += 1
+                    row["ok"] = False
+                    row["error"] = f"{type(e).__name__}: {e}"
+                    row["traceback"] = traceback.format_exc()
+                f.write(json.dumps(row) + "\n")
+                f.flush()
+                row.pop("traceback", None)
+                if "error" in row:
+                    row["error"] = row["error"][:2000]
+                print(json.dumps(row), flush=True)
     return 1 if failed else 0
 
 

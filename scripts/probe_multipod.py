@@ -162,11 +162,8 @@ def main() -> int:
 
         sessions = {"hoisted": lambda k: HoistedSession(
             cluster, templates, weights, multipod_k=k)}
-        if platform == "tpu":
-            from kubernetes_tpu.ops.pallas_scan import PallasSession
-
-            sessions["pallas"] = lambda k: PallasSession(
-                cluster, templates, weights, multipod_k=k)
+        # (the table PallasSession has no multi-pod step: pods of one
+        # spec all pick the same node against the same carry)
 
         for kind, build in sessions.items():
             print(f"\n--- {profile} / {kind} ---")

@@ -139,14 +139,13 @@ class TestExecQuarantine:
 
         fresh = SimpleNamespace(_exec={})
         n = PallasSession.retire_exec(fresh, bucket=128)
-        assert n == 3
-        assert fresh._exec == {(128, "full"): None, (128, "eval"): None,
-                               (128, "apply"): None}
+        assert n == 1
+        assert fresh._exec == {(128, "full"): None}
         # idempotent; other buckets untouched
         assert PallasSession.retire_exec(fresh, bucket=128) == 0
         live = SimpleNamespace(_exec={(256, "full"): object(),
                                       (128, "full"): object()})
-        assert PallasSession.retire_exec(live, bucket=128, mode="full") == 1
+        assert PallasSession.retire_exec(live, bucket=128) == 1
         assert live._exec[(128, "full")] is None
         assert live._exec[(256, "full")] is not None
         # blanket retirement pins every existing entry

@@ -333,9 +333,13 @@ class TestPallasTerms:
         arrays = _encode_all(enc, pe, pending)
         sess = PallasSession(enc.device_state(), _templates_of(arrays),
                              interpret=True)
-        assert sess._ipa is not None
-        assert sess._ipa["w45_scale"] == 100
-        assert int(np.abs(sess._ipa["w45"]).sum(axis=1).max()) < 256
+        assert sess.dyn_ipa
+        # every preferred pod's score row takes weight 100 from each
+        # writer, inside the int32 headroom guard
+        readers = [r for r in sess._rows if r["score"]]
+        assert readers and all(0 < r["score_w"] < 2 ** 14 for r in readers)
+        assert {w for touch in sess._touch for _, _, w, _ in touch} >= {
+            -100 if anti else 100, 1}
 
     def test_cross_template_anti(self):
         # template A's anti terms must repel template B pods assumed in
@@ -361,50 +365,49 @@ class TestPallasTerms:
         assert got == ref
 
 
-class TestEvalApplySplit:
-    """The sharded session's building blocks: mode="eval" (no carry
-    writes) + mode="apply" (externally-forced placement) replayed
-    per-pod must reproduce the full kernel's decisions and carry
-    exactly — including -1 (off-shard, in the sharded case) forcing a
-    no-op."""
+class TestAdmit:
+    """PallasSession.admit at the session level (the backend's use of it
+    is in tests/test_pallas_table.py): a spec taken into a live session
+    decides as a session built with it from the start."""
 
-    def test_eval_apply_replays_full(self):
+    def _split(self, pending, n_first):
         nodes, init_pods = synth_cluster(12, pods_per_node=1)
-        pending = synth_pending_pods(16, spread=True)
         enc, pe = _presized_encoding(
             copy.deepcopy(nodes), copy.deepcopy(init_pods),
             copy.deepcopy(pending))
         arrays = _encode_all(enc, pe, pending)
-        full = PallasSession(enc.device_state(), _templates_of(arrays),
-                             interpret=True)
-        ref = PallasSession.decisions(full.schedule(arrays))[:len(arrays)]
-
+        whole = HoistedSession(enc.device_state(), _templates_of(arrays))
+        ref = HoistedSession.decisions(whole.schedule(arrays))[:len(arrays)]
         enc2, pe2 = _presized_encoding(nodes, init_pods, pending)
         arrays2 = _encode_all(enc2, pe2, pending)
-        split = PallasSession(enc2.device_state(), _templates_of(arrays2),
-                              interpret=True)
-        got = []
-        for a in arrays2:
-            ((best, _score),) = split.evaluate([a])
-            got.append(best)
-            split.apply_decisions([a], [best])
+        return enc2, arrays2, ref, n_first
+
+    def test_admit_matches_a_session_built_with_the_spec(self):
+        pending = synth_pending_pods(10, spread=True) + [
+            make_pod(f"late-{i}", cpu="300m", memory="200Mi",
+                     labels={"app": "late"}) for i in range(6)]
+        enc, arrays, ref, n = self._split(pending, 10)
+        sess = PallasSession(enc.device_state(), _templates_of(arrays[:n]),
+                             interpret=True)
+        got = PallasSession.decisions(sess.schedule(arrays[:n]))[:n]
+        # the encoding the prologue reads has to hold the first batch
+        for a, lane, pod in zip(arrays[:n], got, pending[:n]):
+            enc.add_pod(pod, enc.node_names[lane])
+        out = sess.admit(enc.host_state, arrays[n:])
+        assert out["n"] == 1 and sess.admits == 1
+        got += PallasSession.decisions(sess.schedule(arrays[n:]))[:6]
         assert got == ref
 
-    def test_off_shard_apply_is_noop(self):
-        """Forcing -1 (the pod landed on ANOTHER shard's nodes) must not
-        move this session's carry: a subsequent eval sees unchanged
-        state."""
-        nodes, init_pods = synth_cluster(8, pods_per_node=1)
-        pending = synth_pending_pods(4, spread=True)
-        enc, pe = _presized_encoding(nodes, init_pods, pending)
-        arrays = _encode_all(enc, pe, pending)
-        s = PallasSession(enc.device_state(), _templates_of(arrays),
-                          interpret=True)
-        before = s.evaluate([arrays[0]])
-        s.apply_decisions([arrays[0]], [-1])  # off-shard: no-op
-        after = s.evaluate([arrays[0]])
-        assert before == after
-        # a real apply then DOES move the carry
-        s.apply_decisions([arrays[0]], [before[0][0]])
-        moved = s.evaluate([arrays[1]])
-        assert isinstance(moved[0][0], int)
+    def test_full_table_raises_table_full(self):
+        from kubernetes_tpu.ops.pallas_scan import TableFull
+
+        pending = [make_pod(f"p-{i}", cpu="100m", labels={"app": f"a{i}"})
+                   for i in range(66)]
+        enc, arrays, _ref, _n = self._split(pending, 2)
+        sess = PallasSession(enc.device_state(), arrays[:2], interpret=True)
+        assert sess.Tcap == 64
+        sess.admit(enc.host_state, arrays[2:64])
+        assert sess.specs == 64
+        with pytest.raises(TableFull) as e:
+            sess.admit(enc.host_state, arrays[64:])
+        assert e.value.reason == "table-full"

@@ -1154,8 +1154,7 @@ class TestDeviceLadder:
             k: v for k, v in backend.pe.encode(pending).items()
             if not k.startswith("_")
         }
-        sess = PallasSession(
-            backend.enc.scratch_state(), [pa], multipod_k=1)
+        sess = PallasSession(backend.enc.scratch_state(), [pa])
         backend._session = sess
         r0 = sum(v for _, v in session_rebuilds.items())
         dp, (dc,) = _device_plan(

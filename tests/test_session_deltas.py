@@ -402,6 +402,9 @@ def test_assume_expiry_is_a_listener_event():
 # pallas carry-layout parity (CPU-verifiable without running the kernel)
 
 
+from kubernetes_tpu.ops.hoisted import template_fingerprint  # noqa: E402
+
+
 def _pallas_fixture():
     from kubernetes_tpu.ops.pallas_scan import PallasSession
 
@@ -443,47 +446,50 @@ def _remove_delta(enc, victim):
     return nidx, dres, dnz, dcount, rows
 
 
-@pytest.mark.parametrize("device_path", [False, True])
-def test_pallas_delta_carry_parity(device_path):
-    """apply_deltas on the pallas carry layout (numpy seed path and the
-    fused _carry_delta_scan) must equal a FRESH PallasSession built from
-    the mutated encoding — compared on valid node lanes, bit for bit."""
+@pytest.mark.parametrize("victim_idx", [3, 5])
+def test_pallas_delta_carry_parity(victim_idx):
+    """apply_deltas on the table session's carry (the fused _delta_scan)
+    must equal a FRESH PallasSession built from the mutated encoding —
+    utilization rows and the spec's count rows, on valid node lanes, bit
+    for bit."""
     PallasSession, enc, bound, tmpl, cluster = _pallas_fixture()
     sess = PallasSession(cluster, [tmpl])
-    victim = bound[3]
+    victim = bound[victim_idx]
     nidx, dres, dnz, dcount, rows = _remove_delta(enc, victim)
     assert sess.delta_compatible(dres, dnz)
     mfa, msa = match_matrices_np(sess._tp_np, [rows])
-    delta = {
+    sess.apply_deltas([{
         "kind": "pod-remove", "node": nidx, "dres": dres, "dnz": dnz,
         "dcount": dcount,
         "mf": mfa[:, 0, :].astype(np.int32) * -1,
         "ms": msa[:, 0, :].astype(np.int32) * -1,
-    }
-    if device_path:
-        sess._carry = sess._initial_carry()
-    sess.apply_deltas([delta])
+    }])
     fresh_cluster = {
         k: np.asarray(va) for k, va in enc.device_state().items()
     }
     fresh = PallasSession(fresh_cluster, [tmpl])
     valid = fresh_cluster["valid"].astype(bool)
     n = valid.shape[0]
-    if device_path:
-        got = {k: np.asarray(va) for k, va in sess._carry.items()}
-    else:
-        got = {
-            "requested": sess._requested0, "nzpc": sess._nzpc0,
-            "cnt_fn": sess._cnt_fn0, "cnt_sn": sess._cnt_sn0,
-        }
-    want = {
-        "requested": fresh._requested0, "nzpc": fresh._nzpc0,
-        "cnt_fn": fresh._cnt_fn0, "cnt_sn": fresh._cnt_sn0,
-    }
-    for key in want:
-        a = np.asarray(got[key])[:, :n][:, valid]
-        b = want[key][:, :n][:, valid]
+    r = sess.R
+    for key, unit in (("requested", lambda x: x._gcd[:, None]),
+                      ("nzpc", lambda x: np.concatenate(
+                          [x._gcd[:2], np.ones(6, np.int64)])[:, None])):
+        a = np.asarray(sess._initial_carry()[key]).astype(np.int64)
+        b = np.asarray(fresh._initial_carry()[key]).astype(np.int64)
+        rows_n = r if key == "requested" else 8
+        a = (a[:rows_n] * unit(sess)[:rows_n])[:, :n][:, valid]
+        b = (b[:rows_n] * unit(fresh)[:rows_n])[:, :n][:, valid]
         assert (a == b).all(), f"carry {key} diverged from fresh build"
+    fp = template_fingerprint(tmpl)
+    checked = 0
+    for side in ("f", "s"):
+        for c in range(sess.C):
+            if sess._rows[0][side][c]:
+                checked += 1
+                assert (sess.count_row(fp, side, c)[valid]
+                        == fresh.count_row(fp, side, c)[valid]).all(), (
+                    f"count row {side}{c} diverged from fresh build")
+    assert checked
 
 
 def test_pallas_gcd_incompatible_delta_rejected():
