@@ -252,6 +252,10 @@ class APIServer:
         if resources is None:
             resources = _default_resources()
         self._resources: Dict[str, ResourceInfo] = {r.name: r for r in resources}
+        # every registered type's codecs exist before the first request:
+        # nothing is generated under traffic (serde_codecs_built_total)
+        for r in resources:
+            serde.build_codecs(r.type)
         self._mutating = mutating_admission or []
         self._validating = validating_admission or []
         # called AFTER a successful create/update/hard-delete with
@@ -280,6 +284,7 @@ class APIServer:
         self._node_proxies: Dict[str, Any] = {}
 
     def register_resource(self, info: ResourceInfo) -> None:
+        serde.build_codecs(info.type)
         self._resources[info.name] = info
 
     # -- node proxy (kubelet API) ------------------------------------------
