@@ -1033,7 +1033,14 @@ class ClusterEncoding:
         CONTRACT: the scatter donates the previous device buffers, so
         arrays from an earlier device_state() call are INVALID once any
         mutation is synced — re-fetch after every mutation, never retain.
-        (CPU silently ignores donation; TPU raises on use-after-donate.)"""
+        (CPU silently ignores donation; TPU raises on use-after-donate.)
+
+        Uploads of the LIVE host arrays copy (jnp.array, never
+        jnp.asarray): on the CPU backend asarray takes a 64-byte-aligned
+        numpy buffer over without a copy, and the next in-place row
+        write on the host (update_node_alloc, a term row) would then
+        show through in a live session's statics — ahead of, and on top
+        of, the delta that reconciles it."""
         import jax.numpy as jnp
 
         if self._rebuild_needed or self._caps_grew():
@@ -1042,7 +1049,7 @@ class ClusterEncoding:
         host.update(self._term_arrays())
         host["n_nodes"] = np.array(self.n_nodes, np.int32)
         if self._device is None:
-            self._device = {k: jnp.asarray(a) for k, a in host.items()}
+            self._device = {k: jnp.array(a) for k, a in host.items()}
             self._dirty_nodes = set()
             self._dirty_pods = set()
             self._dirty_terms = False
@@ -1057,14 +1064,14 @@ class ClusterEncoding:
             self._dirty_pods = set()
         if self._dirty_terms:
             for k, a in self._term_arrays().items():
-                dev[k] = jnp.asarray(a)
+                dev[k] = jnp.array(a)
             self._dirty_terms = False
         if self._dirty_meta:
             # incremental node add/remove changes the live count (kernel
             # image-spread denominator) and the per-image node spread —
             # neither lives in a scattered row group
             dev["n_nodes"] = jnp.asarray(np.array(self.n_nodes, np.int32))
-            dev["img_nodes"] = jnp.asarray(self._arrays["img_nodes"])
+            dev["img_nodes"] = jnp.array(self._arrays["img_nodes"])
             self._dirty_meta = False
         return dev
 

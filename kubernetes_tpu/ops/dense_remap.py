@@ -429,13 +429,6 @@ class DenseRemap:
             raise PallasUnsupported(f"T*CP={TCp} exceeds {LANE} match lanes",
                                     reason="too-many-match-lanes")
 
-        # multipod IPA interference superset (filled by _build_ipa when
-        # the session carries term templates; zeros otherwise): row u,
-        # lane t != 0 means assuming a template-u pod can perturb a
-        # template-t evaluation through the D1-D5 term machinery — the
-        # multipod conflict test then replays instead of speculating
-        self._gmat = np.zeros((_ceil(T, SUB), LANE), np.float32)
-
         # per-(template, constraint) scalars, as structured tables
         self._sc_tables = {
             k: np.asarray(S[k]).copy()
@@ -512,15 +505,6 @@ class DenseRemap:
         M_pref = np.asarray(S["M_pref"]).astype(bool)   # [T, TP, T]
         match_all = np.asarray(S["match_all"]).astype(bool)  # [T, T]
         hard_w = int(np.asarray(c["hard_pod_affinity_weight"]))
-
-        # multipod template-interference superset (the host twin of the
-        # hoisted prologue's G_ipa; symmetrized — a false positive only
-        # costs a replay, never a wrong decision)
-        a1 = M_anti.any(axis=1)
-        a2 = M_aff.any(axis=1)
-        a3 = M_pref.any(axis=1)
-        g = (a1 | a1.T | a2 | a2.T | a3 | a3.T | match_all | match_all.T)
-        self._gmat[:T, :T] = g.astype(np.float32)
 
         t_pad = _ceil(T, SUB)  # per-template matrices: row t (T can be >8)
         g1 = np.zeros((t_pad, UR), np.float32)

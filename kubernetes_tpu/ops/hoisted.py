@@ -38,7 +38,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..utils import knobs
 from . import kernel as K
 from .eval import eval_reqs, eval_reqs_single, ns_member
 from .kernel import _CNT, _F64, _I64, DEFAULT_WEIGHTS
@@ -188,20 +187,9 @@ def _term_gates(tp: Dict):
         jnp.all(jnp.where(tp["ipaa_valid"][:, :, None], m_aff, True), axis=1)
         & has_aff[:, None]
     )  # [T(owner), T(entity)]
-    # template-level IPA interference for the multipod conflict test:
-    # G[u, t] true when assuming a template-u pod can perturb ANY of the
-    # D1-D5 quantities a template-t evaluation reads (u_cnt[u]/k_cnt[u]
-    # flow through M_anti[u,:,t] / M_anti[t,:,u] / match_all[t,u] /
-    # M_aff[u,:,t] / M_pref[u,:,t] / M_pref[t,:,u]). Symmetrized: a
-    # conservative superset is sound — a false positive only costs a
-    # replay, never a wrong decision.
-    a1 = jnp.any(m_anti, axis=1)
-    a2 = jnp.any(m_aff, axis=1)
-    a3 = jnp.any(m_pref, axis=1)
-    g = (a1 | a1.T | a2 | a2.T | a3 | a3.T | match_all | match_all.T)
     return {
         "M_anti": m_anti, "M_aff": m_aff, "M_pref": m_pref,
-        "match_all": match_all, "G_ipa": g,
+        "match_all": match_all,
     }
 
 
@@ -516,10 +504,7 @@ def _eval_pod(S: Dict, c_static: Dict, weights: Dict, dyn_ipa: bool,
               dyn_ports: bool, carry: Dict, tj, explain: bool = False):
     """Filter + score one pod of template `tj` against `carry` WITHOUT
     committing: returns (feasible [N] bool, total [N] int64 with -1 at
-    infeasible nodes, n_feasible scalar, expl). The one-pod _step and the
-    multipod _step_multi both build on this — the eval math exists
-    exactly once, so the speculative k-wide evaluation cannot drift
-    from the sequential reference.
+    infeasible nodes, n_feasible scalar, expl).
 
     expl is None unless `explain`: then a dict with `bits` ([N] int32,
     per-plugin filter verdicts packed in EXPLAIN_FILTER_PLUGINS bit
@@ -767,9 +752,8 @@ def _eval_pod(S: Dict, c_static: Dict, weights: Dict, dyn_ipa: bool,
 def _commit_pod(S: Dict, c_static: Dict, dyn_ipa: bool, dyn_ports: bool,
                 carry: Dict, tj, j, best, ok):
     """Apply one decided pod (batch row j, template tj, node `best`) to
-    the carry — the assume side of the step, shared verbatim by _step
-    and _step_multi. All updates are gated on `ok` (no-op for failed /
-    padding rows)."""
+    the carry — the assume side of the step. All updates are gated on
+    `ok` (no-op for failed / padding rows)."""
     req = S["req"][tj]
     nz_req = S["nz_req"][tj]
     add64 = ok.astype(_I64)
@@ -835,125 +819,6 @@ def _step(S: Dict, c_static: Dict, weights: Dict, dyn_ipa: bool,
         y["expl_topk_total"] = topv
         y["expl_topk_scores"] = expl["scores"][:, topi].T  # [kk, 8]
     return carry, y
-
-
-def _step_multi(S: Dict, c_static: Dict, weights: Dict, dyn_ipa: bool,
-                dyn_ports: bool, k: int, carry: Dict, xk: Dict):
-    """k pods per scan step with EXACT conflict replay (PERF_NOTES
-    round 9): all k pods are filtered + scored in ONE vmapped evaluation
-    against the step-initial carry (the device-parallel win — the common
-    no-conflict case costs one eval for k pods), then a cheap inner scan
-    commits them in order. A pod's speculative decision stands only when
-    NONE of the step's earlier committed pods could have perturbed what
-    its evaluation read:
-
-      same-node  — an earlier pod consumed capacity on the chosen node
-                   (the stale score there cannot stand);
-      PTS        — an earlier pod's row matches one of this template's
-                   VALID spread selectors (Mf/Ms gated by f/s_valid):
-                   the f_cnt/s_cnt/h_cnt rows this pod reads moved.
-                   Counts written to invalid constraint slots are never
-                   read (f_same_key/terms are valid-gated), so the gate
-                   is exact at template granularity;
-      IPA        — template-level interference via the prologue's G_ipa
-                   superset (u_cnt/k_cnt flow through the D1-D5 gates);
-      fit flip / — the shared utilization algebra
-      overtake     (kernel.multipod_utilization_conflicts): fit /
-                   balanced / least are the ONLY carry-reading plugins
-                   left once the count gates are clean, so re-evaluating
-                   exactly those three against the current carry decides
-                   exactness.
-
-    A conflicted pod REPLAYS in-device (lax.cond) — the full eval against
-    the current carry, i.e. the sequential reference computation — so
-    decisions, scores and n_feasible stay bit-identical to
-    one-pod-per-step whatever the conflict rate. Replays are counted in
-    ys["conflicts"] (scheduler_multipod_conflicts_total)."""
-    carry0 = carry
-    ev_feas, ev_total, ev_nfeas, _ = jax.vmap(
-        lambda t: _eval_pod(S, c_static, weights, dyn_ipa, dyn_ports,
-                            carry0, t)
-    )(xk["tmpl"])
-    n = c_static["valid"].shape[0]
-    lane = jnp.arange(n, dtype=jnp.int32)
-    w_bal = weights["balanced"]
-    w_least = weights["least"]
-    alloc = c_static["alloc"]
-
-    def wbl(nz_requested, nz_req):
-        return (
-            K.balanced_score(nz_requested, nz_req, alloc) * w_bal
-            + K.least_allocated_score(nz_requested, nz_req, alloc) * w_least
-        )
-
-    def inner(state, i):
-        carry_i, best_arr, ok_arr = state
-        tj = xk["tmpl"][i]
-        jj = xk["j"][i]
-        valid_i = xk["valid"][i]
-        total_i = ev_total[i]
-        feas_i = ev_feas[i]
-        best_spec = jnp.argmax(total_i).astype(jnp.int32)
-        score_spec = total_i[best_spec]
-        # committed earlier pods of this step (placed: best_arr >= 0)
-        prior = (jnp.arange(k) < i) & ok_arr
-        same = jnp.any(prior & (best_arr == best_spec)) & (score_spec >= 0)
-        mf_k = (S["Mf"][tj][xk["j"]] != 0) & S["f_valid"][tj][None, :]
-        ms_k = (S["Ms"][tj][xk["j"]] != 0) & S["s_valid"][tj][None, :]
-        pts_conf = jnp.any(
-            prior & (jnp.any(mf_k, axis=1) | jnp.any(ms_k, axis=1))
-        )
-        if dyn_ipa:
-            ipa_conf = jnp.any(prior & S["G_ipa"][xk["tmpl"], tj])
-        else:
-            ipa_conf = jnp.bool_(False)
-        nz_req = S["nz_req"][tj]
-        fit_new = K.fit_mask(
-            carry_i["requested"], carry_i["pod_count"], alloc,
-            c_static["allowed_pods"], S["req"][tj], S["req_check"][tj],
-            S["req_has_any"][tj],
-        )
-        flip_row, over_row = K.multipod_utilization_conflicts(
-            feas_i, total_i, best_spec, score_spec, lane, fit_new,
-            wbl(carry0["nz_requested"], nz_req),
-            wbl(carry_i["nz_requested"], nz_req),
-        )
-        util_conf = jnp.any(flip_row) | (
-            jnp.any(over_row) & (score_spec >= 0)
-        )
-        conflict = (same | pts_conf | ipa_conf | util_conf) & valid_i
-
-        def replay(c):
-            _, t2, nf2, _ = _eval_pod(
-                S, c_static, weights, dyn_ipa, dyn_ports, c, tj
-            )
-            b2 = jnp.argmax(t2).astype(jnp.int32)
-            return b2, t2[b2], nf2
-
-        def spec(c):
-            return best_spec, score_spec, ev_nfeas[i]
-
-        best, score, n_feasible = jax.lax.cond(conflict, replay, spec,
-                                               carry_i)
-        ok = (score >= 0) & valid_i
-        carry_i = _commit_pod(
-            S, c_static, dyn_ipa, dyn_ports, carry_i, tj, jj, best, ok
-        )
-        y = {
-            "best": jnp.where(ok, best, -1),
-            "score": jnp.where(ok, score, -1),
-            "n_feasible": n_feasible,
-            "conflicts": conflict.astype(jnp.int32),
-        }
-        return (
-            (carry_i, best_arr.at[i].set(jnp.where(ok, best, -1)),
-             ok_arr.at[i].set(ok)),
-            y,
-        )
-
-    state = (carry, jnp.full(k, -1, jnp.int32), jnp.zeros(k, bool))
-    (carry, _, _), ys = jax.lax.scan(inner, state, jnp.arange(k))
-    return carry, ys
 
 
 # tp keys the step reads directly when the dynamic-IPA / dynamic-ports
@@ -1176,36 +1041,18 @@ def _session_apply_deltas(carry, f_pair_cn, s_pair_cn, s_src,
 
 @functools.partial(
     jax.jit,
-    static_argnames=("weights_key", "dyn_ipa", "dyn_ports", "k",
-                     "explain_k"),
+    static_argnames=("weights_key", "dyn_ipa", "dyn_ports", "explain_k"),
     donate_argnames=("carry",),
 )
 def _session_scan(S, c_static, tp, carry, batch_self, xs, weights_key,
                   dyn_ipa: bool = False, dyn_ports: bool = False,
-                  k: int = 1, explain_k: int = 0):
+                  explain_k: int = 0):
     weights = dict(weights_key)
     S = dict(S)
     S["Mf"], S["Ms"] = _match_matrices(tp, batch_self)
-    # unroll: every scan iteration launches its fused kernels afresh;
-    # unrolling trades compile time for fewer iterations (semantics
-    # identical). Not measured on the present chip.
-    unroll = knobs.get_int("KTPU_SCAN_UNROLL")
-    if k <= 1 or explain_k > 0:
-        # explain rides the one-pod-per-step scan (the session pins
-        # multipod_k to 1 in explain mode; decisions are identical)
-        step = functools.partial(_step, S, c_static, weights, dyn_ipa,
-                                 dyn_ports, explain_k)
-        return jax.lax.scan(step, carry, xs, unroll=unroll)
-    # multipod: fold the batch axis into [steps, k] — every pow2 bucket
-    # divides by the pow2 k (kernel.multipod_k clamps it) — and run the
-    # k-wide step; ys come back [steps, k, ...] and unfold to [Bp, ...]
-    bp = int(xs["tmpl"].shape[0])
-    xk = {key: v.reshape((bp // k, k) + v.shape[1:]) for key, v in xs.items()}
-    step = functools.partial(_step_multi, S, c_static, weights, dyn_ipa,
-                             dyn_ports, k)
-    carry, ys = jax.lax.scan(step, carry, xk, unroll=unroll)
-    ys = {key: v.reshape((bp,) + v.shape[2:]) for key, v in ys.items()}
-    return carry, ys
+    step = functools.partial(_step, S, c_static, weights, dyn_ipa,
+                             dyn_ports, explain_k)
+    return jax.lax.scan(step, carry, xs)
 
 
 class HoistedSession:
@@ -1214,7 +1061,7 @@ class HoistedSession:
     The one session kind with explain support (supports_explain): with
     explain_k > 0 every scan step also returns packed per-plugin filter
     bits and the top-k candidates' weighted score stacks, decoded by
-    explain_payload (decisions stay bit-identical; multipod pins to 1).
+    explain_payload (decisions stay bit-identical).
 
     schedule_batch_hoisted pays the prologue (per-template pod-table
     sweeps + count bases) and a full cluster upload on EVERY dispatch
@@ -1257,7 +1104,6 @@ class HoistedSession:
         cluster: Dict,
         template_arrays_list: List[Dict],
         weights: Optional[Dict[str, int]] = None,
-        multipod_k: Optional[int] = None,
         explain_k: int = 0,
     ):
         self._weights_key = tuple(sorted((weights or DEFAULT_WEIGHTS).items()))
@@ -1304,18 +1150,6 @@ class HoistedSession:
             {k: np.asarray(tp[k]) for k in TERM_NP_KEYS}
             if self._dyn_ipa else None
         )
-        # multi-pod scan steps (PERF_NOTES round 9): k pods decided per
-        # step with exact in-device conflict replay (_step_multi).
-        # Port-carrying sessions are pinned to k=1 — the carried NodePorts
-        # tables sit outside the conflict algebra (kernel.multipod_k)
-        self.multipod_k = K.multipod_k(multipod_k, dyn_ports=self._dyn_ports)
-        if self.explain_k:
-            # explain mode pins one-pod-per-step: attribution is per
-            # decided pod against its exact decision-time carry, which
-            # the k-wide speculative evaluation cannot provide for
-            # conflicted pods. Decisions are bit-identical either way
-            # (the multipod contract).
-            self.multipod_k = 1
 
     # -- incremental device-state deltas -----------------------------------
 
@@ -1397,8 +1231,7 @@ class HoistedSession:
         self._carry, ys = _session_scan(
             self._S, self._c_static, self._tp, self._carry,
             batch_self, xs, self._weights_key,
-            self._dyn_ipa, self._dyn_ports, self.multipod_k,
-            self.explain_k,
+            self._dyn_ipa, self._dyn_ports, self.explain_k,
         )
         ys = dict(ys)
         ys["_b_real"] = b  # padding rows carry no decision
@@ -1411,20 +1244,6 @@ class HoistedSession:
         unschedulable), bucket-padding rows stripped."""
         best = np.asarray(ys["best"])
         return [int(v) for v in best[: ys.get("_b_real", best.shape[0])]]
-
-    @staticmethod
-    # ktpu: allow-sync(harvest decode: host reads conflict planes after the launch completes)
-    def conflict_stats(ys: Dict):
-        """(n_conflicts, replay_suffix_start) for one harvested batch.
-        The hoisted scan replays conflicted pods IN-DEVICE (_step_multi
-        lax.cond), so every decision is already exact: the suffix is
-        always None and the count is observability only
-        (scheduler_multipod_conflicts_total)."""
-        c = ys.get("conflicts")
-        if c is None:
-            return 0, None
-        arr = np.asarray(c)
-        return int(arr[: ys.get("_b_real", arr.shape[0])].sum()), None
 
     @staticmethod
     # ktpu: allow-sync(harvest decode: explain attribution is read back off the hot path)

@@ -673,99 +673,3 @@ def schedule_pods_jit(c: Dict, P: Dict, weights: Dict[str, int] = None) -> Dict:
     vmapped launch amortizes all of it."""
     key = tuple(sorted((weights or DEFAULT_WEIGHTS).items()))
     return _jitted_vmapped(c, P, key)
-
-
-# ---------------------------------------------------------------------------
-# Multi-pod scan steps (PERF_NOTES round 9): k pods decided per scan step
-# with EXACT conflict replay. The policy knob and the shared
-# utilization-side conflict algebra live here so the hoisted, pallas and
-# sharded steps cannot drift apart.
-
-DEFAULT_MULTIPOD_K = 4
-
-
-def multipod_k(explicit=None, dyn_ports: bool = False,
-               platform: str = "", suffix_replay: bool = False) -> int:
-    """Resolve the multi-pod step width for a session build.
-
-    Precedence: port-carrying sessions are pinned to 1 (the carried
-    NodePorts tables are OUTSIDE the conflict algebra — a same-step port
-    clash would not be detected); then an explicit constructor argument;
-    then KTPU_MULTIPOD_K (the kill switch: =1 restores one-pod-per-step
-    everywhere); then the default — 1 for sessions on the conflict-SUFFIX
-    contract (`suffix_replay`: pallas, sharded), else DEFAULT_MULTIPOD_K
-    on TPU and 1 elsewhere (the CPU build env runs the whole test suite
-    through these scans; paying the k-wide vmapped eval compile there
-    buys nothing, and the parity suites pass k explicitly).
-
-    Why suffix sessions default to 1: pods stamped from one template,
-    evaluated against the same group-start carry, all pick the same best
-    node, so the second pod of the first group always conflicts and the
-    kernel leaves the rest of the batch uncommitted — one committed pod
-    per launch, and the host relaunches the suffix. Measured on a v5e at
-    5000 nodes (PR 21): a 2048-pod launch of one template committed 1
-    pod at k=4 and 2048 at k=1, in the same 0.03 s. The hoisted scan
-    replays a conflicted pod in-device, so its cost stays bounded.
-
-    The result is clamped to a power of two <= 64 so every pow2 batch
-    bucket divides into whole steps."""
-    from ..utils import knobs
-
-    if dyn_ports:
-        return 1
-    if explicit is not None:
-        k = int(explicit)
-    else:
-        env = knobs.get_int("KTPU_MULTIPOD_K", default=0)
-        if env:
-            k = int(env)
-        elif suffix_replay:
-            k = 1
-        else:
-            if not platform:
-                import jax as _jax
-
-                platform = _jax.devices()[0].platform
-            k = DEFAULT_MULTIPOD_K if platform == "tpu" else 1
-    k = max(1, k)
-    p = 1
-    while p * 2 <= min(k, 64):
-        p *= 2
-    return p
-
-
-def multipod_utilization_conflicts(feasible, total, best, score, lane,
-                                   fit_new, wbl_old, wbl_new):
-    """The utilization side of the exact conflict test, shared by the
-    multipod steps (hoisted in-device replay, sharded suffix flags; the
-    pallas kernel mirrors it in Mosaic — divergences are bugs).
-
-    Premise: with the PTS/IPA count gates already clean, committing the
-    step's earlier pods changed this pod's true score vector ONLY
-    through NodeResourcesFit / BalancedAllocation / LeastAllocated at
-    the committed nodes — every other plugin reads statics or counts,
-    and the normalization sets are untouched as long as feasibility did
-    not move. So re-evaluating exactly those three against the current
-    carry decides exactness:
-
-      fit_flip  — a speculatively-feasible node no longer fits (the
-                  carry only grows, so fit is monotone non-increasing):
-                  the feasible SET changed, which perturbs the PTS/IPA/
-                  taint/node-affinity normalizations at every node —
-                  the speculative decision cannot stand;
-      overtake  — a still-feasible node's new total now beats (or
-                  first-max-ties below) the speculative winner: the
-                  argmax moved. At untouched nodes wbl_new == wbl_old,
-                  so the test degenerates to comparisons the spec argmax
-                  already won — no touched-node bookkeeping is needed.
-
-    All args are per-node rows (any layout: [N] vectors, (1, Np) shard
-    blocks); returns (fit_flip_row, overtake_row) for the caller to
-    any()/reduce — the sharded step pmax-reduces them globally."""
-    new_total = total + (wbl_new - wbl_old)
-    fit_flip = feasible & ~fit_new
-    overtake = (
-        feasible & fit_new
-        & ((new_total > score) | ((new_total == score) & (lane < best)))
-    )
-    return fit_flip, overtake

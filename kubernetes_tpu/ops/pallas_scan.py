@@ -90,7 +90,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..utils import knobs
 from .hoisted import (
     _eval_reqs_batch_np,
     _session_prologue,
@@ -113,6 +112,10 @@ ADMIT_CHUNK = 8     # specs per prologue launch (one compiled shape)
 WRITE_CHUNK = 64    # rows per table write (one compiled shape per table)
 MAX_QUIRKS = 1024   # balanced float64-quirk states the kernel can list
 TOUCH_W = 4         # words per touch entry: count row, pair row, weight, src row
+# pods per kernel loop iteration: a manual unroll that amortizes Mosaic's
+# per-iteration bookkeeping (partial `unroll=` is unsupported by the TPU
+# lowering)
+POD_GROUP = 4
 
 _MISSING = object()  # exec-cache sentinel (None = AOT failed, use jit)
 
@@ -301,13 +304,11 @@ def _build_kernel(cfg: _Cfg, Bp: int):
     node lane for pod b against the carry, take the first of the maxima,
     commit it to the carry (utilization rows and the count rows on the
     spec's touch list), next pod."""
-    skip = frozenset(
-        knobs.get_str("KTPU_PALLAS_SKIP").split(","))  # profiling only
     (Tcap, PC, Np, R, C, RC, SRc, ZRc, K, LB) = cfg.shapes
     L = _layout(R, C, cfg.ipa)
     Wt = dict(cfg.weights)
     W = L.W
-    dyn_ipa = cfg.ipa and "ipa" not in skip
+    dyn_ipa = cfg.ipa
     f32 = jnp.float32
     i32 = jnp.int32
 
@@ -420,7 +421,7 @@ def _build_kernel(cfg: _Cfg, Bp: int):
 
             # ---- PTS filter: per-node counts of the reader's own rows ----
             fail_pts = jnp.zeros((1, Np), i32)
-            for c in range(C) if "ptsf" not in skip else ():
+            for c in range(C):
                 o = L.PF + PF_W * c
 
                 def hard(o=o, c=c):
@@ -502,7 +503,7 @@ def _build_kernel(cfg: _Cfg, Bp: int):
             n_scored = jnp.sum(scored_f32)
             raw = jnp.zeros((1, Np), f32)
             have_s = i32(0)
-            for c in range(C) if "ptss" not in skip else ():
+            for c in range(C):
                 o = L.PS + PS_W * c
 
                 def soft(o=o, c=c):
@@ -641,8 +642,7 @@ def _build_kernel(cfg: _Cfg, Bp: int):
 
         def one_pod(b):
             sp, lane_n, best, m, ok, n_feasible = eval_pod(b)
-            if "updates" not in skip:
-                apply_pod(sp, lane_n, best, ok.astype(i32))
+            apply_pod(sp, lane_n, best, ok.astype(i32))
             subi = jax.lax.broadcasted_iota(i32, (SUB, Bp), 0)
             lanei = jax.lax.broadcasted_iota(i32, (SUB, Bp), 1)
             at_b = lanei == b
@@ -653,11 +653,8 @@ def _build_kernel(cfg: _Cfg, Bp: int):
             o = jnp.where(at_b & (subi == 2), n_feasible, o)
             out_ref[:] = o
 
-        # manual unroll: U pods per loop iteration amortizes Mosaic's
-        # per-iteration bookkeeping (partial `unroll=` is unsupported by
-        # the TPU lowering). b >= B_real iterations are no-ops via the ok
-        # gate.
-        U = int(knobs.get_int("KTPU_PALLAS_GROUP"))
+        # b >= B_real iterations are no-ops via the ok gate
+        U = POD_GROUP
         while Bp % U:
             U //= 2
 
@@ -1864,9 +1861,7 @@ class PallasSession:
         meta = jnp.asarray(meta)
         key = (Bp, "full")
         fn = self._exec.get(key, _MISSING)
-        if not knobs.get_bool("KTPU_PALLAS_AOT"):
-            fn = None  # kill switch wins even over warm-installed execs
-        elif fn is _MISSING:
+        if fn is _MISSING:
             # Counted miss path: a dispatch-time compile is a stall the
             # device timeline must attribute (warm_buckets prefills are
             # deliberate and uncounted).
@@ -1917,7 +1912,6 @@ class PallasSession:
         path would hit the same compiler error) and is recorded in
         exec_errors — without pinning the entry, so the serving path
         still makes its own attempt."""
-        aot = knobs.get_bool("KTPU_PALLAS_AOT")
         for Bp in sizes:
             if self._warm_stop.is_set():
                 return
@@ -1930,8 +1924,4 @@ class PallasSession:
             except Exception as e:  # noqa: BLE001 — warming is off the serving path; the error is kept
                 self._exec_failed(Bp, "AOT warm compile failed", e)
                 return
-            # with the AOT kill switch set, warming still fills the
-            # (persistent) compile caches, but the serving path must
-            # keep dispatching through jit — don't install
-            if aot:
-                self._exec.setdefault((Bp, "full"), compiled)
+            self._exec.setdefault((Bp, "full"), compiled)

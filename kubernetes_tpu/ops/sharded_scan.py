@@ -63,7 +63,6 @@ from ..parallel.partition import (
     shard_tree,
 )
 from ..parallel.sharded import NODE_AXIS
-from . import kernel as K_ops
 from .hoisted import template_fingerprint
 from .kernel import MAX_NODE_SCORE
 from .dense_remap import DenseRemap, _carry_delta_scan, batch_prologue
@@ -88,8 +87,7 @@ def _doth(a, b, dims):
 
 def _fit_row(cfg, statics, tables, carry, t):
     """NodeResourcesFit row for template t against `carry` (local, no
-    collectives) — shared by the eval and the multipod step's conflict
-    recheck (the fit leg of kernel.multipod_utilization_conflicts)."""
+    collectives)."""
     (T, C, CP, R, SR, K, Npl, TCp, UR) = cfg[0]
     requested, nzpc = carry["requested"], carry["nzpc"]
     alloc = statics["alloc"]
@@ -108,8 +106,7 @@ def _fit_row(cfg, statics, tables, carry, t):
 
 def _resource_scores(cfg, statics, tables, carry, t):
     """(balanced, least) rows for template t against `carry` (local, no
-    collectives) — shared by the eval and the multipod step's wbl
-    recheck (the balanced/least legs of the conflict algebra)."""
+    collectives)."""
     (T, C, CP, R, SR, K, Npl, TCp, UR) = cfg[0]
     f32 = jnp.float32
     nzpc = carry["nzpc"]
@@ -142,8 +139,7 @@ def _eval_fn(cfg, statics, tables, carry, x):
     """Filter + score one pod against `carry` WITHOUT carry updates
     (local partials -> collectives -> finish -> cross-shard argmax).
     Mirrors ops/pallas_scan.py _build_kernel one_pod (mode="full")
-    line for line; divergences are bugs. Returns everything the commit
-    and the multipod conflict test need."""
+    line for line; divergences are bugs."""
     (T, C, CP, R, SR, K, Npl, TCp, UR) = cfg[0]
     W = dict(cfg[1])
     f32 = jnp.float32
@@ -387,19 +383,12 @@ def _eval_fn(cfg, statics, tables, carry, x):
     cand = jnp.min(jnp.where(tf >= m, glane, jnp.int32(POS_BIG)))
     best = pmin(cand).astype(jnp.int32)
     ok = (m >= 0) & x["valid"]
-    return dict(
-        feasible=feasible, total=total, n_feasible=n_feasible,
-        best=best, score=m, ok=ok, glane=glane,
-        balanced=balanced, least=least,
-    )
+    return dict(n_feasible=n_feasible, best=best, score=m, ok=ok)
 
 
 def _commit_fn(cfg, statics, tables, carry, x, t, best, oki):
     """Winner-shard carry updates for one decided pod (hot == 0 on every
-    other shard) — the apply side of the step, shared by _step_fn and
-    the multipod step (where `oki` additionally carries the
-    conflict-suffix gate: flagged pods must NOT commit; the host
-    replays them)."""
+    other shard) — the apply side of the step."""
     (T, C, CP, R, SR, K, Npl, TCp, UR) = cfg[0]
     f32 = jnp.float32
 
@@ -489,8 +478,7 @@ def _commit_fn(cfg, statics, tables, carry, x, t, best, oki):
 
 def _step_fn(cfg, statics, tables, carry, x):
     """One pod through the two-phase step (runs per shard, inside
-    shard_map): _eval_fn -> _commit_fn, the one-pod-per-step reference
-    path."""
+    shard_map): _eval_fn -> _commit_fn."""
     e = _eval_fn(cfg, statics, tables, carry, x)
     ok, best = e["ok"], e["best"]
     new_carry = _commit_fn(cfg, statics, tables, carry, x, x["tmpl"],
@@ -504,146 +492,31 @@ def _step_fn(cfg, statics, tables, carry, x):
     return new_carry, y
 
 
-def _step_multi_fn(cfg, statics, tables, k, carry, xk, seen_in):
-    """k pods per scan step for the sharded session: every pod of the
-    group is evaluated against the GROUP-START carry (k independent
-    evals — no carry chain between them), then committed in order with
-    the exact conflict test of the hoisted multipod step
-    (ops/hoisted.py _step_multi; the utilization legs ride the shared
-    kernel.multipod_utilization_conflicts, pmax-reduced globally).
-
-    Unlike the hoisted step there is NO in-device replay: a replay
-    branch would put collectives under lax.cond inside shard_map.
-    Instead the step uses the CONFLICT-SUFFIX contract the pallas
-    kernel shares: the first conflicted pod and everything after it in
-    the group are left UNCOMMITTED and flagged in ys["conflicts"]; the
-    backend replays exactly that suffix sequentially through the live
-    session (tpu_backend._harvest_locked), which chains on the
-    committed-prefix carry — bit-identical to one-pod-per-step either
-    way. Every conflict predicate is built from replicated scalars
-    (pmax/psum-reduced), so all shards gate commits identically."""
-    (T, C, CP, R, SR, K, Npl, TCp, UR) = cfg[0]
-    W = dict(cfg[1])
-    f32 = jnp.float32
-    w_bal = W["balanced"]
-    w_least = W["least"]
-
-    def x_at(i):
-        return {kk: xk[kk][i] for kk in xk}
-
-    evs = [_eval_fn(cfg, statics, tables, carry, x_at(i)) for i in range(k)]
-    carry_i = carry
-    # the suffix flag rides the SCAN carry (`seen_in`): a conflict in an
-    # earlier group invalidates every later group too — their evals
-    # chained on a carry missing the suffix commits — so once set,
-    # nothing later in the batch commits and everything is flagged for
-    # the host replay
-    conf_seen = seen_in
-    committed = []  # (best, okc) of the already-committed prefix
-    ys = {"best": [], "score": [], "n_feasible": [], "conflicts": []}
-    for i in range(k):
-        e = evs[i]
-        x = x_at(i)
-        t = x["tmpl"]
-        # global int32 winner score for the exact overtake comparison
-        # (e["score"] is the f32 argmax value; totals are int32)
-        score_i = jax.lax.pmax(jnp.max(e["total"]), NODE_AXIS)
-        same = jnp.bool_(False)
-        pts = jnp.bool_(False)
-        ipa = jnp.bool_(False)
-        fv = jnp.pad(jax.lax.dynamic_index_in_dim(
-            tables["f_valid"], t, 0, keepdims=False), (0, CP - C)
-        ).astype(f32)
-        sv = jnp.pad(jax.lax.dynamic_index_in_dim(
-            tables["s_valid"], t, 0, keepdims=False), (0, CP - C)
-        ).astype(f32)
-        for j2 in range(i):
-            bj, okj = committed[j2]
-            prior = okj != 0
-            same = same | (prior & (bj == e["best"]))
-            # PTS: pod j2's Mf/Ms lanes of template t, valid-gated —
-            # nonzero means the f/s/h counts this pod read moved
-            mfj = jax.lax.dynamic_slice_in_dim(xk["mf"][j2], t * CP, CP)
-            msj = jax.lax.dynamic_slice_in_dim(xk["ms"][j2], t * CP, CP)
-            pts = pts | (prior
-                         & ((jnp.sum(mfj * fv) + jnp.sum(msj * sv)) > 0))
-            if UR > 0:
-                g = tables["gmat"][xk["tmpl"][j2], t]
-                ipa = ipa | (prior & (g > 0))
-        same = same & (score_i >= 0)
-        fit_new = _fit_row(cfg, statics, tables, carry_i, t)
-        bal_new, least_new = _resource_scores(cfg, statics, tables,
-                                              carry_i, t)
-        flip_row, over_row = K_ops.multipod_utilization_conflicts(
-            e["feasible"], e["total"], e["best"], score_i, e["glane"],
-            fit_new,
-            e["balanced"] * w_bal + e["least"] * w_least,
-            bal_new * w_bal + least_new * w_least,
-        )
-        util_local = jnp.any(flip_row) | (jnp.any(over_row)
-                                          & (score_i >= 0))
-        util = jax.lax.psum(util_local.astype(jnp.int32), NODE_AXIS) > 0
-        conf_i = (same | pts | ipa | util) & x["valid"]
-        conf_seen = conf_seen | conf_i
-        okc = (e["ok"] & jnp.logical_not(conf_seen)).astype(jnp.int32)
-        carry_i = _commit_fn(cfg, statics, tables, carry_i, x, t,
-                             e["best"], okc)
-        committed.append((e["best"], okc))
-        placed = okc != 0
-        ys["best"].append(jnp.where(placed, e["best"], jnp.int32(-1)))
-        ys["score"].append(jnp.where(placed, e["score"].astype(jnp.int32),
-                                     jnp.int32(-1)))
-        ys["n_feasible"].append(e["n_feasible"])
-        ys["conflicts"].append(conf_seen.astype(jnp.int32))
-    return carry_i, {kk: jnp.stack(v) for kk, v in ys.items()}, conf_seen
-
-
 @functools.partial(
     jax.jit,
-    static_argnames=("cfg", "mesh", "k"),
+    static_argnames=("cfg", "mesh"),
     donate_argnames=("carry",),
 )
-def _sharded_scan(cfg, mesh, statics, tables, carry, xs, k: int = 1):
+def _sharded_scan(cfg, mesh, statics, tables, carry, xs):
     # placements are DECLARED, not wired: the same rule table that placed
     # the session state at build time (parallel/partition.py
     # SESSION_PARTITION_RULES) yields the shard_map in/out specs, so a
     # new carry or static either matches a rule or fails at trace time
     ys_spec = {"best": P(), "score": P(), "n_feasible": P()}
-    if k > 1:
-        ys_spec["conflicts"] = P()
-        # fold the batch axis into [steps, k] (pow2 buckets divide by
-        # the pow2 k) — the k-wide step evaluates a whole group against
-        # the step-initial carry
-        bp = int(np.shape(xs["tmpl"])[0])
-        xs = {kk: v.reshape((bp // k, k) + v.shape[1:])
-              for kk, v in xs.items()}
     statics_spec = session_specs("statics", statics)
     tables_spec = session_specs("tables", tables)
     carry_spec = session_specs("carry", carry)
     xs_spec = session_specs("xs", xs)
 
     def body(statics, tables, carry, xs):
-        if k > 1:
-            def step(state, x):
-                c, seen = state
-                c, y, seen = _step_multi_fn(cfg, statics, tables, k,
-                                            c, x, seen)
-                return (c, seen), y
-
-            (carry, _), ys = jax.lax.scan(
-                step, (carry, jnp.bool_(False)), xs)
-            return carry, ys
         step = functools.partial(_step_fn, cfg, statics, tables)
         return jax.lax.scan(step, carry, xs)
 
-    carry, ys = jax.shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(statics_spec, tables_spec, carry_spec, xs_spec),
         out_specs=(carry_spec, ys_spec), check_vma=False,
     )(statics, tables, carry, xs)
-    if k > 1:
-        ys = {kk: v.reshape((-1,) + v.shape[2:]) for kk, v in ys.items()}
-    return carry, ys
 
 
 @functools.partial(
@@ -690,15 +563,11 @@ class ShardedPallasSession:
     # ktpu: allow-sync(session build: host mirrors of shard planes built once at construction)
     def __init__(self, cluster: Dict, template_arrays_list: List[Dict],
                  weights: Optional[Dict[str, int]] = None,
-                 mesh: Optional[Mesh] = None,
-                 multipod_k: Optional[int] = None):
+                 mesh: Optional[Mesh] = None):
         assert mesh is not None, "ShardedPallasSession needs a mesh"
         if len(mesh.devices.ravel()) < 1:
             raise PallasUnsupported("empty mesh", reason="other")
         inner = DenseRemap(cluster, template_arrays_list, weights)
-        # multi-pod steps (conflict-SUFFIX contract: flagged pods are
-        # uncommitted; the backend replays them through the live session)
-        self.multipod_k = K_ops.multipod_k(multipod_k, suffix_replay=True)
         self.mesh = mesh
         self.weights = inner.weights
         self._fps = inner._fps
@@ -779,10 +648,6 @@ class ShardedPallasSession:
             "s_same": same_pad(tb["s_same_key"]),
             "ipa_present": tb["ipa_present"].astype(np.int32),
             "s_perno_rows": _perno_rows(inner._s_perno, T, self.C, CP),
-            # multipod IPA interference superset (pallas _build_ipa; all
-            # zeros for term-free sessions): G[u, t] != 0 means assuming
-            # a template-u pod can perturb a template-t evaluation
-            "gmat": inner._gmat[:T, :T],
         }
         if self.UR:
             # IPA term machinery (pallas _build_ipa products): node-axis
@@ -893,40 +758,17 @@ class ShardedPallasSession:
             "mf": jnp.asarray(mfx),
             "ms": jnp.asarray(msx),
         }
-        k = min(self.multipod_k, Bp)
         self._carry, ys = _sharded_scan(
             self._cfg, self.mesh, self._statics, self._tables,
-            self._carry, xs, k=k)
-        out = {"best": ys["best"], "score": ys["score"],
-               "n_feasible": ys["n_feasible"], "_b_real": B}
-        if k > 1:
-            out["conflicts"] = ys["conflicts"]
-        return out
+            self._carry, xs)
+        return {"best": ys["best"], "score": ys["score"],
+                "n_feasible": ys["n_feasible"], "_b_real": B}
 
     @staticmethod
     # ktpu: allow-sync(harvest decode: host consumes batch verdicts after the launch completes)
     def decisions(ys: Dict) -> List[int]:
         best = np.asarray(ys["best"])
         return [int(v) for v in best[: ys["_b_real"]]]
-
-    @staticmethod
-    # ktpu: allow-sync(harvest decode: host reads conflict planes after the launch completes)
-    def conflict_stats(ys: Dict):
-        """(n_conflicts, replay_suffix_start): the sharded multipod step
-        does NOT replay in-device (collectives under lax.cond) — the
-        first flagged pod and everything after it in the batch were left
-        uncommitted, and the caller must replay exactly that suffix
-        through the session (the carry holds the committed prefix).
-        n_conflicts is 1 — one detection headed the suffix; later flags
-        are collateral, and genuine later conflicts are re-detected and
-        re-counted when the replayed suffix runs."""
-        c = ys.get("conflicts")
-        if c is None:
-            return 0, None
-        flags = np.asarray(c)[: ys["_b_real"]] != 0
-        if not flags.any():
-            return 0, None
-        return 1, int(np.argmax(flags))
 
     # -- incremental device-state deltas -----------------------------------
 

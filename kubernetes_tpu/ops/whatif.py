@@ -457,7 +457,7 @@ class WhatifContext:
                 {k: np.asarray(a) for k, a in host.items()}, mesh)
         else:
             cluster = {k: jnp.asarray(a) for k, a in host.items()}
-        sess = HoistedSession(cluster, [pod_arrays], multipod_k=1)
+        sess = HoistedSession(cluster, [pod_arrays])
         return cls(sess, sess._carry, node_names)
 
     @classmethod

@@ -4,10 +4,10 @@ Before this module, every node-sharded array in the mesh path was
 hand-wired: `ops/sharded_scan.py` kept a `_NODE_DIM` placement dict that
 had to be edited in lock-step with every new static, and
 `parallel/sharded.py` kept a parallel `NODE_DIM0_KEYS` frozenset for the
-cluster dict. State added since PR 5 (delta statics, multipod conflict
-tables, what-if scratch carries, the explain harvest) each needed a
-matching hand edit — at 100k nodes a forgotten entry silently replicates
-a [rows, N] array onto every host.
+cluster dict. State added since PR 5 (delta statics, what-if scratch
+carries, the explain harvest) each needed a matching hand edit — at
+100k nodes a forgotten entry silently replicates a [rows, N] array onto
+every host.
 
 The declarative form is the `match_partition_rules` pattern from large
 LM trainers: flatten the pytree with key paths, join each path into a

@@ -310,14 +310,9 @@ class Result:
     # workloads (headline_metric says which number to read)
     attempts_per_sec: float = 0.0
     headline_metric: str = "pods_per_sec"
-    # multi-pod scan steps + speculative dispatch (in-window counter
-    # deltas): conflicts = speculative per-step decisions invalidated by
-    # an earlier pod of the same step; replays = the sequential
-    # re-decisions that kept them exact; hits/misses = pipelined
-    # dispatches chained on a not-yet-harvested carry that landed
-    # cleanly / were re-driven
-    multipod_conflicts: int = 0
-    conflict_replays: int = 0
+    # speculative dispatch (in-window counter deltas): hits/misses =
+    # pipelined dispatches chained on a not-yet-harvested carry that
+    # landed cleanly / were re-driven
     speculative_hits: int = 0
     speculative_misses: int = 0
     # kernel-direct pods/s measured in-process for the same config
@@ -871,12 +866,10 @@ def run_workload(w: Workload, quiet: bool = True,
             ))
 
         from ..scheduler.metrics import (
-            conflict_replays,
             gang_admitted as gang_admitted_ctr,
             gang_preempted as gang_preempted_ctr,
             gang_rejected as gang_rejected_ctr,
             gang_rollbacks as gang_rollbacks_ctr,
-            multipod_conflicts,
             parity_drift,
             preemption_planner,
             session_delta_applies,
@@ -891,8 +884,6 @@ def run_workload(w: Workload, quiet: bool = True,
         builds0 = _session_build_counts()
         rebuild_reasons0 = _label_counts(session_rebuilds)
         delta_applies0 = _label_counts(session_delta_applies)
-        conflicts0 = _counter_total(multipod_conflicts)
-        replays0 = _counter_total(conflict_replays)
         spec0 = _label_counts(speculative_dispatches)
         planner0 = _label_counts(preemption_planner)
         whatif0 = _counter_total(whatif_launches)
@@ -1009,9 +1000,8 @@ def run_workload(w: Workload, quiet: bool = True,
              else bound_measured) / dt, 2
         ) if dt else 0.0
         # freeze EVERY in-window counter before the kernel-direct
-        # measurement: its throwaway session teardown/build pair (and
-        # any multipod replays it takes) must not leak into the
-        # loop-phase accounting
+        # measurement: its throwaway session teardown/build pair must
+        # not leak into the loop-phase accounting
         build_reasons = _session_build_reasons()
         rebuild_reasons = _counter_window(
             _label_counts(session_rebuilds), rebuild_reasons0
@@ -1019,8 +1009,6 @@ def run_workload(w: Workload, quiet: bool = True,
         delta_applies = _counter_window(
             _label_counts(session_delta_applies), delta_applies0
         )
-        n_conflicts = _counter_total(multipod_conflicts) - conflicts0
-        n_replays = _counter_total(conflict_replays) - replays0
         spec_now = _label_counts(speculative_dispatches)
         planner_paths = _counter_window(
             _label_counts(preemption_planner), planner0
@@ -1147,8 +1135,6 @@ def run_workload(w: Workload, quiet: bool = True,
             headline_metric=(
                 "attempts_per_sec" if w.saturating else "pods_per_sec"
             ),
-            multipod_conflicts=n_conflicts,
-            conflict_replays=n_replays,
             speculative_hits=spec_now.get("hit", 0) - spec0.get("hit", 0),
             speculative_misses=spec_now.get("miss", 0)
             - spec0.get("miss", 0),

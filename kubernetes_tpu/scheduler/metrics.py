@@ -276,35 +276,6 @@ mesh_shards = legacy_registry.register(
         (),
     )
 )
-multipod_conflicts = legacy_registry.register(
-    Counter(
-        "scheduler_multipod_conflicts_total",
-        "Multi-pod-step conflict DETECTIONS: a speculative decision was "
-        "invalidated by an earlier pod of the same step (same-node "
-        "pick, PTS/IPA count interference, or a fit/balanced/least "
-        "recheck failure — the exact conflict algebra). The hoisted "
-        "scan counts every conflicted pod; the pallas/sharded kernels "
-        "count one per conflict SUFFIX (later flags are collateral, "
-        "and genuine later conflicts are re-detected when the replayed "
-        "suffix runs). Decisions stay bit-identical to "
-        "one-pod-per-step either way. A detection rate near 1/k means "
-        "the workload class wants a smaller KTPU_MULTIPOD_K "
-        "(scripts/probe_multipod.py picks defaults).",
-        (),
-    )
-)
-conflict_replays = legacy_registry.register(
-    Counter(
-        "scheduler_conflict_replays_total",
-        "Conflicted multi-pod-step pods re-decided sequentially: "
-        "in-device lax.cond replays on the hoisted scan, host-side "
-        "suffix replays through the live session on the pallas/sharded "
-        "kernels (their conflicted suffix is left uncommitted and "
-        "flagged). Replays are the exactness cost of multipod steps — "
-        "this counter vs the step count is the effective speedup.",
-        (),
-    )
-)
 preemption_planner = legacy_registry.register(
     Counter(
         "scheduler_preemption_planner_total",
@@ -535,8 +506,8 @@ explain_harvests = legacy_registry.register(
         "Batches harvested WITH per-pod decision attribution attached "
         "(KTPU_EXPLAIN / shadow sampling): the sessions returned "
         "per-plugin filter verdicts and weighted score splits alongside "
-        "decisions. Explain mode pins the hoisted one-pod-per-step "
-        "kernel (scheduler_tpu_session_builds_total reason=explain), so "
+        "decisions. Explain mode pins the hoisted session "
+        "(scheduler_tpu_session_builds_total reason=explain), so "
         "this counter moving on a pallas-class platform names the "
         "audit-mode throughput cost.",
         (),
@@ -550,8 +521,8 @@ speculative_dispatches = legacy_registry.register(
         "flight), by outcome: outcome=hit harvested cleanly; "
         "outcome=miss was re-driven synchronously because the carry it "
         "chained on was invalidated (device fault, harvest validation "
-        "failure, a multipod conflict suffix, or a completion-worker "
-        "crash abandon). KTPU_SPECULATION=0 serializes dispatch on "
+        "failure, or a completion-worker crash abandon). "
+        "KTPU_SPECULATION=0 serializes dispatch on "
         "harvest and zeroes this counter.",
         ("outcome",),
     )

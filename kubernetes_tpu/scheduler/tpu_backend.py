@@ -92,8 +92,7 @@ class _BatchHandle:
 
     __slots__ = ("group", "ys", "decide", "node_names", "results",
                  "deadline", "bucket", "timed_out", "speculative",
-                 "conflicts", "prov", "explain", "basis_mutations", "dt",
-                 "batch")
+                 "prov", "explain", "basis_mutations", "dt", "batch")
 
     def __init__(self, group: List[v1.Pod], batch: Optional[int] = None):
         self.group = group
@@ -106,13 +105,9 @@ class _BatchHandle:
         # batches were still in flight — it chained on a carry whose
         # decisions had not been harvested/validated yet. A clean FIFO
         # harvest is a speculation hit; a re-drive because that carry
-        # was invalidated (fault, validation failure, conflict suffix,
-        # worker-crash abandon) is a miss.
+        # was invalidated (fault, validation failure, worker-crash
+        # abandon) is a miss.
         self.speculative = False
-        # session-captured conflict decoder (like `decide`): maps ys to
-        # (n_conflicts, replay_suffix_start) — None for sessions without
-        # multipod support
-        self.conflicts = None
         # decisions are node INDICES into the cluster as of dispatch; a
         # node remove/rebuild before harvest would shift enc.node_names,
         # so the dispatch-time table rides the handle
@@ -254,10 +249,10 @@ class TPUBackend(CacheListener):
         # context is a SCRATCH view of the cluster (live-session carry
         # copy, or a non-donating encoding snapshot for pallas/sharded
         # sessions) — launches never chain onto or invalidate the live
-        # session. Platform default mirrors kernel.multipod_k: ON where
-        # the launch is a real device dispatch (TPU), OFF on CPU where
-        # the jnp what-if pays XLA compiles the numpy fast rung + oracle
-        # don't (the parity suites and probe enable it explicitly).
+        # session. Platform default: ON where the launch is a real device
+        # dispatch (TPU), OFF on CPU where the jnp what-if pays XLA
+        # compiles the numpy fast rung + oracle don't (the parity suites
+        # and probe enable it explicitly).
         # KTPU_WHATIF=0 is the kill switch / =1 the CPU opt-in.
         self.whatif = knobs.get_bool(
             "KTPU_WHATIF",
@@ -342,7 +337,6 @@ class TPUBackend(CacheListener):
         # today the env vars are invisible at runtime; /configz shows
         # the values this backend actually resolved
         from ..models.vocab import node_headroom as _nh
-        from ..ops.kernel import multipod_k as _mk
         from ..utils import configz
         from .metrics import mesh_shards
 
@@ -350,9 +344,6 @@ class TPUBackend(CacheListener):
             float(self.mesh.devices.size) if self.mesh is not None else 0.0)
         configz.install_knobs(
             "ktpu",
-            multipod_k=_mk(
-                platform=jax.devices()[0].platform,
-                suffix_replay=self.use_pallas or self.mesh is not None),
             mesh_devices=(
                 int(self.mesh.devices.size) if self.mesh is not None else 0),
             node_headroom=_nh(),
@@ -1148,9 +1139,9 @@ class TPUBackend(CacheListener):
         lane space exhausted, node still carrying pods). The session half
         gates itself (node_join_delta / node_leave_delta return None
         outside their exactness envelope — shared topology pairs, term
-        templates, image-locality mass, conflict mode). True -> the event
-        is fully reconciled; False -> the caller tears the session down
-        (rebuild from the already-mutated encoding is always correct)."""
+        templates, image-locality mass). True -> the event is fully
+        reconciled; False -> the caller tears the session down (rebuild
+        from the already-mutated encoding is always correct)."""
         if lane is None or not self.delta_patching:
             return False
         sess = self._session
@@ -1631,8 +1622,6 @@ class TPUBackend(CacheListener):
                     if isinstance(ys, dict):
                         h.bucket = ys.get("bucket")
                     h.decide = type(self._session).decisions
-                    h.conflicts = getattr(
-                        type(self._session), "conflict_stats", None)
                     h.node_names = list(self.enc.node_names)
                     h.deadline = _time.monotonic() + self.watchdog_timeout
                     # chained on a not-yet-harvested carry: speculative
@@ -1814,62 +1803,15 @@ class TPUBackend(CacheListener):
                 from .metrics import explain_harvests
 
                 explain_harvests.inc()
-        from .metrics import (
-            conflict_replays,
-            multipod_conflicts,
-            speculative_dispatches,
-        )
+        from .metrics import speculative_dispatches
 
         if h.speculative:
             speculative_dispatches.inc(outcome="hit")
-        n_conf, suffix = (
-            h.conflicts(ys) if h.conflicts is not None else (0, None)
-        )
-        if n_conf:
-            multipod_conflicts.inc(n_conf)
         if h.prov is not None:
             h.prov["spec_outcome"] = "hit" if h.speculative else None
-            h.prov["conflicts"] = n_conf
-        if suffix is None:
-            if n_conf:
-                # hoisted multipod: conflicts were replayed IN-DEVICE
-                # (exact); decisions below are final
-                conflict_replays.inc(n_conf)
-            h.results = self._apply_decisions_locked(
-                h.group, decisions, h.node_names, prov=h.prov,
-                explain=h.explain)
-            return
-        # conflict SUFFIX (pallas/sharded multipod): pods [suffix:] were
-        # left UNCOMMITTED by the kernel — the carry holds exactly the
-        # committed prefix. Land the prefix, then replay the suffix
-        # sequentially through the session. Any LATER pending batches
-        # chained their scans on a carry missing the suffix commits AND
-        # polluted it with their own — speculation misses: abandon the
-        # chain, tear the session down, and re-decide them in dispatch
-        # order (the PR-4 re-drive discipline, minus the fault: the
-        # ladder is untouched and nothing is quarantined).
-        results = self._apply_decisions_locked(
-            h.group[:suffix], decisions[:suffix], h.node_names,
-            prov=h.prov)
-        conflict_replays.inc(len(h.group) - suffix)
-        dropped = list(self._pending)
-        self._pending.clear()
-        self._pending_cv.notify_all()
-        if dropped:
-            self._miss_speculative(dropped)
-            for hd in dropped:
-                hd.ys = None
-            self._invalidate_session("conflict-replay")
-        # with no dropped batches the live session replays the suffix
-        # chained on its committed-prefix carry (exact); after a drop it
-        # rebuilds from the encoding, which now holds the prefix assumes
-        with tracing.span("conflict-suffix-replay", "replay",
-                          n=len(h.group) - suffix,
-                          n_dropped=len(dropped), bucket=h.bucket):
-            results.extend(self.schedule_many(h.group[suffix:]))
-        h.results = results
-        for hd in dropped:
-            hd.results = self.schedule_many(hd.group)
+        h.results = self._apply_decisions_locked(
+            h.group, decisions, h.node_names, prov=h.prov,
+            explain=h.explain)
 
     def schedule_many(self, pods: List[v1.Pod]) -> List[Tuple[v1.Pod, Optional[str]]]:
         """Batched sequential scheduling: groups batchable same-shape pods
@@ -2001,52 +1943,20 @@ class TPUBackend(CacheListener):
             self._apply_session_deltas_locked()
             if self._session is None:  # apply failed -> rebuild now
                 self._session = self._build_session()
-        from .metrics import conflict_replays, multipod_conflicts
-
-        decisions: List[int] = []
         self._launch_stats = None
-        while arrays:
-            ys = self._session.schedule(arrays)
-            if self._launch_stats is None and isinstance(ys, dict) \
-                    and "templates" in ys:
-                self._launch_stats = (ys["templates"], ys["term_pods"])
-            # decisions() decodes through np.asarray, an UNBOUNDED device
-            # wait — bound it with the watchdog first or the synchronous
-            # re-decide path (fault recovery!) could hang on the very
-            # device wedge it is recovering from, with the backend lock
-            # held
-            if not self._wait_ready(ys, self.watchdog_timeout):
-                raise DeviceFault(
-                    "synchronous dispatch exceeded the watchdog",
-                    kind="timeout")
-            got = type(self._session).decisions(ys)
-            stats = getattr(type(self._session), "conflict_stats", None)
-            n_conf, suffix = stats(ys) if stats is not None else (0, None)
-            if n_conf:
-                multipod_conflicts.inc(n_conf)
-            if suffix is None:
-                if n_conf:
-                    # hoisted multipod: conflicts replayed IN-DEVICE
-                    conflict_replays.inc(n_conf)
-                decisions.extend(got)
-                break
-            # conflict-SUFFIX contract (pallas/sharded multipod): pods
-            # [suffix:] were left UNCOMMITTED by the kernel — keep the
-            # prefix and replay exactly the suffix through the live
-            # session, whose carry holds the committed prefix. The step
-            # algebra guarantees a batch's FIRST pod never conflicts
-            # (its eval ran against the very carry it commits to), so
-            # every round lands at least one pod and the loop
-            # terminates; a suffix of 0 would mean that invariant broke
-            # — fail loudly as a device fault rather than loop.
-            if suffix <= 0:
-                raise DeviceFault(
-                    "conflict suffix at batch head (kernel invariant "
-                    "violation)", kind="invalid")
-            conflict_replays.inc(len(arrays) - suffix)
-            decisions.extend(got[:suffix])
-            arrays = arrays[suffix:]
-        return decisions
+        ys = self._session.schedule(arrays)
+        if isinstance(ys, dict) and "templates" in ys:
+            self._launch_stats = (ys["templates"], ys["term_pods"])
+        # decisions() decodes through np.asarray, an UNBOUNDED device
+        # wait — bound it with the watchdog first or the synchronous
+        # re-decide path (fault recovery!) could hang on the very
+        # device wedge it is recovering from, with the backend lock
+        # held
+        if not self._wait_ready(ys, self.watchdog_timeout):
+            raise DeviceFault(
+                "synchronous dispatch exceeded the watchdog",
+                kind="timeout")
+        return type(self._session).decisions(ys)
 
     def _remember_templates(self, uniq: Dict) -> None:
         """Note the batch's specs as the most recently used. Specs whose

@@ -26,8 +26,8 @@ def install(name: str, config: Any) -> None:
 
 def install_knobs(name: str, **knobs: Any) -> None:
     """Merge key/value knobs into a named dict entry. The KTPU_* env-var
-    surface registers its RUNTIME-EFFECTIVE values here (the resolved
-    multipod k, speculation/whatif/session-delta switches, trace level,
+    surface registers its RUNTIME-EFFECTIVE values here (the
+    speculation/whatif/session-delta switches, trace level,
     watchdog/drain timeouts) so a running scheduler's configuration is
     inspectable via /configz instead of invisible process environment.
     Multiple components (TPUBackend, Scheduler) contribute to one entry."""

@@ -22,7 +22,7 @@ knob was visible on ``/configz`` only if someone remembered to
     the README, or mentioned in the README without a declaration.
 
 Defaults declared as ``DERIVED`` are resolved at the call site (e.g.
-``KTPU_MULTIPOD_K`` depends on the platform, ``KTPU_DRAIN_TIMEOUT`` on
+``KTPU_WHATIF`` depends on the platform, ``KTPU_DRAIN_TIMEOUT`` on
 the watchdog budget); the accessor then requires an explicit
 ``default=`` from the caller so the derivation stays next to the code
 that owns it — but the knob itself still registers here.
@@ -169,10 +169,6 @@ def get_flag(name: str) -> bool:
 # the declarations — one line per knob, THE source of truth for defaults
 
 # -- device backend / dispatch loop
-_declare("KTPU_MULTIPOD_K", "int", DERIVED,
-         "pods decided per fused scan step (default 1 for pallas/sharded "
-         "sessions; hoisted: 4 on TPU, 1 on CPU; 1 restores "
-         "one-pod-per-step everywhere)")
 _declare("KTPU_SPECULATION", "bool", True,
          "speculative dispatch: chain batch k+1 on the pre-harvest carry "
          "(0 serializes dispatch on harvest)")
@@ -205,18 +201,6 @@ _declare("KTPU_DEBUG_INVALIDATE", "flag", "",
          "debug: print a stack trace at every session teardown")
 
 # -- kernels / sessions
-_declare("KTPU_SCAN_UNROLL", "int", 1,
-         "hoisted lax.scan unroll factor (compile time for fewer "
-         "scan iterations)")
-_declare("KTPU_PALLAS_AOT", "bool", True,
-         "AOT-compile + cache pallas executables per batch bucket "
-         "(0 pins the lazy jit path)")
-_declare("KTPU_PALLAS_GROUP", "int", 4,
-         "pods per pallas loop iteration (manual unroll amortizing "
-         "Mosaic bookkeeping)")
-_declare("KTPU_PALLAS_SKIP", "str", "",
-         "comma-separated kernel terms to skip (profiling only — "
-         "decisions change)")
 _declare("KTPU_COMPILATION_CACHE", "bool", True,
          "jax persistent compilation cache (0 disables; the directory is "
          "JAX_COMPILATION_CACHE_DIR, else <checkout>/.xla_cache)")

@@ -78,7 +78,7 @@ STAGES = (
     "dispatch",     # scan enqueue on the session (incl. speculative)
     "wait",         # watchdog-bounded device wait
     "harvest",      # decode + validate + apply decisions
-    "replay",       # conflict-suffix / re-drive sequential replays
+    "replay",       # sequential re-drives after a fault
     "assume",       # cache.assume (completion worker)
     "reserve-permit",  # Reserve + Permit plugin pass
     "bind",         # batched bind POST

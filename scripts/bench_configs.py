@@ -394,16 +394,9 @@ def main() -> None:
         line["session_delta_applies_runs"] = [
             r.get("session_delta_applies") for r in runs
         ]
-        # per-rep multipod/speculation accounting (round 9): same
-        # reasoning as the session counters above — a conflict storm or
-        # a speculation-miss cascade in one rep must not hide behind
-        # the median rep's dict
-        line["multipod_conflicts_runs"] = [
-            r.get("multipod_conflicts") for r in runs
-        ]
-        line["conflict_replays_runs"] = [
-            r.get("conflict_replays") for r in runs
-        ]
+        # per-rep speculation accounting: same reasoning as the session
+        # counters above — a speculation-miss cascade in one rep must
+        # not hide behind the median rep's dict
         line["speculative_hits_runs"] = [
             r.get("speculative_hits") for r in runs
         ]

@@ -37,8 +37,6 @@ for a in arrays:
 print("templates:", len(templates), "device:", jax.devices()[0])
 
 t0 = time.perf_counter()
-# multipod_k=1: this script treats decisions() as final (no
-# conflict-suffix replay loop) — profile the one-pod-per-step path
 ps = PallasSession(enc.device_state(), templates)
 print(f"session build (prologue + remap): {time.perf_counter()-t0:.1f}s")
 t0 = time.perf_counter()

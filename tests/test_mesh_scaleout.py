@@ -2,8 +2,7 @@
 
 The sharded session (ops/sharded_scan.py) must be a pure performance
 property — every subsystem that rides it (session carry deltas, the
-multipod conflict-suffix contract, the what-if preemption planner)
-stays BIT-IDENTICAL to the single-device reference at every shard
+what-if preemption planner) stays BIT-IDENTICAL to the single-device reference at every shard
 count, including mid-run node churn. And churn itself must stay
 delta-class: node add/remove on pre-warmed vocab patches the live
 session's node columns instead of tearing it down (the rebuild-storm
@@ -151,20 +150,20 @@ class TestSessionDeltaParity:
         assert got == ref
 
 
-# --------------------------------------- multipod conflict-suffix parity
+# ------------------------------------------------- directed race parity
 
 
-class TestConflictSuffixParity:
-    """Satellite: the sharded multipod step's conflict-SUFFIX contract —
-    flagged pods stay uncommitted and the host replays them — must
-    land every pod exactly where the sequential reference does."""
+class TestDirectedParity:
+    """Two pods of one batch whose second answer depends on the first's
+    commit: the sharded step must land both where the single-device
+    HoistedSession does."""
 
     @pytest.mark.parametrize("nsh", [2, 4, 8])
     def test_directed_last_slot_race(self, nsh):
         from kubernetes_tpu.ops.sharded_scan import ShardedPallasSession
 
         mesh = _mesh_or_skip(nsh)
-        # node-0 fits ONE 2-cpu pod; two racing pods in one k=2 step
+        # node-0 fits ONE 2-cpu pod; two racing pods in one batch
         cache, be = _mk_backend(2, cpu="3")
         cache.remove_node("node-1")
         cache.add_node(_node(1, cpu="1"))
@@ -174,45 +173,14 @@ class TestConflictSuffixParity:
         arrays = [{k: a for k, a in be.pe.encode(p).items()
                    if not k.startswith("_")} for p in pods]
         cluster = be.enc.device_state()
-        ref = HoistedSession(cluster, [arrays[0]], be.weights, multipod_k=1)
+        ref = HoistedSession(cluster, [arrays[0]], be.weights)
         want = HoistedSession.decisions(ref.schedule(list(arrays)))
         assert want == [0, -1], f"reference surprised us: {want}"
 
         sess = ShardedPallasSession(
-            cluster, [arrays[0]], be.weights, mesh=mesh, multipod_k=2)
-        assert sess.multipod_k == 2
-        ys = sess.schedule(list(arrays))
-        got = ShardedPallasSession.decisions(ys)
-        n_conf, suffix = ShardedPallasSession.conflict_stats(ys)
-        assert n_conf >= 1, "last-slot race produced no conflict"
-        assert suffix == 1, "conflict must head the uncommitted suffix"
-        assert got[:suffix] == want[:suffix]
-        # host-side replay of the suffix through the SAME session
-        ys2 = sess.schedule([arrays[i] for i in range(suffix, 2)])
-        replay = ShardedPallasSession.decisions(ys2)
-        assert got[:suffix] + replay == want
-
-    @pytest.mark.parametrize("nsh", [2, 4, 8])
-    def test_backend_replays_suffix(self, nsh, monkeypatch):
-        """End to end: schedule_many on a mesh backend with multipod
-        enabled equals the sequential no-mesh reference, and the
-        conflict actually flowed through the suffix-replay path."""
-        mesh = _mesh_or_skip(nsh)
-        monkeypatch.setenv("KTPU_MULTIPOD_K", "2")
-        pods = [make_pod(f"race-{i}", namespace="default", cpu="2",
-                         memory="128Mi", labels={"app": "race"})
-                for i in range(4)]
-
-        _, be = _mk_backend(3, mesh=mesh, cpu="3")
-        r0 = sum(v for _, v in metrics.conflict_replays.items())
-        got = [n for _, n in be.schedule_many(copy.deepcopy(pods))]
-        assert sum(v for _, v in metrics.conflict_replays.items()) > r0, \
-            "race group produced no conflict replay"
-
-        monkeypatch.setenv("KTPU_MULTIPOD_K", "1")
-        _, ref_be = _mk_backend(3, mesh=None, cpu="3")
-        ref = [n for _, n in ref_be.schedule_many(copy.deepcopy(pods))]
-        assert got == ref, f"nsh={nsh}: {got} != {ref}"
+            cluster, [arrays[0]], be.weights, mesh=mesh)
+        got = ShardedPallasSession.decisions(sess.schedule(list(arrays)))
+        assert got == want
 
 
 # ------------------------------------------------------- what-if parity

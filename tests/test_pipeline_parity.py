@@ -13,21 +13,27 @@ that tears the session down while batches are still in flight.
 
 from __future__ import annotations
 
+import copy
 import random
 import threading
 import time
 
+import jax
 import pytest
 
 from kubernetes_tpu.api import types as v1
 from kubernetes_tpu.apiserver import APIServer
 from kubernetes_tpu.client import Clientset, SharedInformerFactory
-from kubernetes_tpu.ops.hoisted import HoistedSession
+from kubernetes_tpu.ops.hoisted import HoistedSession, template_fingerprint
+from kubernetes_tpu.ops.pallas_scan import PallasSession
+from kubernetes_tpu.ops.sharded_scan import ShardedPallasSession
+from kubernetes_tpu.parallel.sharded import make_mesh
 from kubernetes_tpu.scheduler import metrics
 from kubernetes_tpu.scheduler.internal.cache import SchedulerCache
 from kubernetes_tpu.scheduler.scheduler import Scheduler
 from kubernetes_tpu.scheduler.tpu_backend import TPUBackend
 from kubernetes_tpu.testing.faults import BindIntegrityChecker, FaultInjector
+from kubernetes_tpu.testing.oracle import first_max_decisions
 
 from .util import make_node, make_pod, spread_constraint
 
@@ -145,22 +151,29 @@ def _bound_map(cs):
     }
 
 
-@pytest.mark.parametrize("seed", [0, 1, 2])
-def test_pipelined_matches_sequential(seed):
-    rng = random.Random(seed)
-    n = rng.randint(24, 48)
-    batch_sizes = [rng.choice([1, 2, 3, 5, 8]) for _ in range(64)]
+def _maps_by_depth(seed, n, batch_sizes, mutate_at=None):
+    """{depth: bound map} of the same stream and batch partition through
+    a depth-0 and a depth-2 scheduler."""
     maps = {}
     for depth in (0, 2):
         _, cs = _cluster()
         sched = _mk_scheduler(cs, depth)
         try:
             pods = _pod_stream(random.Random(seed), n)
-            _drive(sched, cs, pods, batch_sizes)
+            _drive(sched, cs, pods, batch_sizes, mutate_at=mutate_at)
             maps[depth] = _bound_map(cs)
         finally:
             sched.stop()
             sched.informers.stop()
+    return maps
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_pipelined_matches_sequential(seed):
+    rng = random.Random(seed)
+    n = rng.randint(24, 48)
+    batch_sizes = [rng.choice([1, 2, 3, 5, 8]) for _ in range(64)]
+    maps = _maps_by_depth(seed, n, batch_sizes)
     assert maps[0] == maps[2], (
         "pipelined decisions diverged from the sequential path"
     )
@@ -177,19 +190,8 @@ def test_pipelined_matches_sequential_with_foreign_mutation():
     dispatch order either way."""
     seed = 7
     rng = random.Random(seed)
-    n = 32
     batch_sizes = [rng.choice([2, 3, 5]) for _ in range(32)]
-    maps = {}
-    for depth in (0, 2):
-        _, cs = _cluster()
-        sched = _mk_scheduler(cs, depth)
-        try:
-            pods = _pod_stream(random.Random(seed), n)
-            _drive(sched, cs, pods, batch_sizes, mutate_at=2)
-            maps[depth] = _bound_map(cs)
-        finally:
-            sched.stop()
-            sched.informers.stop()
+    maps = _maps_by_depth(seed, 32, batch_sizes, mutate_at=2)
     assert maps[0] == maps[2]
 
 
@@ -257,7 +259,7 @@ def test_columnar_zero_drift_at_sample_rate(monkeypatch):
     )
 
 
-# -- multi-pod scan steps + speculative dispatch (round 9) -------------------
+# -- speculative dispatch (round 9) ------------------------------------------
 
 
 def _label_counts(counter):
@@ -273,33 +275,17 @@ def _spec_counts():
 
 
 @pytest.mark.parametrize("seed", [0, 1, 2])
-def test_multipod_speculation_matches_depth0(seed, monkeypatch):
-    """Multi-pod scan steps (k=4) + speculative pipelining (depth 2)
-    vs the one-pod-per-step depth-0 reference over randomized churn:
-    decisions must be bit-identical — the exact-conflict-replay
-    contract, end to end through the scheduler loop."""
-    rng = random.Random(seed)
-    n = rng.randint(24, 48)
-    batch_sizes = [rng.choice([1, 2, 3, 5, 8]) for _ in range(64)]
-    maps = {}
-    for depth, k in ((0, 1), (2, 4)):
-        monkeypatch.setenv("KTPU_MULTIPOD_K", str(k))
-        _, cs = _cluster()
-        sched = _mk_scheduler(cs, depth)
-        try:
-            pods = _pod_stream(random.Random(seed), n)
-            _drive(sched, cs, pods, batch_sizes)
-            if depth:
-                s = sched.tpu._session
-                assert s is None or s.multipod_k == 4, (
-                    "multipod width did not reach the session"
-                )
-            maps[depth] = _bound_map(cs)
-        finally:
-            sched.stop()
-            sched.informers.stop()
+def test_speculation_matches_depth0(seed):
+    """Speculative pipelining (depth 2: scans chained on un-harvested
+    carries) vs the depth-0 reference over randomized churn in SMALL
+    batches (many launches in flight behind one another): decisions
+    must be bit-identical, end to end through the scheduler loop."""
+    rng = random.Random(100 + seed)
+    n = rng.randint(32, 56)
+    batch_sizes = [rng.choice([1, 2, 3]) for _ in range(96)]
+    maps = _maps_by_depth(100 + seed, n, batch_sizes)
     assert maps[0] == maps[2], (
-        "multipod+speculation decisions diverged from one-pod-per-step"
+        "speculative decisions diverged from the depth-0 reference"
     )
     assert any(maps[0].values())
 
@@ -344,7 +330,7 @@ def test_speculation_kill_switch(monkeypatch):
 
 def _mini_backend(node_cpus, reserve=256):
     """Cache + backend with the given per-node cpu sizes (no apiserver:
-    these tests pin SESSION-level multipod semantics)."""
+    these tests pin BACKEND-level pipeline semantics)."""
     cache = SchedulerCache()
     be = TPUBackend()
     cache.add_listener(be)
@@ -364,212 +350,139 @@ def _encode(be, pods):
     ]
 
 
-def test_directed_conflict_replay_last_slot():
-    """Two pods of ONE multipod step racing for the last slot on a node:
-    the speculative evals both pick it; the conflict test must catch the
-    second (same-node + fit-flip) and the replay must leave it exactly
-    where the sequential reference does (unschedulable)."""
-    _, be = _mini_backend(["3", "1"])  # node-0 fits ONE 2-cpu pod
-    pods = [
-        make_pod(f"race-{i}", namespace="default", cpu="2", memory="128Mi",
-                 labels={"app": "race"})
-        for i in range(2)
+# -- directed two-pod cases: the second pod's answer depends on the first's
+# commit, so a step that read a stale carry gets it wrong ---------------------
+
+
+def _hostname_node(i, cpu, memory):
+    return make_node(f"node-{i}", cpu=cpu, memory=memory, pods=64,
+                     labels={v1.LABEL_HOSTNAME: f"node-{i}"})
+
+
+def _case_last_slot():
+    """Two pods of one spec race for the one free slot on the best
+    node: the second must come back unschedulable, not double-booked."""
+    nodes = [_hostname_node(0, "3", "16Gi"), _hostname_node(1, "1", "16Gi")]
+    pending = [make_pod(f"race-{i}", cpu="2", memory="128Mi",
+                        labels={"app": "race"}) for i in range(2)]
+    return nodes, [], pending, ["node-0", None]
+
+
+def _case_overtake():
+    """The first pod lands on a node the second would NOT have picked,
+    and rebalances its cpu/mem fractions enough (BalancedAllocation)
+    that the node overtakes the second pod's stale winner."""
+    nodes = [_hostname_node(i, "10", "10Gi") for i in range(2)]
+    # node-0 cpu-heavy and mem-empty (poor balanced score); node-1
+    # balanced and slightly fuller: a tiny pod alone picks node-1
+    bound = [
+        make_pod("fill0", cpu="4", memory="1Mi", labels={"app": "f"},
+                 node_name="node-0"),
+        make_pod("fill1", cpu="4300m", memory="4400Mi", labels={"app": "f"},
+                 node_name="node-1"),
     ]
-    arrays = _encode(be, pods)
-    cluster = be.enc.device_state()
-    ref = HoistedSession(cluster, [arrays[0]], be.weights, multipod_k=1)
-    ys_ref = ref.schedule(list(arrays))
-    want = HoistedSession.decisions(ys_ref)
-    assert want == [0, -1], f"reference surprised us: {want}"
-
-    sess = HoistedSession(cluster, [arrays[0]], be.weights, multipod_k=2)
-    assert sess.multipod_k == 2
-    ys = sess.schedule(list(arrays))
-    got = HoistedSession.decisions(ys)
-    n_conf, suffix = HoistedSession.conflict_stats(ys)
-    assert got == want, "conflict replay changed the race outcome"
-    assert n_conf >= 1, "last-slot race produced no conflict"
-    assert suffix is None  # hoisted replays in-device
+    pending = [
+        make_pod("big", cpu="50m", memory="4Gi", labels={"app": "x"}),
+        make_pod("small", cpu="100m", memory="100Mi", labels={"app": "y"}),
+    ]
+    return nodes, bound, pending, ["node-0", "node-0"]
 
 
-def test_directed_conflict_replay_overtake():
-    """Isolates the OVERTAKE leg of the utilization conflict algebra:
-    pod 1 commits on a node the second pod did NOT speculatively pick
-    (so the same-node predicate cannot fire, and the pods carry no
-    PTS/IPA terms), yet that commit REBALANCES the node's cpu/mem
-    fractions enough that its refreshed total overtakes the second
-    pod's speculative winner — only kernel.multipod_utilization_
-    conflicts' overtake comparison can catch it."""
-    cache = SchedulerCache()
-    be = TPUBackend()
-    cache.add_listener(be)
-    for i in range(2):
-        cache.add_node(make_node(
-            f"node-{i}", cpu="10", memory="10Gi", pods=64,
-            labels={v1.LABEL_HOSTNAME: f"node-{i}"},
-        ))
-    # node-0: cpu-heavy and mem-empty (imbalanced -> poor balanced
-    # score); node-1: balanced and slightly fuller (the speculative
-    # winner for a tiny pod)
-    cache.add_pod(make_pod(
-        "fill0", namespace="default", cpu="4", memory="1Mi",
-        labels={"app": "f"}, node_name="node-0"))
-    cache.add_pod(make_pod(
-        "fill1", namespace="default", cpu="4300m", memory="4400Mi",
-        labels={"app": "f"}, node_name="node-1"))
-    be.enc.reserve(pods=128)
-    # pod 1: mem-heavy -> lands on node-0 (rebalances it); pod 2: tiny
-    p1 = make_pod("big", namespace="default", cpu="50m", memory="4Gi",
-                  labels={"app": "x"})
-    p2 = make_pod("small", namespace="default", cpu="100m",
-                  memory="100Mi", labels={"app": "y"})
-    a1, a2 = _encode(be, [p1, p2])
-    cluster = be.enc.device_state()
-
-    # pod 2 ALONE picks node-1: that is its (stale) speculative winner
-    solo = HoistedSession(cluster, [a1, a2], be.weights, multipod_k=1)
-    assert HoistedSession.decisions(solo.schedule([a2])) == [1]
-    # sequential reference: pod 1 -> node-0, whose rebalanced total then
-    # overtakes node-1 for pod 2
-    ref = HoistedSession(cluster, [a1, a2], be.weights, multipod_k=1)
-    want = HoistedSession.decisions(ref.schedule([a1, a2]))
-    assert want == [0, 0], f"reference surprised us: {want}"
-
-    sess = HoistedSession(cluster, [a1, a2], be.weights, multipod_k=2)
-    ys = sess.schedule([a1, a2])
-    got = HoistedSession.decisions(ys)
-    n_conf, _ = HoistedSession.conflict_stats(ys)
-    assert got == want, "overtake replay diverged from the reference"
-    # same-node could not have fired (committed node-0 != speculative
-    # winner node-1) and the pods carry no terms: this conflict IS the
-    # overtake leg
-    assert n_conf >= 1, "argmax moved but no conflict was recorded"
+def _case_term_behind_plain():
+    """A plain pod of a service, then a pod of the same service that
+    carries required hostname anti-affinity against it: the term pod
+    must see the plain pod's commit and leave the best node to it."""
+    nodes = [_hostname_node(0, "8", "16Gi"), _hostname_node(1, "4", "16Gi")]
+    anti = v1.Affinity(pod_anti_affinity=v1.PodAntiAffinity(
+        required_during_scheduling_ignored_during_execution=[
+            v1.PodAffinityTerm(
+                label_selector=v1.LabelSelector(match_labels={"app": "svc"}),
+                topology_key=v1.LABEL_HOSTNAME)]))
+    pending = [
+        make_pod("plain", cpu="100m", memory="64Mi", labels={"app": "svc"}),
+        make_pod("guarded", cpu="100m", memory="64Mi", labels={"app": "svc"},
+                 affinity=anti),
+    ]
+    return nodes, [], pending, ["node-0", "node-1"]
 
 
-class TestMultipodHostHalves:
-    """The CPU env cannot execute the pallas/sharded multipod kernels
-    (interpret mode cannot lower here) — these pin their HOST halves,
-    which the backend's suffix handling depends on: the k resolution
-    rules and the conflict_stats decode of the suffix contract."""
-
-    def test_multipod_k_resolution(self, monkeypatch):
-        from kubernetes_tpu.ops.kernel import multipod_k
-
-        monkeypatch.delenv("KTPU_MULTIPOD_K", raising=False)
-        # port-carrying sessions are pinned to 1 whatever else says
-        assert multipod_k(8, dyn_ports=True) == 1
-        # explicit beats env; clamped to a pow2 <= 64
-        monkeypatch.setenv("KTPU_MULTIPOD_K", "16")
-        assert multipod_k(8) == 8
-        assert multipod_k(6) == 4
-        assert multipod_k(200) == 64
-        assert multipod_k(0) == 1
-        # env beats the platform default (the kill switch)
-        assert multipod_k() == 16
-        monkeypatch.setenv("KTPU_MULTIPOD_K", "1")
-        assert multipod_k() == 1
-        # platform default: TPU rides DEFAULT_MULTIPOD_K, others 1
-        monkeypatch.delenv("KTPU_MULTIPOD_K")
-        assert multipod_k(platform="tpu") == 4
-        assert multipod_k(platform="cpu") == 1
-        # conflict-suffix sessions (pallas, sharded) stay at 1 on every
-        # platform unless asked: one template's pods all pick the same
-        # node, so k > 1 commits one pod per launch there
-        assert multipod_k(platform="tpu", suffix_replay=True) == 1
-        monkeypatch.setenv("KTPU_MULTIPOD_K", "8")
-        assert multipod_k(platform="tpu", suffix_replay=True) == 8
-        assert multipod_k(2, suffix_replay=True) == 2
-
-    def test_pallas_session_runs_one_pod_a_step(self):
-        """The table session has no multi-pod step (pods of one spec all
-        pick the same node against the same carry, so k > 1 committed
-        ONE pod a launch there): it reports no conflict suffix, and the
-        backend reads that as "decisions final"."""
-        from kubernetes_tpu.ops.pallas_scan import PallasSession
-
-        assert getattr(PallasSession, "conflict_stats", None) is None
-
-    def test_sharded_conflict_stats_decodes_suffix(self):
-        import numpy as np
-
-        from kubernetes_tpu.ops.sharded_scan import ShardedPallasSession
-
-        ys = {"best": np.zeros(8), "_b_real": 6}
-        assert ShardedPallasSession.conflict_stats(ys) == (0, None)
-        conf = np.zeros(8, np.int32)
-        conf[3:] = 1  # flags run to the batch end (incl. padding)
-        ys["conflicts"] = conf
-        assert ShardedPallasSession.conflict_stats(ys) == (1, 3)
-        ys["conflicts"] = np.zeros(8, np.int32)
-        assert ShardedPallasSession.conflict_stats(ys) == (0, None)
+_DIRECTED = {
+    "last-slot": _case_last_slot,
+    "overtake": _case_overtake,
+    "term-behind-plain": _case_term_behind_plain,
+}
 
 
-class _FakeSuffixSession:
-    """Simulates the pallas/sharded conflict-SUFFIX contract (the CPU
-    env cannot run those kernels): schedule() "commits" a prefix and
-    flags everything from `suffix_at` on as an uncommitted conflict
-    suffix; the replayed suffix then lands clean. Lets the sync-path
-    suffix loop in TPUBackend._session_schedule be pinned on CPU."""
-
-    def __init__(self, suffix_at):
-        self.suffix_at = suffix_at
-        self.calls = []
-
-    def schedule(self, arrays):
-        n = len(arrays)
-        first = not self.calls
-        self.calls.append(n)
-        if first and n > self.suffix_at:
-            return {"best": list(range(n)), "suffix": self.suffix_at,
-                    "n": n}
-        # replay round: distinct decisions so the test can see which
-        # round produced each pod's answer
-        return {"best": [100 + i for i in range(n)], "suffix": None,
-                "n": n}
-
-    @staticmethod
-    def decisions(ys):
-        return list(ys["best"])
-
-    @staticmethod
-    def conflict_stats(ys):
-        if ys["suffix"] is None:
-            return 0, None
-        return 1, ys["suffix"]
+def _directed_backend(nodes, bound):
+    be = TPUBackend(pallas_interpret=True)
+    be.enc.set_cluster(copy.deepcopy(nodes), copy.deepcopy(bound))
+    be.enc.reserve(pods=64, anti_terms=64)
+    return be
 
 
-def test_sync_path_replays_conflict_suffix():
-    """The SYNCHRONOUS dispatch path (depth-0, fault re-drives, and
-    _harvest_locked's own suffix replay all route through
-    _session_schedule) must honor the conflict-SUFFIX contract: keep
-    the committed prefix, replay exactly the suffix through the live
-    session, and never report an uncommitted pod as unschedulable."""
-    _, be = _mini_backend(["4"] * 4)
-    pod = make_pod("seed", namespace="default", cpu="100m", memory="64Mi",
-                   labels={"app": "sx"})
-    arrays = _encode(be, [pod] * 5)
-    # register the template through the real path, then swap in the fake
-    be.schedule_many([pod])
-    fake = _FakeSuffixSession(suffix_at=2)
-    be._session = fake
-    conf0 = _label_counts(metrics.multipod_conflicts).get("-", 0)
-    repl0 = _label_counts(metrics.conflict_replays).get("-", 0)
-    got = be._session_schedule(arrays)
-    # prefix [0, 1] from round 1; suffix pods re-decided in round 2
-    assert got == [0, 1, 100, 101, 102], got
-    assert fake.calls == [5, 3], fake.calls
-    assert _label_counts(metrics.multipod_conflicts).get("-", 0) \
-        - conf0 == 1
-    assert _label_counts(metrics.conflict_replays).get("-", 0) \
-        - repl0 == 3
+def _directed_oracle(nodes, bound, pending):
+    return first_max_decisions(
+        copy.deepcopy(nodes), copy.deepcopy(bound), copy.deepcopy(pending),
+        {n.metadata.name: i for i, n in enumerate(nodes)})
 
-    # a suffix at the batch head would loop forever — the invariant
-    # says it cannot happen; _session_schedule must fail loudly
-    from kubernetes_tpu.scheduler.tpu_backend import DeviceFault
 
-    be._session = _FakeSuffixSession(suffix_at=0)
-    with pytest.raises(DeviceFault):
-        be._session_schedule(arrays)
+def _build_session(kind, cluster, templates, weights):
+    if kind == "hoisted":
+        return HoistedSession(cluster, templates, weights)
+    if kind == "pallas":
+        return PallasSession(cluster, templates, weights, interpret=True)
+    nsh = int(kind.split("-")[1])
+    if len(jax.devices()) < nsh:
+        pytest.skip(f"needs {nsh} virtual devices")
+    return ShardedPallasSession(cluster, templates, weights,
+                                mesh=make_mesh(n_devices=nsh))
+
+
+@pytest.mark.parametrize("case", sorted(_DIRECTED))
+@pytest.mark.parametrize(
+    "kind", ["hoisted", "pallas", "sharded-2", "sharded-4", "sharded-8"])
+def test_directed_pair_matches_oracle(kind, case):
+    """Every session kind decides one pod a step: both pods of ONE
+    launch land where the host oracle puts them, the second against the
+    carry the first just committed to."""
+    nodes, bound, pending, expect = _DIRECTED[case]()
+    want = _directed_oracle(nodes, bound, pending)
+    assert want == expect, f"oracle surprised us: {want}"
+    be = _directed_backend(nodes, bound)
+    arrays = _encode(be, pending)
+    templates = list({template_fingerprint(a): a for a in arrays}.values())
+    sess = _build_session(kind, be.enc.device_state(), templates, be.weights)
+    lanes = type(sess).decisions(sess.schedule(arrays))[:len(pending)]
+    got = [be.enc.node_names[d] if d >= 0 else None for d in lanes]
+    assert got == want
+
+
+@pytest.mark.parametrize("case", sorted(_DIRECTED))
+def test_directed_pair_across_chained_batches(case):
+    """The same pairs split over two dispatch_many launches, the second
+    enqueued BEFORE the first is harvested: it rides the first's
+    un-harvested device carry to the oracle's answer. The one exception
+    is a new spec whose rows COUNT the pod in flight (the term pod's
+    selector matches the plain pod): its admission lands that batch
+    first, so the second launch chains on a harvested carry."""
+    nodes, bound, pending, expect = _DIRECTED[case]()
+    want = _directed_oracle(nodes, bound, pending)
+    assert want == expect, f"oracle surprised us: {want}"
+    be = _directed_backend(nodes, bound)
+    # a pod that fits nowhere builds the session (synchronous path) and
+    # commits nothing; the table session admits the pair's specs later
+    hungry = make_pod("hungry", cpu="64", memory="64Mi",
+                      labels={"app": "hungry"})
+    assert [n for _, n in be.schedule_many([hungry])] == [None]
+    assert type(be._session) is PallasSession
+    h1 = be.dispatch_many([copy.deepcopy(pending[0])])
+    h2 = be.dispatch_many([copy.deepcopy(pending[1])])
+    assert h1.ys is not None and h2.ys is not None, (
+        "batches did not ride the pipelined session path")
+    assert not h1.speculative
+    assert h2.speculative is (case != "term-behind-plain")
+    got = [n for _, n in be.harvest(h1)] + [n for _, n in be.harvest(h2)]
+    assert got == want
 
 
 def test_speculation_miss_redrives_bit_identical():
@@ -643,9 +556,9 @@ def test_speculation_miss_redrives_bit_identical():
     assert spec2.get("miss", 0) == spec1.get("miss", 0)
 
 
-def test_speculation_miss_drill_through_loop(monkeypatch):
-    """Speculation-miss drill through the FULL loop: multipod k=4,
-    depth 2, a wedged device wait injected mid-stream while later
+def test_speculation_miss_drill_through_loop():
+    """Speculation-miss drill through the FULL loop: depth 2, a wedged
+    device wait injected mid-stream while later
     batches pile up behind it. The watchdog fault must roll the chained
     batches back through the re-drive path bit-identically, with the
     BindIntegrityChecker clean (no pod bound twice) and the misses
@@ -657,8 +570,7 @@ def test_speculation_miss_drill_through_loop(monkeypatch):
     inj = None
     checker = None
     spec0 = _spec_counts()
-    for depth, k in ((0, 1), (2, 4)):
-        monkeypatch.setenv("KTPU_MULTIPOD_K", str(k))
+    for depth in (0, 2):
         _, cs = _cluster()
         sched = _mk_scheduler(cs, depth)
         try:

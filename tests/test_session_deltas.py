@@ -133,8 +133,22 @@ def _event_stream(seed: int):
 
 
 def _replay(ops, delta_patching: bool):
+    """(decisions, backend, teardown reasons) of one replay. The
+    teardowns are THIS backend's own — every live-session teardown goes
+    through its _invalidate_session — not a delta of the process-wide
+    scheduler_session_rebuilds_total, which any backend left running by
+    an earlier test of the worker also moves."""
     cache, be = _mk_cluster()
     be.delta_patching = delta_patching
+    teardowns = []
+    invalidate = be._invalidate_session
+
+    def counting(reason="unspecified"):
+        if be._session is not None:
+            teardowns.append(reason)
+        invalidate(reason)
+
+    be._invalidate_session = counting
     decisions = {}
     bound = {}
     alloc_bumped = set()
@@ -184,7 +198,7 @@ def _replay(ops, delta_patching: bool):
                 pods=64,
                 labels={v1.LABEL_HOSTNAME: f"node-{i}", "zone": f"z{i % 3}"},
             ))
-    return decisions, be
+    return decisions, be, teardowns
 
 
 @pytest.mark.parametrize("seed", [0, 1, 2, 3])
@@ -193,21 +207,57 @@ def test_delta_vs_rebuild_parity(seed):
     decide bit-identically to the rebuild-everything control."""
     ops = _event_stream(seed)
     applies0 = _counter_total(metrics.session_delta_applies)
-    rebuilds0 = _counter_total(metrics.session_rebuilds)
-    with_deltas, _ = _replay(ops, delta_patching=True)
+    with_deltas, be_patched, teardowns_patched = _replay(
+        ops, delta_patching=True)
     applies = _counter_total(metrics.session_delta_applies) - applies0
-    rebuilds_patched = _counter_total(metrics.session_rebuilds) - rebuilds0
-    rebuilds1 = _counter_total(metrics.session_rebuilds)
-    without, _ = _replay(ops, delta_patching=False)
-    rebuilds_control = _counter_total(metrics.session_rebuilds) - rebuilds1
+    without, be_control, teardowns_control = _replay(
+        ops, delta_patching=False)
+    be_patched.close()
+    be_control.close()
     assert with_deltas == without, (
         "delta-patched decisions diverged from fresh-rebuild decisions"
     )
     # the stream must actually exercise the fast path (not vacuous)
     assert applies > 0, "no event rode the carry-delta path"
     assert any(node for node in with_deltas.values())
-    if rebuilds_control:
-        assert rebuilds_patched < rebuilds_control
+    if teardowns_control:
+        assert len(teardowns_patched) < len(teardowns_control)
+
+
+def _aligned_copy(a: np.ndarray) -> np.ndarray:
+    """`a` in a buffer that starts on a 64-byte boundary: the alignment
+    at which the CPU backend's jnp.asarray takes a numpy buffer over
+    WITHOUT copying. numpy's own allocations land there one time in
+    four, by chance."""
+    raw = np.zeros(a.nbytes + 64, np.uint8)
+    start = -raw.ctypes.data % 64
+    out = raw[start:start + a.nbytes].view(a.dtype).reshape(a.shape)
+    out[...] = a
+    return out
+
+
+def test_device_state_owns_its_buffers():
+    """The device dict must not share memory with the live host arrays.
+    It did, on the CPU backend, whenever a host array happened to be
+    64-byte aligned: update_node_alloc's in-place row write then showed
+    through in the live session's alloc static, and the node-alloc
+    delta added the same difference a second time —
+    test_delta_vs_rebuild_parity[2] diverged in about one process in
+    four."""
+    _, be = _mk_cluster()
+    enc = be.enc
+    enc.rebuild()
+    for k, a in enc._arrays.items():
+        enc._arrays[k] = _aligned_copy(a)
+    dev = enc.device_state()
+    before = {k: np.array(v) for k, v in dev.items()}
+    got = enc.update_node_alloc(make_node(
+        "node-2", cpu="8", memory="16Gi", pods=64,
+        labels={v1.LABEL_HOSTNAME: "node-2", "zone": "z2"}))
+    assert got is not None and got[0].any(), "the update changed nothing"
+    for k, v in dev.items():
+        assert np.array_equal(np.asarray(v), before[k]), (
+            f"device array {k!r} moved with an in-place host write")
 
 
 def test_remove_unknown_pod_is_noop():
