@@ -181,6 +181,19 @@ device_faults = legacy_registry.register(
         ("kind",),
     )
 )
+device_waits = legacy_registry.register(
+    Counter(
+        "scheduler_device_waits_total",
+        "Watchdog-bounded waits for a device launch's results, by how "
+        "each ended: outcome=ready (done at the first look, no hand-over), "
+        "outcome=woken (the caller slept on a device waiter's event and "
+        "was woken when the launch ended), outcome=timed_out (the "
+        "deadline passed: a device fault follows), outcome=polled (no "
+        "waiter thread could be started, or a fault drill held the wait "
+        "wedged: the 2 ms poll served). One wait a pipelined launch.",
+        ("outcome",),
+    )
+)
 dispatch_retries = legacy_registry.register(
     Counter(
         "scheduler_dispatch_retries_total",

@@ -19,9 +19,11 @@ from __future__ import annotations
 
 import logging
 import os
+import queue
 import random
 import threading
 import time as _time
+import weakref
 from collections import OrderedDict, deque
 from typing import Dict, List, Optional, Tuple
 
@@ -84,6 +86,67 @@ def _explain_topk(payload: Dict, node_names: List[str]) -> List[Tuple[str, int]]
     return out
 
 
+class _DeviceWaiter:
+    """A long-lived daemon thread whose one job is to block in the
+    runtime until a launch's result leaves are ready, and then to set
+    an event. The pipeline's threads wait on that event under the
+    watchdog (TPUBackend._wait_ready), so a wedged device pins this
+    thread and never one of theirs: a waiter that has not come back by
+    the deadline is told to stop and left behind, and the next wait
+    starts another. One wait at a time: a waiter is either in the
+    backend's idle list or in the hands of the one thread that took it
+    out."""
+
+    __slots__ = ("done", "thread", "_work")
+
+    def __init__(self):
+        self.done = threading.Event()
+        self._work: "queue.SimpleQueue" = queue.SimpleQueue()
+        self.thread = threading.Thread(
+            target=self._run, name="tpu-device-waiter", daemon=True)
+        self.thread.start()
+
+    def _run(self) -> None:
+        import jax
+
+        while True:
+            leaves = self._work.get()
+            if leaves is None:
+                return
+            try:
+                # ktpu: allow-sync(device waiter: the one thread whose job is to block; the pipeline waits on its event under the watchdog)
+                jax.block_until_ready(leaves)
+            except Exception:  # noqa: BLE001 — reported as ready: let
+                pass  # decode surface it
+            finally:
+                # set even if this thread is dying: no wait sleeps out
+                # its deadline for a waiter that is gone
+                leaves = None  # the arrays are the harvest's to free
+                self.done.set()
+
+    def wait(self, leaves, timeout: float) -> bool:
+        """Hand the leaves over and sleep until they are ready (True)
+        or `timeout` seconds have passed (False)."""
+        self.done.clear()
+        self._work.put(leaves)
+        return self.done.wait(timeout)
+
+    def stop(self) -> None:
+        """Exit once the current blocking call, if any, returns."""
+        self._work.put(None)
+
+
+def _stop_waiters(waiters: List[_DeviceWaiter]) -> List[_DeviceWaiter]:
+    """Stop every idle waiter of a backend (close(), and the backend's
+    finalizer: a backend dropped without close() leaves no thread)."""
+    stopped = []
+    while waiters:
+        w = waiters.pop()
+        w.stop()
+        stopped.append(w)
+    return stopped
+
+
 class _BatchHandle:
     """One dispatched batch: device outputs + how to decode them. The
     decode fn is captured at dispatch time because the session may be
@@ -91,7 +154,7 @@ class _BatchHandle:
     ys stay valid either way."""
 
     __slots__ = ("group", "ys", "decide", "node_names", "results",
-                 "deadline", "bucket", "timed_out", "speculative",
+                 "deadline", "bucket", "timed_out", "waited", "speculative",
                  "prov", "explain", "basis_mutations", "dt", "batch")
 
     def __init__(self, group: List[v1.Pod], batch: Optional[int] = None):
@@ -118,6 +181,9 @@ class _BatchHandle:
         self.deadline: Optional[float] = None
         self.bucket: Optional[int] = None  # pallas AOT-exec bucket (Bp)
         self.timed_out = False
+        # harvest() saw the results ready outside the lock: the locked
+        # harvest does not ask again
+        self.waited = False
         # flight-recorder provenance captured at dispatch time (rung,
         # session kind, build reason, ...). None unless KTPU_TRACE >= 2
         # — the disabled path must not allocate per batch beyond the
@@ -291,6 +357,10 @@ class TPUBackend(CacheListener):
         self._suspect_buckets: set = set()
         # (session, thread) per live pallas bucket-warm thread
         self._warm: List[Tuple] = []
+        # device waiters not in use (_wait_ready): one in steady state,
+        # two while the completion worker and a locked flush both wait
+        self._idle_waiters: List[_DeviceWaiter] = []
+        weakref.finalize(self, _stop_waiters, self._idle_waiters)
         self._whatif_cache: Dict = {}
         self._whatif_cache_version = -1
         # backend-health event hook: the Scheduler wires this to its
@@ -546,22 +616,86 @@ class TPUBackend(CacheListener):
         if inj is not None:
             inj.on_dispatch(rung=self.ladder.rung() if rung is None else rung)
 
-    def _wait_ready(self, ys, timeout: float) -> bool:
+    def _wait_ready(self, ys, timeout: float,
+                    span=tracing.NOOP_SPAN) -> bool:
         """Watchdog-bounded device wait: True when every result leaf is
-        ready, False when the deadline passes (wedged device). Polling
-        is_ready() instead of block_until_ready keeps a hung XLA wait
-        from pinning the calling thread forever — the one failure
-        PR 3's pipeline could not survive."""
+        ready, False when the deadline passes (wedged device). Leaves
+        that are ready at the first look cost nothing more. Otherwise a
+        device waiter (_DeviceWaiter) blocks in the runtime for them
+        and the caller sleeps on its event until the deadline, so it is
+        woken when the launch ends and a hung XLA wait pins the waiter,
+        never the calling thread — the one failure PR 3's pipeline
+        could not survive. How the wait ended is counted
+        (scheduler_device_waits_total) and set on `span` as `outcome`:
+        ready, woken, timed_out, or polled — the 2 ms poll that serves
+        while a fault drill holds the wait wedged or no waiter thread
+        can be started."""
         import jax
+
+        from .metrics import device_waits
 
         deadline = _time.monotonic() + max(0.0, timeout)
         leaves = [
             x for x in jax.tree_util.tree_leaves(ys) if hasattr(x, "is_ready")
         ]
+        outcome = None
+        if not self._wedged():
+            try:
+                leaves = [x for x in leaves if not x.is_ready()]
+            except Exception:  # noqa: BLE001 — let decode surface it
+                leaves = []
+            if not leaves:
+                outcome = "ready"
+            elif _time.monotonic() >= deadline:
+                outcome = "timed_out"
+            else:
+                waiter = self._take_waiter()  # None: no thread, poll
+                if waiter is not None:
+                    if not waiter.wait(leaves, deadline - _time.monotonic()):
+                        # pinned in the runtime, or about to come back
+                        # too late: either way not handed out again
+                        waiter.stop()
+                        outcome = "timed_out"
+                    else:
+                        self._idle_waiters.append(waiter)
+                        # a wedge armed meanwhile holds this wait too
+                        if not self._wedged():
+                            outcome = "woken"
+        if outcome is None:
+            outcome = ("polled" if self._poll_ready(leaves, deadline)
+                       else "timed_out")
+        device_waits.inc(outcome=outcome)
+        span.set(outcome=outcome)
+        return outcome != "timed_out"
+
+    def _wedged(self) -> bool:
+        """A fault drill holds device waits wedged (faults.wedge-wait)."""
+        inj = self.faults
+        return inj is not None and inj.wedge_active()
+
+    def _take_waiter(self) -> Optional[_DeviceWaiter]:
+        """An idle device waiter, or a new one; None when no thread can
+        be started (the wait then polls)."""
+        idle = self._idle_waiters
+        while idle:
+            try:
+                w = idle.pop()
+            except IndexError:  # another waiting thread took the last
+                break
+            if w.thread.is_alive():
+                return w
+        try:
+            return _DeviceWaiter()
+        except Exception:  # noqa: BLE001 — never a fault of the wait
+            logger.warning("device waiter did not start", exc_info=True)
+            return None
+
+    def _poll_ready(self, leaves, deadline: float) -> bool:
+        """The wait without a waiter: ask every 2 ms until the deadline.
+        While a wedge is armed the answer is not-ready whatever the
+        device says."""
         while True:
-            inj = self.faults
-            wedged = inj is not None and inj.wedge_active()
-            if not wedged:
+            if not self._wedged():
                 try:
                     leaves = [x for x in leaves if not x.is_ready()]
                 except Exception:  # noqa: BLE001 — let decode surface it
@@ -950,6 +1084,8 @@ class TPUBackend(CacheListener):
             t.join(timeout=2)
         for wt in self._stop_warm_threads():
             wt.join()
+        for w in _stop_waiters(self._idle_waiters):
+            w.thread.join(timeout=2)
 
     def wait_warm(self) -> None:
         """Block until the live session's bucket-warm thread has built
@@ -1596,6 +1732,13 @@ class TPUBackend(CacheListener):
                         with sp, devtime.TIMELINE.maybe_profile(
                                 "dispatch"):
                             ys = self._session.schedule(clean)  # async
+                            # the decisions' copy to the host starts
+                            # behind the launch: the harvest is woken
+                            # for bytes that are already there
+                            rows = ys.get("rows") \
+                                if isinstance(ys, dict) else None
+                            if hasattr(rows, "copy_to_host_async"):
+                                rows.copy_to_host_async()
                             if isinstance(ys, dict) and "templates" in ys:
                                 sp.set(templates=ys["templates"],
                                        term_pods=ys["term_pods"],
@@ -1653,7 +1796,9 @@ class TPUBackend(CacheListener):
                               bucket=handle.bucket,
                               speculative=handle.speculative,
                               batch=handle.batch) as sp:
-                if not self._wait_ready(ys, self.watchdog_timeout):
+                if self._wait_ready(ys, self.watchdog_timeout, sp):
+                    handle.waited = True
+                else:
                     handle.timed_out = True
                     sp.set(timed_out=True)
         with self._lock:
@@ -1765,11 +1910,11 @@ class TPUBackend(CacheListener):
                            batch=h.batch)
         try:
             with hsp:
-                if h.timed_out or not self._wait_ready(
+                if h.timed_out or not (h.waited or self._wait_ready(
                     h.ys, self.watchdog_timeout
                     if h.deadline is None
                     else h.deadline - _time.monotonic()
-                ):
+                )):
                     raise DeviceFault(
                         "device wait exceeded the dispatch watchdog",
                         kind="timeout")

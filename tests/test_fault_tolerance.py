@@ -128,6 +128,225 @@ class TestFaultInjector:
         assert inj.corrupt_harvest(ys) is ys
 
 
+# -- unit: the device wait ---------------------------------------------------
+
+
+class _FakeLeaf:
+    """A result leaf whose readiness the test controls: is_ready() and a
+    block_until_ready() that really blocks (on an event, so it releases
+    the interpreter as the runtime's does)."""
+
+    def __init__(self, ready=False, raises=False):
+        self._ready = threading.Event()
+        self.raises = raises
+        self.ready_at = None
+        if ready:
+            self.make_ready()
+
+    def make_ready(self):
+        self.ready_at = time.monotonic()
+        self._ready.set()
+
+    def ready_after(self, seconds):
+        t = threading.Timer(seconds, self.make_ready)
+        t.daemon = True
+        t.start()
+        return self
+
+    def is_ready(self):
+        return self._ready.is_set()
+
+    def block_until_ready(self):
+        self._ready.wait()
+        if self.raises:
+            raise RuntimeError("device said no")
+        return self
+
+
+def _wait_backend(monkeypatch):
+    """A backend whose wait must not sleep: the poll is the fallback, and
+    a case that should not take it fails if it does."""
+    from kubernetes_tpu.scheduler import tpu_backend
+
+    class NoSleep:
+        def __getattr__(self, name):
+            return getattr(time, name)
+
+        @staticmethod
+        def sleep(_s):
+            raise AssertionError("the device wait polled")
+
+    monkeypatch.setattr(tpu_backend, "_time", NoSleep())
+    return tpu_backend.TPUBackend()
+
+
+def _waits_delta(before):
+    after = dict(metrics.device_waits.items())
+    return {k[0]: int(v - before.get(k, 0)) for k, v in after.items()
+            if v != before.get(k, 0)}
+
+
+def _case_ready(b, monkeypatch):
+    monkeypatch.setattr(
+        b, "_take_waiter",
+        lambda: pytest.fail("ready at the first look took a waiter"))
+    before = dict(metrics.device_waits.items())
+    assert b._wait_ready({"rows": _FakeLeaf(ready=True), "n": 3}, 1.0)
+    assert not b._idle_waiters
+    assert _waits_delta(before) == {"ready": 1}
+
+
+def _case_woken(b, monkeypatch):
+    before = dict(metrics.device_waits.items())
+    lags = []
+    for _ in range(5):
+        leaf = _FakeLeaf().ready_after(0.005)
+        assert b._wait_ready({"rows": leaf}, 1.0)
+        lags.append(time.monotonic() - leaf.ready_at)
+    # woken when the launch ends: the 2 ms poll looked at 0, 2.1, 4.3
+    # and 6.4 ms and came back 1.4 ms late, every time
+    assert sorted(lags)[len(lags) // 2] < 0.001, lags
+    assert _waits_delta(before) == {"woken": 5}
+    # one long-lived waiter served all five, and close() stops it
+    (w,) = b._idle_waiters
+    b.close()
+    w.thread.join(timeout=2)
+    assert not w.thread.is_alive() and not b._idle_waiters
+
+
+def _case_timed_out(b, monkeypatch):
+    before = dict(metrics.device_waits.items())
+    stuck = _FakeLeaf()
+    t0 = time.monotonic()
+    assert b._wait_ready({"rows": stuck}, 0.05) is False
+    assert 0.05 <= time.monotonic() - t0 < 0.5
+    assert not b._idle_waiters  # the pinned waiter is left behind …
+    pinned = [t for t in threading.enumerate()
+              if t.name == "tpu-device-waiter"]
+    # … and the next wait, on a healthy device, gets a fresh one
+    assert b._wait_ready({"rows": _FakeLeaf().ready_after(0.005)}, 1.0)
+    assert _waits_delta(before) == {"timed_out": 1, "woken": 1}
+    assert b._wait_ready({"rows": _FakeLeaf()}, 0.0) is False  # no budget
+    # the runtime lets go at last: the abandoned thread exits
+    stuck.make_ready()
+    for t in pinned:
+        t.join(timeout=2)
+    assert not any(t.is_alive() for t in pinned)
+
+
+def _case_wedged(b, monkeypatch):
+    from kubernetes_tpu.scheduler import tpu_backend
+
+    monkeypatch.setattr(tpu_backend, "_time", time)
+    inj = FaultInjector()
+    b.faults = inj
+    inj.arm("wedge-wait", shots=1)
+    before = dict(metrics.device_waits.items())
+    t0 = time.monotonic()
+    assert b._wait_ready({"rows": _FakeLeaf(ready=True)}, 0.05) is False
+    assert time.monotonic() - t0 >= 0.05
+    assert inj.wedge_active(), "the wait consumed the wedge shot"
+    assert _waits_delta(before) == {"timed_out": 1}
+    # armed while the caller sleeps on the waiter's event: held too
+    inj.disarm()
+    leaf = _FakeLeaf()
+
+    def arm_then_ready():
+        inj.arm("wedge-wait", shots=1)
+        leaf.make_ready()
+
+    threading.Timer(0.005, arm_then_ready).start()
+    assert b._wait_ready({"rows": leaf}, 0.05) is False
+    assert inj.wedge_active()
+
+
+def _case_raises(b, monkeypatch):
+    before = dict(metrics.device_waits.items())
+    leaf = _FakeLeaf(raises=True).ready_after(0.005)
+    assert b._wait_ready({"rows": leaf}, 1.0)  # decode will surface it
+
+    class Sick:
+        def is_ready(self):
+            raise RuntimeError("device said no")
+
+    assert b._wait_ready({"rows": Sick()}, 1.0)
+    assert _waits_delta(before) == {"woken": 1, "ready": 1}
+
+
+def _case_concurrent(b, monkeypatch):
+    # the completion worker and a locked flush wait at once, the flush
+    # on the OLDER launch: neither stands behind the other
+    before = dict(metrics.device_waits.items())
+    first, second = _FakeLeaf(), _FakeLeaf()
+    got = {}
+
+    def wait(name, leaf):
+        got[name] = b._wait_ready({"rows": leaf}, 2.0)
+
+    ts = [threading.Thread(target=wait, args=(n, leaf))
+          for n, leaf in (("worker", second), ("flush", first))]
+    for t in ts:
+        t.start()
+    assert wait_until(lambda: sum(
+        t.name == "tpu-device-waiter" for t in threading.enumerate()) >= 2)
+    second.make_ready()  # out of order: its waiter is its own
+    ts[0].join(timeout=2)
+    assert got == {"worker": True}
+    first.make_ready()
+    ts[1].join(timeout=2)
+    assert got == {"worker": True, "flush": True}
+    assert _waits_delta(before) == {"woken": 2}
+    assert len(b._idle_waiters) == 2
+
+
+def _case_polled(b, monkeypatch):
+    from kubernetes_tpu.scheduler import tpu_backend
+
+    def no_thread():
+        raise RuntimeError("can't start new thread")
+
+    monkeypatch.setattr(tpu_backend, "_DeviceWaiter", no_thread)
+    monkeypatch.setattr(tpu_backend, "_time", time)
+    before = dict(metrics.device_waits.items())
+    assert b._wait_ready({"rows": _FakeLeaf().ready_after(0.005)}, 1.0)
+    assert b._wait_ready({"rows": _FakeLeaf()}, 0.02) is False
+    assert _waits_delta(before) == {"polled": 1, "timed_out": 1}
+
+
+def _case_dead_waiter(b, monkeypatch):
+    # a waiter whose thread died is dropped, not handed work
+    assert b._wait_ready({"rows": _FakeLeaf().ready_after(0.002)}, 1.0)
+    (dead,) = b._idle_waiters
+    dead.stop()
+    dead.thread.join(timeout=2)
+    b._idle_waiters.append(dead)
+    assert b._wait_ready({"rows": _FakeLeaf().ready_after(0.002)}, 1.0)
+    assert dead not in b._idle_waiters and len(b._idle_waiters) == 1
+
+
+_WAIT_CASES = {
+    "ready-first-look": _case_ready,
+    "woken-when-done": _case_woken,
+    "timed-out-then-fresh-waiter": _case_timed_out,
+    "wedge-holds-and-keeps-shot": _case_wedged,
+    "raising-wait-reads-ready": _case_raises,
+    "two-threads-at-once": _case_concurrent,
+    "no-thread-polls": _case_polled,
+    "dead-waiter-replaced": _case_dead_waiter,
+}
+
+
+@pytest.mark.parametrize("case", sorted(_WAIT_CASES))
+def test_device_wait(case, monkeypatch):
+    """TPUBackend._wait_ready on leaves a test controls: told when a
+    launch is done, bounded by the watchdog, never a fault of its own."""
+    b = _wait_backend(monkeypatch)
+    try:
+        _WAIT_CASES[case](b, monkeypatch)
+    finally:
+        b.close()
+
+
 class TestExecQuarantine:
     def test_retire_exec_pre_pins_fresh_cache(self):
         """A quarantined bucket must stay jit-only on a REBUILT session:
