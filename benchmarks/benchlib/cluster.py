@@ -9,6 +9,13 @@ the order pods are created in, the pod watch and every clock.
 Pods are named `p-<index>`; index i is the i-th pod ever created, which is
 also the order the scheduling queue pops them in (one priority, creation
 order) and therefore the order the plain reference decides them in.
+
+What the benchmark did to the cluster is `Cluster.log`, in order: creates
+(`order`), and the events that are not creates (`delete`, `remove_node`, `add_node`),
+which are issued only inside a `barrier` (scheduler paused, every pod it
+has popped bound) and carry the number of pods bound at that instant. The
+objects are built by the module the configuration names as its `builder`
+(`benchmarks/builders/<name>.py`), or by the two functions below.
 """
 
 from __future__ import annotations
@@ -22,6 +29,7 @@ from kubernetes_tpu.apiserver import APIServer
 from kubernetes_tpu.client import Clientset, SharedInformerFactory
 from kubernetes_tpu.scheduler import metrics as sched_metrics
 from kubernetes_tpu.scheduler.scheduler import Scheduler
+from kubernetes_tpu.utils.metrics import legacy_registry
 
 NAMESPACE = "default"
 STAGE_PARK_S = 0.3  # lets a pop already blocked in the queue park (it
@@ -84,20 +92,36 @@ def build_pod(name: str, cls: Dict) -> v1.Pod:
 
 class Cluster:
     def __init__(self, config: Dict, pod_ceiling: int,
-                 interpret: bool = False):
+                 interpret: bool = False, builder=None):
         self.config = config
         self.pod_ceiling = pod_ceiling
         self.interpret = interpret
+        # `builder`: a module with build_node(i, config), build_pod(name,
+        # cls); None: the two functions of this file
+        self._build_node = (
+            (lambda i: builder.build_node(i, config)) if builder is not None
+            else (lambda i: build_node(i, config["nodes"])))
+        self._build_pod = builder.build_pod if builder is not None \
+            else build_pod
         self.classes: List[Dict] = []
         self._class_index: Dict[tuple, int] = {}
         # per pod, by index
         self.pods: List[v1.Pod] = []
         self.cls: List[int] = []
         self.order: List[int] = []  # indices in creation order
+        # the events that are not creates: (creates issued before it, event,
+        # index, pods bound in all at that instant, time)
+        self._events: List[tuple] = []
         self.bound_t: List[float] = []
         self.bound_node: List[Optional[str]] = []
         self.rebinds = 0
         self._n_bound = 0
+        # pods the benchmark deleted -> were they bound; pods the watch
+        # saw deleted -> when
+        self.deleted: Dict[int, bool] = {}
+        self.deleted_t: Dict[int, float] = {}
+        self.n_deleted_pending = 0
+        self.nodes: set = set()  # indices of the nodes there are
         self._watch = None
         self._watch_thread: Optional[threading.Thread] = None
         self.sched: Optional[Scheduler] = None
@@ -124,8 +148,8 @@ class Cluster:
         indices. The API call is all the window pays for a pod."""
         first = len(self.pods)
         for c in class_ids:
-            self.pods.append(build_pod(f"p-{len(self.pods):07d}",
-                                       self.classes[c]))
+            self.pods.append(self._build_pod(f"p-{len(self.pods):07d}",
+                                             self.classes[c]))
             self.cls.append(c)
             self.bound_t.append(0.0)
             self.bound_node.append(None)
@@ -139,6 +163,89 @@ class Cluster:
         self.order.append(i)
         self.cs.pods.create(self.pods[i])
 
+    # -- events that are not creates: inside a barrier only ------------------
+
+    def _event(self, op: str, index: int) -> None:
+        self._events.append((len(self.order), op, index, self._n_bound,
+                             time.perf_counter()))
+
+    @property
+    def log(self) -> List[tuple]:
+        """Everything the benchmark did to the cluster, in order:
+        ("create", i, class) or (event, index, pods bound, time). Put
+        together when it is asked for: a create pays nothing for it."""
+        out: List[tuple] = []
+        done = 0
+        for at, *event in self._events + [(len(self.order),)]:
+            out += [("create", i, self.cls[i]) for i in self.order[done:at]]
+            out += [tuple(event)] if event else []
+            done = at
+        return out
+
+    def delete(self, i: int) -> None:
+        """Pod `i`, bound (a rolling update ends it, its node is drained)
+        or still pending."""
+        bound = self.deleted[i] = self.bound_node[i] is not None
+        self.n_deleted_pending += not bound
+        self._event("delete", i)
+        self.cs.pods.delete(self.pods[i].metadata.name, NAMESPACE)
+
+    def remove_node(self, n: int) -> None:
+        self.nodes.remove(n)
+        self._event("node_remove", n)
+        self.cs.nodes.delete(node_name(n))
+
+    def add_node(self, n: int) -> None:
+        """A removed index again, or a new one."""
+        self.nodes.add(n)
+        self._event("node_add", n)
+        self.cs.nodes.create(self._build_node(n))
+
+    node_name = staticmethod(node_name)
+
+    def live_pods(self) -> tuple:
+        """(bound, pending): the pods created and not deleted by the
+        benchmark, in creation order, by whether the watch has seen their
+        bind."""
+        bound, pending = [], []
+        for i in self.order:
+            if i not in self.deleted:
+                (bound if self.bound_node[i] is not None
+                 else pending).append(i)
+        return bound, pending
+
+    def n_unbound(self) -> int:
+        """Pods created, not deleted while pending, and not seen bound."""
+        return len(self.order) - self.n_deleted_pending - self._n_bound
+
+    def barrier(self, deadline: float) -> bool:
+        """With the scheduler paused: until every pod it has popped is
+        bound on the benchmark's own watch, so that the pods bound are a
+        prefix of creation order and `n_bound()` says how long a one. A
+        pod is pending in the scheduler's queue, or popped; all popped
+        pods are bound when queue + bound = created."""
+        self.sched._drain_pipeline(timeout=30.0)
+        while sum(self.sched.queue.depths()) != self.n_unbound():
+            if time.perf_counter() >= deadline:
+                return False
+            time.sleep(0.002)
+        return True
+
+    def settle(self, deadline: float) -> bool:
+        """Until the scheduler's informers have delivered every event
+        issued so far: its cache holds the nodes there are and the bound
+        pods that are left. (A pending pod's delete rides the stream of
+        the creates that follow it, which `stage_end` waits for.)"""
+        cache = self.sched.cache
+        live_bound = self._n_bound - (
+            len(self.deleted) - self.n_deleted_pending)
+        while (cache.node_count() != len(self.nodes)
+               or cache.pod_count() != live_bound):
+            if time.perf_counter() >= deadline:
+                return False
+            time.sleep(0.001)
+        return True
+
     # -- build and teardown --------------------------------------------------
 
     def build(self) -> None:
@@ -146,13 +253,20 @@ class Cluster:
         self.api = APIServer()
         self.cs = Clientset(self.api)
         for i in range(cfg["nodes"]["count"]):
-            self.cs.nodes.create(build_node(i, cfg["nodes"]))
+            self.cs.nodes.create(self._build_node(i))
+        self.nodes = set(range(cfg["nodes"]["count"]))
         self.factory = SharedInformerFactory(self.cs)
         backend = None
-        if self.interpret:
+        n_mesh = cfg["scheduler"].get("mesh")
+        if self.interpret or n_mesh:
             from kubernetes_tpu.scheduler.tpu_backend import TPUBackend
 
-            backend = TPUBackend(pallas_interpret=True)
+            mesh = None
+            if n_mesh:  # the node axis sharded over this many chips
+                from kubernetes_tpu.parallel.sharded import make_mesh
+
+                mesh = make_mesh(n_devices=n_mesh)
+            backend = TPUBackend(mesh=mesh, pallas_interpret=self.interpret)
         self.sched = Scheduler(
             self.cs, self.factory, backend="tpu",
             max_batch=cfg["scheduler"]["max_batch"], tpu_backend=backend)
@@ -182,12 +296,15 @@ class Cluster:
     # -- the benchmark's own pod watch ----------------------------------------
 
     def _watch_loop(self) -> None:
-        """Binds as the API server publishes them, stamped on arrival.
-        Raw events: hydrating every pod would be the benchmark taxing the
-        interpreter it shares with the scheduler."""
+        """Binds as the API server publishes them, stamped on arrival,
+        and deletes. Raw events: hydrating every pod would be the
+        benchmark taxing the interpreter it shares with the scheduler."""
         now = time.perf_counter
         for ev in self._watch.raw_events():
             if ev.type != "MODIFIED":
+                if ev.type == "DELETED":
+                    self.deleted_t[int(ev.value["metadata"]["name"][2:])] \
+                        = now()
                 continue
             node = ev.value["spec"].get("nodeName")
             if not node:
@@ -249,14 +366,24 @@ class Cluster:
     def counters(self) -> Dict:
         def by_label(counter) -> Dict[str, int]:
             out: Dict[str, int] = {}
-            for key, val in counter.items():
+            # a gauge has no items() of its own
+            with counter._lock:
+                items = list(counter._values.items())
+            for key, val in items:
                 slug = "/".join(str(k) for k in key if k) or "-"
-                out[slug] = out.get(slug, 0) + int(val)
+                out[slug] = out.get(slug, 0) + (
+                    int(val) if float(val).is_integer() else val)
             return out
 
         tpu = self.sched.tpu
         sess = tpu._session
         return {
+            # every counter and gauge the program registers, for a metric
+            # reader that a later PR adds: {name: {label slug: value}}
+            "registry": {
+                m.name: by_label(m) for m in
+                list(legacy_registry._metrics.values())
+                if m.type_name in ("counter", "gauge")},
             "device_faults": by_label(sched_metrics.device_faults),
             "dispatch_retries": sum(
                 by_label(sched_metrics.dispatch_retries).values()),
@@ -276,8 +403,9 @@ class Cluster:
                 for k, v in dict(getattr(sess, "_exec", {})).items()},
         }
 
-    def stored_binds(self) -> Dict[int, str]:
-        """pod index -> node, as the API server holds them now."""
+    def stored(self) -> Dict[int, Optional[str]]:
+        """pod index -> node (None: not bound), as the API server holds
+        them now."""
         pods, _ = self.cs.pods.list(namespace=NAMESPACE)
-        return {int(p.metadata.name[2:]): p.spec.node_name
-                for p in pods if p.spec.node_name}
+        return {int(p.metadata.name[2:]): p.spec.node_name or None
+                for p in pods}

@@ -30,7 +30,7 @@ SKIP_LINES = ("XLA Modules", "Steps", "XLA TraceMe", "Framework Ops",
               "Framework Name Scope", "Source code")
 KERNEL_MARKS = ("custom-call", "custom_call", "pallas", "mosaic")
 # innermost first: a span later in this list is painted over by an earlier
-SPAN_ORDER = ("stage", "bind", "assume", "harvest", "wait", "dispatch",
+SPAN_ORDER = ("stage", "mutate", "barrier", "bind", "assume", "harvest", "wait", "dispatch",
               "encode", "pop")
 CELL_S = 50e-6
 

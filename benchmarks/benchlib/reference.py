@@ -19,6 +19,12 @@ the nodes of the highest total the FIRST in node order wins (the reference
 scheduler draws one of them at random; a deterministic order is the only
 way two schedulers can be compared bind for bind).
 
+A run is a LOG of cluster events (`replay`): creates, deletes, nodes that
+leave and nodes that join. Node order, for "the first of the maxima", is
+node INDEX order whatever happened to the cluster in between: a node that
+left and came back, or one that joined past the first `count`, stands where
+its index puts it.
+
 `variant` breaks one guarantee on purpose; those are the controls that
 have to come out as not correct (benchmarks/README.md):
   "sampled"   scores only the first half of the feasible nodes
@@ -29,7 +35,8 @@ have to come out as not correct (benchmarks/README.md):
 from __future__ import annotations
 
 import math
-from typing import Dict, List, Optional, Sequence, Tuple
+from collections import OrderedDict
+from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -53,6 +60,17 @@ def quantity_bytes(q: str) -> int:
 
 def _matches(selector: Dict[str, str], labels: Dict[str, str]) -> bool:
     return all(labels.get(k) == v for k, v in selector.items())
+
+
+class LogError(ValueError):
+    """The log asks for what cannot be: a node removed with pods on it, a
+    node added twice. The benchmark drains before it removes, so this is
+    either a fault of the benchmark or a cluster that has already parted
+    from the reference; `binds` and `evicted` are what was decided up to
+    the event."""
+
+    binds: Dict = {}
+    evicted: List = []
 
 
 class PodClass:
@@ -87,10 +105,26 @@ class ReferenceCluster:
         self.n_pods = np.zeros(n, np.int64)
         self.zone = np.arange(n, dtype=np.int64) % n_zones
         self.n_zones = n_zones
+        self.present = np.ones(n, bool)  # False: the node has left
+        # the one node shape, for a node that joins past the first `n`
+        self._shape = (milli_cpu(cpu), quantity_bytes(memory), int(max_pods))
         self._classes: Dict[Tuple, int] = {}
         self._class_objs: List[PodClass] = []
         self._per_node: List[np.ndarray] = []  # class -> pods per node
         self._per_zone: List[np.ndarray] = []  # class -> pods per zone
+
+    @classmethod
+    def from_config(cls, config: Dict, variant: str = ""
+                    ) -> "ReferenceCluster":
+        """The cluster a configuration file describes, before any event."""
+        nodes = config["nodes"]
+        return cls(nodes["count"], nodes["cpu"], nodes["memory"],
+                   nodes["pods"], nodes["zones"], variant=variant)
+
+    def shape_of(self, i: int) -> Tuple[int, int, int, int]:
+        """(milli-CPU, bytes, pod limit, zone) of node `i` when it joins;
+        a reference of several node pools overrides this."""
+        return self._shape + (i % self.n_zones,)
 
     def _class_id(self, pc: PodClass) -> int:
         k = pc.key()
@@ -110,6 +144,7 @@ class ReferenceCluster:
             (self.n_pods + 1 <= self.alloc_pods)
             & (self.req_cpu + pc.cpu <= self.alloc_cpu)
             & (self.req_mem + pc.mem <= self.alloc_mem)
+            & self.present
         )
         for cid, other in enumerate(self._class_objs):
             # the incoming pod's own term against pods already there, and
@@ -170,26 +205,115 @@ class ReferenceCluster:
         self.place(pc, node)
         return node
 
-    def place(self, pc: PodClass, node: int) -> None:
+    def place(self, pc: PodClass, node: int, sign: int = 1) -> None:
         cid = self._class_id(pc)
-        self.req_cpu[node] += pc.cpu
-        self.req_mem[node] += pc.mem
-        self.n_pods[node] += 1
-        self._per_node[cid][node] += 1
-        self._per_zone[cid][self.zone[node]] += 1
+        self.req_cpu[node] += sign * pc.cpu
+        self.req_mem[node] += sign * pc.mem
+        self.n_pods[node] += sign
+        self._per_node[cid][node] += sign
+        self._per_zone[cid][self.zone[node]] += sign
+
+    # -- events that are not creates -----------------------------------------
+
+    def unplace(self, pc: PodClass, node: int) -> None:
+        """A bound pod of class `pc` was deleted from `node`."""
+        self.place(pc, node, -1)
+
+    def remove_node(self, node: int) -> None:
+        """Infeasible from here on. Operators drain, then delete: a log
+        that removes a node with pods still on it is the benchmark's own
+        fault, not a decision to compare."""
+        if not (0 <= node < self.n and self.present[node]):
+            raise LogError(f"node_remove {node}: no such node")
+        if self.n_pods[node]:
+            raise LogError(f"node_remove {node}: {self.n_pods[node]} pods "
+                             "still on it; the log has to delete them first")
+        self.present[node] = False
+
+    def add_node(self, node: int) -> None:
+        """Present and empty: a removed index again, or a new one past the
+        nodes there are (the indices between then exist and are absent)."""
+        if node >= self.n:
+            grow = node + 1 - self.n
+            for name in ("alloc_cpu", "alloc_mem", "alloc_pods"):
+                # 1, not 0: an absent node is never feasible, and its
+                # share of a score is never read; 0 would divide
+                setattr(self, name, np.concatenate(
+                    [getattr(self, name), np.ones(grow, np.int64)]))
+            for name in ("req_cpu", "req_mem", "n_pods", "zone"):
+                setattr(self, name, np.concatenate(
+                    [getattr(self, name), np.zeros(grow, np.int64)]))
+            self.present = np.concatenate([self.present, np.zeros(grow, bool)])
+            self._per_node = [np.concatenate([a, np.zeros(grow, np.int64)])
+                              for a in self._per_node]
+            self.n = node + 1
+        elif self.present[node]:
+            raise LogError(f"node_add {node}: already there")
+        cpu, mem, pods, zone = self.shape_of(node)
+        self.alloc_cpu[node], self.alloc_mem[node] = cpu, mem
+        self.alloc_pods[node], self.zone[node] = pods, zone
+        self.present[node] = True
 
 
-def replay(nodes: Dict, classes: Sequence[Dict], sequence: Sequence[int],
-           variant: str = "") -> List[Optional[int]]:
-    """Decide one pod after another on an empty cluster of `nodes` (the
-    configuration's node block). `classes` are pod shapes (dicts of the
-    configuration's template keys, labels filled in) and `sequence` names
-    the class of each pod in the order the pods were created; returns the
-    node index of each (None = no node fits)."""
-    cluster = ReferenceCluster(
-        nodes["count"], nodes["cpu"], nodes["memory"], nodes["pods"],
-        nodes["zones"], variant=variant)
+def replay(config: Dict, classes: Sequence[Dict], log: Iterable[Tuple],
+           variant: str = "", cluster_cls=ReferenceCluster
+           ) -> Tuple[Dict[int, Optional[int]], List[int]]:
+    """What should have happened: the cluster of `config` (the whole
+    configuration file) taken through `log`, the ordered events the
+    benchmark issued. `classes` are pod shapes (dicts of the
+    configuration's template keys, labels filled in).
+
+      ("create", i, c)          pod i of class c joins the pending pods
+      ("delete", i, bound, ..)  pod i leaves its node, or the pending pods
+      ("node_remove", n, bound, ..) / ("node_add", n, bound, ..)
+
+    A pod waits in a standing backlog, so it is decided cycles after its
+    create. An event that is not a create was issued with the scheduler
+    paused and drained, and carries `bound`: how many pods had been bound
+    in all at that instant. One priority and a FIFO queue make those the
+    first `bound` pending pods that find a node, so they are decided
+    before the event is applied and the rest after it.
+
+    Returns (binds, evicted): the node index of every pod that was decided
+    (None: no node fits; a pod deleted while it was pending is not in it),
+    and the pods the scheduler should itself have deleted to make room
+    (none: one priority; a reference with priorities says them here)."""
+    cluster = cluster_cls.from_config(config, variant)
     pcs = [PodClass(c) for c in classes]
     for pc in pcs:
         cluster._class_id(pc)
-    return [cluster.decide(pcs[c]) for c in sequence]
+    pending: "OrderedDict[int, int]" = OrderedDict()
+    binds: Dict[int, Optional[int]] = {}
+    placed: Dict[int, int] = {}  # live bound pod -> its class
+    n_bound = 0
+
+    def decide(upto: Optional[int]) -> None:
+        nonlocal n_bound
+        while pending and (upto is None or n_bound < upto):
+            i, c = pending.popitem(last=False)
+            node = binds[i] = cluster.decide(pcs[c])
+            if node is not None:
+                placed[i] = c
+                n_bound += 1
+
+    for ev in log:
+        op, idx = ev[0], ev[1]
+        if op == "create":
+            pending[idx] = ev[2]
+            continue
+        decide(ev[2])
+        try:
+            if op == "delete":
+                if pending.pop(idx, None) is None and idx in placed:
+                    cluster.unplace(pcs[placed.pop(idx)], binds[idx])
+            elif op == "node_remove":
+                cluster.remove_node(idx)
+            elif op == "node_add":
+                cluster.add_node(idx)
+            else:
+                raise LogError(f"event {op!r} is not one the reference knows")
+        except LogError as e:
+            e.binds, e.evicted = binds, []
+            raise
+    decide(None)
+    return binds, []

@@ -4,10 +4,11 @@ check every bind against the plain reference, print one JSON line.
     python3 benchmarks/run.py --workload <config>.<traffic> --seed <n> \
         --seconds <s> --trace <0|1>
 
-A cell is data: `configs/<config>.json` (the deployment), `traffic/
-<traffic>.json` (the mix, whose `kind` names a generator in `kinds/`) and
-the metric readers in `metrics/`. Nothing here knows a cell by name. See
-README.md beside this file.
+A cell is data: `configs/<config>.json` (the deployment; it may name its
+own `reference` in `references/` and its own `builder` of node and pod
+objects in `builders/`), `traffic/<traffic>.json` (the mix, whose `kind`
+names a generator in `kinds/`) and the metric readers in `metrics/`.
+Nothing here knows a cell by name. See README.md beside this file.
 
 Exit codes: 0 a result was printed (read its `correct`); 4 no TPU, or
 fewer chips than the cell asks for (nothing is printed); 1 anything else.
@@ -20,6 +21,7 @@ import time
 T_PROCESS = time.perf_counter()  # set-up is counted from here
 
 import argparse  # noqa: E402
+import bisect  # noqa: E402
 import gc  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
@@ -49,6 +51,38 @@ def load_module(directory: str, name: str):
 def load_json(directory: str, name: str) -> Dict:
     with open(os.path.join(HERE, directory, name + ".json")) as f:
         return json.load(f)
+
+
+def barrier_not_a_prefix(log: List[tuple], bound_t: List[float],
+                         bound_node: List[Optional[str]]) -> int:
+    """Pods whose bind the watch saw on the other side of an event that is
+    not a create than a FIFO queue puts it. Such an event carries the
+    number of pods bound at that instant; with one priority those are the
+    first that many pods created and not deleted while pending. 0 on a log
+    of creates."""
+    times = [ev[3] for ev in log if ev[0] != "create"]
+    if not times:
+        return 0
+    pending: Dict[int, None] = {}  # insertion order is creation order
+    want: Dict[int, int] = {}  # pod -> events that came before its bind
+    n_decided = n_events = 0
+    for ev in log:
+        if ev[0] == "create":
+            pending[ev[1]] = None
+            continue
+        while pending and n_decided < ev[2]:
+            first = next(iter(pending))
+            del pending[first]
+            want[first] = n_events
+            n_decided += 1
+        if ev[0] == "delete":
+            pending.pop(ev[1], None)
+        n_events += 1
+    for i in pending:
+        want[i] = n_events
+    return sum(1 for i, n in want.items() if (
+        bisect.bisect_left(times, bound_t[i]) if bound_node[i] is not None
+        else n_events) != n)
 
 
 def resolve(workload: str) -> Dict:
@@ -221,6 +255,8 @@ def main(argv=None) -> int:
         key, _, val = kv.partition("=")
         traffic[key] = json.loads(val)
     kind = load_module("kinds", traffic["kind"])
+    builder = (load_module("builders", config["builder"])
+               if config.get("builder") else None)
 
     os.environ.setdefault("JAX_ENABLE_X64", "1")
     if args.trace:
@@ -240,7 +276,10 @@ def main(argv=None) -> int:
     device = {"platform": platform, "kind": devices[0].device_kind,
               "count": len(devices)}
 
-    from benchlib import reference
+    if config.get("reference"):
+        reference = load_module("references", config["reference"])
+    else:
+        from benchlib import reference
     from benchlib.stats import percentile
     from benchlib.cluster import Cluster, node_name
     from kubernetes_tpu.utils import tracing
@@ -254,7 +293,8 @@ def main(argv=None) -> int:
     now = time.perf_counter
 
     # -- set-up ---------------------------------------------------------------
-    cluster = Cluster(config, traffic["pod_ceiling"], interpret=on_cpu_by_name)
+    cluster = Cluster(config, traffic["pod_ceiling"], interpret=on_cpu_by_name,
+                      builder=builder)
     cluster.build()
     groups = config.get("init_groups", 0)
     cluster.stage(cluster.prebuild([
@@ -302,7 +342,9 @@ def main(argv=None) -> int:
     # close fell in, so that no second of the window drops out of a rate
     t_end = max(t_close, kind_out.get("t_end", t_close))
     cluster.sched.resume()
-    cluster.wait_bound(len(cluster.order), t_close + SETTLE_S)
+    # the live pods: a pod deleted while it was pending never binds
+    cluster.wait_bound(len(cluster.order) - cluster.n_deleted_pending,
+                       t_close + SETTLE_S)
     t_settled = now()
     gc.callbacks.remove(gc_clock)
 
@@ -319,48 +361,75 @@ def main(argv=None) -> int:
     device["memory_peak_bytes"] = max(
         (s.get("peak_bytes_in_use", 0) for s in stats), default=0)
     trace = prof.result(spans, args.dump_trace) if prof is not None else None
-    stored = cluster.stored_binds()
+    stored = cluster.stored()
     watch_node = list(cluster.bound_node)
     rebinds = cluster.rebinds
     cluster.close()
-    order, cls, classes = cluster.order, cluster.cls, cluster.classes
-    bound_t = cluster.bound_t
+    order, log, classes = cluster.order, cluster.log, cluster.classes
+    bound_t, deleted = cluster.bound_t, cluster.deleted
+    watch_deleted = set(cluster.deleted_t)
     del cluster, plan
     gc.unfreeze()
     gc.collect()
 
     # -- correct: every bind against the plain reference ------------------------
     t_ref = now()
-    want = reference.replay(config["nodes"], classes, [cls[i] for i in order])
-    got = stored
+
+    def replay(variant: str = ""):
+        """(binds, evicted, why the reference refused the log)."""
+        try:
+            return reference.replay(config, classes, log, variant) + ("",)
+        except ValueError as e:  # LogError: what was decided till then
+            return e.binds, e.evicted, str(e)
+
+    want, evicted, refused = replay()
+    gone = set(deleted) | set(evicted)
+    live = [i for i in order if i not in gone]
+    # what happened: a live pod's node as the store holds it, and for a pod
+    # that was deleted the first bind the watch saw
+    got = {}
+    for i in order:
+        node = watch_node[i] if i in gone else stored.get(i)
+        if node:
+            got[i] = node
     if args.control:
         # the control: the reference with one guarantee broken, put in the
-        # program's place
-        ctl = reference.replay(config["nodes"], classes,
-                               [cls[i] for i in order], variant=args.control)
-        got = {i: node_name(n) for i, n in zip(order, ctl) if n is not None}
+        # program's place (the log drained the nodes the PROGRAM had filled:
+        # a control that cannot follow it stops there)
+        ctl = replay(args.control)[0]
+        got = {i: node_name(n) for i, n in ctl.items() if n is not None}
     mismatched = sum(
-        1 for i, n in zip(order, want)
+        1 for i, n in want.items()
         if i in got and (n is None or got[i] != node_name(n)))
     reference_s = now() - t_ref
-    unbound = sum(1 for i in order if i not in got)
+    unbound = sum(1 for i in live if i not in got)
     faults0, faults1 = counters0["device_faults"], counters1["device_faults"]
+    # the session the configuration asks for, and the build kind it is
+    # counted under
+    session = config["scheduler"].get("session", "PallasSession")
     builds = {k: v for k, v in counters1["session_builds"].items()
-              if not k.startswith("pallas")}
+              if not k.startswith(
+                  config["scheduler"].get("session_builds", "pallas"))}
     checks = [
         ("mismatched_binds", mismatched, 0),
         ("unbound_pods", unbound, 0),
         ("rebound_pods", rebinds, 0),
+        ("barriers_not_reached", kind_out.get("barriers_not_reached", 0), 0),
+        ("log_refused_by_reference", int(bool(refused)), 0),
         ("watch_differs_from_store", sum(
-            1 for i in order if watch_node[i] != stored.get(i)), 0),
+            1 for i in live if watch_node[i] != stored.get(i)), 0),
+        ("deleted_pods_still_stored", sum(1 for i in gone if i in stored), 0),
+        ("unasked_deletes", len(watch_deleted ^ gone), 0),
+        ("barrier_not_a_prefix",
+         barrier_not_a_prefix(log, bound_t, watch_node), 0),
         ("device_faults", sum(faults1.values()) - sum(faults0.values()), 0),
         ("dispatch_retries", counters1["dispatch_retries"], 0),
         ("ladder_demotions", counters1["ladder_demotions"]
          + int(counters1["rung_below_top"]), 0),
         ("worker_restarts", counters1["worker_restarts"], 0),
         ("failed_executables", len(counters1["exec_errors"]), 0),
-        ("sessions_not_pallas", sum(builds.values()) + int(
-            counters1["session_kind"] != "PallasSession"), 0),
+        ("sessions_not_as_configured", sum(builds.values()) + int(
+            counters1["session_kind"] != session), 0),
         ("compiles_in_window", meter1["requests"] - meter0["requests"], 0),
     ]
     correct = all(v <= lim for _, v, lim in checks)
@@ -371,7 +440,8 @@ def main(argv=None) -> int:
         created=rec.created, due=rec.due, issued=rec.issued, ready=rec.ready,
         create_done=rec.create_done, bound_t=bound_t, bound_node=watch_node,
         latencies=[(bound_t[i] if watch_node[i] is not None else t_settled)
-                   - rec.due[i] for i in rec.created],
+                   - rec.due[i] for i in rec.created
+                   if deleted.get(i, True)],  # not: deleted while pending
         kind_out=kind_out, spans=spans, trace=trace, counters0=counters0,
         counters1=counters1, setup_s=t_open - T_PROCESS,
         setup_compile_s=meter0["seconds"], config=config, traffic=traffic,
@@ -394,7 +464,8 @@ def main(argv=None) -> int:
     result = {
         "correct": correct,
         "attempted": len(rec.created),
-        "failed": sum(1 for i in rec.created if watch_node[i] is None),
+        "failed": sum(1 for i in rec.created
+                      if watch_node[i] is None and i not in deleted),
         "metrics": metrics,
         "device": device,
     }
@@ -403,6 +474,13 @@ def main(argv=None) -> int:
         device["window_s"] = trace["window_s"]
         result["breakdown"] = {"device_ops": trace["device_ops"],
                                "idle_gaps": trace["idle_gaps"]}
+    def moved(name: str) -> Dict:
+        """By label, what the window added to a counter of the registry."""
+        was = counters0["registry"].get(name, {})
+        return {k: v - was.get(k, 0) for k, v in
+                counters1["registry"].get(name, {}).items()
+                if v != was.get(k, 0)}
+
     half = len(run.latencies) // 2
     result["detail"] = {
         "workload": args.workload, "seed": args.seed, "overrides": args.set,
@@ -420,10 +498,21 @@ def main(argv=None) -> int:
         "rehearsal": on_cpu_by_name, "control": args.control,
         "setup_pods": n_setup_pods, "pods_total": len(order),
         "reference_s": reference_s, "settle_s": t_settled - t_close,
+        "reference_refused": refused,
         "notes": run.notes,
         "trace_layout": trace["layout"] if trace else None,
         "trace_aligned": trace["aligned"] if trace else None,
         "session_rebuilds": counters1["session_rebuilds"],
+        # the events of the run, and what the window added to the two
+        # counters that say how the backend took them
+        "window": {
+            "events": {op: sum(1 for ev in log if ev[0] == op)
+                       for op in ("delete", "node_remove", "node_add")},
+            "deleted_pending": sum(1 for b in deleted.values() if not b),
+            "session_rebuilds": moved("scheduler_session_rebuilds_total"),
+            "session_delta_applies": moved(
+                "scheduler_session_delta_applies_total"),
+        },
         "executables": counters1["executables"],
         "compile_setup": meter0,
         "waves": [[round(w[k] - t_open, 4) for k in
@@ -435,6 +524,8 @@ def main(argv=None) -> int:
     print(json.dumps(result), flush=True)
     for n, v, lim in checks:
         print(f"check {n}: {v} (limit {lim})", file=sys.stderr)
+    if refused:
+        print(f"reference refused the log: {refused}", file=sys.stderr)
     print(f"correct: {correct}", file=sys.stderr, flush=True)
     return 0
 

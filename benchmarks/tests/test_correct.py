@@ -11,32 +11,42 @@ import run as bench_run
 from benchlib import reference
 from conftest import BENCH
 
-NODES = {"count": 96, "cpu": "4", "memory": "32Gi", "pods": 110, "zones": 3}
+CONFIG = {"nodes": {"count": 96, "cpu": "4", "memory": "32Gi", "pods": 110,
+                    "zones": 3}}
 SPREAD = {"cpu": "100m", "memory": "128Mi", "labels": {"app": "perf"},
           "spread_zone_soft": True}
 ANTI = [{"cpu": "100m", "memory": "128Mi", "labels": {"app": f"svc-{g}"},
          "anti_affinity_hostname": True} for g in range(4)]
 
 
+def replay(classes, seq, variant=""):
+    """The node of each pod of a log of creates, in creation order."""
+    binds, evicted = reference.replay(
+        CONFIG, classes, [("create", i, c) for i, c in enumerate(seq)],
+        variant=variant)
+    assert evicted == []
+    return [binds[i] for i in range(len(seq))]
+
+
 @pytest.mark.parametrize("variant", ["sampled", "last-max"])
 def test_control_differs_from_the_reference(variant):
     seq = [0] * 1500
-    want = reference.replay(NODES, [SPREAD], seq)
-    got = reference.replay(NODES, [SPREAD], seq, variant=variant)
+    want = replay([SPREAD], seq)
+    got = replay([SPREAD], seq, variant=variant)
     assert sum(a != b for a, b in zip(want, got)) > 0
 
 
 @pytest.mark.parametrize("variant", ["sampled", "last-max"])
 def test_control_differs_under_anti_affinity(variant):
     seq = [i % 4 for i in range(300)]
-    want = reference.replay(NODES, ANTI, seq)
-    got = reference.replay(NODES, ANTI, seq, variant=variant)
+    want = replay(ANTI, seq)
+    got = replay(ANTI, seq, variant=variant)
     assert sum(a != b for a, b in zip(want, got)) > 0
 
 
 def test_anti_affinity_never_doubles_a_service_on_a_node():
     seq = [i % 4 for i in range(4 * 96 + 8)]
-    got = reference.replay(NODES, ANTI, seq)
+    got = replay(ANTI, seq)
     placed = [(c, n) for c, n in zip(seq, got) if n is not None]
     assert len(set(placed)) == len(placed) == 4 * 96
     assert got[-8:] == [None] * 8  # every node already holds one of each
