@@ -78,8 +78,7 @@ def _after_window(workload, n_parity: int):
         t0 = time.perf_counter()
         want = first_max_decisions(
             nodes, bound,
-            [workload.template.build(f"parity-{i}") for i in range(n_parity)],
-            dict(tpu.enc.node_index))
+            [workload.template.build(f"parity-{i}") for i in range(n_parity)])
         oracle_s = time.perf_counter() - t0
 
         got = bind_more(cs, sched, stage, workload.template, n_parity,

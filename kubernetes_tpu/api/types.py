@@ -13,6 +13,7 @@ wire-compatible in shape with the reference's REST API.
 
 from __future__ import annotations
 
+import re
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
@@ -681,3 +682,21 @@ RESOURCE_PODS = "pods"
 def pod_key(pod: Pod) -> str:
     """namespace/name cache key (reference: framework.GetPodKey)."""
     return f"{pod.metadata.namespace}/{pod.metadata.name}"
+
+
+_DIGIT_RUNS = re.compile(r"(\d+)")
+
+
+def node_order_key(name: str) -> tuple:
+    """THE node order of the program: by name, a run of digits read as
+    the number it writes, so `node-9` stands before `node-10` and
+    `node-99999` before `node-100000`: index order whatever the padding.
+    It is a function of the cluster's state alone, not of the order in
+    which nodes arrived, left and came back. "The first of the maxima"
+    is the first in this order on every path: the encoding's lanes
+    (models/encoding.py), the host snapshot (scheduler/internal/
+    cache.py) and the plain oracle (testing/oracle.py). The name itself
+    breaks a tie between `node-01` and `node-1`."""
+    parts = _DIGIT_RUNS.split(name)
+    parts[1::2] = [int(d) for d in parts[1::2]]
+    return parts, name

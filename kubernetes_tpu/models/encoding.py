@@ -23,6 +23,7 @@ scores stay int64 in [0,100] (interface.go:95). jax x64 must be enabled.
 
 from __future__ import annotations
 
+import bisect
 import functools
 import json
 import math
@@ -169,7 +170,14 @@ class ClusterEncoding:
         self.hard_pod_affinity_weight = hard_pod_affinity_weight
         # authoritative object state (for rebuilds)
         self._nodes: Dict[str, v1.Node] = {}
+        # the live nodes' names in NODE ORDER (api.types.node_order_key),
+        # with their keys beside them for bisect. The invariant of this
+        # class: live lanes, read upwards, name the nodes in this order —
+        # on the incremental paths and after a rebuild alike — so "the
+        # first of the maxima" (the lowest lane, in every kernel) is the
+        # first in node order whatever left or joined in between.
         self._node_order: List[str] = []
+        self._node_keys: List[tuple] = []
         self._pods: Dict[str, Tuple[v1.Pod, str]] = {}  # key -> (pod, node name)
         # vocabularies (shared; ids are permanent)
         self.ns_vocab = Interner()
@@ -196,8 +204,19 @@ class ClusterEncoding:
         self.node_names: List[Optional[str]] = []
         self.pod_index: Dict[str, int] = {}
         self._pod_free: List[int] = []
-        # tombstone lanes available for incremental node adds
+        # tombstone lanes, ascending, and the name each was left by. A
+        # joining node takes one only if it lies between the lanes of the
+        # node's live neighbours in node order (its own old lane does,
+        # unless a later join moved in beside it).
         self._node_free: List[int] = []
+        self._tomb_owner: Dict[int, str] = {}
+        # how the last add_node / remove_node was taken: "own-lane",
+        # "free-lane", "tail-lane" (incremental: the lane is returned),
+        # "shifted" (live rows moved over by a lane to open one where the
+        # name sorts, no pod re-encoded: lanes have moved) or "structural"
+        # (rebuild flagged); "incremental" or "structural" for a leave
+        self.last_join_path: Optional[str] = None
+        self.last_leave_path: Optional[str] = None
         # node names referenced by pods that have NO encoded row (their
         # node was deleted; rebuild skipped them). Re-adding such a name
         # incrementally would miss re-encoding those pods — structural.
@@ -264,7 +283,8 @@ class ClusterEncoding:
         """Full state load (snapshot ingest)."""
         self.version += 1
         self._nodes = {n.metadata.name: n for n in nodes}
-        self._node_order = [n.metadata.name for n in nodes]
+        self._node_keys = sorted(map(v1.node_order_key, self._nodes))
+        self._node_order = [k[1] for k in self._node_keys]
         self._pods = {}
         for p in pods:
             if p.spec.node_name and p.spec.node_name in self._nodes:
@@ -274,26 +294,102 @@ class ClusterEncoding:
     def add_node(self, node: v1.Node) -> Optional[int]:
         """Add (or update) a node. A brand-new node whose vocab needs fit
         the current capacity buckets lands INCREMENTALLY in a free lane
-        (a tombstone from a prior remove, or a pre-padded tail lane from
-        the headroom/reserve sizing): the row is encoded in place, the
+        that keeps live lanes in node order: a tombstone between the
+        lanes of its live neighbours (a returning name's own old lane
+        is one), or, for a name that sorts after every live node, a
+        pre-padded tail lane. The row is encoded in place, the
         n_nodes/img_nodes meta marked for device sync, and the lane
-        index returned so session-level node deltas can ride along.
+        index returned so session-level node deltas can ride along. A
+        free lane is never handed to a name it would put out of order:
+        where none lies where the name sorts, the live rows between that
+        place and the nearest free lane move over by one (_open_lane: no
+        pod re-encoded; returns None, for lanes have moved).
         Updates of existing nodes and anything that would grow a vocab
         bucket or the lane space stay structural (returns None, rebuild
-        flagged) — at 100k nodes the headroom knob is what keeps churn
-        on the incremental path."""
+        flagged). `last_join_path` says which it was."""
         self.version += 1
         name = node.metadata.name
         fresh = name not in self._nodes
+        pos = None
         if fresh:
-            self._node_order.append(name)
+            key = v1.node_order_key(name)
+            pos = bisect.bisect_left(self._node_keys, key)
+            self._node_keys.insert(pos, key)
+            self._node_order.insert(pos, name)
         self._nodes[name] = node
-        lane = self._try_add_node_arrays(node) if fresh else None
-        if lane is None:
+        self.last_join_path = "structural"
+        lane = self._try_add_node_arrays(node, pos) if fresh else None
+        if lane is None and self.last_join_path == "structural":
             self._rebuild_needed = True
         return lane
 
-    def _try_add_node_arrays(self, node: v1.Node) -> Optional[int]:
+    def _lane_in_order(self, name: str, pos: int) -> Tuple[Optional[int], str]:
+        """The free lane `name`, at place `pos` of the node order, may
+        take, and what kind it is. Live lanes stay in node order: the
+        lane lies above the predecessor's and below the successor's."""
+        order, index = self._node_order, self.node_index
+        lo = index[order[pos - 1]] if pos > 0 else -1
+        last = pos + 1 == len(order)
+        hi = len(self.node_names) if last else index[order[pos + 1]]
+        free = self._node_free
+        a, b = bisect.bisect_right(free, lo), bisect.bisect_left(free, hi)
+        if a < b:
+            for lane in free[a:b]:
+                if self._tomb_owner.get(lane) == name:
+                    return lane, "own-lane"
+            return free[a], "free-lane"
+        if last and len(self.node_names) < self._arrays["valid"].shape[0]:
+            return len(self.node_names), "tail-lane"
+        return None, "shifted"
+
+    def _open_lane(self, pos: int) -> Optional[int]:
+        """No lane is free where the name at place `pos` of the node order
+        sorts: shift the live rows between that place and the NEAREST
+        free lane (a tombstone on either side, or the first tail lane)
+        one lane towards it, point their pods at the new lanes, and
+        return the lane that opened. Live lanes stay in node order and no
+        pod is re-encoded (a rebuild walks every pod object: seconds at
+        100k pods; this moves a few dozen rows where tombstones are
+        spread over the lane space). Every lane between has moved: no
+        lane delta can carry that, the caller's session is rebuilt from
+        these arrays. None if no lane is free at all (the capacity
+        ladder decides)."""
+        A = self._arrays
+        names, free = self.node_names, self._node_free
+        lo = self.node_index[self._node_order[pos - 1]] if pos > 0 else -1
+        i = bisect.bisect_left(free, lo)
+        below = free[i - 1] if i else None
+        above = free[i] if i < len(free) else (
+            len(names) if len(names) < A["valid"].shape[0] else None)
+        if below is None and above is None:
+            return None
+        if above is None or (below is not None
+                             and lo - below < above - lo - 1):
+            # rows below+1 .. lo move down: lane lo opens
+            a, b, step = below + 1, lo, -1
+            del free[i - 1]
+            self._tomb_owner.pop(below, None)
+        else:
+            # rows lo+1 .. above-1 move up: lane lo+1 opens
+            a, b, step = lo + 1, above - 1, 1
+            if i < len(free):
+                del free[i]
+                self._tomb_owner.pop(above, None)
+            else:
+                names.append(None)  # the first tail lane
+        for k in self._NODE_ROW_KEYS:
+            A[k][a + step: b + 1 + step] = A[k][a: b + 1]
+        on = A["pvalid"] & (A["pnode"] >= a) & (A["pnode"] <= b)
+        A["pnode"][on] += step
+        self._dirty_pods.update(np.flatnonzero(on).tolist())
+        names[a + step: b + 1 + step] = names[a: b + 1]
+        for lane in range(a + step, b + 1 + step):
+            self.node_index[names[lane]] = lane
+        self._dirty_nodes.update(range(a + min(step, 0), b + 1 + max(step, 0)))
+        return lo if step < 0 else lo + 1
+
+    def _try_add_node_arrays(self, node: v1.Node,
+                             pos: int) -> Optional[int]:
         A = self._arrays
         name = node.metadata.name
         # a name with ghost pods (rows skipped because this node was
@@ -316,13 +412,16 @@ class ClusterEncoding:
         )
         if before != after:
             return None
-        if self._node_free:
-            lane = self._node_free.pop()
-        elif len(self.node_names) < A["valid"].shape[0]:
-            lane = len(self.node_names)
+        lane, path = self._lane_in_order(name, pos)
+        if lane is None:
+            lane = self._open_lane(pos)
+            if lane is None:
+                return None  # lane space exhausted: capacity ladder decides
+        elif lane == len(self.node_names):
             self.node_names.append(None)
         else:
-            return None  # lane space exhausted: capacity ladder decides
+            del self._node_free[bisect.bisect_left(self._node_free, lane)]
+            self._tomb_owner.pop(lane, None)
         self._encode_node_row(lane, node)
         self.node_names[lane] = name
         self.node_index[name] = lane
@@ -330,7 +429,8 @@ class ClusterEncoding:
             A["img_nodes"][iid] += 1
         self._dirty_nodes.add(lane)
         self._dirty_meta = True
-        return lane
+        self.last_join_path = path
+        return None if path == "shifted" else lane
 
     def update_node(self, node: v1.Node) -> None:
         self.add_node(node)
@@ -387,13 +487,18 @@ class ClusterEncoding:
         rows must be dropped too, which only rebuild does."""
         self.version += 1
         node = self._nodes.pop(node_name, None)
-        self._node_order = [n for n in self._node_order if n != node_name]
+        if node is not None:
+            i = bisect.bisect_left(
+                self._node_keys, v1.node_order_key(node_name))
+            del self._node_keys[i], self._node_order[i]
         lane = (
             self._try_remove_node_arrays(node_name, node)
             if node is not None else None
         )
+        self.last_leave_path = "incremental"
         if lane is None:
             self._rebuild_needed = True
+            self.last_leave_path = "structural"
         return lane
 
     def _try_remove_node_arrays(self, node_name: str,
@@ -413,7 +518,8 @@ class ClusterEncoding:
             A[k][lane] = 0
         self.node_index.pop(node_name, None)
         self.node_names[lane] = None
-        self._node_free.append(lane)
+        bisect.insort(self._node_free, lane)
+        self._tomb_owner[lane] = node_name
         self._dirty_nodes.add(lane)
         self._dirty_meta = True
         return lane
@@ -737,6 +843,7 @@ class ClusterEncoding:
         self.node_index = {}
         self.node_names = []
         self._node_free = []
+        self._tomb_owner = {}
         for i, node_name in enumerate(self._node_order):
             self.node_index[node_name] = i
             self.node_names.append(node_name)

@@ -1191,6 +1191,7 @@ class HoistedSession:
             return
         e = len(pods)
         ep = batch_bucket(e, minimum=4)  # pow2: one compile per bucket
+        self.last_delta_shape = (e, ep)
         r = self._carry["requested"].shape[1]
         t_n = self._S["f_pair_cn"].shape[0]
         c_n = self._S["f_pair_cn"].shape[2]

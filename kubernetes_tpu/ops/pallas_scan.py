@@ -77,6 +77,7 @@ restructured as a single accelerator program.
 
 from __future__ import annotations
 
+import fractions
 import functools
 import logging
 import math
@@ -111,6 +112,41 @@ CARRY_KEYS = ("requested", "nzpc", "cnt")
 ADMIT_CHUNK = 8     # specs per prologue launch (one compiled shape)
 WRITE_CHUNK = 64    # rows per table write (one compiled shape per table)
 MAX_QUIRKS = 1024   # balanced float64-quirk states the kernel can list
+# PodTopologySpread's raw score is int(float64(count) * log(size + 2))
+# (scoring.go). With a zone key the count is every matching pod of a zone:
+# tens of thousands, where a float32 product reads one off at a count in
+# ~300 (and the normalised score with it, once zones stand apart by more
+# than a pod: a drained node, a scale-down). The kernel multiplies in
+# int32 limbs instead: the float64 weight of each size as an exact
+# integer W = log(size + 2) * 2**53, in five limbs of 11 bits (the first
+# 12: three integer bits), listed after the balanced quirks in the `bad`
+# scalars. count * limb < 2**30 holds counts below PTS_MAX_COUNT.
+PTS_LIMBS = 5
+PTS_BASE = 1 + 2 * MAX_QUIRKS          # where the limbs start in `bad`
+PTS_MAX_COUNT = 1 << 18
+
+
+@functools.lru_cache(maxsize=None)
+def _spread_limbs() -> np.ndarray:
+    """[VZ + 1, 5] int32: limbs of log(size + 2) * 2**53, most
+    significant first, for size 0..VZ (read-only: one table a process)."""
+    out = np.zeros((VZ + 1, PTS_LIMBS), np.int32)
+    for size in range(VZ + 1):
+        w = int(fractions.Fraction(math.log(size + 2)) * (1 << 53))
+        out[size] = [w >> 44] + [(w >> sh) & 2047 for sh in (33, 22, 11, 0)]
+    out.setflags(write=False)
+    return out
+
+
+def spread_raw_exact(count: np.ndarray, size: int) -> np.ndarray:
+    """What the kernel computes for a zone constraint, in numpy: the
+    limb product below, floor(count * log(size + 2)) without a float."""
+    k = _spread_limbs()[size].astype(np.int64)
+    c = np.asarray(count, np.int64)
+    acc = c * k[4]
+    for j in (3, 2, 1, 0):
+        acc = c * k[j] + (acc >> 11)
+    return acc >> 9
 TOUCH_W = 4         # words per touch entry: count row, pair row, weight, src row
 # pods per kernel loop iteration: a manual unroll that amortizes Mosaic's
 # per-iteration bookkeeping (partial `unroll=` is unsupported by the TPU
@@ -293,6 +329,8 @@ class _Cfg(NamedTuple):
     ipa: bool
     bal_int: bool   # exact int32 balanced (else f32: caps too large)
     interpret: bool
+    pts_int: bool = True   # exact int32 zone-spread raw (else f32: more
+    # pod rows than PTS_MAX_COUNT)
 
 
 # ---------------------------------------------------------------------------
@@ -501,7 +539,13 @@ def _build_kernel(cfg: _Cfg, Bp: int):
             ignored = feasible & (shasall == 0)
             scored_f32 = scored.astype(f32)
             n_scored = jnp.sum(scored_f32)
-            raw = jnp.zeros((1, Np), f32)
+            # raw = sum over the soft constraints of count * weight +
+            # (maxSkew - 1), truncated once: whole lanes in raw_w, the
+            # zone products' fractions in raw_q (31 bits), a per-node
+            # constraint's float32 product in raw_f
+            raw_f = jnp.zeros((1, Np), f32)
+            raw_w = jnp.zeros((1, Np), i32)
+            raw_q = jnp.zeros((1, Np), i32)
             have_s = i32(0)
             for c in range(C):
                 o = L.PS + PS_W * c
@@ -511,8 +555,16 @@ def _build_kernel(cfg: _Cfg, Bp: int):
                     for c2 in range(C):
                         sh = sh + sp(L.SS + c * C + c2) * crow(
                             sp(L.PS + PS_W * c2 + 5))
-                    shf = sh.astype(f32)
-                    perno = sp(o + 4) != 0
+                    on = srow(sp(o + 7)) != 0
+                    skew = sp(o + 1) - 1
+                    zero = jnp.zeros((1, Np), i32)
+
+                    def in_f32(cnt, size):
+                        weight = jnp.max(jnp.log(
+                            jnp.full((1, LANE), size, f32) + f32(2.0)))
+                        return (jnp.where(on, cnt.astype(f32) * weight
+                                          + skew.astype(f32), f32(0.0)),
+                                zero, zero)
 
                     def zone():
                         # zone presence among scored nodes and its
@@ -529,21 +581,43 @@ def _build_kernel(cfg: _Cfg, Bp: int):
                         topo = jnp.sum(p * zval_l)
                         regn = zpn * (srow(sp(o + 6)) != 0)
                         topo = jnp.where(sp(o + 2) != 0, topo, f32(0.0))
-                        return jnp.where(regn > 0, shf, f32(0.0)), topo
+                        cnt = jnp.where(regn > 0, sh, zero)
+                        if not cfg.pts_int:
+                            return in_f32(cnt, topo)
+                        # count * log(size + 2) in int32 limbs of the
+                        # float64 weight (PTS_BASE): exact
+                        b = PTS_BASE + PTS_LIMBS * topo.astype(i32)
+                        acc = cnt * bad_ref[b + 4]
+                        acc = cnt * bad_ref[b + 3] + (acc >> 11)
+                        acc = cnt * bad_ref[b + 2] + (acc >> 11)
+                        q2 = acc & 2047
+                        acc = cnt * bad_ref[b + 1] + (acc >> 11)
+                        q1 = acc & 2047
+                        acc = cnt * bad_ref[b] + (acc >> 11)
+                        # 31 bits of the fraction; what lies below is
+                        # dropped
+                        frac = (((acc & 511) << 22) | (q1 << 11) | q2)
+                        return (jnp.zeros((1, Np), f32),
+                                jnp.where(on, (acc >> 9) + skew, zero),
+                                jnp.where(on, frac, zero))
 
-                    cnt_n, size = jax.lax.cond(
-                        perno, lambda: (shf, n_scored), zone)
-                    weight = jnp.max(jnp.log(
-                        jnp.full((1, LANE), size, f32) + f32(2.0)))
-                    return jnp.where(
-                        srow(sp(o + 7)) != 0,
-                        cnt_n * weight + (sp(o + 1) - 1).astype(f32),
-                        f32(0.0))
+                    # a per-node key counts the pods of one node (a pod
+                    # limit at most): float32 holds that product
+                    return jax.lax.cond(
+                        sp(o + 4) != 0, lambda: in_f32(sh, n_scored), zone)
 
-                raw = raw + jax.lax.cond(
-                    sp(o) != 0, soft, lambda: jnp.zeros((1, Np), f32))
+                rf, rw, rq = jax.lax.cond(
+                    sp(o) != 0, soft,
+                    lambda: (jnp.zeros((1, Np), f32),
+                             jnp.zeros((1, Np), i32),
+                             jnp.zeros((1, Np), i32)))
+                raw_f = raw_f + rf
+                raw_q = raw_q + rq                    # wraps past 2**31:
+                raw_w = raw_w + rw + (raw_q < 0).astype(i32)   # a carry
+                raw_q = raw_q & i32(0x7FFFFFFF)
                 have_s = jnp.maximum(have_s, (sp(o) != 0).astype(i32))
-            raw_i = raw.astype(i32)
+            raw_i = raw_w + (raw_f + raw_q.astype(f32)
+                             * f32(2.0 ** -31)).astype(i32)
             min_r = jnp.min(jnp.where(scored, raw_i, i32(POS_BIG)))
             max_r = jnp.max(jnp.where(scored, raw_i, i32(0)))
             min_r = jnp.where(min_r == POS_BIG, i32(0), min_r)
@@ -934,7 +1008,10 @@ class PallasSession:
             shapes=(self.Tcap, self.PC, self.Np, self.R, self.C, self.RC,
                     self.SRc, self.ZRc, self.K, MAX_QUIRKS),
             weights=tuple(sorted(self.weights.items())),
-            ipa=self.dyn_ipa, bal_int=self._bal_int, interpret=interpret)
+            ipa=self.dyn_ipa, bal_int=self._bal_int, interpret=interpret,
+            # a zone's count is at most the pod rows there are
+            pts_int=int(np.asarray(cluster["pvalid"]).shape[0])
+            < PTS_MAX_COUNT)
         self.admits = 0   # admissions after the build
         # (Bp, "full") -> AOT-compiled executable (None = AOT unavailable,
         # dispatch through jit). Shared between the serving path and the
@@ -1020,7 +1097,8 @@ class PallasSession:
         pairs = np.unique(cap[:, valid].T, axis=0) if valid.any() \
             else np.zeros((0, 2), np.int64)
         grp = np.zeros((SUB, self.Np), np.int32)
-        bad = np.zeros(1 + 2 * MAX_QUIRKS, np.int32)
+        bad = np.zeros(PTS_BASE + PTS_LIMBS * (VZ + 1), np.int32)
+        bad[PTS_BASE:] = _spread_limbs().ravel()
         ok = len(pairs) <= 64 and all(
             MAX_NODE_SCORE * int(cc) * int(cm) < 2 ** 31 for cc, cm in pairs)
         n = 0
@@ -1037,7 +1115,7 @@ class PallasSession:
                 n += len(q)
         if not ok:
             grp[:] = 0
-            bad[:] = 0
+            bad[:PTS_BASE] = 0
             n = 0
         bad[0] = n
         if not build and ok != self._bal_int:
@@ -1788,6 +1866,7 @@ class PallasSession:
                 self._patch_alloc_static(d)
         entries = [e for d in deltas for e in self._delta_entries(d)]
         ep = batch_bucket(len(entries), minimum=8)  # pow2: one compile each
+        self.last_delta_shape = (len(entries), ep)
         rp = self._requested0.shape[0]
         xs = {
             "node": np.zeros(ep, np.int32),

@@ -845,6 +845,7 @@ class ShardedPallasSession:
         from .hoisted import batch_bucket
 
         ep = batch_bucket(len(rows), minimum=8)
+        self.last_delta_shape = (len(rows), ep)
         xs = {
             "node": np.zeros(ep, np.int32),
             "dres": np.zeros((ep, rp), np.int32),

@@ -29,6 +29,7 @@ tests/test_columnar_cache.py and the pipeline-parity A/B.
 
 from __future__ import annotations
 
+import bisect
 import os
 import threading
 import time
@@ -102,6 +103,11 @@ class SchedulerCache:
         # most-recently-updated FIRST — an OrderedDict used as the cache.go
         # doubly-linked node list (move_to_end(last=False) == moveToHead)
         self._nodes: "OrderedDict[str, NodeInfo]" = OrderedDict()
+        # the names that have a Node object, in NODE ORDER
+        # (api.types.node_order_key; the keys beside them for bisect):
+        # the order a snapshot lists its nodes in. The MRU list above
+        # says what changed; it says nothing about where a node stands.
+        self._node_keys: List[tuple] = []
         self._listeners: List[CacheListener] = []
         # snapshot bookkeeping
         self._last_snapshot_generation: Dict[str, int] = {}
@@ -556,6 +562,8 @@ class SchedulerCache:
     def _set_node_locked(self, node: v1.Node) -> NodeInfo:
         name = node.metadata.name
         ni = self._node_info(name)
+        if ni.node is None:
+            bisect.insort(self._node_keys, v1.node_order_key(name))
         ni.set_node(node)
         self._touch(name)
         self._foreign_mutations += 1
@@ -586,6 +594,9 @@ class SchedulerCache:
             ni = self._nodes.pop(node_name, None)
             if ni is None:
                 return
+            if ni.node is not None:
+                del self._node_keys[bisect.bisect_left(
+                    self._node_keys, v1.node_order_key(node_name))]
             self._last_snapshot_generation.pop(node_name, None)
             self._foreign_mutations += 1
             if self._columnar:
@@ -782,9 +793,10 @@ class SchedulerCache:
                     break  # list is MRU-first: the rest are unchanged
                 self._last_snapshot_generation[name] = ni.generation
                 changed = True
-            names_with_node = [
-                n for n, ni in self._nodes.items() if ni.node is not None
-            ]
+            # node order, not the MRU list's: a snapshot of one cluster
+            # state lists its nodes the same way whatever the order they
+            # arrived, left and came back in, as the encoding's lanes do
+            names_with_node = [k[1] for k in self._node_keys]
             if changed or len(snapshot.node_info_list) != len(names_with_node):
                 self._refresh_image_states_locked()
                 new_snap = Snapshot([self._nodes[n] for n in names_with_node])

@@ -243,6 +243,32 @@ session_delta_applies = legacy_registry.register(
         ("kind",),
     )
 )
+node_joins = legacy_registry.register(
+    Counter(
+        "scheduler_node_joins_total",
+        "Nodes added to the cluster encoding, by the path that kept "
+        "live lanes in node order (TPU-build metric): own-lane (a "
+        "returning name took back the lane it left), free-lane (another "
+        "tombstone between its live neighbours' lanes), tail-lane (a "
+        "name after every live node, into the padded tail) are "
+        "incremental; shifted (no free lane where the name sorts: the "
+        "live rows up to the nearest free lane moved over by one, no pod "
+        "re-encoded, the session rebuilt); structural (vocabulary or "
+        "lane space grew, "
+        "or the name was there: the encoding is rebuilt from its "
+        "objects, seconds at 100k pods).",
+        ("path",),
+    )
+)
+node_leaves = legacy_registry.register(
+    Counter(
+        "scheduler_node_leaves_total",
+        "Nodes removed from the cluster encoding (TPU-build metric): "
+        "incremental (a drained node: its lane becomes a tombstone) or "
+        "structural (it still carried pods: the encoding is rebuilt).",
+        ("path",),
+    )
+)
 session_builds = legacy_registry.register(
     Counter(
         "scheduler_tpu_session_builds_total",

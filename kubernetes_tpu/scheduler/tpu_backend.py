@@ -1202,6 +1202,9 @@ class TPUBackend(CacheListener):
         with self._lock:
             self._node_fps[node.metadata.name] = ClusterEncoding.node_fingerprint(node)
             lane = self.enc.add_node(node)
+            from .metrics import node_joins
+
+            node_joins.inc(path=self.enc.last_join_path)
             if not self._queue_node_delta(lane, "node-join"):
                 self._invalidate_session("node-add")
 
@@ -1265,6 +1268,9 @@ class TPUBackend(CacheListener):
         with self._lock:
             self._node_fps.pop(node_name, None)
             lane = self.enc.remove_node(node_name)
+            from .metrics import node_leaves
+
+            node_leaves.inc(path=self.enc.last_leave_path)
             if not self._queue_node_delta(lane, "node-leave"):
                 self._invalidate_session("node-remove")
 
@@ -1436,7 +1442,7 @@ class TPUBackend(CacheListener):
 
         try:
             with tracing.span("queued-delta-apply", "delta-apply",
-                              n=len(deltas), batch=batch):
+                              n=len(deltas), batch=batch) as sp:
                 if devtime.enabled():
                     # measured delta apply: the fused patch launch gets
                     # its own submit->ready interval via an explicit
@@ -1457,6 +1463,12 @@ class TPUBackend(CacheListener):
                         "kernel", _time.perf_counter() - lt.submit)
                 else:
                     self._session.apply_deltas(deltas)
+                # what the launch was shaped by: the carry entries the
+                # deltas came to and the bucket they were padded to (one
+                # compiled program a bucket)
+                entries, bucket = getattr(
+                    self._session, "last_delta_shape", (None, None))
+                sp.set(entries=entries, bucket=bucket)
         except Exception:  # noqa: BLE001 — rebuild is always correct
             logger.warning(
                 "session delta apply failed; falling back to a rebuild",

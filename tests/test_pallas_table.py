@@ -96,10 +96,7 @@ def _names(results):
 def _oracle(nodes, bound, pending, be):
     return first_max_decisions(
         copy.deepcopy(nodes), copy.deepcopy(list(bound)),
-        copy.deepcopy(pending),
-        # lanes are the nodes in set_cluster order (the encoding builds
-        # lazily: node_index is empty before the first batch)
-        {n.metadata.name: i for i, n in enumerate(nodes)})
+        copy.deepcopy(pending))
 
 
 def _rebuilds():

@@ -422,8 +422,7 @@ def _directed_backend(nodes, bound):
 
 def _directed_oracle(nodes, bound, pending):
     return first_max_decisions(
-        copy.deepcopy(nodes), copy.deepcopy(bound), copy.deepcopy(pending),
-        {n.metadata.name: i for i, n in enumerate(nodes)})
+        copy.deepcopy(nodes), copy.deepcopy(bound), copy.deepcopy(pending))
 
 
 def _build_session(kind, cluster, templates, weights):
