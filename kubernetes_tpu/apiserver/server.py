@@ -35,7 +35,7 @@ from typing import Any, Callable, Dict, Iterator, List, Optional, Tuple, Type
 from ..api import types as v1
 from ..api.labels import Selector
 from ..store import kv
-from ..utils import serde, tracing
+from ..utils import selfstats, serde, tracing
 
 
 class APIError(Exception):
@@ -248,6 +248,7 @@ class APIServer:
         mutating_admission: Optional[List[AdmissionFunc]] = None,
         validating_admission: Optional[List[AdmissionFunc]] = None,
     ):
+        selfstats.adopt_heap_policy()
         self.store = store or kv.KVStore()
         if resources is None:
             resources = _default_resources()

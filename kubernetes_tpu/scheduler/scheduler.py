@@ -43,7 +43,7 @@ from ..apiserver.server import APIError, FenceExpired
 from ..client.clientset import Clientset
 from ..client.events import EventRecorder
 from ..client.informer import EventHandler, SharedInformerFactory, meta_namespace_key
-from ..utils import devtime, knobs, serde, tracing
+from ..utils import devtime, knobs, selfstats, serde, tracing
 from . import metrics
 from .core import GenericScheduler, ScheduleResult
 from .framework.interface import Code, CycleState, FitError
@@ -100,6 +100,7 @@ class Scheduler:
         parallelism: int = 16,
         pipeline_depth: int = 2,
     ):
+        selfstats.adopt_heap_policy()
         self.client = clientset
         self.informers = informer_factory
         self.cache = SchedulerCache()
