@@ -249,6 +249,9 @@ class ClusterEncoding:
         # what-if context keys its scratch snapshot off this) compare it
         # instead of re-deriving per use.
         self.version = 0
+        # bumps when node lanes may move (a node joins or leaves, a
+        # rebuild): a lane map built against it stays good while it holds
+        self.lane_version = 0
 
     def reserve(self, pods: int = 0, anti_terms: int = 0,
                 score_terms: int = 0, nodes: int = 0) -> None:
@@ -282,6 +285,7 @@ class ClusterEncoding:
     def set_cluster(self, nodes: List[v1.Node], pods: List[v1.Pod]) -> None:
         """Full state load (snapshot ingest)."""
         self.version += 1
+        self.lane_version += 1
         self._nodes = {n.metadata.name: n for n in nodes}
         self._node_keys = sorted(map(v1.node_order_key, self._nodes))
         self._node_order = [k[1] for k in self._node_keys]
@@ -310,6 +314,7 @@ class ClusterEncoding:
         self.version += 1
         name = node.metadata.name
         fresh = name not in self._nodes
+        self.lane_version += fresh
         pos = None
         if fresh:
             key = v1.node_order_key(name)
@@ -486,6 +491,7 @@ class ClusterEncoding:
         deltas. A node still carrying pods stays structural — its pods'
         rows must be dropped too, which only rebuild does."""
         self.version += 1
+        self.lane_version += 1
         node = self._nodes.pop(node_name, None)
         if node is not None:
             i = bisect.bisect_left(
@@ -762,6 +768,7 @@ class ClusterEncoding:
         # directly; capacity growth triggers here): derived-view caches
         # keyed on `version` must refresh
         self.version += 1
+        self.lane_version += 1
         for node_name in self._node_order:
             self._intern_node_vocabs(self._nodes[node_name])
         pod_infos: Dict[str, PodInfo] = {}

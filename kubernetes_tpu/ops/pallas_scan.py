@@ -841,6 +841,12 @@ def _rescale(requested, nzpc, alloc, k_res, k_nz):
             alloc * k_res[:, None])
 
 
+# the largest _delta_scan entry bucket warmed before a window (the
+# backend's queued-delta backstop, KTPU_MAX_QUEUED_DELTAS)
+DELTA_WARM_MAX = 4096
+_DELTA_WARMED: set = set()  # carry/table shapes whose buckets are warm
+
+
 @functools.partial(jax.jit, donate_argnums=(0,))
 def _delta_scan(carry, srow, xs):
     """Apply cluster-event deltas to the carry in ONE fused launch: each
@@ -2004,3 +2010,36 @@ class PallasSession:
                 self._exec_failed(Bp, "AOT warm compile failed", e)
                 return
             self._exec.setdefault((Bp, "full"), compiled)
+        self._warm_delta_buckets()
+
+    def _warm_delta_buckets(self) -> None:
+        """Run _delta_scan once per entry bucket up to DELTA_WARM_MAX on a
+        throwaway zero carry, so that a burst of pod deletes and adds (a
+        preemption wave's victims, a churn cycle) compiles its bucket
+        here and not inside a window. Once per process per shape; the
+        live carry is never touched."""
+        rp = self._requested0.shape[0]
+        # an admission may donate the live table meanwhile: a zero one
+        srow = self._statics["srow"]
+        srow = jnp.zeros(srow.shape, srow.dtype)
+        key = (self._requested0.shape, self._nzpc0.shape,
+               (self.RC, self.Np), tuple(srow.shape))
+        if key in _DELTA_WARMED:
+            return
+        carry = {k: jnp.zeros(v.shape, v.dtype)
+                 for k, v in self._carry_struct().items()}
+        ep = 8
+        while ep <= DELTA_WARM_MAX and not self._warm_stop.is_set():
+            xs = {"node": np.zeros(ep, np.int32),
+                  "dres": np.zeros((ep, rp), np.int32),
+                  "dnzpc": np.zeros((ep, SUB), np.int32),
+                  "row": np.zeros(ep, np.int32),
+                  "pair": np.zeros(ep, np.int32),
+                  "w": np.zeros(ep, np.int32),
+                  "src": np.full(ep, -1, np.int32)}
+            # the carry is donated: one buffer serves every bucket
+            carry = _delta_scan(carry, srow, {k: jnp.asarray(v)
+                                              for k, v in xs.items()})
+            ep *= 2
+        if ep > DELTA_WARM_MAX:
+            _DELTA_WARMED.add(key)

@@ -429,6 +429,9 @@ class WhatifContext:
         self.dyn_ports = sess._dyn_ports
         self.tp_np = sess._tp_np  # match_matrices_np tables
         self._np_cache: Dict[int, Dict] = {}  # tj -> host-side slices
+        # keys of the pods the view holds (the backend sets it; None:
+        # unknown, a caller then takes the view as holding its books')
+        self.pod_keys: Optional[frozenset] = None
         self.vnp = int(np.asarray(sess._c_static["npair"]).shape[1])
         self._pok_np: Optional[np.ndarray] = None
 

@@ -29,6 +29,7 @@ from ..framework.types import NodeInfo, PodInfo
 
 MIN_CANDIDATE_NODES_PERCENTAGE = 10  # default_preemption.go args default
 MIN_CANDIDATE_NODES_ABSOLUTE = 100
+PRIORITY_OFFSET = 2 ** 31  # math.MaxInt32 + 1, pickOneNodeForPreemption
 
 
 @dataclass
@@ -326,9 +327,10 @@ class DefaultPreemption(fwk.PostFilterPlugin):
             return max((_pod_priority(p) for p in c.victims), default=0)
 
         def sum_priorities(c: Candidate) -> int:
-            # :497 uses priority+MaxInt32+1 per victim to stay positive;
-            # python ints don't overflow, plain sum keeps the same order
-            return sum(_pod_priority(p) for p in c.victims)
+            # :497 adds MaxInt32+1 to each victim's priority: a victim
+            # more weighs more than any priority difference, so among
+            # nodes of equal highest victim priority fewer victims win
+            return sum(_pod_priority(p) + PRIORITY_OFFSET for p in c.victims)
 
         def latest_start_of_highest(c: Candidate) -> float:
             hi = max_priority(c)

@@ -50,6 +50,7 @@ from .plugins.defaultpreemption import (
     DefaultPreemption,
     MIN_CANDIDATE_NODES_ABSOLUTE,
     MIN_CANDIDATE_NODES_PERCENTAGE,
+    PRIORITY_OFFSET,
 )
 
 
@@ -643,7 +644,7 @@ class FastPreemptionPlanner:
         best_mask = alive
         for crit, reverse in (
             (n_pdbv, False),
-            (max_prio, False), (sum_prio, False),
+            (max_prio, False), (sum_prio + PRIORITY_OFFSET * n_vict, False),
             (n_vict, False), (latest, True),
         ):
             vals = np.where(best_mask, crit, np.inf if not reverse else -np.inf)

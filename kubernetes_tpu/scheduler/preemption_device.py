@@ -34,6 +34,7 @@ move from planning).
 from __future__ import annotations
 
 import logging
+import time as _time
 from typing import Dict, List, Optional, Sequence, Set, Tuple
 
 import numpy as np
@@ -93,6 +94,7 @@ class DevicePreemptionPlanner(FastPreemptionPlanner):
         self.backend = backend
         self.eligibility = eligibility or {}
         self.planner_paths: List[str] = []
+        self._prep_s = self._wait_s = 0.0  # the last launch's (whatif span)
 
     # -- wave books: device-side extensions --------------------------------
 
@@ -106,18 +108,25 @@ class DevicePreemptionPlanner(FastPreemptionPlanner):
             # rebuild — the cached device dict is untouched)
             if enc._rebuild_needed or not enc._arrays:
                 enc.rebuild()
-            # pin the encoding epoch the wave books were built against:
-            # concurrent churn (informer threads mutate enc under the
-            # backend lock) bumps enc.version, the what-if context
-            # rebuilds over the REORDERED encoding, and the lane map
-            # below would attribute verdicts to the wrong nodes — the
-            # per-pod launch re-checks this pin and falls a rung instead
-            self._books_version = enc.version
+            # pin the lane layout the wave books were built against: a
+            # node that joins or leaves (or a rebuild) may reorder the
+            # lanes, and the lane map below would attribute verdicts to
+            # the wrong nodes — the per-pod launch re-checks this pin and
+            # falls a rung instead. Pod binds and deletes move no lane
+            self._books_version = enc.lane_version
+        # one what-if view per template for the whole wave: taken once,
+        # it is a fixed point the books' claims are counted against
+        self._ctx: Dict[str, object] = {}
         # memoized per-row-object match tensors: claim lists only grow
         # across a wave, and re-matching EVERY accumulated entry per
         # preemptor is the O(wave^2) trap the base class's running
         # totals exist to avoid (preemption.py _nom_sum comment)
-        self._match_memo: Dict[Tuple[int, int], Tuple] = {}
+        self._match_memo: Dict[Tuple[int, int, int], Tuple] = {}
+        self._slot_memo: Dict[Tuple[int, int], Tuple] = {}
+        self._held_memo: Dict[int, np.ndarray] = {}
+        # running totals of the nominated and claimed-victim tensors
+        self._nom_acc: Dict[Tuple[int, int, int], Dict] = {}
+        self._pre_acc: Dict[Tuple[int, int], Dict] = {}
         # planner (snapshot) node order -> encoding lane
         self._enc_idx = np.array(
             [enc.node_index.get(ni.node.metadata.name, -1)
@@ -140,22 +149,24 @@ class DevicePreemptionPlanner(FastPreemptionPlanner):
         self._v_term: List[List[List[bool]]] = [
             [[] for _ in range(vm)] for _ in range(self.n)
         ]
+        victim_rows = self.backend.victim_rows
         for i in range(self.n):
             for j, slot_pods in enumerate(self._vpods[i]):
                 for vpod in slot_pods:
-                    vec, _nz = enc.pod_row_delta(vpod)
+                    vec, rows = victim_rows(vpod)
                     if vec.shape[0] == R:
                         self._v_enc_req[i, j] += vec
-                    self._v_rows[i][j].append(
-                        self.backend._pod_self_rows(vpod)
-                    )
+                    self._v_rows[i][j].append(rows)
                     self._v_term[i][j].append(
                         vpod.metadata.deletion_timestamp is not None
                     )
+        self.backend.victim_rows_done()
         # claimed victims (earlier in-flight waves): resident in the
         # encoding but already spoken for — every what-if state drains
-        # them, at topology-pair granularity (their groups span nodes)
-        self._pre: List[Tuple[int, Dict, np.ndarray, bool]] = []
+        # them, at topology-pair granularity (their groups span nodes);
+        # the last field is the pod key (the view drains only those it
+        # still holds)
+        self._pre: List[Tuple[int, Dict, np.ndarray, bool, str]] = []
         for i, ni in enumerate(self.nodes):
             lane = int(self._enc_idx[i])
             if lane < 0:
@@ -168,10 +179,15 @@ class DevicePreemptionPlanner(FastPreemptionPlanner):
                     lane, self.backend._pod_self_rows(pi.pod),
                     vec if vec.shape[0] == R else np.zeros(R, np.int64),
                     pi.pod.metadata.deletion_timestamp is not None,
+                    v1.pod_key(pi.pod),
                 ))
         # nominated entries with pod rows (the base class keeps only
-        # request vectors in planner dims); claims append here too
-        self._nom_entries: List[Tuple[int, int, Dict, np.ndarray]] = []
+        # request vectors in planner dims); claims append here too, with
+        # no key: the nominator's entries carry theirs, for a view that
+        # already holds the pod (held by the backend, or bound since)
+        # counts it itself
+        self._nom_entries: List[
+            Tuple[int, int, Dict, np.ndarray, Optional[str]]] = []
         if self.nominator is not None:
             wave_keys = {v1.pod_key(p) for p in wave}
             for i, ni in enumerate(self.nodes):
@@ -185,6 +201,7 @@ class DevicePreemptionPlanner(FastPreemptionPlanner):
                         i, _prio(np_pod),
                         self.backend._pod_self_rows(np_pod),
                         vec if vec.shape[0] == R else np.zeros(R, np.int64),
+                        v1.pod_key(np_pod),
                     ))
 
     def _claim(self, cand: Candidate, pod: v1.Pod, prio: int,
@@ -207,6 +224,7 @@ class DevicePreemptionPlanner(FastPreemptionPlanner):
                         vec if vec.shape[0] == self._enc_r
                         else np.zeros(self._enc_r, np.int64),
                         bool(self._v_term[i][j][m]),
+                        v1.pod_key(vp),
                     ))
         super()._claim(cand, pod, prio, req)
         # the victims just left the books; later what-ifs must drain
@@ -219,6 +237,7 @@ class DevicePreemptionPlanner(FastPreemptionPlanner):
                 i, prio, self.backend._pod_self_rows(pod),
                 vec if vec.shape[0] == self._enc_r
                 else np.zeros(self._enc_r, np.int64),
+                None,
             ))
 
     # -- per-pod rung routing ----------------------------------------------
@@ -240,6 +259,7 @@ class DevicePreemptionPlanner(FastPreemptionPlanner):
                 ) if tracing.enabled() else tracing.NOOP_SPAN
                 with sp:
                     fits, cand = self._plan_one_device(pod, limit)
+                    sp.set(prep_s=self._prep_s, wait_s=self._wait_s)
                 self.fits_now.append(fits)
                 self.planner_paths.append("device")
                 metrics.preemption_planner.inc(path="device")
@@ -274,13 +294,19 @@ class DevicePreemptionPlanner(FastPreemptionPlanner):
         from ..ops.whatif import WhatifUnavailable, slot_bucket
         from .volume_device import VolumeResolutionChanged
 
+        from ..ops.hoisted import template_fingerprint
+
         backend = self.backend
         try:
             enc_pa = backend.pe.encode(pod)
         except VolumeResolutionChanged as e:
             raise WhatifUnavailable(str(e), reason="encode") from e
         pa = {k: v for k, v in enc_pa.items() if not k.startswith("_")}
-        ctx = backend.whatif_context(pa)
+        fp = template_fingerprint(pa)
+        ctx = self._ctx.get(fp)
+        if ctx is None:
+            ctx = self._ctx[fp] = backend.whatif_context(pa)
+        t_prep = _time.perf_counter()
         tj = ctx.template_index(pa)
         nps = ctx.np_slices(tj)
         prio = _prio(pod)
@@ -295,7 +321,7 @@ class DevicePreemptionPlanner(FastPreemptionPlanner):
             # context snapshotted: concurrent churn reorders lanes
             # in-range (capacities are pow2 buckets), so the version
             # pin — not the range check — is the real guard
-            or backend.enc.version != self._books_version
+            or backend.enc.lane_version != self._books_version
         ):
             raise WhatifUnavailable("node table skew vs the encoding",
                                     reason="node-skew")
@@ -306,7 +332,8 @@ class DevicePreemptionPlanner(FastPreemptionPlanner):
         # the fast rung) --------------------------------------------------
         allC = np.arange(self.n)
         violating = self._pdb_violating(allC, prio)        # [n, Vmax]
-        valid_ij = self._valive & (self._vprio < prio)     # [n, Vmax]
+        valid_ij = (self._valive & (self._vprio < prio)    # [n, Vmax]
+                    & self._slot_held(ctx))
         js = self._vsort
         valid_sorted = np.take_along_axis(valid_ij, js, axis=1)
         vio_sorted = np.take_along_axis(violating, js, axis=1)
@@ -336,22 +363,9 @@ class DevicePreemptionPlanner(FastPreemptionPlanner):
         same_key = nps["f_same_key"].astype(np.int32)      # [C, C]
         C_n = same_key.shape[0]
         taa = nps["ipaaa_valid"].shape[0]
-        flat_rows: List[Dict] = []
-        flat_pos: List[Tuple[int, int, int]] = []  # (node, slot, member)
-        for i in range(self.n):
-            for s in range(L):
-                if slot_valid[i, s]:
-                    j = int(slot_j[i, s])
-                    for m, row in enumerate(self._v_rows[i][j]):
-                        flat_rows.append(row)
-                        flat_pos.append((i, s, m))
-        mf_flat, manti_flat, mall_flat = self._match_rows(
-            ctx, nps, tj, flat_rows)
-        # terminating victims never entered the PTS counts (~pterm gate)
-        for b, (i, s, m) in enumerate(flat_pos):
-            if self._v_term[i][int(slot_j[i, s])][m]:
-                mf_flat[b] = 0
-        mfs_flat = mf_flat @ same_key.T                    # [B, C]
+        smfs, smanti, small = self._slot_matches(ctx, nps, tj, same_key)
+        at = (np.arange(self.n)[:, None], slot_j)          # [n, L] slots
+        sv = slot_valid
         v = {
             "valid": np.zeros((Ncap, L), bool),
             "cnt": np.zeros((Ncap, L), np.int64),
@@ -360,16 +374,12 @@ class DevicePreemptionPlanner(FastPreemptionPlanner):
             "manti": np.zeros((Ncap, L, taa), np.int32),
             "mall": np.zeros((Ncap, L), np.int32),
         }
-        for b, (i, s, m) in enumerate(flat_pos):
-            lane = int(lanes[i])
-            j = int(slot_j[i, s])
-            if not v["valid"][lane, s]:
-                v["valid"][lane, s] = True
-                v["cnt"][lane, s] = self._vsize[i, j]
-                v["req"][lane, s] = self._v_enc_req[i, j]
-            v["mfs"][lane, s] += mfs_flat[b]
-            v["manti"][lane, s] += manti_flat[b]
-            v["mall"][lane, s] += mall_flat[b]
+        v["valid"][lanes] = sv
+        v["cnt"][lanes] = np.where(sv, self._vsize[at], 0)
+        v["req"][lanes] = np.where(sv[..., None], self._v_enc_req[at], 0)
+        v["mfs"][lanes] = np.where(sv[..., None], smfs[at], 0)
+        v["manti"][lanes] = np.where(sv[..., None], smanti[at], 0)
+        v["mall"][lanes] = np.where(sv, small[at], 0)
 
         nom = self._nom_tensors(ctx, nps, tj, prio, Ncap, C_n, taa,
                                 same_key)
@@ -380,12 +390,15 @@ class DevicePreemptionPlanner(FastPreemptionPlanner):
             backend.check_whatif_fault()
             metrics.whatif_launches.inc()
             ys = ctx.run(tj, v, nom, pre)
+            t_wait = _time.perf_counter()
+            self._prep_s = t_wait - t_prep
             if not backend._wait_ready(ys, backend.watchdog_timeout):
                 raise DeviceFault("what-if launch exceeded the watchdog",
                                   kind="timeout")
             fits_now = np.asarray(ys["fits_now"])
             base = np.asarray(ys["base"])
             victims_dev = np.asarray(ys["victims"])
+            self._wait_s = _time.perf_counter() - t_wait
         except DeviceFault:
             raise
         except Exception as e:  # noqa: BLE001 — launch-path raise = fault
@@ -440,6 +453,58 @@ class DevicePreemptionPlanner(FastPreemptionPlanner):
 
     # -- host tensor prep helpers ------------------------------------------
 
+    def _slot_held(self, ctx) -> np.ndarray:
+        """[n, Vm]: the slots whose every member the view holds. A victim
+        of the books (the cache's snapshot) whose delete landed before
+        the view was taken is not in the view's carry: evicting it there
+        again would free its room twice. Once per view."""
+        got = self._held_memo.get(id(ctx))
+        if got is None:
+            held = ctx.pod_keys
+            got = np.ones((self.n, max(self._vmax, 1)), bool)
+            if held is not None:
+                for i, slots in enumerate(self._vpods):
+                    for j, members in enumerate(slots):
+                        if any(v1.pod_key(p) not in held for p in members):
+                            got[i, j] = False
+            self._held_memo[id(ctx)] = got
+        return got
+
+    def _slot_matches(self, ctx, nps, tj, same_key):
+        """Per victim slot of the books, its members' match rows against
+        the preemptor's template summed: (mfs [n, Vm, C], manti [n, Vm,
+        TAA], mall [n, Vm]). A claim only takes a slot out of play, so
+        once per view and template for the whole wave."""
+        key = (id(ctx), tj)
+        got = self._slot_memo.get(key)
+        if got is not None:
+            return got
+        vm = max(self._vmax, 1)
+        rows: List[Dict] = []
+        at: List[int] = []
+        term: List[bool] = []
+        for i, slots in enumerate(self._v_rows):
+            for j, members in enumerate(slots):
+                for m, row in enumerate(members):
+                    rows.append(row)
+                    at.append(i * vm + j)
+                    term.append(self._v_term[i][j][m])
+        mf, manti, mall = self._match_rows(ctx, nps, tj, rows)
+        # terminating victims never entered the PTS counts (~pterm gate)
+        mf[np.asarray(term, bool)] = 0
+        idx = np.asarray(at, np.int64)
+        out_mfs = np.zeros((self.n * vm, same_key.shape[0]), np.int32)
+        out_manti = np.zeros((self.n * vm, manti.shape[1]), np.int32)
+        out_mall = np.zeros(self.n * vm, np.int32)
+        np.add.at(out_mfs, idx, mf @ same_key.T)
+        np.add.at(out_manti, idx, manti)
+        np.add.at(out_mall, idx, mall)
+        got = self._slot_memo[key] = (
+            out_mfs.reshape(self.n, vm, -1),
+            out_manti.reshape(self.n, vm, -1),
+            out_mall.reshape(self.n, vm))
+        return got
+
     def _match_rows(self, ctx, nps, tj, rows: List[Optional[Dict]]):
         """(mf [B, C], manti [B, TAA], mall [B]) for a list of pod label
         rows against the preemptor's template. Memoized per (template,
@@ -458,9 +523,10 @@ class DevicePreemptionPlanner(FastPreemptionPlanner):
         mall = np.zeros(B, np.int32)
         if B == 0:
             return mf, manti, mall
+        view = id(ctx)  # a template index names a row of one view only
         miss = [
             b for b, r in enumerate(rows)
-            if (tj, id(r)) not in self._match_memo
+            if (view, tj, id(r)) not in self._match_memo
         ]
         if miss:
             miss_rows = [rows[b] for b in miss]
@@ -472,78 +538,98 @@ class DevicePreemptionPlanner(FastPreemptionPlanner):
                 manti_new = np.zeros((len(miss), taa), np.int32)
                 mall_new = np.zeros(len(miss), np.int32)
             for k, b in enumerate(miss):
-                self._match_memo[(tj, id(rows[b]))] = (
+                self._match_memo[(view, tj, id(rows[b]))] = (
                     mf_new[k], manti_new[k], mall_new[k])
         for b, r in enumerate(rows):
-            mf[b], manti[b], mall[b] = self._match_memo[(tj, id(r))]
+            mf[b], manti[b], mall[b] = self._match_memo[(view, tj, id(r))]
         return mf, manti, mall
 
     def _nom_tensors(self, ctx, nps, tj, prio, Ncap, C_n, taa, same_key):
         """Per-node aggregates of nominated pods with priority >= the
-        preemptor's (framework.go:610's add set), as POSITIVE deltas."""
-        entries = [e for e in self._nom_entries if e[1] >= prio]
-        nom = {
-            "req": np.zeros((Ncap, self._enc_r), np.int64),
-            "cnt": np.zeros(Ncap, np.int64),
-            "mfs": np.zeros((Ncap, C_n), np.int32),
-            "manti": np.zeros((Ncap, taa), np.int32),
-            "mall": np.zeros(Ncap, np.int32),
-            "has_nom": bool(entries),
-        }
-        if not entries:
-            return nom
-        mf, manti, mall = self._match_rows(
-            ctx, nps, tj, [e[2] for e in entries])
-        mfs = mf @ same_key.T
-        for b, (i, _p, _rows, vec) in enumerate(entries):
-            lane = int(self._enc_idx[i])
-            if lane < 0:
-                continue
-            nom["req"][lane] += vec
-            nom["cnt"][lane] += 1
-            nom["mfs"][lane] += mfs[b]
-            nom["manti"][lane] += manti[b]
-            nom["mall"][lane] += mall[b]
+        preemptor's (framework.go:610's add set), as POSITIVE deltas.
+        The entries only grow across a wave (claims append), so running
+        totals per view, template and priority take in the new ones
+        only: a wave of thousands of preemptors is not quadratic."""
+        acc = self._nom_acc.get((id(ctx), tj, prio))
+        if acc is None:
+            acc = self._nom_acc[(id(ctx), tj, prio)] = {
+                "done": 0, "n": 0,
+                "req": np.zeros((Ncap, self._enc_r), np.int64),
+                "cnt": np.zeros(Ncap, np.int64),
+                "mfs": np.zeros((Ncap, C_n), np.int32),
+                "manti": np.zeros((Ncap, taa), np.int32),
+                "mall": np.zeros(Ncap, np.int32),
+            }
+        held = ctx.pod_keys or ()
+        entries = [e for e in self._nom_entries[acc["done"]:]
+                   if e[1] >= prio and e[4] not in held]
+        acc["done"] = len(self._nom_entries)
+        if entries:
+            mf, manti, mall = self._match_rows(
+                ctx, nps, tj, [e[2] for e in entries])
+            lane = self._enc_idx[[e[0] for e in entries]]
+            ok = lane >= 0
+            lane = lane[ok]
+            np.add.at(acc["req"], lane,
+                      np.stack([e[3] for e in entries])[ok])
+            np.add.at(acc["cnt"], lane, 1)
+            np.add.at(acc["mfs"], lane, (mf @ same_key.T)[ok])
+            np.add.at(acc["manti"], lane, manti[ok])
+            np.add.at(acc["mall"], lane, mall[ok])
+            acc["n"] += len(entries)
+        nom = {k: acc[k].copy()
+               for k in ("req", "cnt", "mfs", "manti", "mall")}
+        nom["has_nom"] = acc["n"] > 0
         return nom
 
     def _pre_tensors(self, ctx, nps, tj, Ncap, C_n, taa, same_key):
         """Already-claimed-victim drains, applied to every what-if
         state. Utilization is node-local; PTS/IPA counts drain at
         topology-PAIR granularity because a claimed victim on another
-        node still empties this node's shared groups."""
+        node still empties this node's shared groups. Only the claimed
+        victims the view still holds drain it; running totals as
+        _nom_tensors'."""
         vnp = ctx.vnp
-        pre = {
-            "req": np.zeros((Ncap, self._enc_r), np.int64),
-            "cnt": np.zeros(Ncap, np.int64),
-            "shared": np.zeros((C_n, vnp), np.int32),
-            "anti": np.zeros((taa, vnp), np.int32),
-            "aff": np.zeros(vnp, np.int32),
-            "atot": np.int32(0),
-        }
-        if not self._pre:
-            return pre
-        mf, manti, mall = self._match_rows(
-            ctx, nps, tj, [e[1] for e in self._pre])
-        pair_cn = nps["f_pair_cn"]  # [Ncap, C] for this template
-        pok = ctx.pok_np()
-        anti_keys = nps["ipaaa_key"]
-        aff_keys = nps["ipaa_key"]
-        aff_valid = nps["ipaa_valid"]
-        raw = np.zeros((C_n, vnp), np.int32)
-        for b, (lane, _rows, vec, terminating) in enumerate(self._pre):
-            pre["req"][lane] += vec
-            pre["cnt"][lane] += 1
-            if not terminating:
-                for c in range(C_n):
-                    raw[c, pair_cn[lane, c]] += mf[b, c]
+        acc = self._pre_acc.get((id(ctx), tj))
+        if acc is None:
+            acc = self._pre_acc[(id(ctx), tj)] = {
+                "done": 0,
+                "req": np.zeros((Ncap, self._enc_r), np.int64),
+                "cnt": np.zeros(Ncap, np.int64),
+                "raw": np.zeros((C_n, vnp), np.int32),
+                "anti": np.zeros((taa, vnp), np.int32),
+                "aff": np.zeros(vnp, np.int32),
+            }
+        held = ctx.pod_keys
+        claimed = [e for e in self._pre[acc["done"]:]
+                   if held is None or e[4] in held]
+        acc["done"] = len(self._pre)
+        if claimed:
+            mf, manti, mall = self._match_rows(
+                ctx, nps, tj, [e[1] for e in claimed])
+            pair_cn = nps["f_pair_cn"]  # [Ncap, C] for this template
+            lane = np.array([e[0] for e in claimed], np.int64)
+            np.add.at(acc["req"], lane, np.stack([e[2] for e in claimed]))
+            np.add.at(acc["cnt"], lane, 1)
+            # terminating victims never entered the PTS counts
+            live = ~np.array([e[3] for e in claimed], bool)
+            cs = np.broadcast_to(np.arange(C_n), mf.shape)
+            np.add.at(acc["raw"], (cs[live], pair_cn[lane][live]), mf[live])
             if ctx.dyn_ipa:
-                for t in range(taa):
-                    pre["anti"][t, pok[lane, anti_keys[t]]] += manti[b, t]
-                if mall[b]:
-                    for t in range(aff_valid.shape[0]):
-                        if aff_valid[t]:
-                            pre["aff"][pok[lane, aff_keys[t]]] += 1
-        pre["shared"] = (same_key @ raw).astype(np.int32)
+                pok = ctx.pok_np()
+                anti_keys = nps["ipaaa_key"]
+                aff_keys = nps["ipaa_key"]
+                aff_valid = nps["ipaa_valid"]
+                for b, lb in enumerate(lane):
+                    for t in range(taa):
+                        acc["anti"][t, pok[lb, anti_keys[t]]] += manti[b, t]
+                    if mall[b]:
+                        for t in range(aff_valid.shape[0]):
+                            if aff_valid[t]:
+                                acc["aff"][pok[lb, aff_keys[t]]] += 1
+        pre = {"req": acc["req"].copy(), "cnt": acc["cnt"].copy(),
+               "shared": (same_key @ acc["raw"]).astype(np.int32),
+               "anti": acc["anti"].copy(), "aff": acc["aff"].copy()}
         pre["shared"][:, 0] = 0
         pre["anti"][:, 0] = 0
         pre["aff"][0] = 0

@@ -223,6 +223,8 @@ class Scheduler:
         self._preempt_lock = threading.Lock()
         self._node_waves: Dict[str, Tuple[set, List]] = {}  # node -> (victim keys, infos)
         self._victim_waiters: Dict[str, str] = {}  # victim key -> node
+        # node -> (first registration, victims): the preemption-wait span
+        self._wave_t0: Dict[str, Tuple[float, int]] = {}
         self._inflight_preemptors: set = set()  # pod keys
         self._thread: Optional[threading.Thread] = None
         # device-fault plumbing: the injector seam (None in production),
@@ -457,7 +459,7 @@ class Scheduler:
                 self.queue.move_all_to_active_or_backoff_queue("AssignedPodDelete")
                 self._on_victim_deleted(pod)
             else:
-                self.nominator.delete_nominated_pod_if_exists(pod)
+                self._drop_nomination(pod)
                 self.queue.delete(pod)
                 self._clear_preempt_tracking(pod)
                 # a deleted pod parked at Permit must resolve NOW, not
@@ -1010,6 +1012,11 @@ class Scheduler:
             return True
         if current.metadata.deletion_timestamp is not None:
             return True
+        # bound already: an update that landed while the pod was popped
+        # (its nomination's status patch) queued it again, and the bind
+        # that confirmed its assume has since been seen
+        if current.spec.node_name:
+            return True
         return self.cache.is_assumed_pod(pod)
 
     def _needs_oracle(self, pod: v1.Pod) -> bool:
@@ -1558,12 +1565,17 @@ class Scheduler:
         pod). The per-pod schedule() the redispatch replaces was a
         session teardown + full kernel launch each (r2's preemption
         crawl); the fast planner removes even the redispatch."""
+        # a pod can sit in the queue twice (an update re-adds it while it
+        # is popped): the copy that failed is dropped once the other was
+        # bound or assumed, never planned again
+        failed = [i for i in failed if not self._skip(i.pod)]
         has_post_filter = bool(
             self.framework is not None and self.framework.post_filter_plugins
         )
         min_prio = self.cache.min_pod_priority() if has_post_filter else 0
         redispatch: List = []
         preemptable: List = []
+        nominated: List = []
         for info in failed:
             if self._preemption_in_flight(info.pod):
                 # victims from a previous plan are still dying — park and
@@ -1575,11 +1587,40 @@ class Scheduler:
                 self._record_failure(info, cycle, {})
                 if not self._preemption_in_flight(info.pod):
                     self.queue.activate(info.pod)
+            elif self.framework is not None and (
+                    info.nominated_node or info.pod.status.nominated_node_name):
+                nominated.append(info)
             elif not has_post_filter or (info.pod.spec.priority or 0) <= min_prio:
                 self._record_failure(info, cycle, {})
             else:
                 preemptable.append(info)
+        if nominated:
+            # a nominated preemptor whose victims are all gone can still
+            # fail a launch: it was popped before the last echo, and the
+            # hold on its node (reserve_nominated) counts against it
+            # there. It binds where it was nominated (evaluateNominatedNode)
+            # and is planned again only where that node no longer takes
+            # it. No drain first: launches in flight decided with its
+            # room held, so the node's feasibility does not wait on
+            # their assumes
+            placed = self._place_nominated(nominated)
+            for info in nominated:
+                if id(info) in placed:
+                    continue
+                if not has_post_filter or (
+                        info.pod.spec.priority or 0) <= min_prio:
+                    self._record_failure(info, cycle, {})
+                else:
+                    preemptable.append(info)
         if preemptable:
+            # victims claimed by in-flight waves whose delete echoes
+            # have not landed in the cache yet must not be claimed
+            # again (their capacity is already spoken for by the
+            # claiming preemptor's nominator entry). Read BEFORE the
+            # snapshot: a victim whose echo lands in between is then
+            # claimed and gone, never present and unclaimed
+            with self._preempt_lock:
+                claimed = set(self._victim_waiters)
             self.snapshot = self.cache.update_snapshot(self.snapshot)
             pdbs = self._list_pdbs()
             # a nominated pod's required anti-affinity only matters to a
@@ -1627,12 +1668,6 @@ class Scheduler:
                 else:
                     redispatch.append(info)
             if fast:
-                # victims claimed by in-flight waves whose delete echoes
-                # have not landed in the cache yet must not be claimed
-                # again (their capacity is already spoken for by the
-                # claiming preemptor's nominator entry)
-                with self._preempt_lock:
-                    claimed = set(self._victim_waiters)
                 if use_device:
                     # three-rung planner ladder: device what-if scan ->
                     # numpy fast planner -> oracle redispatch, one shared
@@ -1747,6 +1782,7 @@ class Scheduler:
         run on a worker so the scheduler is already parked on the queue
         when the delete echoes flush the wave back (the r3 serial apply
         held the scheduling thread for the whole wave)."""
+        now = _time.perf_counter()
         for info, cand in items:
             pod = info.pod
             metrics.preemption_attempts.inc()
@@ -1756,12 +1792,8 @@ class Scheduler:
                 f"preempted {len(cand.victims)} pod(s) on node "
                 f"{cand.node_name}",
             )
-            self.nominator.add_nominated_pod(pod, cand.node_name)
+            self._nominate(pod, cand.node_name)
             info.nominated_node = cand.node_name
-            for lower in get_lower_priority_nominated_pods(
-                self.nominator, pod, cand.node_name
-            ):
-                self.nominator.delete_nominated_pod_if_exists(lower)
             # register the victim set on the node's wave, THEN park: the
             # node's preemptors re-activate together when its last
             # claimed victim's delete echoes
@@ -1771,6 +1803,9 @@ class Scheduler:
                 pending, infos = self._node_waves.setdefault(
                     cand.node_name, (set(), [])
                 )
+                # the preemption-wait span: registration -> last echo
+                t0, nv = self._wave_t0.get(cand.node_name, (now, 0))
+                self._wave_t0[cand.node_name] = (t0, nv + len(vkeys))
                 pending |= vkeys
                 infos.append(info)
                 self._inflight_preemptors.add(pkey)
@@ -1916,13 +1951,31 @@ class Scheduler:
                 )
         return extra
 
+    def _nominate(self, pod: v1.Pod, node_name: str) -> None:
+        """PrepareCandidate's nomination (default_preemption.go:690): the
+        nominator, the backend's hold on the node (no launch may hand the
+        freed room to another pod), and lower-priority nominations on
+        that node cleared."""
+        self.nominator.add_nominated_pod(pod, node_name)
+        if self.tpu is not None:
+            self.tpu.reserve_nominated(pod, node_name)
+        for lower in get_lower_priority_nominated_pods(
+            self.nominator, pod, node_name
+        ):
+            self._drop_nomination(lower)
+
+    def _drop_nomination(self, pod: v1.Pod) -> None:
+        self.nominator.delete_nominated_pod_if_exists(pod)
+        if self.tpu is not None:
+            self.tpu.release_nominated(pod)
+
     def _clear_nomination(self, info) -> None:
         """util.ClearNominatedNodeName equivalent: the nomination can no
         longer lead anywhere (no candidate and no fit) — drop it from the
         nominator, the queue bookkeeping, and the API status."""
         pod = info.pod
         info.nominated_node = ""
-        self.nominator.delete_nominated_pod_if_exists(pod)
+        self._drop_nomination(pod)
         if pod.status.nominated_node_name:
             def _clear(pod=pod):
                 try:
@@ -1949,6 +2002,7 @@ class Scheduler:
         capacity they were promised just finished freeing)."""
         key = v1.pod_key(pod)
         ready: List = []
+        t0 = None
         with self._preempt_lock:
             node = self._victim_waiters.pop(key, None)
             if node is None:
@@ -1963,6 +2017,13 @@ class Scheduler:
                 for info in infos:
                     self._inflight_preemptors.discard(v1.pod_key(info.pod))
                 ready = infos
+                t0, n_victims = self._wave_t0.pop(node, (None, 0))
+        if ready and t0 is not None:
+            tracing.RECORDER.record(
+                "preemption-wait", "preemption-wait", t0,
+                _time.perf_counter() - t0,
+                {"victims": n_victims, "preemptors": len(ready),
+                 "node": node})
         for info in ready:
             self.queue.activate(info.pod)
 
@@ -1978,6 +2039,7 @@ class Scheduler:
                 infos[:] = [i for i in infos if v1.pod_key(i.pod) != key]
                 if not infos and not pending:
                     del self._node_waves[node]
+                    self._wave_t0.pop(node, None)
 
     def _preemption_in_flight(self, pod: v1.Pod) -> bool:
         with self._preempt_lock:
@@ -2436,7 +2498,7 @@ class Scheduler:
         )
         # PrepareCandidate (default_preemption.go:690): patch nomination,
         # evict victims, clear lower-priority nominations on that node
-        self.nominator.add_nominated_pod(pod, node_name)
+        self._nominate(pod, node_name)
         try:
             fresh = self.client.pods.get(pod.metadata.name, pod.metadata.namespace)
             fresh.status.nominated_node_name = node_name
@@ -2451,8 +2513,6 @@ class Scheduler:
                 )
             except APIError:
                 pass
-        for lower in get_lower_priority_nominated_pods(self.nominator, pod, node_name):
-            self.nominator.delete_nominated_pod_if_exists(lower)
 
     # -- assume + binding cycle (scheduler.go:359,:540) --------------------
 

@@ -363,6 +363,12 @@ class TPUBackend(CacheListener):
         weakref.finalize(self, _stop_waiters, self._idle_waiters)
         self._whatif_cache: Dict = {}
         self._whatif_cache_version = -1
+        # nominated preemptors held in the encoding: pod key -> node
+        # (reserve_nominated)
+        self._reserved: Dict[str, str] = {}
+        # victim_rows: pod key -> ((pod, vocab widths), row, label rows)
+        self._victim_rows: Dict[str, Tuple] = {}
+        self._victim_rows_next: Dict[str, Tuple] = {}
         # backend-health event hook: the Scheduler wires this to its
         # EventRecorder so ladder demote/promote, supervised-worker
         # restarts and speculation-miss re-drives surface as k8s Events
@@ -924,12 +930,15 @@ class TPUBackend(CacheListener):
                 # carry first (the normal pre-dispatch apply — the
                 # scratch copy must see them); an apply failure falls
                 # through to the encoding path
+                t0 = _time.perf_counter()
                 self._apply_session_deltas_locked()
                 sess = self._session
                 if isinstance(sess, HoistedSession) and fp in sess._fps:
                     ctx = WhatifContext.from_session(
                         sess, self.enc.node_names)
+                    ctx.pod_keys = frozenset(self.enc._pods)
                     self._whatif_cache[("sess",)] = ctx
+                    self._record_whatif_context(t0, "session", ctx)
                     return ctx
             ctx = self._whatif_cache.get(("enc", fp))
             if ctx is not None:
@@ -938,16 +947,30 @@ class TPUBackend(CacheListener):
             # prologue build — carry a consistent host copy out and do
             # the expensive part WITHOUT the lock (dispatch/harvest
             # contend on it); double-checked insert below
+            t0 = _time.perf_counter()
             host = self.enc.host_snapshot()
             node_names = list(self.enc.node_names)
             version = self.enc.version
+            pod_keys = frozenset(self.enc._pods)
         ctx = WhatifContext.from_host_snapshot(host, node_names, pod_arrays,
                                                mesh=self.mesh)
+        # the pods the view holds: a planner drains only those of its
+        # claimed victims, and adds only those of its nominees, that it
+        # does not already hold
+        ctx.pod_keys = pod_keys
+        self._record_whatif_context(t0, "snapshot", ctx)
         with self._lock:
             if (self._whatif_cache_version == version
                     and self.enc.version == version):
                 self._whatif_cache[("enc", fp)] = ctx
         return ctx
+
+    @staticmethod
+    def _record_whatif_context(t0: float, how: str, ctx) -> None:
+        """The `whatif-context` span: one build of a what-if view."""
+        tracing.RECORDER.record(
+            "whatif-context", "whatif-context", t0,
+            _time.perf_counter() - t0, {"how": how, "lanes": ctx.n_lanes})
 
     def gang_feasible(self, pod: v1.Pod, k: int) -> Optional[bool]:
         """Joint co-placement probe for the gang deadlock breaker: can
@@ -1123,9 +1146,53 @@ class TPUBackend(CacheListener):
     #                     changes): the old path — session teardown, full
     #                     rebuild at the next dispatch.
 
+    def reserve_nominated(self, pod: v1.Pod, node_name: str) -> None:
+        """A preemptor nominated on `node_name`: held in the encoding as
+        if placed there until it binds, so that no launch hands the room
+        its victims leave to another pod (the filter with nominated pods,
+        framework.go:610). The cache and the host planners keep it as a
+        nomination; its own bind or release_nominated ends the hold."""
+        with self._lock:
+            key = v1.pod_key(pod)
+            held = self._reserved.get(key)
+            if held == node_name:
+                return
+            if held is not None:
+                self._unreserve_locked(pod, self._reserved.pop(key))
+            if key in self.enc._pods:
+                return  # placed already
+            self._reserved[key] = node_name
+            if not self._queue_pod_delta(
+                pod, node_name, +1,
+                lambda: self.enc.add_pod(pod, node_name),
+            ):
+                self._invalidate_session("foreign-pod-add")
+
+    def release_nominated(self, pod: v1.Pod) -> None:
+        """The nomination ended without a bind (cleared, the pod deleted,
+        outranked): the held room goes back."""
+        with self._lock:
+            node = self._reserved.pop(v1.pod_key(pod), None)
+            if node is not None:
+                self._unreserve_locked(pod, node)
+
+    def _unreserve_locked(self, pod: v1.Pod, node_name: str) -> None:
+        if not self._queue_pod_delta(
+            pod, node_name, -1, lambda: self.enc.remove_pod(pod),
+        ):
+            self._invalidate_session("pod-remove")
+
     def on_add_pod(self, pod: v1.Pod, node_name: str) -> None:
         with self._lock:
             key = (pod.metadata.namespace, pod.metadata.name, node_name)
+            held = self._reserved.pop(v1.pod_key(pod), None)
+            if held == node_name and key not in self._session_assumed:
+                # a nominated preemptor bound where it was held: the
+                # encoding and the session already count it
+                self.enc.swap_pod_object(v1.pod_key(pod), pod, node_name)
+                return
+            if held is not None:
+                self._unreserve_locked(pod, held)
             if key in self._session_assumed:
                 # the cache confirming an assume the session already
                 # applied on-device: host bookkeeping only
@@ -1310,6 +1377,28 @@ class TPUBackend(CacheListener):
         return True
 
     # -- session-delta classification + apply ------------------------------
+
+    def victim_rows(self, pod: v1.Pod) -> Tuple[np.ndarray, Dict]:
+        """(requested-row delta, label rows) of a bound pod for the
+        preemption planner's books, kept from the wave before while the
+        pod object and the vocab widths are the same: a wave's books
+        walk every bound pod of the cluster (20 000 at preemption-5000n).
+        victim_rows_done() drops the pods the wave did not ask for."""
+        enc = self.enc
+        key = v1.pod_key(pod)
+        sig = (id(pod), pod.metadata.resource_version,
+               enc.pod_pair_vocab.capacity,
+               enc.pod_key_vocab.capacity, enc._res_width())
+        got = self._victim_rows.get(key)
+        if got is None or got[0] != sig or enc.volume_hook is not None:
+            vec, _nz = enc.pod_row_delta(pod)
+            got = (sig, vec, self._pod_self_rows(pod))
+        self._victim_rows_next[key] = got
+        return got[1], got[2]
+
+    def victim_rows_done(self) -> None:
+        self._victim_rows, self._victim_rows_next = \
+            self._victim_rows_next, {}
 
     def _pod_self_rows(self, pod: v1.Pod) -> Dict:
         """The pod's label/namespace bit rows at current vocab widths —
@@ -1848,12 +1937,17 @@ class TPUBackend(CacheListener):
         record_assume = self._session_assumed.add
         enc_add = self.enc.add_pod
         append = results.append
+        reserved = self._reserved
         for i, (g, best) in enumerate(zip(pods, decisions)):
             if best < 0:
                 append((g, None))
                 node = None
             else:
                 node = node_names[best]
+                if reserved and v1.pod_key(g) in reserved:
+                    # a held preemptor the launch placed: the hold goes
+                    # (the carry holds the launch's assume)
+                    self._unreserve_locked(g, reserved.pop(v1.pod_key(g)))
                 if live:
                     record_assume(
                         (g.metadata.namespace, g.metadata.name, node)
