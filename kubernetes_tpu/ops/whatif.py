@@ -25,7 +25,11 @@ fused device program per preemptor:
     InterPodAffinity counts) — the sequential greedy the oracle runs,
     node-parallel because nodes' dry runs are independent;
   * nominated pods ride as POSITIVE deltas with the framework's two-pass
-    semantics (framework.go:610: pass with them added AND without).
+    semantics (framework.go:610: pass with them added AND without);
+  * the inputs stay on the device for a wave: a launch donates them and
+    returns them updated, so the planner uploads them whole once per
+    view, template and priority, and then sends each launch one
+    fixed-size delta of the lanes the claims since changed.
 
 Exactness domain: the preemptor may carry pod (anti-)affinity terms and
 topology-spread constraints — the capability the numpy fast planner's
@@ -44,6 +48,7 @@ affinity/spread extension.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Dict, List, Optional
 
 import jax
@@ -112,18 +117,121 @@ def ipa_victim_matches_np(tt: Dict, rows_list: List[Dict]):
 # ---------------------------------------------------------------------------
 # the fused what-if program
 
+# A launch's inputs, by name: victim slots v_* [N, L, ...], nominated
+# load nom_* [N, ...], claimed-victim drains pre_* (pair-level [.., Vnp])
+INPUT_KEYS = (
+    "v_valid", "v_cnt", "v_req", "v_mfs", "v_manti", "v_mall",
+    "nom_req", "nom_cnt", "nom_mfs", "nom_manti", "nom_mall",
+    "pre_req", "pre_cnt", "pre_shared", "pre_anti", "pre_aff", "pre_atot",
+)
+
+# A launch's delta: at most DELTA_LANES lanes per kind (whole victim-slot
+# rows set; nominated and drained rows added) and DELTA_PAIRS topology-
+# pair entries per kind; padding indexes past every axis and is dropped
+DELTA_LANES = 8
+DELTA_PAIRS = 32
+_DROP = np.iinfo(np.int32).max
+
+
+@functools.lru_cache(maxsize=64)
+def _delta_layout(L: int, R: int, C: int, taa: int):
+    """The flat int64 delta vector for inputs of L slots, R resources, C
+    spread classes and TAA anti-affinity terms: {field: (offset,
+    shape)}, in order, and the all-padding vector."""
+    K, P = DELTA_LANES, DELTA_PAIRS
+    fields, pad, off = {}, [], 0
+    for name, shape in (
+        ("v_lane", (K,)), ("v_valid", (K, L)), ("v_cnt", (K, L)),
+        ("v_req", (K, L, R)), ("v_mfs", (K, L, C)),
+        ("v_manti", (K, L, taa)), ("v_mall", (K, L)),
+        ("nom_lane", (K,)), ("nom_req", (K, R)), ("nom_cnt", (K,)),
+        ("nom_mfs", (K, C)), ("nom_manti", (K, taa)), ("nom_mall", (K,)),
+        ("pre_lane", (K,)), ("pre_req", (K, R)), ("pre_cnt", (K,)),
+        ("shared_col", (P,)), ("shared_val", (P, C)),
+        ("anti_t", (P,)), ("anti_col", (P,)), ("anti_val", (P,)),
+        ("aff_col", (P,)), ("aff_val", (P,)),
+        ("atot", (1,)),
+    ):
+        size = math.prod(shape)
+        fields[name] = (off, shape)
+        index = name.endswith(("_lane", "_col"))
+        pad.append(np.full(size, _DROP if index else 0, np.int64))
+        off += size
+    return fields, np.concatenate(pad)
+
+
+def pack_delta(parts: Dict[str, np.ndarray], L: int, R: int, C: int,
+               taa: int) -> np.ndarray:
+    """The flat int64 delta vector of `parts` (each field's leading rows;
+    absent fields and missing rows are padding). Raises ValueError when
+    a field has more rows than its fixed size."""
+    fields, pad = _delta_layout(L, R, C, taa)
+    buf = pad.copy()
+    for name, got in parts.items():
+        off, shape = fields[name]
+        flat = np.asarray(got, np.int64).ravel()
+        if flat.size > math.prod(shape):
+            raise ValueError(f"delta field {name} overflows")
+        buf[off:off + flat.size] = flat
+    return buf
+
+
+def _apply_delta(x: Dict, delta) -> Dict:
+    """The resident inputs with one packed delta applied: victim-slot
+    rows set whole at their lanes, nominated and drained rows added,
+    pair-level drains added at their columns. Padding indexes past the
+    end and is dropped by the scatter."""
+    L, R = x["v_req"].shape[1:]
+    C, taa = x["v_mfs"].shape[2], x["v_manti"].shape[2]
+    fields, _ = _delta_layout(L, R, C, taa)
+    d = {name: delta[off:off + math.prod(shape)].reshape(shape)
+         for name, (off, shape) in fields.items()}
+    x = dict(x)
+
+    def scatter(key, idx, val, how):
+        ref = x[key].at[idx]
+        val = val.astype(x[key].dtype)
+        x[key] = (ref.set(val, mode="drop") if how == "set"
+                  else ref.add(val, mode="drop"))
+
+    v_lane = d["v_lane"].astype(jnp.int32)
+    for k in ("v_valid", "v_cnt", "v_req", "v_mfs", "v_manti", "v_mall"):
+        scatter(k, v_lane, d[k], "set")
+    nom_lane = d["nom_lane"].astype(jnp.int32)
+    for k in ("nom_req", "nom_cnt", "nom_mfs", "nom_manti", "nom_mall"):
+        scatter(k, nom_lane, d[k], "add")
+    pre_lane = d["pre_lane"].astype(jnp.int32)
+    for k in ("pre_req", "pre_cnt"):
+        scatter(k, pre_lane, d[k], "add")
+    scatter("pre_shared", (slice(None), d["shared_col"].astype(jnp.int32)),
+            d["shared_val"].T, "add")
+    scatter("pre_anti", (d["anti_t"].astype(jnp.int32),
+                         d["anti_col"].astype(jnp.int32)),
+            d["anti_val"], "add")
+    scatter("pre_aff", d["aff_col"].astype(jnp.int32), d["aff_val"], "add")
+    x["pre_atot"] = x["pre_atot"] + d["atot"][0].astype(x["pre_atot"].dtype)
+    return x
+
 
 @functools.partial(
-    jax.jit, static_argnames=("tj", "dyn_ipa", "dyn_ports", "has_nom")
+    jax.jit, static_argnames=("tj", "dyn_ipa", "dyn_ports", "has_nom"),
+    donate_argnames=("x",),
 )
-def _whatif_run(
-    S: Dict, c_static: Dict, carry: Dict,
-    v_valid, v_cnt, v_req, v_mfs, v_manti, v_mall,
-    nom_req, nom_cnt, nom_mfs, nom_manti, nom_mall,
-    pre_req, pre_cnt, pre_shared, pre_anti, pre_aff, pre_atot,
-    tj: int = 0, dyn_ipa: bool = False, dyn_ports: bool = False,
-    has_nom: bool = False,
-):
+def _whatif_run(S: Dict, c_static: Dict, carry: Dict, x: Dict, delta,
+                tj: int = 0, dyn_ipa: bool = False, dyn_ports: bool = False,
+                has_nom: bool = False):
+    """One launch: the delta scattered into the device-resident inputs
+    `x` (donated), then the dry run over them. Returns (results, the
+    updated inputs) — the next launch of the same inputs donates those.
+    A launch from freshly uploaded inputs passes an all-padding delta:
+    one program serves both."""
+    x = _apply_delta(x, delta)
+    return _whatif_eval(S, c_static, carry, x, tj, dyn_ipa, dyn_ports,
+                        has_nom), x
+
+
+def _whatif_eval(S: Dict, c_static: Dict, carry: Dict, x: Dict, tj: int,
+                 dyn_ipa: bool, dyn_ports: bool, has_nom: bool):
     """One preemptor's whole dry run: fits_now[N], base feasibility with
     every victim evicted, and the reprieve walk — one launch.
 
@@ -142,6 +250,10 @@ def _whatif_run(
     the member count the pod-count filter must release/re-add per slot.
     Singleton slots pass v_cnt == v_valid, preserving the original
     per-pod arithmetic bit-for-bit."""
+    (v_valid, v_cnt, v_req, v_mfs, v_manti, v_mall,
+     nom_req, nom_cnt, nom_mfs, nom_manti, nom_mall,
+     pre_req, pre_cnt, pre_shared, pre_anti, pre_aff, pre_atot) = (
+        x[k] for k in INPUT_KEYS)
 
     def sel(key):
         return S[key][tj]
@@ -510,45 +622,39 @@ class WhatifContext:
         self._np_cache[tj] = out
         return out
 
-    def run(self, tj: int, v, nom, pre):
-        """Launch the fused what-if program; returns device arrays
-        (caller bounds the wait and decodes). v/nom/pre are dicts of
-        numpy tensors shaped as _whatif_run documents."""
+    def run(self, tj: int, x: Dict, delta: np.ndarray, has_nom: bool,
+            h2d_bytes: int = 0):
+        """Launch the fused what-if program; returns (results, inputs),
+        device arrays (caller bounds the wait and decodes). `x` holds
+        the INPUT_KEYS tensors shaped as _whatif_eval documents: numpy
+        arrays (uploaded here) or the inputs a previous launch returned
+        (donated: unusable afterwards, whatever the launch's outcome);
+        `delta` is pack_delta's vector."""
         from ..utils import devtime
-        sess = self._sess
         if devtime.enabled():
             # Measured path: the launch is synchronous (block_until_ready
             # inside the record window) so submit→ready is device time,
             # not host wall-clock to the first decode. Decision-inert:
             # the caller's watchdog wait then sees an already-ready tree.
-            lt = devtime.launch(
-                "kernel", "whatif", tj=tj,
-                h2d_bytes=devtime.payload_bytes((v, nom, pre)))
-            ys = self._run_impl(tj, v, nom, pre, sess)
+            lt = devtime.launch("kernel", "whatif", tj=tj,
+                                h2d_bytes=h2d_bytes)
+            ys, x = self._run_impl(tj, x, delta, has_nom)
             # ktpu: allow-sync(devtime fence: whatif launch is timed end-to-end inside its measurement window)
             jax.block_until_ready(ys)
             lt.done(d2h_bytes=devtime.payload_bytes(ys))
-            return ys
-        return self._run_impl(tj, v, nom, pre, sess)
+            return ys, x
+        return self._run_impl(tj, x, delta, has_nom)
 
-    def _run_impl(self, tj: int, v, nom, pre, sess):
-        # singleton slots: count == validity (one member per slot)
-        v_cnt = v.get("cnt")
-        if v_cnt is None:
-            v_cnt = np.asarray(v["valid"]).astype(np.int64)
+    def _run_impl(self, tj: int, x: Dict, delta: np.ndarray, has_nom: bool):
+        sess = self._sess
+        # jnp.array, not asarray: the CPU backend would take an aligned
+        # host buffer over without a copy, and the launch donates it
+        x = {k: a if isinstance(a, jax.Array) else jnp.array(a)
+             for k, a in x.items()}
         return _whatif_run(
-            sess._S, sess._c_static, self.carry,
-            jnp.asarray(v["valid"]), jnp.asarray(v_cnt),
-            jnp.asarray(v["req"]), jnp.asarray(v["mfs"]),
-            jnp.asarray(v["manti"]), jnp.asarray(v["mall"]),
-            jnp.asarray(nom["req"]), jnp.asarray(nom["cnt"]),
-            jnp.asarray(nom["mfs"]), jnp.asarray(nom["manti"]),
-            jnp.asarray(nom["mall"]),
-            jnp.asarray(pre["req"]), jnp.asarray(pre["cnt"]),
-            jnp.asarray(pre["shared"]), jnp.asarray(pre["anti"]),
-            jnp.asarray(pre["aff"]), jnp.asarray(pre["atot"]),
+            sess._S, sess._c_static, self.carry, x, delta,
             tj=tj, dyn_ipa=self.dyn_ipa, dyn_ports=self.dyn_ports,
-            has_nom=bool(nom["has_nom"]),
+            has_nom=has_nom,
         )
 
     def gang_fits(self, tj: int, k: int) -> bool:

@@ -341,6 +341,21 @@ whatif_launches = legacy_registry.register(
         (),
     )
 )
+whatif_inputs = legacy_registry.register(
+    Counter(
+        "scheduler_whatif_inputs_total",
+        "How each what-if launch got its inputs onto the device: "
+        "path=delta (reason=resident) sent only the lanes the wave's "
+        "claims changed since the same view, template and priority "
+        "last launched, into inputs kept on the device; path=full "
+        "uploaded them whole, reason=first (that view, template and "
+        "priority's first launch), overflow (more changes than a delta "
+        "holds), fault (the previous launch raised: its donated inputs "
+        "are gone), pdb (a PDB budget of the wave's books moved), off "
+        "(a planner built without resident inputs).",
+        ("path", "reason"),
+    )
+)
 whatif_fallbacks = legacy_registry.register(
     Counter(
         "scheduler_whatif_fallbacks_total",

@@ -76,25 +76,69 @@ def device_eligible(pod: v1.Pod, extenders: Sequence,
     return eviction_invariant_gates(pod)
 
 
+class _Inputs:
+    """One (what-if view, template, priority)'s launch inputs across a
+    wave: running totals of its nominated and claimed-victim tensors,
+    and the device's copy of the whole input set (`x`, None until a
+    launch returns one) with what the host keeps in step with it — the
+    slot order the epilogue reads, the claims it has taken in, and the
+    PDB budgets its violating split was derived from."""
+
+    __slots__ = ("nom", "pre", "x", "lost", "L", "slot_j", "slot_valid",
+                 "slot_vio", "claims", "pdb_allowed")
+
+    def __init__(self, n_lanes: int, R: int, C: int, taa: int, vnp: int):
+        self.nom = {
+            "done": 0, "n": 0,
+            "req": np.zeros((n_lanes, R), np.int64),
+            "cnt": np.zeros(n_lanes, np.int64),
+            "mfs": np.zeros((n_lanes, C), np.int32),
+            "manti": np.zeros((n_lanes, taa), np.int32),
+            "mall": np.zeros(n_lanes, np.int32),
+        }
+        self.pre = {
+            "done": 0,
+            "req": np.zeros((n_lanes, R), np.int64),
+            "cnt": np.zeros(n_lanes, np.int64),
+            "raw": np.zeros((C, vnp), np.int32),
+            "anti": np.zeros((taa, vnp), np.int32),
+            "aff": np.zeros(vnp, np.int32),
+        }
+        self.x: Optional[Dict] = None
+        self.lost = False  # x went to a launch that did not return
+        self.L = 0
+        self.slot_j = self.slot_valid = self.slot_vio = None
+        self.claims = 0
+        self.pdb_allowed: Optional[np.ndarray] = None
+
+
 class DevicePreemptionPlanner(FastPreemptionPlanner):
     """FastPreemptionPlanner books + a device what-if rung.
 
     `eligibility` maps pod_key -> (device_ok, fast_ok) as computed by
     the scheduler's wave partition (one WaveAntiTerms pass); pods
-    missing from the map ride the fast rung (base-class behavior)."""
+    missing from the map ride the fast rung (base-class behavior).
+
+    A launch's inputs stay on the device for the wave: the first launch
+    of a (view, template, priority) uploads them whole, each later one
+    only the lanes the claims since changed (`resident_inputs=False`
+    uploads every launch whole: the parity control)."""
 
     def __init__(self, snapshot, nominator, backend, framework=None,
                  args: Optional[dict] = None,
                  claimed_victims: Optional[Set[str]] = None,
                  pdbs: Optional[Sequence[v1.PodDisruptionBudget]] = None,
-                 eligibility: Optional[Dict[str, Tuple[bool, bool]]] = None):
+                 eligibility: Optional[Dict[str, Tuple[bool, bool]]] = None,
+                 resident_inputs: bool = True):
         super().__init__(snapshot, nominator, framework=framework,
                          args=args, claimed_victims=claimed_victims,
                          pdbs=pdbs)
         self.backend = backend
         self.eligibility = eligibility or {}
+        self.resident_inputs = resident_inputs
         self.planner_paths: List[str] = []
-        self._prep_s = self._wait_s = 0.0  # the last launch's (whatif span)
+        # the last launch's attributes of the `whatif` span
+        self._launch_attrs: Dict[str, object] = {}
 
     # -- wave books: device-side extensions --------------------------------
 
@@ -124,9 +168,10 @@ class DevicePreemptionPlanner(FastPreemptionPlanner):
         self._match_memo: Dict[Tuple[int, int, int], Tuple] = {}
         self._slot_memo: Dict[Tuple[int, int], Tuple] = {}
         self._held_memo: Dict[int, np.ndarray] = {}
-        # running totals of the nominated and claimed-victim tensors
-        self._nom_acc: Dict[Tuple[int, int, int], Dict] = {}
-        self._pre_acc: Dict[Tuple[int, int], Dict] = {}
+        # launch inputs by (view, template, priority), and the planner
+        # rows whose victims each claim took, in claim order
+        self._inputs: Dict[Tuple[int, int, int], _Inputs] = {}
+        self._claimed_at: List[int] = []
         # planner (snapshot) node order -> encoding lane
         self._enc_idx = np.array(
             [enc.node_index.get(ni.node.metadata.name, -1)
@@ -229,6 +274,7 @@ class DevicePreemptionPlanner(FastPreemptionPlanner):
         super()._claim(cand, pod, prio, req)
         # the victims just left the books; later what-ifs must drain
         # them from every state, and the preemptor is nominated load
+        self._claimed_at.append(i)
         self._pre.extend(claimed_rows)
         if lane >= 0:
             enc = self.backend.enc
@@ -259,7 +305,7 @@ class DevicePreemptionPlanner(FastPreemptionPlanner):
                 ) if tracing.enabled() else tracing.NOOP_SPAN
                 with sp:
                     fits, cand = self._plan_one_device(pod, limit)
-                    sp.set(prep_s=self._prep_s, wait_s=self._wait_s)
+                    sp.set(**self._launch_attrs)
                 self.fits_now.append(fits)
                 self.planner_paths.append("device")
                 metrics.preemption_planner.inc(path="device")
@@ -291,7 +337,7 @@ class DevicePreemptionPlanner(FastPreemptionPlanner):
         """One fused what-if launch for this preemptor; returns
         (fits_now, Candidate | None). Raises WhatifUnavailable /
         DeviceFault to fall a rung."""
-        from ..ops.whatif import WhatifUnavailable, slot_bucket
+        from ..ops.whatif import WhatifUnavailable
         from .volume_device import VolumeResolutionChanged
 
         from ..ops.hoisted import template_fingerprint
@@ -326,84 +372,63 @@ class DevicePreemptionPlanner(FastPreemptionPlanner):
             raise WhatifUnavailable("node table skew vs the encoding",
                                     reason="node-skew")
 
-        # -- per-node reprieve slot order: PDB-violating group first,
-        # then the rest, each in MoreImportantPod order (the oracle's
-        # :633-646 walk; the split is host PDB bookkeeping shared with
-        # the fast rung) --------------------------------------------------
-        allC = np.arange(self.n)
-        violating = self._pdb_violating(allC, prio)        # [n, Vmax]
-        valid_ij = (self._valive & (self._vprio < prio)    # [n, Vmax]
-                    & self._slot_held(ctx))
-        js = self._vsort
-        valid_sorted = np.take_along_axis(valid_ij, js, axis=1)
-        vio_sorted = np.take_along_axis(violating, js, axis=1)
-        max_valid = int(valid_sorted.sum(axis=1).max(initial=0))
-        L = slot_bucket(max_valid)
-        order_key = np.where(
-            ~valid_sorted, 2, np.where(vio_sorted, 0, 1)
-        )
-        perm = np.argsort(order_key, axis=1, kind="stable")
-        Lp = min(L, js.shape[1])
-        slot_j = np.take_along_axis(js, perm, axis=1)[:, :Lp]
-        slot_valid = np.take_along_axis(valid_sorted, perm, axis=1)[:, :Lp]
-        slot_vio = np.take_along_axis(vio_sorted, perm, axis=1)[:, :Lp]
-        if Lp < L:  # pad slots to the pow2 bucket
-            pad = L - Lp
-            slot_j = np.concatenate(
-                [slot_j, np.zeros((self.n, pad), slot_j.dtype)], axis=1)
-            slot_valid = np.concatenate(
-                [slot_valid, np.zeros((self.n, pad), bool)], axis=1)
-            slot_vio = np.concatenate(
-                [slot_vio, np.zeros((self.n, pad), bool)], axis=1)
-
-        # -- victim tensors in encoding-lane space: a slot aggregates
-        # its unit's members (per-member match rows summed; request row
-        # is the prebuilt unit sum; cnt carries the member count the
-        # kernel's pod-count filter releases/re-adds per slot) ---------
+        key = (id(ctx), tj, prio)
         same_key = nps["f_same_key"].astype(np.int32)      # [C, C]
         C_n = same_key.shape[0]
         taa = nps["ipaaa_valid"].shape[0]
-        smfs, smanti, small = self._slot_matches(ctx, nps, tj, same_key)
-        at = (np.arange(self.n)[:, None], slot_j)          # [n, L] slots
-        sv = slot_valid
-        v = {
-            "valid": np.zeros((Ncap, L), bool),
-            "cnt": np.zeros((Ncap, L), np.int64),
-            "req": np.zeros((Ncap, L, self._enc_r), np.int64),
-            "mfs": np.zeros((Ncap, L, C_n), np.int32),
-            "manti": np.zeros((Ncap, L, taa), np.int32),
-            "mall": np.zeros((Ncap, L), np.int32),
-        }
-        v["valid"][lanes] = sv
-        v["cnt"][lanes] = np.where(sv, self._vsize[at], 0)
-        v["req"][lanes] = np.where(sv[..., None], self._v_enc_req[at], 0)
-        v["mfs"][lanes] = np.where(sv[..., None], smfs[at], 0)
-        v["manti"][lanes] = np.where(sv[..., None], smanti[at], 0)
-        v["mall"][lanes] = np.where(sv, small[at], 0)
-
-        nom = self._nom_tensors(ctx, nps, tj, prio, Ncap, C_n, taa,
-                                same_key)
-        pre = self._pre_tensors(ctx, nps, tj, Ncap, C_n, taa, same_key)
+        inp = self._inputs.get(key)
+        if inp is None:
+            inp = self._inputs[key] = _Inputs(
+                Ncap, self._enc_r, C_n, taa, ctx.vnp)
+        nom_new = self._nom_take(ctx, nps, tj, prio, inp, same_key)
+        pre_new = self._pre_take(ctx, nps, tj, inp, same_key)
+        why = self._full_reason(inp)
+        # the device's copy goes to this launch (donated): a raise from
+        # here on leaves none, and the next launch uploads whole
+        x, inp.x, inp.lost = inp.x, None, True
+        if why is None:
+            try:
+                delta, n_delta = self._delta(ctx, nps, tj, prio, inp,
+                                             nom_new, pre_new, same_key)
+            except ValueError:  # more changes than the delta holds
+                why = "overflow"
+        if why is not None:
+            x, delta = self._full_inputs(ctx, nps, tj, prio, inp, same_key)
+        inp.claims = len(self._claimed_at)
+        inp.pdb_allowed = self._pdb_allowed.copy()
+        h2d = delta.nbytes + (sum(a.nbytes for a in x.values())
+                              if why is not None else 0)
+        metrics.whatif_inputs.inc(path="full" if why else "delta",
+                                  reason=why or "resident")
 
         # -- the launch ----------------------------------------------------
         try:
             backend.check_whatif_fault()
             metrics.whatif_launches.inc()
-            ys = ctx.run(tj, v, nom, pre)
+            ys, x = ctx.run(tj, x, delta, inp.nom["n"] > 0, h2d_bytes=h2d)
             t_wait = _time.perf_counter()
-            self._prep_s = t_wait - t_prep
             if not backend._wait_ready(ys, backend.watchdog_timeout):
                 raise DeviceFault("what-if launch exceeded the watchdog",
                                   kind="timeout")
             fits_now = np.asarray(ys["fits_now"])
             base = np.asarray(ys["base"])
             victims_dev = np.asarray(ys["victims"])
-            self._wait_s = _time.perf_counter() - t_wait
         except DeviceFault:
             raise
         except Exception as e:  # noqa: BLE001 — launch-path raise = fault
             raise DeviceFault(f"what-if launch raised: {e}",
                               kind="raise") from e
+        if self.resident_inputs:
+            inp.x, inp.lost = x, False
+        self._launch_attrs = {
+            "prep_s": t_wait - t_prep,
+            "wait_s": _time.perf_counter() - t_wait,
+            "inputs": "full" if why else "delta",
+            "h2d_bytes": h2d,
+            "delta_lanes": 0 if why else n_delta,
+        }
+        L, slot_j, slot_valid, slot_vio = (
+            inp.L, inp.slot_j, inp.slot_valid, inp.slot_vio)
 
         # -- epilogue: candidate cut + pick, host-side like the fast
         # rung (snapshot order is the oracle's candidate order) -------------
@@ -544,94 +569,241 @@ class DevicePreemptionPlanner(FastPreemptionPlanner):
             mf[b], manti[b], mall[b] = self._match_memo[(view, tj, id(r))]
         return mf, manti, mall
 
-    def _nom_tensors(self, ctx, nps, tj, prio, Ncap, C_n, taa, same_key):
-        """Per-node aggregates of nominated pods with priority >= the
-        preemptor's (framework.go:610's add set), as POSITIVE deltas.
-        The entries only grow across a wave (claims append), so running
-        totals per view, template and priority take in the new ones
-        only: a wave of thousands of preemptors is not quadratic."""
-        acc = self._nom_acc.get((id(ctx), tj, prio))
-        if acc is None:
-            acc = self._nom_acc[(id(ctx), tj, prio)] = {
-                "done": 0, "n": 0,
-                "req": np.zeros((Ncap, self._enc_r), np.int64),
-                "cnt": np.zeros(Ncap, np.int64),
-                "mfs": np.zeros((Ncap, C_n), np.int32),
-                "manti": np.zeros((Ncap, taa), np.int32),
-                "mall": np.zeros(Ncap, np.int32),
-            }
+    def _nom_take(self, ctx, nps, tj, prio, inp: _Inputs, same_key):
+        """Take the nominated entries appended since `inp`'s last launch
+        into its running totals: per-node aggregates of nominated pods
+        with priority >= the preemptor's (framework.go:610's add set),
+        as POSITIVE deltas, less those the view already holds. The
+        entries only grow across a wave (claims append), so a wave of
+        thousands of preemptors is not quadratic. Returns the new rows:
+        (lanes [k], {field: [k, ...]})."""
+        acc = inp.nom
         held = ctx.pod_keys or ()
         entries = [e for e in self._nom_entries[acc["done"]:]
                    if e[1] >= prio and e[4] not in held]
         acc["done"] = len(self._nom_entries)
-        if entries:
-            mf, manti, mall = self._match_rows(
-                ctx, nps, tj, [e[2] for e in entries])
-            lane = self._enc_idx[[e[0] for e in entries]]
-            ok = lane >= 0
-            lane = lane[ok]
-            np.add.at(acc["req"], lane,
-                      np.stack([e[3] for e in entries])[ok])
-            np.add.at(acc["cnt"], lane, 1)
-            np.add.at(acc["mfs"], lane, (mf @ same_key.T)[ok])
-            np.add.at(acc["manti"], lane, manti[ok])
-            np.add.at(acc["mall"], lane, mall[ok])
-            acc["n"] += len(entries)
-        nom = {k: acc[k].copy()
-               for k in ("req", "cnt", "mfs", "manti", "mall")}
-        nom["has_nom"] = acc["n"] > 0
-        return nom
+        if not entries:
+            return np.zeros(0, np.int64), {}
+        mf, manti, mall = self._match_rows(
+            ctx, nps, tj, [e[2] for e in entries])
+        lane = self._enc_idx[[e[0] for e in entries]]
+        ok = lane >= 0
+        lane = lane[ok]
+        rows = {
+            "req": np.stack([e[3] for e in entries])[ok],
+            "cnt": np.ones(lane.size, np.int64),
+            "mfs": (mf @ same_key.T)[ok],
+            "manti": manti[ok],
+            "mall": mall[ok],
+        }
+        for k, r in rows.items():
+            np.add.at(acc[k], lane, r)
+        acc["n"] += len(entries)
+        return lane, rows
 
-    def _pre_tensors(self, ctx, nps, tj, Ncap, C_n, taa, same_key):
-        """Already-claimed-victim drains, applied to every what-if
-        state. Utilization is node-local; PTS/IPA counts drain at
-        topology-PAIR granularity because a claimed victim on another
-        node still empties this node's shared groups. Only the claimed
-        victims the view still holds drain it; running totals as
-        _nom_tensors'."""
-        vnp = ctx.vnp
-        acc = self._pre_acc.get((id(ctx), tj))
-        if acc is None:
-            acc = self._pre_acc[(id(ctx), tj)] = {
-                "done": 0,
-                "req": np.zeros((Ncap, self._enc_r), np.int64),
-                "cnt": np.zeros(Ncap, np.int64),
-                "raw": np.zeros((C_n, vnp), np.int32),
-                "anti": np.zeros((taa, vnp), np.int32),
-                "aff": np.zeros(vnp, np.int32),
-            }
+    def _pre_take(self, ctx, nps, tj, inp: _Inputs, same_key):
+        """Take the victims claimed since `inp`'s last launch into its
+        running totals of drains, applied to every what-if state.
+        Utilization is node-local; PTS/IPA counts drain at topology-PAIR
+        granularity because a claimed victim on another node still
+        empties this node's shared groups. Only the claimed victims the
+        view still holds drain it. Returns the new drains — (lanes [k],
+        req [k, R]) and the pair entries raw (c, col, val), anti (t,
+        col, val), aff (col, val) — or None."""
+        acc = inp.pre
         held = ctx.pod_keys
         claimed = [e for e in self._pre[acc["done"]:]
                    if held is None or e[4] in held]
         acc["done"] = len(self._pre)
-        if claimed:
-            mf, manti, mall = self._match_rows(
-                ctx, nps, tj, [e[1] for e in claimed])
-            pair_cn = nps["f_pair_cn"]  # [Ncap, C] for this template
-            lane = np.array([e[0] for e in claimed], np.int64)
-            np.add.at(acc["req"], lane, np.stack([e[2] for e in claimed]))
-            np.add.at(acc["cnt"], lane, 1)
-            # terminating victims never entered the PTS counts
-            live = ~np.array([e[3] for e in claimed], bool)
-            cs = np.broadcast_to(np.arange(C_n), mf.shape)
-            np.add.at(acc["raw"], (cs[live], pair_cn[lane][live]), mf[live])
-            if ctx.dyn_ipa:
-                pok = ctx.pok_np()
-                anti_keys = nps["ipaaa_key"]
-                aff_keys = nps["ipaa_key"]
-                aff_valid = nps["ipaa_valid"]
-                for b, lb in enumerate(lane):
-                    for t in range(taa):
-                        acc["anti"][t, pok[lb, anti_keys[t]]] += manti[b, t]
-                    if mall[b]:
-                        for t in range(aff_valid.shape[0]):
-                            if aff_valid[t]:
-                                acc["aff"][pok[lb, aff_keys[t]]] += 1
-        pre = {"req": acc["req"].copy(), "cnt": acc["cnt"].copy(),
-               "shared": (same_key @ acc["raw"]).astype(np.int32),
-               "anti": acc["anti"].copy(), "aff": acc["aff"].copy()}
-        pre["shared"][:, 0] = 0
-        pre["anti"][:, 0] = 0
-        pre["aff"][0] = 0
-        pre["atot"] = np.int32(pre["aff"].sum())
-        return pre
+        if not claimed:
+            return None
+        mf, manti, mall = self._match_rows(
+            ctx, nps, tj, [e[1] for e in claimed])
+        lane = np.array([e[0] for e in claimed], np.int64)
+        req = np.stack([e[2] for e in claimed])
+        np.add.at(acc["req"], lane, req)
+        np.add.at(acc["cnt"], lane, 1)
+        # terminating victims never entered the PTS counts
+        live = ~np.array([e[3] for e in claimed], bool)
+        cs = np.broadcast_to(np.arange(same_key.shape[0]), mf.shape)
+        raw = (cs[live], nps["f_pair_cn"][lane][live], mf[live])
+        np.add.at(acc["raw"], raw[:2], raw[2])
+        anti = aff = None
+        if ctx.dyn_ipa:
+            pok = ctx.pok_np()
+            anti_col = pok[lane[:, None], nps["ipaaa_key"][None, :]]
+            anti_t = np.broadcast_to(np.arange(anti_col.shape[1]),
+                                     anti_col.shape)
+            anti = (anti_t, anti_col, manti)
+            np.add.at(acc["anti"], anti[:2], manti)
+            # a victim matching ALL of the preemptor's affinity terms
+            # drains one count at each valid term's pair on its node
+            aff_col = pok[lane[:, None], nps["ipaa_key"][None, :]]
+            aff_val = ((mall != 0)[:, None]
+                       & nps["ipaa_valid"][None, :].astype(bool))
+            aff = (aff_col, aff_val.astype(np.int32))
+            np.add.at(acc["aff"], aff_col, aff[1])
+        return lane, req, raw, anti, aff
+
+    # -- launch inputs: whole, or the delta since the last launch ----------
+
+    def _full_reason(self, inp: _Inputs) -> Optional[str]:
+        """Why this launch uploads its inputs whole, or None: it may
+        send only what the claims since its last launch changed."""
+        if not self.resident_inputs:
+            return "off"
+        if inp.x is None:
+            return "fault" if inp.lost else "first"
+        if not np.array_equal(inp.pdb_allowed, self._pdb_allowed):
+            # a budget moved: every node's violating split may move
+            return "pdb"
+        return None
+
+    def _slot_order(self, rows: np.ndarray, prio: int, held: np.ndarray,
+                    L: int = 0):
+        """Per-node reprieve slot order of planner rows `rows`:
+        PDB-violating group first, then the rest, each in
+        MoreImportantPod order (the oracle's :633-646 walk; the split is
+        host PDB bookkeeping shared with the fast rung). Returns
+        (slot_j, slot_valid, slot_vio) [len(rows), L] and L: unless
+        given, the pow2 bucket of the most valid slots a row holds."""
+        from ..ops.whatif import slot_bucket
+
+        violating = self._pdb_violating(rows, prio)        # [k, Vmax]
+        valid_ij = (self._valive[rows] & (self._vprio[rows] < prio)
+                    & held[rows])
+        js = self._vsort[rows]
+        valid_sorted = np.take_along_axis(valid_ij, js, axis=1)
+        vio_sorted = np.take_along_axis(violating, js, axis=1)
+        if not L:
+            L = slot_bucket(int(valid_sorted.sum(axis=1).max(initial=0)))
+        order_key = np.where(
+            ~valid_sorted, 2, np.where(vio_sorted, 0, 1)
+        )
+        perm = np.argsort(order_key, axis=1, kind="stable")
+        Lp = min(L, js.shape[1])
+        out = [np.take_along_axis(a, perm, axis=1)[:, :Lp]
+               for a in (js, valid_sorted, vio_sorted)]
+        if Lp < L:  # pad slots to the pow2 bucket
+            out = [np.concatenate(
+                [a, np.zeros((len(rows), L - Lp), a.dtype)], axis=1)
+                for a in out]
+        return (*out, L)
+
+    def _victim_rows(self, ctx, nps, tj, rows, slot_j, slot_valid,
+                     same_key) -> Dict[str, np.ndarray]:
+        """Victim tensors of planner rows `rows` ([k, L, ...]): a slot
+        aggregates its unit's members (per-member match rows summed;
+        request row is the prebuilt unit sum; cnt carries the member
+        count the kernel's pod-count filter releases/re-adds per
+        slot)."""
+        smfs, smanti, small = self._slot_matches(ctx, nps, tj, same_key)
+        at = (rows[:, None], slot_j)
+        sv = slot_valid
+        return {
+            "v_valid": sv,
+            "v_cnt": np.where(sv, self._vsize[at], 0),
+            "v_req": np.where(sv[..., None], self._v_enc_req[at], 0),
+            "v_mfs": np.where(sv[..., None], smfs[at], 0),
+            "v_manti": np.where(sv[..., None], smanti[at], 0),
+            "v_mall": np.where(sv, small[at], 0),
+        }
+
+    def _full_inputs(self, ctx, nps, tj, prio, inp: _Inputs, same_key):
+        """Every input of the launch in encoding-lane space, as host
+        arrays, and an all-padding delta. Records on `inp` the slot
+        order the epilogue reads. A key keeps its L: validity only
+        falls within a wave."""
+        from ..ops.whatif import pack_delta
+
+        rows = np.arange(self.n)
+        L = inp.L if self.resident_inputs else 0
+        *slots, L = self._slot_order(rows, prio, self._slot_held(ctx), L)
+        inp.L, (inp.slot_j, inp.slot_valid, inp.slot_vio) = L, slots
+        lanes, Ncap = self._enc_idx, ctx.n_lanes
+        x = {}
+        for k, r in self._victim_rows(ctx, nps, tj, rows, inp.slot_j,
+                                      inp.slot_valid, same_key).items():
+            x[k] = np.zeros((Ncap,) + r.shape[1:], r.dtype)
+            x[k][lanes] = r
+        for k in ("req", "cnt", "mfs", "manti", "mall"):
+            x["nom_" + k] = inp.nom[k]
+        pre = inp.pre
+        shared = (same_key @ pre["raw"]).astype(np.int32)
+        anti, aff = pre["anti"].copy(), pre["aff"].copy()
+        shared[:, 0] = anti[:, 0] = aff[0] = 0
+        x.update(pre_req=pre["req"], pre_cnt=pre["cnt"], pre_shared=shared,
+                 pre_anti=anti, pre_aff=aff, pre_atot=np.int32(aff.sum()))
+        R, C, taa = self._enc_r, same_key.shape[0], anti.shape[0]
+        return x, pack_delta({}, L, R, C, taa)
+
+    def _delta(self, ctx, nps, tj, prio, inp: _Inputs, nom_new, pre_new,
+               same_key):
+        """What the claims since `inp`'s last launch changed, packed:
+        the whole slot rows of the nodes they took victims from, the
+        nominated rows they added, and the drains of their victims.
+        Returns (delta, lanes it touches); raises ValueError when it
+        outgrows the delta's fixed size."""
+        from ..ops.whatif import pack_delta
+
+        parts: Dict[str, np.ndarray] = {}
+        rows = np.unique(np.asarray(self._claimed_at[inp.claims:], np.int64))
+        if rows.size:
+            slot_j, slot_valid, slot_vio, _ = self._slot_order(
+                rows, prio, self._slot_held(ctx), inp.L)
+            inp.slot_j[rows] = slot_j
+            inp.slot_valid[rows] = slot_valid
+            inp.slot_vio[rows] = slot_vio
+            parts["v_lane"] = self._enc_idx[rows]
+            parts.update(self._victim_rows(ctx, nps, tj, rows, slot_j,
+                                           slot_valid, same_key))
+        touched = [parts.get("v_lane", np.zeros(0, np.int64))]
+        lane, rows_new = nom_new
+        if lane.size:
+            parts["nom_lane"], sums = _sum_by(lane, rows_new)
+            parts.update({"nom_" + k: a for k, a in sums.items()})
+            touched.append(parts["nom_lane"])
+        if pre_new is not None:
+            lane, req, raw, anti, aff = pre_new
+            parts["pre_lane"], sums = _sum_by(
+                lane, {"req": req, "cnt": np.ones(lane.size, np.int64)})
+            parts["pre_req"], parts["pre_cnt"] = sums["req"], sums["cnt"]
+            touched.append(parts["pre_lane"])
+            # shared = same_key @ raw is linear: a raw entry (c, col, v)
+            # adds v * same_key[:, c] at column col. Pair column 0 is
+            # no pair: the launch zeroes it, so no entry lands there
+            c, col, val = (a.ravel() for a in raw)
+            parts["shared_col"], sums = _sum_by(
+                col, {"v": same_key[:, c].T.astype(np.int64)
+                      * val[:, None]}, keep=(val != 0) & (col != 0))
+            parts["shared_val"] = sums["v"]
+            if anti is not None:
+                t, col, val = (a.ravel() for a in anti)
+                code, sums = _sum_by(t * ctx.vnp + col, {"v": val},
+                                     keep=(val != 0) & (col != 0))
+                parts["anti_t"], parts["anti_col"] = divmod(code, ctx.vnp)
+                parts["anti_val"] = sums["v"]
+                col, val = (a.ravel() for a in aff)
+                parts["aff_col"], sums = _sum_by(
+                    col, {"v": val}, keep=(val != 0) & (col != 0))
+                parts["aff_val"] = sums["v"]
+                parts["atot"] = np.array([sums["v"].sum()])
+        n_lanes = np.unique(np.concatenate(touched)).size
+        delta = pack_delta(parts, inp.L, self._enc_r, same_key.shape[0],
+                           nps["ipaaa_valid"].shape[0])
+        return delta, n_lanes
+
+
+def _sum_by(idx: np.ndarray, vals: Dict[str, np.ndarray],
+            keep: Optional[np.ndarray] = None):
+    """Rows of `vals` summed by index, over the entries `keep` marks
+    (all by default): (unique indices, {field: sums})."""
+    if keep is not None:
+        idx = idx[keep]
+        vals = {k: v[keep] for k, v in vals.items()}
+    uniq, inv = np.unique(idx, return_inverse=True)
+    out = {}
+    for k, v in vals.items():
+        out[k] = np.zeros((uniq.size,) + v.shape[1:], v.dtype)
+        np.add.at(out[k], inv, v)
+    return uniq, out

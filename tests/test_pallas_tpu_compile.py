@@ -68,3 +68,43 @@ def test_table_kernel_compiles_for_v5e_at_5000_nodes(one_chip, terms,
     need = pallas_scan._kernel_vmem_bytes(
         sess._statics, sess._carry_struct(), 2048)
     assert pallas_scan._vmem_request(need) < 112 << 20
+
+
+def test_whatif_launch_compiles_for_v5e_at_5000_nodes(one_chip, monkeypatch):
+    """The preemption what-if launch of the bursts cell (5000 nodes, four
+    victims a node), as the second preemptor of a wave makes it:
+    nominated load, a delta into the inputs the first launch left on
+    the device. The chip's compiler must take the inputs as donated
+    (aliased to the outputs), or every launch copies them."""
+    from kubernetes_tpu.api import types as v1
+    from kubernetes_tpu.ops import whatif
+    from kubernetes_tpu.scheduler.framework.snapshot import Snapshot
+    from kubernetes_tpu.scheduler.internal.nominator import PodNominator
+    from kubernetes_tpu.scheduler.preemption_device import (
+        DevicePreemptionPlanner,
+    )
+
+    from .test_preemption_fast import _mk_backend
+    from .test_whatif_resident import _burst
+
+    launches = []
+    run = whatif._whatif_run
+
+    def capture(*args, **kw):
+        launches.append((args, kw))
+        return run(*args, **kw)
+
+    monkeypatch.setattr(whatif, "_whatif_run", capture)
+    nodes, pods, wave = _burst(5000, 2)
+    planner = DevicePreemptionPlanner(
+        Snapshot.from_objects(pods, nodes), PodNominator(),
+        _mk_backend(nodes, pods),
+        eligibility={v1.pod_key(p): (True, False) for p in wave})
+    assert all(planner.plan(wave))
+    args, kw = launches[1]  # its inputs were donated: shapes only
+    assert kw["has_nom"]
+    shapes = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        args)
+    compiled = run.lower(*shapes, **kw).compile()
+    assert "input_output_alias" in compiled.as_text()
