@@ -622,30 +622,13 @@ class WhatifContext:
         self._np_cache[tj] = out
         return out
 
-    def run(self, tj: int, x: Dict, delta: np.ndarray, has_nom: bool,
-            h2d_bytes: int = 0):
+    def run(self, tj: int, x: Dict, delta: np.ndarray, has_nom: bool):
         """Launch the fused what-if program; returns (results, inputs),
         device arrays (caller bounds the wait and decodes). `x` holds
         the INPUT_KEYS tensors shaped as _whatif_eval documents: numpy
         arrays (uploaded here) or the inputs a previous launch returned
         (donated: unusable afterwards, whatever the launch's outcome);
         `delta` is pack_delta's vector."""
-        from ..utils import devtime
-        if devtime.enabled():
-            # Measured path: the launch is synchronous (block_until_ready
-            # inside the record window) so submit→ready is device time,
-            # not host wall-clock to the first decode. Decision-inert:
-            # the caller's watchdog wait then sees an already-ready tree.
-            lt = devtime.launch("kernel", "whatif", tj=tj,
-                                h2d_bytes=h2d_bytes)
-            ys, x = self._run_impl(tj, x, delta, has_nom)
-            # ktpu: allow-sync(devtime fence: whatif launch is timed end-to-end inside its measurement window)
-            jax.block_until_ready(ys)
-            lt.done(d2h_bytes=devtime.payload_bytes(ys))
-            return ys, x
-        return self._run_impl(tj, x, delta, has_nom)
-
-    def _run_impl(self, tj: int, x: Dict, delta: np.ndarray, has_nom: bool):
         sess = self._sess
         # jnp.array, not asarray: the CPU backend would take an aligned
         # host buffer over without a copy, and the launch donates it

@@ -43,6 +43,7 @@ from typing import Dict, List, Optional, Sequence, Set, Tuple
 import numpy as np
 
 from ..api import types as v1
+from ..utils import tracing
 from .framework.interface import CycleState
 from .framework.types import NodeInfo, calculate_resource
 from .plugins.defaultpreemption import (
@@ -199,6 +200,9 @@ class FastPreemptionPlanner:
         # per-distinct-priority caches
         self._lower_sum: Dict[int, np.ndarray] = {}
         self._lower_cnt: Dict[int, np.ndarray] = {}
+        # the `preemption-books` span of the wave being built: _build's
+        # parts are its steps
+        self._books_span = tracing.NOOP_SPAN
 
     # -- wave setup --------------------------------------------------------
 
@@ -422,6 +426,7 @@ class FastPreemptionPlanner:
                         p if self._nom_min_prio is None
                         else min(self._nom_min_prio, p)
                     )
+        self._books_span.step("base")
 
     # -- static node gates (victim-independent filters) --------------------
 
@@ -466,7 +471,9 @@ class FastPreemptionPlanner:
         self.fits_now: List[bool] = []
         if not wave:
             return []
-        self._build(wave)
+        with tracing.span("preemption-books", "preemption-books",
+                          n=len(wave)) as self._books_span:
+            self._build(wave)
         limit = self._num_candidates()
         out: List[Optional[Candidate]] = []
         for pod in wave:

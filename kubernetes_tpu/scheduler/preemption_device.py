@@ -137,13 +137,16 @@ class DevicePreemptionPlanner(FastPreemptionPlanner):
         self.eligibility = eligibility or {}
         self.resident_inputs = resident_inputs
         self.planner_paths: List[str] = []
-        # the last launch's attributes of the `whatif` span
+        # the last launch's attributes of the `whatif` span, and when its
+        # results reached the host
         self._launch_attrs: Dict[str, object] = {}
+        self._t_results = 0.0
 
     # -- wave books: device-side extensions --------------------------------
 
     def _build(self, wave: List[v1.Pod]) -> None:
-        super()._build(wave)
+        super()._build(wave)  # the books' step `base`
+        books = self._books_span
         self.planner_paths = []
         enc = self.backend.enc
         with self.backend._lock:
@@ -178,6 +181,7 @@ class DevicePreemptionPlanner(FastPreemptionPlanner):
              for ni in self.nodes],
             dtype=np.int64,
         )
+        books.step("lanes")
         # victim device rows, dense by (planner node, victim slot): a
         # slot is an eviction UNIT (singleton or whole co-located gang)
         # — its request row is the members' SUM, while label rows and
@@ -206,6 +210,7 @@ class DevicePreemptionPlanner(FastPreemptionPlanner):
                         vpod.metadata.deletion_timestamp is not None
                     )
         self.backend.victim_rows_done()
+        books.step("victims")
         # claimed victims (earlier in-flight waves): resident in the
         # encoding but already spoken for — every what-if state drains
         # them, at topology-pair granularity (their groups span nodes);
@@ -226,6 +231,7 @@ class DevicePreemptionPlanner(FastPreemptionPlanner):
                     pi.pod.metadata.deletion_timestamp is not None,
                     v1.pod_key(pi.pod),
                 ))
+        books.step("claimed")
         # nominated entries with pod rows (the base class keeps only
         # request vectors in planner dims); claims append here too, with
         # no key: the nominator's entries carry theirs, for a view that
@@ -248,6 +254,7 @@ class DevicePreemptionPlanner(FastPreemptionPlanner):
                         vec if vec.shape[0] == R else np.zeros(R, np.int64),
                         v1.pod_key(np_pod),
                     ))
+        books.step("nominated")
 
     def _claim(self, cand: Candidate, pod: v1.Pod, prio: int,
                req: np.ndarray) -> None:
@@ -305,7 +312,10 @@ class DevicePreemptionPlanner(FastPreemptionPlanner):
                 ) if tracing.enabled() else tracing.NOOP_SPAN
                 with sp:
                     fits, cand = self._plan_one_device(pod, limit)
-                    sp.set(**self._launch_attrs)
+                    if sp is not tracing.NOOP_SPAN:
+                        # pick: results on the host -> candidate claimed
+                        sp.set(pick_s=_time.perf_counter() - self._t_results,
+                               **self._launch_attrs)
                 self.fits_now.append(fits)
                 self.planner_paths.append("device")
                 metrics.preemption_planner.inc(path="device")
@@ -405,7 +415,7 @@ class DevicePreemptionPlanner(FastPreemptionPlanner):
         try:
             backend.check_whatif_fault()
             metrics.whatif_launches.inc()
-            ys, x = ctx.run(tj, x, delta, inp.nom["n"] > 0, h2d_bytes=h2d)
+            ys, x = ctx.run(tj, x, delta, inp.nom["n"] > 0)
             t_wait = _time.perf_counter()
             if not backend._wait_ready(ys, backend.watchdog_timeout):
                 raise DeviceFault("what-if launch exceeded the watchdog",
@@ -420,9 +430,10 @@ class DevicePreemptionPlanner(FastPreemptionPlanner):
                               kind="raise") from e
         if self.resident_inputs:
             inp.x, inp.lost = x, False
+        self._t_results = _time.perf_counter()
         self._launch_attrs = {
             "prep_s": t_wait - t_prep,
-            "wait_s": _time.perf_counter() - t_wait,
+            "wait_s": self._t_results - t_wait,
             "inputs": "full" if why else "delta",
             "h2d_bytes": h2d,
             "delta_lanes": 0 if why else n_delta,

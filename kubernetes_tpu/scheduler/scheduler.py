@@ -1037,12 +1037,12 @@ class Scheduler:
         if cycle is None:
             cycle = self.queue.scheduling_cycle
         with tracing.span("prep", "prep", n=len(infos), batch=cycle):
-            todo = self._kernel_pods(infos)
+            todo = self._kernel_pods(infos, cycle)
         if not todo:
             return
         self._dispatch_batch(todo, cycle)
 
-    def _kernel_pods(self, infos: List) -> List:
+    def _kernel_pods(self, infos: List, cycle: int) -> List:
         """The pods of a popped batch that ride the kernel: deleted and
         assumed pods dropped, oracle-only and nominated pods scheduled
         here and now. [] when nothing is left to dispatch."""
@@ -1098,7 +1098,7 @@ class Scheduler:
                 # requirement as the oracle path
                 if not self._drain_or_requeue(todo):
                     return []
-                placed = self._place_nominated(nominated)
+                placed = self._place_nominated_traced(nominated, cycle)
                 if placed:
                     todo = [i for i in todo if id(i) not in placed]
         return todo
@@ -1573,7 +1573,6 @@ class Scheduler:
             self.framework is not None and self.framework.post_filter_plugins
         )
         min_prio = self.cache.min_pod_priority() if has_post_filter else 0
-        redispatch: List = []
         preemptable: List = []
         nominated: List = []
         for info in failed:
@@ -1603,7 +1602,7 @@ class Scheduler:
             # it. No drain first: launches in flight decided with its
             # room held, so the node's feasibility does not wait on
             # their assumes
-            placed = self._place_nominated(nominated)
+            placed = self._place_nominated_traced(nominated, cycle)
             for info in nominated:
                 if id(info) in placed:
                     continue
@@ -1612,153 +1611,175 @@ class Scheduler:
                     self._record_failure(info, cycle, {})
                 else:
                     preemptable.append(info)
-        if preemptable:
-            # victims claimed by in-flight waves whose delete echoes
-            # have not landed in the cache yet must not be claimed
-            # again (their capacity is already spoken for by the
-            # claiming preemptor's nominator entry). Read BEFORE the
-            # snapshot: a victim whose echo lands in between is then
-            # claimed and gone, never present and unclaimed
-            with self._preempt_lock:
-                claimed = set(self._victim_waiters)
-            self.snapshot = self.cache.update_snapshot(self.snapshot)
-            pdbs = self._list_pdbs()
-            # a nominated pod's required anti-affinity only matters to a
-            # preemptor its terms MATCH (the nominated pod is ADDed in
-            # RunFilterPluginsWithNominatedPods) — collect the terms once,
-            # gate per pod
-            from .framework.types import PodInfo as _PI
+        if not preemptable:
+            return
+        with tracing.span("preemption-wave", "preemption-wave",
+                          batch=cycle, n=len(preemptable)) as wsp:
+            redispatch = self._plan_wave(preemptable, cycle, wsp)
+            if redispatch:
+                self._redispatch(redispatch, cycle)
+            wsp.step("redispatch")
 
-            nominated_anti_terms = [
-                t
-                for p in self.nominator.all_nominated_pods()
-                if _has_required_anti_affinity(p)
-                for t in _PI(p).required_anti_affinity_terms
-            ]
-            from .preemption_device import (
-                ORACLE_FALLBACK,
-                DevicePreemptionPlanner,
-                device_eligible,
+    def _plan_wave(self, preemptable: List, cycle: int, wsp) -> List:
+        """Plan a wave's preemptable pods on the planner ladder and
+        register the preemptions; returns the pods for the oracle
+        redispatch. `wsp` is the wave's `preemption-wave` span."""
+        redispatch: List = []
+        # victims claimed by in-flight waves whose delete echoes
+        # have not landed in the cache yet must not be claimed
+        # again (their capacity is already spoken for by the
+        # claiming preemptor's nominator entry). Read BEFORE the
+        # snapshot: a victim whose echo lands in between is then
+        # claimed and gone, never present and unclaimed
+        with self._preempt_lock:
+            claimed = set(self._victim_waiters)
+        self.snapshot = self.cache.update_snapshot(self.snapshot)
+        pdbs = self._list_pdbs()
+        # a nominated pod's required anti-affinity only matters to a
+        # preemptor its terms MATCH (the nominated pod is ADDed in
+        # RunFilterPluginsWithNominatedPods) — collect the terms once,
+        # gate per pod
+        from .framework.types import PodInfo as _PI
+
+        nominated_anti_terms = [
+            t
+            for p in self.nominator.all_nominated_pods()
+            if _has_required_anti_affinity(p)
+            for t in _PI(p).required_anti_affinity_terms
+        ]
+        from .preemption_device import (
+            ORACLE_FALLBACK,
+            DevicePreemptionPlanner,
+            device_eligible,
+        )
+
+        # ONE cluster pass over the pods with required anti-affinity
+        # for the whole wave (satellite of the planner-ladder PR):
+        # fast_eligible used to re-walk them per failed pod
+        anti_terms = fast_preemption.WaveAntiTerms(self.snapshot)
+        wsp.step("snapshot")
+        use_device = self.tpu is not None and self.tpu.whatif_enabled()
+        fast: List = []
+        eligibility: Dict[str, Tuple[bool, bool]] = {}
+        for info in preemptable:
+            pod = info.pod
+            nominated_hit = any(
+                t.matches(pod) for t in nominated_anti_terms
             )
+            fast_ok = not nominated_hit and fast_preemption.fast_eligible(
+                pod, self.snapshot, pdbs, self.extenders,
+                anti_terms=anti_terms,
+            )
+            dev_ok = (
+                use_device
+                and not nominated_hit
+                and device_eligible(pod, self.extenders, anti_terms)
+            )
+            if fast_ok or dev_ok:
+                eligibility[v1.pod_key(pod)] = (dev_ok, fast_ok)
+                fast.append(info)
+            else:
+                redispatch.append(info)
+        wsp.step("eligibility")
+        if not fast:
+            return redispatch
+        if tracing.enabled():
+            wsp.set(keys=[v1.pod_key(i.pod) for i in fast])
+        if use_device:
+            # three-rung planner ladder: device what-if scan ->
+            # numpy fast planner -> oracle redispatch, one shared
+            # set of wave books so rungs never double-claim
+            planner = DevicePreemptionPlanner(
+                self.snapshot, self.nominator, self.tpu,
+                args=self._preemption_args(),
+                claimed_victims=claimed,
+                pdbs=pdbs,
+                eligibility=eligibility,
+            )
+        else:
+            planner = fast_preemption.FastPreemptionPlanner(
+                self.snapshot, self.nominator,
+                args=self._preemption_args(),
+                claimed_victims=claimed,
+                pdbs=pdbs,
+            )
+        with tracing.span("preemption-plan", "planner",
+                          n=len(fast)) as psp:
+            cands = planner.plan([i.pod for i in fast])
+            paths = getattr(planner, "planner_paths", None)
+            if paths and tracing.enabled():
+                mix: Dict[str, int] = {}
+                for p in paths:
+                    mix[p] = mix.get(p, 0) + 1
+                psp.set(**mix)
+                if tracing.RECORDER.pod_level():
+                    for info, path in zip(fast, paths):
+                        tracing.provenance(
+                            v1.pod_key(info.pod), planner=path)
+        wsp.step("plan")
+        preempted: List[Tuple] = []
+        for info, cand, fits in zip(fast, cands, planner.fits_now):
+            if cand is ORACLE_FALLBACK:
+                # mid-wave rung exhaustion (device fault on a pod
+                # the numpy envelope rejects): the oracle rung
+                redispatch.append(info)
+            elif fits:
+                # cluster state moved since the batch dispatched:
+                # the pod fits without preemption — let the
+                # kernel re-evaluate (scores + sequential assume)
+                redispatch.append(info)
+            elif cand is None:
+                # preemption cannot help anymore: a stale
+                # nomination would keep short-circuiting the
+                # batch path for nothing — clear it and take
+                # normal backoff
+                if info.nominated_node or \
+                        info.pod.status.nominated_node_name:
+                    self._clear_nomination(info)
+                self._record_failure(info, cycle, {})
+            else:
+                preempted.append((info, cand))
+        if preempted:
+            self._apply_preemptions(preempted, cycle)
+        wsp.step("register")
+        return redispatch
 
-            # ONE cluster pass over the pods with required anti-affinity
-            # for the whole wave (satellite of the planner-ladder PR):
-            # fast_eligible used to re-walk them per failed pod
-            anti_terms = fast_preemption.WaveAntiTerms(self.snapshot)
-            use_device = self.tpu is not None and self.tpu.whatif_enabled()
-            fast: List = []
-            eligibility: Dict[str, Tuple[bool, bool]] = {}
-            for info in preemptable:
-                pod = info.pod
-                nominated_hit = any(
-                    t.matches(pod) for t in nominated_anti_terms
-                )
-                fast_ok = not nominated_hit and fast_preemption.fast_eligible(
-                    pod, self.snapshot, pdbs, self.extenders,
-                    anti_terms=anti_terms,
-                )
-                dev_ok = (
-                    use_device
-                    and not nominated_hit
-                    and device_eligible(pod, self.extenders, anti_terms)
-                )
-                if fast_ok or dev_ok:
-                    eligibility[v1.pod_key(pod)] = (dev_ok, fast_ok)
-                    fast.append(info)
-                else:
-                    redispatch.append(info)
-            if fast:
-                if use_device:
-                    # three-rung planner ladder: device what-if scan ->
-                    # numpy fast planner -> oracle redispatch, one shared
-                    # set of wave books so rungs never double-claim
-                    planner = DevicePreemptionPlanner(
-                        self.snapshot, self.nominator, self.tpu,
-                        args=self._preemption_args(),
-                        claimed_victims=claimed,
-                        pdbs=pdbs,
-                        eligibility=eligibility,
-                    )
-                else:
-                    planner = fast_preemption.FastPreemptionPlanner(
-                        self.snapshot, self.nominator,
-                        args=self._preemption_args(),
-                        claimed_victims=claimed,
-                        pdbs=pdbs,
-                    )
-                with tracing.span("preemption-plan", "planner",
-                                  n=len(fast)) as psp:
-                    cands = planner.plan([i.pod for i in fast])
-                    paths = getattr(planner, "planner_paths", None)
-                    if paths and tracing.enabled():
-                        mix: Dict[str, int] = {}
-                        for p in paths:
-                            mix[p] = mix.get(p, 0) + 1
-                        psp.set(**mix)
-                        if tracing.RECORDER.pod_level():
-                            for info, path in zip(fast, paths):
-                                tracing.provenance(
-                                    v1.pod_key(info.pod), planner=path)
-                preempted: List[Tuple] = []
-                for info, cand, fits in zip(fast, cands, planner.fits_now):
-                    if cand is ORACLE_FALLBACK:
-                        # mid-wave rung exhaustion (device fault on a pod
-                        # the numpy envelope rejects): the oracle rung
-                        redispatch.append(info)
-                    elif fits:
-                        # cluster state moved since the batch dispatched:
-                        # the pod fits without preemption — let the
-                        # kernel re-evaluate (scores + sequential assume)
-                        redispatch.append(info)
-                    elif cand is None:
-                        # preemption cannot help anymore: a stale
-                        # nomination would keep short-circuiting the
-                        # batch path for nothing — clear it and take
-                        # normal backoff
-                        if info.nominated_node or \
-                                info.pod.status.nominated_node_name:
-                            self._clear_nomination(info)
-                        self._record_failure(info, cycle, {})
-                    else:
-                        preempted.append((info, cand))
-                if preempted:
-                    self._apply_preemptions(preempted, cycle)
-        if redispatch:
-            # ONE batched re-evaluation recovers per-node failure
-            # statuses for every failed pod (the preemption dry-run's
-            # input). A pod that now FITS (state moved since its batch)
-            # binds; the batched evaluation is against one state, so only
-            # the first fit binds directly — later fits re-dispatch
-            # singly to keep sequential-assume semantics (rare: failure
-            # waves mostly stay failed).
-            from .tpu_backend import RETRY_NODE
+    def _redispatch(self, redispatch: List, cycle: int) -> None:
+        """ONE batched re-evaluation recovers per-node failure statuses
+        for every failed pod (the preemption dry-run's input). A pod
+        that now FITS (state moved since its batch) binds; the batched
+        evaluation is against one state, so only the first fit binds
+        directly — later fits re-dispatch singly to keep
+        sequential-assume semantics (rare: failure waves mostly stay
+        failed)."""
+        from .tpu_backend import RETRY_NODE
 
-            bound_once = False
-            for info, (node, statuses) in zip(
-                redispatch, self.tpu.reevaluate([i.pod for i in redispatch])
-            ):
-                if node == RETRY_NODE:
+        bound_once = False
+        for info, (node, statuses) in zip(
+            redispatch, self.tpu.reevaluate([i.pod for i in redispatch])
+        ):
+            if node == RETRY_NODE:
+                self.queue.add(info.pod)
+            elif node is None:
+                self._record_failure(info, cycle, statuses)
+            elif not bound_once:
+                bound_once = True
+                self._assume_and_bind(info.pod, node, info=info)
+            else:
+                try:
+                    r = self.tpu.schedule(info.pod)
+                    self._assume_and_bind(
+                        info.pod, r.suggested_host, info=info
+                    )
+                except FitError as fe:
+                    self._record_failure(
+                        info, cycle, fe.filtered_nodes_statuses
+                    )
+                except DeviceFault:
+                    # retries exhausted inside schedule(): back to
+                    # the queue exactly once; the ladder (already
+                    # fault-counted) decides the next attempt's path
                     self.queue.add(info.pod)
-                elif node is None:
-                    self._record_failure(info, cycle, statuses)
-                elif not bound_once:
-                    bound_once = True
-                    self._assume_and_bind(info.pod, node, info=info)
-                else:
-                    try:
-                        r = self.tpu.schedule(info.pod)
-                        self._assume_and_bind(
-                            info.pod, r.suggested_host, info=info
-                        )
-                    except FitError as fe:
-                        self._record_failure(
-                            info, cycle, fe.filtered_nodes_statuses
-                        )
-                    except DeviceFault:
-                        # retries exhausted inside schedule(): back to
-                        # the queue exactly once; the ladder (already
-                        # fault-counted) decides the next attempt's path
-                        self.queue.add(info.pod)
 
     def _preemption_args(self) -> dict:
         """The DefaultPreemption plugin's candidate-count args, so the
@@ -1819,66 +1840,19 @@ class Scheduler:
 
         extra_victims = self._gang_preemption_closure(items)
 
-        def _effects(items=items, extra_victims=extra_victims):
-            # victims first — their deletion unblocks the preemptors; the
-            # status patch is observability (the in-memory nominated_node
-            # already steers the queue and the placement short-circuit)
-            from ..apiserver.server import NotFound
+        # the `evict` span: the preemptors' keys only with tracing on
+        keys = ([v1.pod_key(info.pod) for info, _ in items]
+                if tracing.enabled() else None)
+        t_submit = _time.perf_counter()
 
-            for info, cand in items:
-                for victim in cand.victims:
-                    try:
-                        self.client.pods.delete(
-                            victim.metadata.name, victim.metadata.namespace,
-                            fence=self._fence,
-                        )
-                    except NotFound:
-                        # already gone — but ONLY resolve the wave here
-                        # if the delete echo has also been processed
-                        # (victim absent from the informer cache);
-                        # otherwise the in-flight echo fires
-                        # _on_victim_deleted itself, and resolving
-                        # early would activate preemptors against a
-                        # cache that still shows the victim
-                        if self.informers.pods().get(
-                            meta_namespace_key(victim)
-                        ) is None:
-                            self._on_victim_deleted(victim)
-                    except APIError:
-                        # transient server error: the victim may still
-                        # be alive — leave the wave pending (the 60s
-                        # leftover flush is the honest fallback)
-                        logger.warning(
-                            "victim delete failed for %s",
-                            v1.pod_key(victim), exc_info=True,
-                        )
-            # gang closure: bound siblings of evicted gang members go
-            # too (whole gangs or none), same echo bookkeeping
-            for victim in extra_victims:
-                try:
-                    self.client.pods.delete(
-                        victim.metadata.name, victim.metadata.namespace,
-                        fence=self._fence,
-                    )
-                except NotFound:
-                    if self.informers.pods().get(
-                        meta_namespace_key(victim)
-                    ) is None:
-                        self._on_victim_deleted(victim)
-                except APIError:
-                    logger.warning(
-                        "gang sibling delete failed for %s",
-                        v1.pod_key(victim), exc_info=True,
-                    )
-            for info, cand in items:
-                try:
-                    fresh = self.client.pods.get(
-                        info.pod.metadata.name, info.pod.metadata.namespace
-                    )
-                    fresh.status.nominated_node_name = cand.node_name
-                    self.client.pods.update_status(fresh, fence=self._fence)
-                except APIError:
-                    pass
+        def _effects():
+            sp = tracing.NOOP_SPAN if keys is None else tracing.span(
+                "evict", "evict", batch=cycle, keys=keys,
+                victims=sum(len(c.victims) for _, c in items)
+                + len(extra_victims),
+                queued_s=_time.perf_counter() - t_submit)
+            with sp:
+                self._preemption_effects(items, extra_victims, sp)
 
         with self._inflight_lock:
             self._inflight += 1
@@ -1888,6 +1862,74 @@ class Scheduler:
             with self._inflight_lock:
                 self._inflight -= 1
             _effects()
+
+    def _preemption_effects(self, items: List[Tuple],
+                            extra_victims: List[v1.Pod], sp) -> None:
+        """The API effects of a registered preemption wave, on a binder
+        thread: victim deletes, gang siblings, nominated-status patches.
+        `sp` is the wave's `evict` span (steps deletes / gang / status)."""
+        # victims first — their deletion unblocks the preemptors; the
+        # status patch is observability (the in-memory nominated_node
+        # already steers the queue and the placement short-circuit)
+        from ..apiserver.server import NotFound
+
+        for info, cand in items:
+            for victim in cand.victims:
+                try:
+                    self.client.pods.delete(
+                        victim.metadata.name, victim.metadata.namespace,
+                        fence=self._fence,
+                    )
+                except NotFound:
+                    # already gone — but ONLY resolve the wave here
+                    # if the delete echo has also been processed
+                    # (victim absent from the informer cache);
+                    # otherwise the in-flight echo fires
+                    # _on_victim_deleted itself, and resolving
+                    # early would activate preemptors against a
+                    # cache that still shows the victim
+                    if self.informers.pods().get(
+                        meta_namespace_key(victim)
+                    ) is None:
+                        self._on_victim_deleted(victim)
+                except APIError:
+                    # transient server error: the victim may still
+                    # be alive — leave the wave pending (the 60s
+                    # leftover flush is the honest fallback)
+                    logger.warning(
+                        "victim delete failed for %s",
+                        v1.pod_key(victim), exc_info=True,
+                    )
+        sp.step("deletes")
+        # gang closure: bound siblings of evicted gang members go
+        # too (whole gangs or none), same echo bookkeeping
+        for victim in extra_victims:
+            try:
+                self.client.pods.delete(
+                    victim.metadata.name, victim.metadata.namespace,
+                    fence=self._fence,
+                )
+            except NotFound:
+                if self.informers.pods().get(
+                    meta_namespace_key(victim)
+                ) is None:
+                    self._on_victim_deleted(victim)
+            except APIError:
+                logger.warning(
+                    "gang sibling delete failed for %s",
+                    v1.pod_key(victim), exc_info=True,
+                )
+        sp.step("gang")
+        for info, cand in items:
+            try:
+                fresh = self.client.pods.get(
+                    info.pod.metadata.name, info.pod.metadata.namespace
+                )
+                fresh.status.nominated_node_name = cand.node_name
+                self.client.pods.update_status(fresh, fence=self._fence)
+            except APIError:
+                pass
+        sp.step("status")
 
     def _gang_preemption_closure(self, items: List[Tuple]) -> List[v1.Pod]:
         """Whole-gangs-or-none eviction closure for a preemption wave.
@@ -2018,12 +2060,13 @@ class Scheduler:
                     self._inflight_preemptors.discard(v1.pod_key(info.pod))
                 ready = infos
                 t0, n_victims = self._wave_t0.pop(node, (None, 0))
-        if ready and t0 is not None:
+        if ready and t0 is not None and tracing.enabled():
             tracing.RECORDER.record(
                 "preemption-wait", "preemption-wait", t0,
                 _time.perf_counter() - t0,
                 {"victims": n_victims, "preemptors": len(ready),
-                 "node": node})
+                 "node": node,
+                 "keys": [v1.pod_key(info.pod) for info in ready]})
         for info in ready:
             self.queue.activate(info.pod)
 
@@ -2081,6 +2124,17 @@ class Scheduler:
             placed.add(id(info))
         if bound:
             self._assume_and_bind_batch(bound)
+        return placed
+
+    def _place_nominated_traced(self, infos: List, batch: int) -> set:
+        """_place_nominated under a `nominated-place` span: `batch` is the
+        cycle of the launch the pods came in, `keys` the pods placed."""
+        with tracing.span("nominated-place", "nominated-place",
+                          batch=batch, n=len(infos)) as sp:
+            placed = self._place_nominated(infos)
+            if tracing.enabled():
+                sp.set(keys=[v1.pod_key(i.pod) for i in infos
+                             if id(i) in placed])
         return placed
 
     def _assume_and_bind_batch(self, bound: List[Tuple],

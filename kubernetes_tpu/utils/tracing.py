@@ -26,7 +26,9 @@ Levels (KTPU_TRACE):
      dur, tid, attrs) into the ring. At most 20 events per dispatched
      batch and 4 per pod (today 18 and 3: the pod's create, the
      informer's ADDED, the Scheduled event's create), empty polls of
-     an idle thread aside; bounded memory.
+     an idle thread aside; a preemptor adds its `whatif` and its
+     node's `preemption-wait`, a failure wave four (wave, plan, books,
+     evict), a batch that places nominated pods one; bounded memory.
   2  per-pod provenance — additionally, every decided pod records a
      provenance event: backend rung, session kind, last build/rebuild
      reason, pallas bucket, speculative chaining, replay/re-drive
@@ -110,7 +112,26 @@ STAGES = (
     "planner",      # preemption planner ladder: the per-WAVE plan span
     "whatif",       # per-pod fused what-if launches (nested inside a
                     # planner span — a separate stage so stage_stats
-                    # never double-counts the wave's wall-clock)
+                    # never double-counts the wave's wall-clock); steps
+                    # prep / wait / pick as attrs prep_s, wait_s, pick_s
+    "whatif-context",  # one what-if view of the cluster built (inside
+                    # the first whatif span of a wave and template)
+    "preemption-wave",  # completion worker, umbrella of one failure
+                    # wave that has preemptable pods: batch, n, keys;
+                    # steps snapshot / eligibility / plan / register /
+                    # redispatch
+    "preemption-books",  # the planner's wave books (_build), inside
+                    # the planner span; steps base / lanes / victims /
+                    # claimed / nominated
+    "evict",        # binder thread: one wave's victim deletes and
+                    # nominated-status patches; queued_s, keys,
+                    # victims; steps deletes / gang / status
+    "preemption-wait",  # one node's preemption: victims registered ->
+                    # the last delete echo; keys of the preemptors it
+                    # activates (starts on one thread, ends on another)
+    "nominated-place",  # a nominated preemptor's feasibility on its
+                    # node, assume and bind hand-off: batch, keys
+    "template-admit",  # a live session taking new pod specs in
     "session",      # session builds / teardowns
     "fault",        # fault + recovery markers (zero-duration events)
     "provenance",   # per-pod provenance records (level 2)
@@ -124,6 +145,7 @@ STAGES = (
 NOT_PIPELINE_WORK = (
     "queue-empty", "paused", "backpressure", "worker-idle", "binder-queue",
     "cycle", "complete", "path", "apiserver", "informer",
+    "preemption-wave",
 )
 
 
@@ -275,7 +297,8 @@ class FlightRecorder:
             return
         seq = next(self._seq)
         ev = (seq, name, stage, t0, dur, threading.get_ident(), attrs)
-        i = seq % self.capacity
+        buf = self._buf  # read once: set_level may replace the ring
+        i = seq % len(buf)
         # monotonic slot guard: a writer descheduled for a full ring
         # revolution between its seq draw and its store must not clobber
         # the newer occupant with its stale record (the check/store pair
@@ -283,9 +306,24 @@ class FlightRecorder:
         # latency" to two adjacent bytecodes — in the worst case one
         # slot briefly holds an older record, which snapshot()'s sort
         # tolerates)
-        cur = self._buf[i]
+        cur = buf[i]
         if cur is None or cur[0] < seq:
-            self._buf[i] = ev
+            buf[i] = ev
+
+    def set_level(self, n: int) -> int:
+        """Set the live level; returns the old one. Switching tracing on
+        reads KTPU_TRACE_CAPACITY again: a launcher that sets it after
+        this module was first imported, and before it turns tracing on,
+        gets the ring it asked for (a smaller ring keeps only the newest
+        events of a run). Nothing is recorded while the level is 0."""
+        old = self.level
+        if not old and n:
+            capacity = max(1, knobs.get_int("KTPU_TRACE_CAPACITY"))
+            if capacity != self.capacity:
+                self.capacity = capacity
+                self._buf = [None] * capacity
+        self.level = int(n)
+        return old
 
     def event(self, name: str, stage: str, **attrs) -> None:
         """Zero-duration marker (fault seams, state transitions)."""
@@ -395,9 +433,9 @@ def enabled() -> bool:
 
 
 def set_level(n: int) -> int:
-    """Set the live trace level (tests, drills); returns the old level."""
-    old, RECORDER.level = RECORDER.level, int(n)
-    return old
+    """Set the live trace level (tests, drills, the benchmark); returns
+    the old level (FlightRecorder.set_level)."""
+    return RECORDER.set_level(n)
 
 
 span = RECORDER.span  # no second call, no second packing of the attrs

@@ -244,6 +244,9 @@ class TestFlightRecorder:
         assert rec.span("a", "dispatch") is tracing.NOOP_SPAN
         assert rec.span("b", "harvest", n=1) is tracing.NOOP_SPAN
         # every stage a trace point can name, the new ones with the old
+        assert {"preemption-wave", "preemption-books", "evict",
+                "preemption-wait", "nominated-place", "whatif-context",
+                "template-admit"} <= set(tracing.STAGES)
         for stage in tracing.STAGES:
             assert rec.span(stage, stage, batch=7) is tracing.NOOP_SPAN
         # and what a site calls on its span is a no-op on the singleton
@@ -255,6 +258,26 @@ class TestFlightRecorder:
         assert rec.snapshot() == []
         assert rec.dump("device-fault-timeout") == []
         assert rec.dump_history == []
+
+    def test_switching_tracing_on_takes_the_capacity_asked_for(
+            self, monkeypatch):
+        """The recorder is built when the module is first imported; a
+        launcher that asks for a larger ring afterwards, before it turns
+        tracing on, must get it, or a long run keeps only its newest
+        events."""
+        rec = tracing.FlightRecorder(capacity=16, level=0)
+        monkeypatch.setenv("KTPU_TRACE_CAPACITY", "64")
+        assert rec.set_level(tracing.TRACE_STAGES) == 0
+        assert rec.capacity == 64
+        for i in range(100):
+            rec.record(f"s{i}", "pop", 0.0, 0.0)
+        assert [e[1] for e in rec.snapshot()][:2] == ["s36", "s37"]
+        # raising a level that is already on keeps the ring and its events
+        monkeypatch.setenv("KTPU_TRACE_CAPACITY", "8")
+        assert rec.set_level(tracing.TRACE_PODS) == tracing.TRACE_STAGES
+        assert rec.capacity == 64 and len(rec.snapshot()) == 64
+        assert rec.set_level(0) == tracing.TRACE_PODS
+        assert rec.capacity == 64
 
     def test_busy_span_records_thread_cpu_below_wall(self):
         def first_span_of_a_recorder(stage, body):
