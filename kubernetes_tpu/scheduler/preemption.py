@@ -53,10 +53,7 @@ from .plugins.defaultpreemption import (
     MIN_CANDIDATE_NODES_PERCENTAGE,
     PRIORITY_OFFSET,
 )
-
-
-def _prio(pod: v1.Pod) -> int:
-    return pod.spec.priority or 0
+from .wave_books import Row, WaveBooks, _prio, fill, walk
 
 
 class WaveAntiTerms:
@@ -150,9 +147,6 @@ def eviction_invariant_gates(pod: v1.Pod) -> bool:
     return True
 
 
-_PRIO_SENTINEL = np.iinfo(np.int64).max  # padding rows never match `< prio`
-
-
 class FastPreemptionPlanner:
     """Plans preemption for a wave of failed pods against one snapshot.
 
@@ -160,13 +154,18 @@ class FastPreemptionPlanner:
     cpu (milli), memory, ephemeral storage, pod count, plus any scalar
     resource a wave pod requests. Victim bookkeeping tracks the same
     dims. All arrays are [D, N] int64.
+
+    `books` holds each node's part of the books from one wave to the
+    next (wave_books.py); an empty WaveBooks builds every node afresh.
     """
 
     def __init__(self, snapshot, nominator, framework=None,
                  args: Optional[dict] = None,
                  claimed_victims: Optional[Set[str]] = None,
-                 pdbs: Optional[Sequence[v1.PodDisruptionBudget]] = None):
+                 pdbs: Optional[Sequence[v1.PodDisruptionBudget]] = None,
+                 books: Optional[WaveBooks] = None):
         self.snapshot = snapshot
+        self.books = books if books is not None else WaveBooks()
         self.nominator = nominator
         self.framework = framework
         self.pdbs = list(pdbs or [])
@@ -228,130 +227,109 @@ class FastPreemptionPlanner:
             scalars.update(res.scalar_resources)
         self._dims = dims + sorted(scalars)
         D, N = len(self._dims), self.n
+        books = self.books
+        # the kept rows of every node whose generation moved are walked
+        # again; the wave works on copies of the rest
+        self._rebuilt = set(books.sync(self.nodes).tolist())
+        slots = {k: a.copy() for k, a in books.slots.items()}
+        node = {k: a.copy() for k, a in books.node.items()}
+        self._vpods: List[List[List[v1.Pod]]] = list(books.units)
+        scal = list(books.scal)
+        Vmax = self._vmax = books.V
+        wave_prios = sorted({_prio(p) for p in wave})
+        # victims claimed by in-flight waves leave the books: whole units
+        # as dead slots; a node where a claim splits a gang unit is walked
+        # again for this wave without them (the unit is the rest)
+        claimed_at: Dict[int, Dict[int, List[int]]] = {}
+        self._claimed_locs: List[Tuple[int, int, int, str]] = []
+        for key in self.claimed_victims:
+            loc = books.where.get(key)
+            i = self._name_to_idx.get(loc[0]) if loc is not None else None
+            if i is None:
+                continue
+            claimed_at.setdefault(i, {}).setdefault(loc[1], []).append(loc[2])
+            self._claimed_locs.append((i, loc[1], loc[2], key))
+        self._claimed_locs.sort()
+        self._own_rows: Dict[int, Row] = {}
+        dead: List[Tuple[int, int]] = []
+        split: List[Tuple[int, v1.Pod]] = []
+        for i, by_slot in claimed_at.items():
+            if all(len(ms) == len(self._vpods[i][j])
+                   for j, ms in by_slot.items()):
+                dead.extend((i, j) for j in by_slot)
+                continue
+            gone = [self._vpods[i][j][m]
+                    for j, ms in by_slot.items() for m in ms]
+            row = walk(self.nodes[i], frozenset(v1.pod_key(p) for p in gone))
+            fill(slots, node, [(i, row)])
+            self._vpods[i] = row.units
+            scal[i] = row.scal
+            self._own_rows[i] = row
+            self._rebuilt.add(i)
+            split.extend((i, p) for p in gone)
+        # slot requests in the wave's dims
+        self._vvec = np.zeros((N, max(Vmax, 1), D), dtype=np.int64)
+        self._vvec[:, :, :3] = slots["vec3"]
+        sdim = {name: 3 + d for d, name in enumerate(self._dims[3:])}
+        if sdim:
+            for i, entries in enumerate(scal):
+                for j, sums in entries:
+                    for name, val in sums.items():
+                        if name in sdim:
+                            self._vvec[i, j, sdim[name]] = val
         self._alloc = np.zeros((D, N), dtype=np.int64)
         self._used = np.zeros((D, N), dtype=np.int64)
-        self._npods = np.zeros(N, dtype=np.int64)
-        self._max_pods = np.zeros(N, dtype=np.int64)
-
-        wave_prios = sorted({_prio(p) for p in wave})
         cols = getattr(self.snapshot, "columnar_util", None)
-        col_base = (
+        if (
             cols is not None
             and [ni.node.metadata.name for ni in self.nodes] == cols["names"]
-        )
-        if col_base:
+        ):
             # the base dims (cpu/memory/ephemeral — the columnar cache's
             # fixed row layout) land as one transposed array copy off
-            # the snapshot's utilization gather instead of a per-node
-            # Python attribute walk; scalar dims (wave-discovered, not
-            # columnar) still walk below
+            # the snapshot's utilization gather
             self._alloc[0:3, :] = cols["alloc"].T
             self._used[0:3, :] = cols["requested"].T
-        for d in range(D):
-            name = self._dims[d]
-            if col_base and d < 3:
-                continue
-            for i, ni in enumerate(self.nodes):
-                if name == "cpu":
-                    self._alloc[d, i] = ni.allocatable.milli_cpu
-                    self._used[d, i] = ni.requested.milli_cpu
-                elif name == "memory":
-                    self._alloc[d, i] = ni.allocatable.memory
-                    self._used[d, i] = ni.requested.memory
-                elif name == "ephemeral-storage":
-                    self._alloc[d, i] = ni.allocatable.ephemeral_storage
-                    self._used[d, i] = ni.requested.ephemeral_storage
-                else:
-                    self._alloc[d, i] = ni.allocatable.scalar_resources.get(name, 0)
-                    self._used[d, i] = ni.requested.scalar_resources.get(name, 0)
-        lo_sum = {p: np.zeros((D, N), dtype=np.int64) for p in wave_prios}
-        lo_cnt = {p: np.zeros(N, dtype=np.int64) for p in wave_prios}
-        per_node: List[List] = []
-        from .plugins.coscheduling import pod_group
-
-        for i, ni in enumerate(self.nodes):
-            self._npods[i] = len(ni.pods)
-            self._max_pods[i] = ni.allocatable.allowed_pod_number
-            # victim slots are same-node eviction UNITS: singletons for
-            # plain pods, whole gangs for co-located gang members (the
-            # oracle's _victim_units — whole gangs or none). A unit's
-            # slot carries the members' summed request vector; its
-            # priority is the members' MAX so the `< prio` validity
-            # check admits a gang only when EVERY member is outranked
-            gang_units: Dict[Tuple[str, str], List[v1.Pod]] = {}
-            victims = []
-            for pi in ni.pods:
-                if v1.pod_key(pi.pod) in self.claimed_victims:
-                    # an in-flight wave already evicted it: neither
-                    # present (its resources are spoken for) nor
-                    # evictable again
-                    self._used[:, i] -= self._req_vec(pi.pod)
-                    self._npods[i] -= 1
-                    continue
-                group, min_available = pod_group(pi.pod)
-                if group and min_available > 1:
-                    gang_units.setdefault(
-                        (pi.pod.metadata.namespace, group), []
-                    ).append(pi.pod)
-                    continue
-                vp = _prio(pi.pod)
-                if vp >= wave_prios[-1]:
-                    continue
-                vec = self._req_vec(pi.pod)
-                victims.append(
-                    (vp, pi.pod.status.start_time or 0.0, vec, [pi.pod])
-                )
-                for p in wave_prios:
-                    if vp < p:
-                        lo_sum[p][:, i] += vec
-                        lo_cnt[p][i] += 1
-            for members in gang_units.values():
-                vp = max(_prio(m) for m in members)
-                if vp >= wave_prios[-1]:
-                    continue
-                members.sort(
-                    key=lambda m: (-_prio(m), m.status.start_time or 0.0)
-                )
-                vec = np.sum(
-                    [self._req_vec(m) for m in members], axis=0
-                ).astype(np.int64)
-                start = min(
-                    m.status.start_time or 0.0
-                    for m in members if _prio(m) == vp
-                )
-                victims.append((vp, start, vec, members))
-                for p in wave_prios:
-                    if vp < p:
-                        lo_sum[p][:, i] += vec
-                        lo_cnt[p][i] += len(members)
-            # victims stored in ni.pods ORDER; both PDB allowance
-            # consumption (:612 sorts by MoreImportantPod BEFORE
-            # filterPodsWithPDBViolation) and the reprieve (highest
-            # priority, earliest start, :633) walk the _vsort permutation
-            per_node.append(victims)
-        self._lower_sum = lo_sum
-        self._lower_cnt = lo_cnt
-        # padded victim books [N, Vmax, ...] — the reprieve loop runs
-        # vectorized over every candidate node at once (per-candidate
-        # Python iteration was the wave's dominant cost at 500x100x4)
-        Vmax = max((len(v) for v in per_node), default=0)
-        self._vmax = Vmax
-        self._vvec = np.zeros((N, max(Vmax, 1), D), dtype=np.int64)
-        # pad priority with a sentinel above any real priority so the
-        # `< prio` validity check rejects padding rows
-        self._vprio = np.full((N, max(Vmax, 1)), _PRIO_SENTINEL, dtype=np.int64)
-        self._vstart = np.zeros((N, max(Vmax, 1)), dtype=np.float64)
-        self._valive = np.zeros((N, max(Vmax, 1)), dtype=bool)
+        else:
+            self._alloc[0:3, :] = node["alloc3"].T
+            self._used[0:3, :] = node["used3"].T
+        if sdim:
+            for i, (alloc, req) in enumerate(books.node_scal):
+                for name, d in sdim.items():
+                    self._alloc[d, i] = alloc.get(name, 0)
+                    self._used[d, i] = req.get(name, 0)
+        self._npods = node["npods"]
+        self._max_pods = node["max_pods"]
+        self._vprio = slots["prio"]
+        self._vstart = slots["start"]
         # per-slot unit shape: member count (pod-count arithmetic +
         # victim tallies), summed member priority (the pick ladder's
         # sum_prio is per POD), and the LATEST start among the slot's
         # highest-priority members (_vstart keeps the EARLIEST — the
         # MoreImportantPod sort key — while the ladder's latest-start
         # tiebreak reads per-pod maxima)
-        self._vsize = np.zeros((N, max(Vmax, 1)), dtype=np.int64)
-        self._vpriosum = np.zeros((N, max(Vmax, 1)), dtype=np.int64)
-        self._vlatest_hi = np.zeros((N, max(Vmax, 1)), dtype=np.float64)
-        self._vpods: List[List[List[v1.Pod]]] = []
+        self._vsize = slots["size"]
+        self._vpriosum = slots["priosum"]
+        self._vlatest_hi = slots["latest"]
+        # a slot is a victim of this wave while its unit is outranked by
+        # the wave's highest priority and nobody claimed it. A claimed
+        # victim is neither present (its resources are spoken for) nor
+        # evictable again
+        self._valive = slots["live"] & (self._vprio < wave_prios[-1])
+        for i, j in dead:
+            self._valive[i, j] = False
+            self._used[:, i] -= self._vvec[i, j]
+            self._npods[i] -= self._vsize[i, j]
+        for i, pod in split:
+            self._used[:, i] -= self._req_vec(pod)
+            self._npods[i] -= 1
+        # per-priority prefix sums of the victims outranked
+        self._lower_sum = {}
+        self._lower_cnt = {}
+        for p in wave_prios:
+            m = self._valive & (self._vprio < p)
+            self._lower_sum[p] = np.ascontiguousarray(
+                (self._vvec * m[..., None]).sum(axis=1).T)
+            self._lower_cnt[p] = (self._vsize * m).sum(axis=1)
         # PDB match tensor [N, Vmax, P]: how many of slot (i, j)'s
         # members consume pdb p's budget (same namespace + selector
         # match)? Counts, not booleans — a gang unit can hold several
@@ -359,31 +337,18 @@ class FastPreemptionPlanner:
         P = len(self.pdbs)
         self._pdb_match = np.zeros((N, max(Vmax, 1), max(P, 1)), dtype=np.int64)
         self._pdb_allowed = np.zeros(max(P, 1), dtype=np.int64)
-        sels = []
         if P:
             from ..api.labels import Selector
 
+            sels = []
             for p_i, pdb in enumerate(self.pdbs):
                 self._pdb_allowed[p_i] = pdb.status.disruptions_allowed
                 sels.append(
                     Selector.from_label_selector(pdb.spec.selector)
                     if pdb.spec.selector else None
                 )
-        for i, victims in enumerate(per_node):
-            pods_row: List[List[v1.Pod]] = []
-            for j, (vp, start, vec, members) in enumerate(victims):
-                self._vvec[i, j] = vec
-                self._vprio[i, j] = vp
-                self._vstart[i, j] = start
-                self._valive[i, j] = True
-                self._vsize[i, j] = len(members)
-                self._vpriosum[i, j] = sum(_prio(m) for m in members)
-                self._vlatest_hi[i, j] = max(
-                    m.status.start_time or 0.0
-                    for m in members if _prio(m) == vp
-                )
-                pods_row.append(members)
-                for vpod in members:
+            for i, j in zip(*np.nonzero(self._valive)):
+                for vpod in self._vpods[i][j]:
                     for p_i, pdb in enumerate(self.pdbs):
                         if pdb.metadata.namespace != vpod.metadata.namespace:
                             continue
@@ -391,9 +356,11 @@ class FastPreemptionPlanner:
                         if sel is not None and sel.matches(
                                 vpod.metadata.labels):
                             self._pdb_match[i, j, p_i] += 1
-            self._vpods.append(pods_row)
         # reprieve permutation: order victims (highest priority, earliest
-        # start); padding rows sort last
+        # start); padding and dead slots sort last. Both PDB allowance
+        # consumption (:612 sorts by MoreImportantPod BEFORE
+        # filterPodsWithPDBViolation) and the reprieve (highest priority,
+        # earliest start, :633) walk it
         skey = np.where(
             self._valive, self._vprio, np.int64(-(2 ** 62))
         )
@@ -471,9 +438,16 @@ class FastPreemptionPlanner:
         self.fits_now: List[bool] = []
         if not wave:
             return []
+        from . import metrics
+
         with tracing.span("preemption-books", "preemption-books",
                           n=len(wave)) as self._books_span:
-            self._build(wave)
+            with self.books.lock:
+                self._build(wave)
+            rebuilt = len(self._rebuilt)
+            self._books_span.set(kept=self.n - rebuilt, rebuilt=rebuilt)
+        metrics.preemption_books_nodes.inc(self.n - rebuilt, path="kept")
+        metrics.preemption_books_nodes.inc(rebuilt, path="rebuilt")
         limit = self._num_candidates()
         out: List[Optional[Candidate]] = []
         for pod in wave:
@@ -667,6 +641,8 @@ class FastPreemptionPlanner:
         per-priority prefix (they are being evicted — later wave pods
         must not count them as either present or evictable)."""
         i = self._name_to_idx[cand.node_name]
+        # the row is the kept books' until the wave first writes it
+        row = self._vpods[i] = list(self._vpods[i])
         self._nominated.setdefault(i, []).append((prio, req, v1.pod_key(pod)))
         self._nom_sum[:, i] += req
         self._nom_cnt[i] += 1
@@ -675,7 +651,7 @@ class FastPreemptionPlanner:
             else min(self._nom_min_prio, prio)
         )
         victim_keys = {v1.pod_key(v) for v in cand.victims}
-        for j, slot_pods in enumerate(self._vpods[i]):
+        for j, slot_pods in enumerate(row):
             if not slot_pods or not any(
                 v1.pod_key(vp) in victim_keys for vp in slot_pods
             ):
@@ -687,7 +663,7 @@ class FastPreemptionPlanner:
             vec = self._vvec[i, j]
             size = int(self._vsize[i, j])
             self._valive[i, j] = False
-            self._vpods[i][j] = []
+            row[j] = []
             self._used[:, i] -= vec
             self._npods[i] -= size
             for p in self._lower_sum:

@@ -50,6 +50,7 @@ from .preemption import (
     _prio,
     eviction_invariant_gates,
 )
+from .wave_books import WaveBooks, device_row
 
 logger = logging.getLogger(__name__)
 
@@ -129,10 +130,11 @@ class DevicePreemptionPlanner(FastPreemptionPlanner):
                  claimed_victims: Optional[Set[str]] = None,
                  pdbs: Optional[Sequence[v1.PodDisruptionBudget]] = None,
                  eligibility: Optional[Dict[str, Tuple[bool, bool]]] = None,
-                 resident_inputs: bool = True):
+                 resident_inputs: bool = True,
+                 books: Optional[WaveBooks] = None):
         super().__init__(snapshot, nominator, framework=framework,
                          args=args, claimed_victims=claimed_victims,
-                         pdbs=pdbs)
+                         pdbs=pdbs, books=books)
         self.backend = backend
         self.eligibility = eligibility or {}
         self.resident_inputs = resident_inputs
@@ -146,7 +148,7 @@ class DevicePreemptionPlanner(FastPreemptionPlanner):
 
     def _build(self, wave: List[v1.Pod]) -> None:
         super()._build(wave)  # the books' step `base`
-        books = self._books_span
+        sp = self._books_span
         self.planner_paths = []
         enc = self.backend.enc
         with self.backend._lock:
@@ -181,57 +183,45 @@ class DevicePreemptionPlanner(FastPreemptionPlanner):
              for ni in self.nodes],
             dtype=np.int64,
         )
-        books.step("lanes")
+        sp.step("lanes")
         # victim device rows, dense by (planner node, victim slot): a
         # slot is an eviction UNIT (singleton or whole co-located gang)
         # — its request row is the members' SUM, while label rows and
         # terminating flags stay per member (match tensors and the
         # prologue's ~pterm PTS gate are per-pod facts the slot
-        # aggregates at tensor-prep time)
+        # aggregates at tensor-prep time). Kept with the books: only the
+        # rows walked again, and rows with volumes, are built
         R = enc._arrays["requested"].shape[1] if enc._arrays else 0
         self._enc_r = R
-        vm = max(self._vmax, 1)
-        self._v_enc_req = np.zeros((self.n, vm, R), np.int64)
-        self._v_rows: List[List[List[Dict]]] = [
-            [[] for _ in range(vm)] for _ in range(self.n)
-        ]
-        self._v_term: List[List[List[bool]]] = [
-            [[] for _ in range(vm)] for _ in range(self.n)
-        ]
-        victim_rows = self.backend.victim_rows
-        for i in range(self.n):
-            for j, slot_pods in enumerate(self._vpods[i]):
-                for vpod in slot_pods:
-                    vec, rows = victim_rows(vpod)
-                    if vec.shape[0] == R:
-                        self._v_enc_req[i, j] += vec
-                    self._v_rows[i][j].append(rows)
-                    self._v_term[i][j].append(
-                        vpod.metadata.deletion_timestamp is not None
-                    )
-        self.backend.victim_rows_done()
-        books.step("victims")
+        books = self.books
+        self._rebuilt.update(books.sync_device(self.backend, R).tolist())
+        self._v_enc_req = books.dev_req.copy()
+        self._v_rows: List[List[List[Dict]]] = list(books.dev_rows)
+        self._v_term: List[List[List[bool]]] = list(books.dev_term)
+        for i, row in self._own_rows.items():
+            req, rows, term, _vecs, _vol = device_row(
+                self.backend, row.units, R)
+            self._v_enc_req[i] = 0
+            self._v_enc_req[i, :len(req)] = req
+            self._v_rows[i], self._v_term[i] = rows, term
+        sp.step("victims")
         # claimed victims (earlier in-flight waves): resident in the
         # encoding but already spoken for — every what-if state drains
         # them, at topology-pair granularity (their groups span nodes);
         # the last field is the pod key (the view drains only those it
         # still holds)
         self._pre: List[Tuple[int, Dict, np.ndarray, bool, str]] = []
-        for i, ni in enumerate(self.nodes):
+        for i, j, m, key in self._claimed_locs:
             lane = int(self._enc_idx[i])
             if lane < 0:
                 continue
-            for pi in ni.pods:
-                if v1.pod_key(pi.pod) not in self.claimed_victims:
-                    continue
-                vec, _nz = enc.pod_row_delta(pi.pod)
-                self._pre.append((
-                    lane, self.backend._pod_self_rows(pi.pod),
-                    vec if vec.shape[0] == R else np.zeros(R, np.int64),
-                    pi.pod.metadata.deletion_timestamp is not None,
-                    v1.pod_key(pi.pod),
-                ))
-        books.step("claimed")
+            vec = books.dev_vec[i][j][m]
+            self._pre.append((
+                lane, books.dev_rows[i][j][m],
+                vec if vec.shape[0] == R else np.zeros(R, np.int64),
+                books.dev_term[i][j][m], key,
+            ))
+        sp.step("claimed")
         # nominated entries with pod rows (the base class keeps only
         # request vectors in planner dims); claims append here too, with
         # no key: the nominator's entries carry theirs, for a view that
@@ -254,7 +244,7 @@ class DevicePreemptionPlanner(FastPreemptionPlanner):
                         vec if vec.shape[0] == R else np.zeros(R, np.int64),
                         v1.pod_key(np_pod),
                     ))
-        books.step("nominated")
+        sp.step("nominated")
 
     def _claim(self, cand: Candidate, pod: v1.Pod, prio: int,
                req: np.ndarray) -> None:
@@ -499,10 +489,11 @@ class DevicePreemptionPlanner(FastPreemptionPlanner):
             held = ctx.pod_keys
             got = np.ones((self.n, max(self._vmax, 1)), bool)
             if held is not None:
-                for i, slots in enumerate(self._vpods):
-                    for j, members in enumerate(slots):
-                        if any(v1.pod_key(p) not in held for p in members):
-                            got[i, j] = False
+                # a slot dead now stays dead for the wave
+                for i, j in zip(*np.nonzero(self._valive)):
+                    if any(v1.pod_key(p) not in held
+                           for p in self._vpods[i][j]):
+                        got[i, j] = False
             self._held_memo[id(ctx)] = got
         return got
 
@@ -519,12 +510,11 @@ class DevicePreemptionPlanner(FastPreemptionPlanner):
         rows: List[Dict] = []
         at: List[int] = []
         term: List[bool] = []
-        for i, slots in enumerate(self._v_rows):
-            for j, members in enumerate(slots):
-                for m, row in enumerate(members):
-                    rows.append(row)
-                    at.append(i * vm + j)
-                    term.append(self._v_term[i][j][m])
+        for i, j in zip(*np.nonzero(self._valive)):  # dead stays dead
+            for row, t in zip(self._v_rows[i][j], self._v_term[i][j]):
+                rows.append(row)
+                at.append(i * vm + j)
+                term.append(t)
         mf, manti, mall = self._match_rows(ctx, nps, tj, rows)
         # terminating victims never entered the PTS counts (~pterm gate)
         mf[np.asarray(term, bool)] = 0

@@ -57,6 +57,7 @@ from .plugins.defaultpreemption import get_lower_priority_nominated_pods
 from .plugins.registry import default_plugins, new_in_tree_registry
 from .degradation import RUNG_ORACLE, DeviceFault
 from .tpu_backend import TPUBackend
+from .wave_books import WaveBooks
 
 logger = logging.getLogger(__name__)
 
@@ -223,6 +224,8 @@ class Scheduler:
         self._preempt_lock = threading.Lock()
         self._node_waves: Dict[str, Tuple[set, List]] = {}  # node -> (victim keys, infos)
         self._victim_waiters: Dict[str, str] = {}  # victim key -> node
+        # the preemption planner's books, kept from one wave to the next
+        self._wave_books = WaveBooks()
         # node -> (first registration, victims): the preemption-wait span
         self._wave_t0: Dict[str, Tuple[float, int]] = {}
         self._inflight_preemptors: set = set()  # pod keys
@@ -1695,6 +1698,7 @@ class Scheduler:
                 claimed_victims=claimed,
                 pdbs=pdbs,
                 eligibility=eligibility,
+                books=self._wave_books,
             )
         else:
             planner = fast_preemption.FastPreemptionPlanner(
@@ -1702,6 +1706,7 @@ class Scheduler:
                 args=self._preemption_args(),
                 claimed_victims=claimed,
                 pdbs=pdbs,
+                books=self._wave_books,
             )
         with tracing.span("preemption-plan", "planner",
                           n=len(fast)) as psp:

@@ -366,9 +366,6 @@ class TPUBackend(CacheListener):
         # nominated preemptors held in the encoding: pod key -> node
         # (reserve_nominated)
         self._reserved: Dict[str, str] = {}
-        # victim_rows: pod key -> ((pod, vocab widths), row, label rows)
-        self._victim_rows: Dict[str, Tuple] = {}
-        self._victim_rows_next: Dict[str, Tuple] = {}
         # backend-health event hook: the Scheduler wires this to its
         # EventRecorder so ladder demote/promote, supervised-worker
         # restarts and speculation-miss re-drives surface as k8s Events
@@ -1377,28 +1374,6 @@ class TPUBackend(CacheListener):
         return True
 
     # -- session-delta classification + apply ------------------------------
-
-    def victim_rows(self, pod: v1.Pod) -> Tuple[np.ndarray, Dict]:
-        """(requested-row delta, label rows) of a bound pod for the
-        preemption planner's books, kept from the wave before while the
-        pod object and the vocab widths are the same: a wave's books
-        walk every bound pod of the cluster (20 000 at preemption-5000n).
-        victim_rows_done() drops the pods the wave did not ask for."""
-        enc = self.enc
-        key = v1.pod_key(pod)
-        sig = (id(pod), pod.metadata.resource_version,
-               enc.pod_pair_vocab.capacity,
-               enc.pod_key_vocab.capacity, enc._res_width())
-        got = self._victim_rows.get(key)
-        if got is None or got[0] != sig or enc.volume_hook is not None:
-            vec, _nz = enc.pod_row_delta(pod)
-            got = (sig, vec, self._pod_self_rows(pod))
-        self._victim_rows_next[key] = got
-        return got[1], got[2]
-
-    def victim_rows_done(self) -> None:
-        self._victim_rows, self._victim_rows_next = \
-            self._victim_rows_next, {}
 
     def _pod_self_rows(self, pod: v1.Pod) -> Dict:
         """The pod's label/namespace bit rows at current vocab widths —

@@ -169,6 +169,10 @@ def test_books_span_has_the_device_planners_steps(burst):
         steps = [a[s + "_s"] for s in
                  ("base", "lanes", "victims", "claimed", "nominated")]
         assert min(steps) >= 0.0 and sum(steps) <= dur
+        # the books of the first burst are kept: only the nodes that
+        # lost victims and bound preemptors since are walked again
+        assert a["kept"] + a["rebuilt"] == N_NODES
+        assert a["rebuilt"] <= BURST
 
 
 def test_whatif_spans_carry_pick_s(burst):
