@@ -58,6 +58,13 @@ Design notes (vs ops/hoisted.py _step, whose semantics this mirrors):
   int32 and then reproduces float64's own rounding at the only states
   where the two can differ — the states whose exact value IS an integer
   — from a short host-enumerated list (see _balanced_quirks).
+- **two widths of that arithmetic**: where every rescaled value times
+  101, and 100 * C * M, fit int32 (one node shape, round requests) the
+  scores are plain int32 divisions. A cluster of real node pools
+  reports allocatable in Ki (GCD 1 Ki: capacities of 10^8 units); there
+  the session is built WIDE (`_Cfg.wide`): a float32 estimate of each
+  score, corrected by one exact comparison in 15-bit limbs
+  (_least_wide, _balanced_wide), for every value below POS_BIG.
 - **gather-free spread counts**: per-node count rows; zone expansion and
   scored-set registration are MXU matvecs against a static one-hot.
 - float64 score math that remains (PTS topology weights, IPA
@@ -100,6 +107,7 @@ from .hoisted import (
     templates_have_ports,
     templates_have_terms,
 )
+from ..utils import tracing
 from .kernel import DEFAULT_WEIGHTS, MAX_NODE_SCORE
 
 VZ = 128          # compact pair-value lanes per shared-value key
@@ -112,6 +120,17 @@ CARRY_KEYS = ("requested", "nzpc", "cnt")
 ADMIT_CHUNK = 8     # specs per prologue launch (one compiled shape)
 WRITE_CHUNK = 64    # rows per table write (one compiled shape per table)
 MAX_QUIRKS = 1024   # balanced float64-quirk states the kernel can list
+# the narrow form's bounds: (cap - req) * 100 and 100 * C * M in int32
+NARROW_MAX = (2 ** 31 - 1) // (MAX_NODE_SCORE + 1)
+# float64's balanced score errs by at most 500 ulps of 1 (five roundings
+# of values <= 100); a state whose exact value is not an integer lies at
+# least 1 / (C * M) from one, so below this product float64 truncates to
+# the exact floor everywhere but at the whole states
+BAL_F64_MAX = 1 << 44
+# whole states _balanced_quirks may enumerate for one capacity pair
+QUIRK_SOLVE_MAX = 1 << 22
+LIMB = 15
+LIMB_MASK = (1 << LIMB) - 1
 # PodTopologySpread's raw score is int(float64(count) * log(size + 2))
 # (scoring.go). With a zone key the count is every matching pod of a zone:
 # tens of thousands, where a float32 product reads one off at a count in
@@ -122,7 +141,10 @@ MAX_QUIRKS = 1024   # balanced float64-quirk states the kernel can list
 # 12: three integer bits), listed after the balanced quirks in the `bad`
 # scalars. count * limb < 2**30 holds counts below PTS_MAX_COUNT.
 PTS_LIMBS = 5
+# a quirk is (group, key) narrow and (group, c, m) wide, where the key
+# c * (M + 1) + m would pass int32
 PTS_BASE = 1 + 2 * MAX_QUIRKS          # where the limbs start in `bad`
+PTS_BASE_WIDE = 1 + 3 * MAX_QUIRKS
 PTS_MAX_COUNT = 1 << 18
 
 
@@ -147,7 +169,90 @@ def spread_raw_exact(count: np.ndarray, size: int) -> np.ndarray:
     for j in (3, 2, 1, 0):
         acc = c * k[j] + (acc >> 11)
     return acc >> 9
-TOUCH_W = 4         # words per touch entry: count row, pair row, weight, src row
+
+
+# ---------------------------------------------------------------------------
+# the wide form's resource scores: exact in int32 for values below POS_BIG
+#
+# Each score is first estimated in float32, whose error (a few 1e-5 on a
+# value of at most 100) is far below one half: rounding the estimate
+# gives an integer r within one of the answer, and ONE exact comparison
+# of two products of up to 67 bits says which side of r the exact value
+# lies. The products are taken in limbs of 15 bits, so that every
+# partial product and every carried sum stays inside int32. Both
+# functions run inside the kernel and, for tests, on plain jnp arrays.
+
+
+def _split(x):
+    return x >> LIMB, x & LIMB_MASK
+
+
+def _least_wide(cap, req):
+    """LeastAllocated of one resource, `(cap - req) * 100 // cap` (0 where
+    cap is 0 or req passes it), for 0 <= cap, req < POS_BIG."""
+    i32, f32 = jnp.int32, jnp.float32
+    ok = (cap > 0) & (req <= cap)
+    cap = jnp.where(ok, cap, i32(1))
+    d = jnp.where(ok, cap - req, i32(0))
+    r = (d.astype(f32) / cap.astype(f32) * f32(MAX_NODE_SCORE)
+         + f32(0.5)).astype(i32)
+    # 100 * d - r * cap < 0: the estimate rounded up
+    d1, d0 = _split(d)
+    k1, k0 = _split(cap)
+    lo = MAX_NODE_SCORE * d0 - r * k0
+    hi = MAX_NODE_SCORE * d1 - r * k1 + (lo >> LIMB)
+    return jnp.where(ok, r - (hi < 0).astype(i32), i32(0))
+
+
+def _balanced_wide(c, m, C, M, full):
+    """(balanced, whole): BalancedAllocation's exact rational floor of
+    (1 - |c/C - m/M|) * 100, and whether that value is an integer, for
+    0 <= c < C < POS_BIG and 0 <= m < M < POS_BIG (0 and False where
+    `full`)."""
+    i32, f32 = jnp.int32, jnp.float32
+    C = jnp.where(full, i32(1), C)
+    M = jnp.where(full, i32(1), M)
+    c = jnp.where(full, i32(0), c)
+    m = jnp.where(full, i32(0), m)
+    # r = round(z), z = 100 * |cM - mC| / (CM), the balanced score 100 -
+    # ceil(z)
+    z = jnp.abs(c.astype(f32) / C.astype(f32) - m.astype(f32) / M.astype(f32))
+    r = (z * f32(MAX_NODE_SCORE) + f32(0.5)).astype(i32)
+    c1, c0 = _split(c)
+    m1, m0 = _split(m)
+    C1, C0 = _split(C)
+    M1, M0 = _split(M)
+    # S = cM - mC = s2 * 2^30 + s1 * 2^15 + s0, s1 and s0 in [0, 2^15)
+    t0 = c0 * M0 - m0 * C0
+    t1 = (c1 * M0 - m1 * C0) + (c0 * M1 - m0 * C1) + (t0 >> LIMB)
+    s2 = c1 * M1 - m1 * C1 + (t1 >> LIMB)
+    s1, s0 = t1 & LIMB_MASK, t0 & LIMB_MASK
+    # |S| in four limbs
+    neg = s2 < 0
+    a0 = jnp.where(neg, -s0, s0)
+    a1 = jnp.where(neg, -s1, s1) + (a0 >> LIMB)
+    a2 = jnp.where(neg, -s2, s2) + (a1 >> LIMB)
+    a3, a2 = _split(a2)
+    a1, a0 = a1 & LIMB_MASK, a0 & LIMB_MASK
+    # C * M in four limbs
+    p0 = C0 * M0
+    p1 = C1 * M0 + C0 * M1 + (p0 >> LIMB)
+    p2 = C1 * M1 + (p1 >> LIMB)
+    p3, p2 = _split(p2)
+    p1, p0 = p1 & LIMB_MASK, p0 & LIMB_MASK
+    # X = 100 * |S| - r * C * M: its sign says z > r, z == r or z < r
+    x0 = MAX_NODE_SCORE * a0 - r * p0
+    x1 = MAX_NODE_SCORE * a1 - r * p1 + (x0 >> LIMB)
+    x2 = MAX_NODE_SCORE * a2 - r * p2 + (x1 >> LIMB)
+    x3 = MAX_NODE_SCORE * a3 - r * p3 + (x2 >> LIMB)
+    low = ((x2 & LIMB_MASK) | (x1 & LIMB_MASK) | (x0 & LIMB_MASK)) != 0
+    above = (x3 > 0) | ((x3 == 0) & low)
+    whole = jnp.logical_not(full) & (x3 == 0) & jnp.logical_not(low)
+    balanced = MAX_NODE_SCORE - r - above.astype(i32)
+    return jnp.where(full, i32(0), balanced), whole
+
+
+TOUCH_W = 4        # words per touch entry: count row, pair row, weight, src row
 # pods per kernel loop iteration: a manual unroll that amortizes Mosaic's
 # per-iteration bookkeeping (partial `unroll=` is unsupported by the TPU
 # lowering)
@@ -225,47 +330,68 @@ def _host_prologue(cluster: Dict, arrays: List[Dict], dyn_ipa: bool) -> Dict:
 # ---------------------------------------------------------------------------
 # BalancedAllocation: where float64 and the exact rational floor part ways
 
-_QUIRK_CACHE: Dict[Tuple[int, int], np.ndarray] = {}
+_QUIRK_CACHE: Dict[Tuple[int, int], Optional[np.ndarray]] = {}
 
 
-def _balanced_quirks(cap_c: int, cap_m: int) -> np.ndarray:
+def _whole_states(C: int, M: int) -> Optional[np.ndarray]:
+    """[(c, m)] of every state 0 <= c < C, 0 <= m < M at which the exact
+    balanced value 100 * (1 - |cM - mC| / CM) is an integer, 100 - |k|;
+    None where there are more than QUIRK_SOLVE_MAX.
+
+    With G = gcd(C, M), C = G C', M = G M' and L = G C' M' (the lcm),
+    100 (cM - mC) = k CM says c M' - m C' = k L / 100: k a multiple of
+    100 / gcd(100, L), and for each such k a linear congruence
+    c M' = k L / 100 (mod C'), solved by c = c_k + j C' (j < G), m
+    following from c. At most 199 G states, however large C and M are."""
+    G = math.gcd(C, M)
+    Cp, Mp = C // G, M // G
+    L = G * Cp * Mp
+    step = MAX_NODE_SCORE // math.gcd(MAX_NODE_SCORE, L)
+    ks = range(-((MAX_NODE_SCORE - 1) // step) * step, MAX_NODE_SCORE, step)
+    if len(ks) * G > QUIRK_SOLVE_MAX:
+        return None
+    inv = pow(Mp, -1, Cp) if Cp > 1 else 0
+    j = np.arange(G, dtype=np.int64)
+    out = []
+    for k in ks:
+        u = k * L // MAX_NODE_SCORE
+        c = (u * inv) % Cp + Cp * j if Cp > 1 else j
+        m = (c * Mp - u) // Cp
+        ok = (m >= 0) & (m < M)
+        out.append(np.stack([c[ok], m[ok]], axis=1))
+    return np.concatenate(out) if out else np.zeros((0, 2), np.int64)
+
+
+def _balanced_quirks(cap_c: int, cap_m: int) -> Optional[np.ndarray]:
     """[(c, m)] of the node states (non-zero requested cpu c < cap_c and
     memory m < cap_m, pod included, in rescaled units) at which the
     reference's float64 `int((1 - |c/C - m/M|) * 100)` reads ONE LESS than
-    the exact rational floor.
+    the exact rational floor; None where they cannot be listed (more
+    whole states than QUIRK_SOLVE_MAX, or C * M past BAL_F64_MAX).
 
     float64 carries ~1e-16 of relative error and the exact value's
     distance to the next integer is at least 1/(C*M) unless it IS an
-    integer, so the two can differ only at exact-integer states (checked
-    over whole grids in tests/test_pallas_table.py). Those states solve a
-    linear congruence and are few; float64 is evaluated there, here, the
-    way the reference evaluates it."""
+    integer, so below BAL_F64_MAX the two can differ only at
+    exact-integer states (checked over whole grids in
+    tests/test_pallas_table.py). Those states solve a linear congruence
+    (_whole_states); float64 is evaluated there, here, the way the
+    reference evaluates it."""
     key = (int(cap_c), int(cap_m))
-    hit = _QUIRK_CACHE.get(key)
-    if hit is not None:
-        return hit
+    if key in _QUIRK_CACHE:
+        return _QUIRK_CACHE[key]
     C, M = key
     out = np.zeros((0, 2), np.int64)
     if C > 0 and M > 0:
-        den = C * M
-        c = np.arange(C, dtype=np.int64)[:, None]
-        pts = []
-        # rows of the grid in slabs: the whole grid is at most 2^31 / 100
-        step = max(1, (1 << 22) // max(M, 1))
-        m = np.arange(M, dtype=np.int64)[None, :]
-        for lo in range(0, C, step):
-            cs = c[lo:lo + step]
-            num = MAX_NODE_SCORE * (den - np.abs(cs * M - m * C))
-            ci, mi = np.nonzero(num % den == 0)
-            if len(ci):
-                pts.append(np.stack([cs[ci, 0], m[0, mi]], axis=1))
-        if pts:
-            p = np.concatenate(pts)
+        p = _whole_states(C, M) if C * M < BAL_F64_MAX else None
+        if p is None:
+            out = None
+        elif len(p):
             cf = p[:, 0] / np.float64(C)
             mf = p[:, 1] / np.float64(M)
             f64 = ((1.0 - np.abs(cf - mf)) * MAX_NODE_SCORE).astype(np.int64)
-            exact = (MAX_NODE_SCORE
-                     * (den - np.abs(p[:, 0] * M - p[:, 1] * C))) // den
+            # 100 * |cM - mC| < 2^51: int64 holds the exact value
+            exact = MAX_NODE_SCORE - (
+                MAX_NODE_SCORE * np.abs(p[:, 0] * M - p[:, 1] * C) // (C * M))
             if (np.abs(f64 - exact) > 1).any() or (f64 > exact).any():
                 raise PallasUnsupported(
                     "float64 balanced score strays from the exact floor "
@@ -331,6 +457,8 @@ class _Cfg(NamedTuple):
     interpret: bool
     pts_int: bool = True   # exact int32 zone-spread raw (else f32: more
     # pod rows than PTS_MAX_COUNT)
+    wide: bool = False     # resource scores in limbs (rescaled values up
+    # to POS_BIG; else int32 divisions, values below NARROW_MAX)
 
 
 # ---------------------------------------------------------------------------
@@ -393,7 +521,10 @@ def _build_kernel(cfg: _Cfg, Bp: int):
             cap_m = alloc_ref[1:2, :]
             full = ((cap_c == 0) | (cap_m == 0)
                     | (nzc >= cap_c) | (nzm >= cap_m))
-            if cfg.bal_int:
+            if cfg.wide and cfg.bal_int:
+                balanced, whole = _balanced_wide(nzc, nzm, cap_c, cap_m, full)
+                key = (nzc, nzm)
+            elif cfg.bal_int:
                 # exact floor of (1 - |c/C - m/M|) * 100; the build
                 # guarantees 100 * C * M < 2^31
                 den = jnp.where(full, i32(1), cap_c * cap_m)
@@ -415,6 +546,8 @@ def _build_kernel(cfg: _Cfg, Bp: int):
                 key = jnp.zeros((1, Np), i32)
 
             def least_dim(cap, reqq):
+                if cfg.wide:
+                    return _least_wide(cap, reqq)
                 d = ((cap - reqq) * MAX_NODE_SCORE
                      // jnp.where(cap == 0, i32(1), cap))
                 return jnp.where((cap == 0) | (reqq > cap), i32(0), d)
@@ -427,7 +560,14 @@ def _build_kernel(cfg: _Cfg, Bp: int):
             grp = balgrp_ref[0:1, :]
 
             def body(i, acc):
-                hit = (grp == bad_ref[1 + 2 * i]) & (key == bad_ref[2 + 2 * i])
+                if cfg.wide:
+                    c, m = key
+                    e = 1 + 3 * i
+                    hit = ((grp == bad_ref[e]) & (c == bad_ref[e + 1])
+                           & (m == bad_ref[e + 2]))
+                else:
+                    hit = ((grp == bad_ref[1 + 2 * i])
+                           & (key == bad_ref[2 + 2 * i]))
                 return jnp.maximum(acc, hit.astype(i32))
 
             return jax.lax.fori_loop(0, bad_ref[0], body,
@@ -586,7 +726,8 @@ def _build_kernel(cfg: _Cfg, Bp: int):
                             return in_f32(cnt, topo)
                         # count * log(size + 2) in int32 limbs of the
                         # float64 weight (PTS_BASE): exact
-                        b = PTS_BASE + PTS_LIMBS * topo.astype(i32)
+                        b = ((PTS_BASE_WIDE if cfg.wide else PTS_BASE)
+                             + PTS_LIMBS * topo.astype(i32))
                         acc = cnt * bad_ref[b + 4]
                         acc = cnt * bad_ref[b + 3] + (acc >> 11)
                         acc = cnt * bad_ref[b + 2] + (acc >> 11)
@@ -1017,7 +1158,7 @@ class PallasSession:
             ipa=self.dyn_ipa, bal_int=self._bal_int, interpret=interpret,
             # a zone's count is at most the pod rows there are
             pts_int=int(np.asarray(cluster["pvalid"]).shape[0])
-            < PTS_MAX_COUNT)
+            < PTS_MAX_COUNT, wide=self._wide)
         self.admits = 0   # admissions after the build
         # (Bp, "full") -> AOT-compiled executable (None = AOT unavailable,
         # dispatch through jit). Shared between the serving path and the
@@ -1075,7 +1216,7 @@ class PallasSession:
                 nz_requested[r] //= g
         hi = max((int(a.max(initial=0)) for a in
                   (alloc, requested, nz_requested)), default=0)
-        if hi * (MAX_NODE_SCORE + 1) >= 2 ** 31:
+        if hi >= POS_BIG:
             raise PallasUnsupported(
                 f"rescaled resource magnitude {hi} too large for int32",
                 reason="resource-magnitude")
@@ -1090,43 +1231,72 @@ class PallasSession:
         vn = np.zeros((SUB, Np), np.int32)
         vn[:, :N] = c["valid"].astype(np.int32)[None, :]
         self._valid_n = vn
+        # the narrow form where it holds these values, the wide one where
+        # only it does (a pool's Ki, a product C * M past 2^31 / 100)
+        pairs = self._cap_pairs()
+        self._wide = hi > NARROW_MAX or (len(pairs) <= 64 and any(
+            MAX_NODE_SCORE * int(cc) * int(cm) >= 2 ** 31
+            for cc, cm in pairs))
         self._bal_int = self._balanced_tables(build=True)
+
+    def _fits(self, hi: int) -> bool:
+        """Does a rescaled magnitude fit the form this session was built
+        in?"""
+        return hi <= (POS_BIG - 1 if self._wide else NARROW_MAX)
+
+    def _cap_pairs(self) -> np.ndarray:
+        """The distinct (cpu, memory) capacities of the valid nodes."""
+        valid = self._c["valid"].astype(bool)
+        cap = self._alloc[:2, : self.N].astype(np.int64)
+        return np.unique(cap[:, valid].T, axis=0) if valid.any() \
+            else np.zeros((0, 2), np.int64)
 
     def _balanced_tables(self, build: bool = False) -> bool:
         """Node groups by (cpu, memory) capacity and the float64-quirk
-        states of each, as the kernel lists them. False: the exact int32
-        form does not fit these capacities (the kernel then evaluates in
-        f32, as wide clusters always did)."""
+        states of each, as the kernel lists them. False: the exact form
+        does not fit these capacities (more than 64 of them, C * M past
+        the form's bound, quirks past MAX_QUIRKS): the kernel then
+        evaluates balanced in f32, and the backend counts the build in
+        scheduler_tpu_inexact_builds_total."""
         N = self.N
-        valid = self._c["valid"].astype(bool)
         cap = self._alloc[:2, :N].astype(np.int64)
-        pairs = np.unique(cap[:, valid].T, axis=0) if valid.any() \
-            else np.zeros((0, 2), np.int64)
+        pairs = self._cap_pairs()
+        qw, base = (3, PTS_BASE_WIDE) if self._wide else (2, PTS_BASE)
         grp = np.zeros((SUB, self.Np), np.int32)
-        bad = np.zeros(PTS_BASE + PTS_LIMBS * (VZ + 1), np.int32)
-        bad[PTS_BASE:] = _spread_limbs().ravel()
+        bad = np.zeros(base + PTS_LIMBS * (VZ + 1), np.int32)
+        bad[base:] = _spread_limbs().ravel()
         ok = len(pairs) <= 64 and all(
-            MAX_NODE_SCORE * int(cc) * int(cm) < 2 ** 31 for cc, cm in pairs)
+            int(cc) * int(cm) < BAL_F64_MAX if self._wide
+            else MAX_NODE_SCORE * int(cc) * int(cm) < 2 ** 31
+            for cc, cm in pairs)
         n = 0
-        if ok:
-            for g, (cc, cm) in enumerate(pairs):
+        with tracing.span("balanced-quirks", "session",
+                          pairs=len(pairs), wide=self._wide) as sp:
+            for g, (cc, cm) in enumerate(pairs if ok else ()):
                 grp[0, :N][(cap[0] == cc) & (cap[1] == cm)] = g
                 q = _balanced_quirks(int(cc), int(cm))
-                if n + len(q) > MAX_QUIRKS:
+                if q is None or n + len(q) > MAX_QUIRKS:
                     ok = False
                     break
-                bad[1 + 2 * n:1 + 2 * (n + len(q)):2] = g
-                bad[2 + 2 * n:2 + 2 * (n + len(q)):2] = (
-                    q[:, 0] * (int(cm) + 1) + q[:, 1])
+                e = 1 + qw * n
+                bad[e:e + qw * len(q):qw] = g
+                if self._wide:
+                    bad[e + 1:e + 3 * len(q):3] = q[:, 0]
+                    bad[e + 2:e + 3 * len(q):3] = q[:, 1]
+                else:
+                    bad[e + 1:e + 2 * len(q):2] = (
+                        q[:, 0] * (int(cm) + 1) + q[:, 1])
                 n += len(q)
-        if not ok:
-            grp[:] = 0
-            bad[:PTS_BASE] = 0
-            n = 0
+            if not ok:
+                grp[:] = 0
+                bad[:base] = 0
+                n = 0
+            sp.set(states=n, exact=ok)
         bad[0] = n
         if not build and ok != self._bal_int:
             raise ValueError("capacities left the exact balanced form")
         self._balgrp, self._bad = grp, bad
+        self.quirk_states = n
         return ok
 
     # -- interned rows ------------------------------------------------------
@@ -1440,16 +1610,18 @@ class PallasSession:
         # the carried utilization is bounded by the capacities
         hi = max(int(alloc.max(initial=0)),
                  int(rec[:, L.REQ:L.REQ + self.R].max(initial=0)))
-        if hi * (MAX_NODE_SCORE + 1) >= 2 ** 31:
-            raise TableFull("refined resource unit too fine for int32",
-                            reason="resource-magnitude")
-        old = (self._alloc, self._gcd, self._balgrp, self._bad)
+        if not self._fits(hi):
+            raise TableFull("refined resource unit too fine for the "
+                            "session's form", reason="resource-magnitude")
+        old = (self._alloc, self._gcd, self._balgrp, self._bad,
+               self.quirk_states)
         self._alloc = alloc.astype(np.int32)
         self._gcd = self._gcd // k
         try:
             self._balanced_tables()
         except ValueError:
-            self._alloc, self._gcd, self._balgrp, self._bad = old
+            (self._alloc, self._gcd, self._balgrp, self._bad,
+             self.quirk_states) = old
             raise TableFull("refined unit leaves the exact balanced form",
                             reason="resource-magnitude")
         self._spec[:] = rec.astype(np.int32).reshape(-1)
@@ -1481,10 +1653,11 @@ class PallasSession:
         rec = np.zeros(L.W, np.int32)
         req = np.asarray(a["req"]).astype(np.int64) // self._gcd
         nz = np.asarray(a["nz_req"]).astype(np.int64) // self._gcd[:2]
-        if max(int(req.max(initial=0)), int(nz.max(initial=0))) \
-                * (MAX_NODE_SCORE + 1) >= 2 ** 31:
-            raise PallasUnsupported("request too large for int32",
-                                    reason="resource-magnitude")
+        if not self._fits(max(int(req.max(initial=0)),
+                              int(nz.max(initial=0)))):
+            # a narrow session: the rebuild, with this spec, is wide
+            raise PallasUnsupported("request too large for the session's "
+                                    "form", reason="resource-magnitude")
         rec[L.REQ:L.REQ + self.R] = req
         rec[L.CHK:L.CHK + self.R] = np.asarray(a["req_check"])
         rec[L.HAS] = int(np.asarray(a["req_has_any"]))
@@ -1794,7 +1967,7 @@ class PallasSession:
     def delta_compatible(self, dres, dnz) -> bool:
         """A utilization delta rides this session's int32 carry only when
         the per-dimension GCD rescale stays exact on it and the rescaled
-        magnitudes keep the int32 headroom the build guaranteed."""
+        magnitudes fit the form the session was built in."""
         dres = np.asarray(dres, np.int64)
         if dres.shape[0] != self._gcd.shape[0]:
             return False
@@ -1807,7 +1980,7 @@ class PallasSession:
             int(np.abs(dres // self._gcd).max(initial=0)),
             int(np.abs(dnz // self._gcd[:2]).max(initial=0)),
         )
-        return hi * (MAX_NODE_SCORE + 1) < 2 ** 31
+        return self._fits(hi)
 
     def _delta_entries(self, d) -> List[tuple]:
         """One backend delta dict -> [(node, dres[Rp], dnzpc[8], row,
@@ -1844,17 +2017,17 @@ class PallasSession:
     def _patch_alloc_static(self, d) -> None:
         """node-alloc patch: the static alloc columns move (the prologue
         never reads alloc, so nothing else needs recompute). The
-        CUMULATIVE rescaled magnitude must keep the int32 headroom the
-        build guaranteed, and the capacities must stay inside the exact
+        CUMULATIVE rescaled magnitude must fit the form the session was
+        built in, and the capacities must stay inside the exact
         balanced form — else this raises (the backend's apply wrapper
         downgrades to a rebuild, whose own envelope then decides)."""
         scaled = (np.asarray(d["dalloc"], np.int64) // self._gcd).astype(
             np.int32)
         n = d["node"]
         col = self._alloc[: self.R, n].astype(np.int64) + scaled
-        if int(np.abs(col).max(initial=0)) * (MAX_NODE_SCORE + 1) >= 2 ** 31:
+        if not self._fits(int(np.abs(col).max(initial=0))):
             raise ValueError(
-                "cumulative alloc patches exceed the int32 score headroom")
+                "cumulative alloc patches exceed the session's form")
         self._alloc[: self.R, n] += scaled
         self._balanced_tables()
         st = self._statics

@@ -294,6 +294,33 @@ session_templates = legacy_registry.register(
         ("what",),
     )
 )
+inexact_builds = legacy_registry.register(
+    Counter(
+        "scheduler_tpu_inexact_builds_total",
+        "Device session builds that cannot score every node as the "
+        "reference does (TPU-build metric): what=balanced a table "
+        "session whose BalancedAllocation runs in float32 (more than 64 "
+        "node capacities, a capacity product past the exact forms, more "
+        "float64-quirk states than the kernel lists), what=spread one "
+        "whose zone-spread product runs in float32 (more pod rows than "
+        "its limbs count), what=demoted a table session that could not "
+        "be built at all, replaced by the jnp hoisted session. Reads 0 "
+        "wherever the table kernel holds the cluster exactly.",
+        ("what",),
+    )
+)
+balanced_quirk_states = legacy_registry.register(
+    Gauge(
+        "scheduler_tpu_balanced_quirk_states",
+        "Node states at which the reference's float64 BalancedAllocation "
+        "reads one less than the exact floor, as the live table session "
+        "lists them for its kernel (TPU-build metric): what=listed the "
+        "states listed, what=capacity the room for them. Past capacity "
+        "the session scores balanced in float32 "
+        "(scheduler_tpu_inexact_builds_total{what=balanced}).",
+        ("what",),
+    )
+)
 session_template_admits = legacy_registry.register(
     Counter(
         "scheduler_session_template_admits_total",

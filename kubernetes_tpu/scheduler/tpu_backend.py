@@ -2251,12 +2251,15 @@ class TPUBackend(CacheListener):
 
     @staticmethod
     def _export_table_gauges(sess) -> None:
-        from .metrics import session_templates
+        from ..ops.pallas_scan import MAX_QUIRKS
+        from .metrics import balanced_quirk_states, session_templates
 
         session_templates.set(float(sess.specs), what="specs")
         session_templates.set(float(sess.Tcap), what="capacity")
         session_templates.set(float(sess.count_rows), what="rows")
         session_templates.set(float(sess.RC), what="row_capacity")
+        balanced_quirk_states.set(float(sess.quirk_states), what="listed")
+        balanced_quirk_states.set(float(MAX_QUIRKS), what="capacity")
 
     def _build_session(self):
         """Span-wrapped _build_session_impl: records the build as a
@@ -2287,7 +2290,7 @@ class TPUBackend(CacheListener):
         LOUD: a pallas->hoisted fallback costs ~2.4x throughput, so every
         build is counted in scheduler_tpu_session_builds_total{kind,reason}
         and downgrades are logged."""
-        from .metrics import session_builds
+        from .metrics import inexact_builds, session_builds
 
         sh = self._shards_label()
         # the specs met, most recent last. A table session has room for
@@ -2394,6 +2397,7 @@ class TPUBackend(CacheListener):
                 # tell them apart; slugs stay bounded
                 session_builds.inc(kind="hoisted",
                                    reason=f"mesh-{e.reason}", shards=sh)
+                inexact_builds.inc(what="demoted")
             from ..parallel import sharded
 
             return HoistedSession(
@@ -2427,6 +2431,12 @@ class TPUBackend(CacheListener):
                 for b in self._suspect_buckets:
                     s.retire_exec(bucket=b)
                 session_builds.inc(kind="pallas", reason="", shards=sh)
+                # a score the kernel cannot take exactly is said here,
+                # not found in a decision
+                if not s._cfg.bal_int:
+                    inexact_builds.inc(what="balanced")
+                if not s._cfg.pts_int:
+                    inexact_builds.inc(what="spread")
                 # AOT-warm the ragged-tail batch buckets OFF the serving
                 # path: a daemon thread populates the (persistent)
                 # compile caches so a mid-window first-tail batch never
@@ -2445,6 +2455,7 @@ class TPUBackend(CacheListener):
                     "downgrading to the jnp hoisted session (~2.4x slower)", e,
                 )
                 session_builds.inc(kind="hoisted", reason=e.reason, shards=sh)
+                inexact_builds.inc(what="demoted")
         else:
             session_builds.inc(kind="hoisted", reason="platform is not tpu",
                                shards=sh)

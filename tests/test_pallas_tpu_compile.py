@@ -49,7 +49,8 @@ def test_table_kernel_compiles_for_v5e_at_5000_nodes(one_chip, terms,
     sess = pallas_scan.PallasSession(
         enc.device_state(), arrays, interpret=True,
         capacity=pallas_scan.table_capacity(120_000), terms=terms)
-    assert sess.Tcap == 1024 and sess._cfg.bal_int
+    # the narrow form, as every cell before the pools compiled it
+    assert sess.Tcap == 1024 and sess._cfg.bal_int and not sess._cfg.wide
     # v5e: 128 MiB of VMEM a core (the session asks the device, which
     # is not attached here)
     monkeypatch.setattr(pallas_scan, "_vmem_cap", lambda: 112 << 20)
@@ -68,6 +69,40 @@ def test_table_kernel_compiles_for_v5e_at_5000_nodes(one_chip, terms,
     need = pallas_scan._kernel_vmem_bytes(
         sess._statics, sess._carry_struct(), 2048)
     assert pallas_scan._vmem_request(need) < 112 << 20
+
+
+def test_wide_table_kernel_compiles_for_v5e_at_5000_nodes(one_chip,
+                                                          monkeypatch):
+    """The gke-pools-5000n cell's launch: 5000 nodes of seven GKE pools
+    (allocatable in Ki), so the resource scores take the wide form, with
+    its three-word quirk list; ha's term rows on."""
+    from .test_pallas_wide import GKE_POOLS, _pool_nodes
+
+    enc = ClusterEncoding()
+    enc.set_cluster(_pool_nodes(5000), [])
+    enc.reserve(pods=256, anti_terms=256)
+    pe = PodEncoder(enc)
+    pods = [_pod("a", "web", 1), _pod("c", "small", 1), _pod("b", "ha", 1)]
+    arrays = [{k: v for k, v in pe.encode(p).items()
+               if not k.startswith("_")} for p in pods]
+    sess = pallas_scan.PallasSession(
+        enc.device_state(), arrays, interpret=True,
+        capacity=pallas_scan.table_capacity(120_000), terms=True)
+    assert sess._cfg.wide and sess._cfg.bal_int and sess.quirk_states > 0
+    assert len(sess._cap_pairs()) == len(GKE_POOLS)
+    monkeypatch.setattr(pallas_scan, "_vmem_cap", lambda: 112 << 20)
+
+    def on_chip(x):
+        return jax.ShapeDtypeStruct(
+            jnp.shape(x), jnp.asarray(x).dtype, sharding=one_chip)
+
+    compiled = pallas_scan._dispatch.lower(
+        sess._cfg._replace(interpret=False),
+        {k: on_chip(v) for k, v in sess._statics.items()},
+        jax.ShapeDtypeStruct((1 + 2048,), jnp.int32, sharding=one_chip),
+        {k: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+         for k, s in sess._carry_struct().items()}).compile()
+    assert "tpu_custom_call" in compiled.as_text()
 
 
 def test_whatif_launch_compiles_for_v5e_at_5000_nodes(one_chip, monkeypatch):
