@@ -29,7 +29,14 @@ fused device program per preemptor:
   * the inputs stay on the device for a wave: a launch donates them and
     returns them updated, so the planner uploads them whole once per
     view, template and priority, and then sends each launch one
-    fixed-size delta of the lanes the claims since changed.
+    fixed-size delta of the lanes the claims since changed;
+  * a WAVE launch plans up to WAVE_STEPS consecutive preemptors of one
+    view, template and priority in one program: a scan whose step is
+    the dry run, the pick (the candidate cut and the pick-one ladder)
+    and the claim applied to the carried inputs, so each preemptor sees
+    every earlier one's claim without a round trip to the host. The
+    planner takes it only where a claim is lane-local (no PDB-covered,
+    pair-matching or gang victims, no pair-matching preemptor).
 
 Exactness domain: the preemptor may carry pod (anti-)affinity terms and
 topology-spread constraints — the capability the numpy fast planner's
@@ -458,6 +465,118 @@ def _whatif_eval(S: Dict, c_static: Dict, carry: Dict, x: Dict, tj: int,
     }
 
 
+# preemptors a wave launch plans: one program for every run length, the
+# steps past a run's end inert
+WAVE_STEPS = 64
+
+
+@functools.partial(
+    jax.jit, static_argnames=("tj", "dyn_ipa", "dyn_ports"),
+    donate_argnames=("x",),
+)
+def _whatif_wave_run(S: Dict, c_static: Dict, carry: Dict, x: Dict, delta,
+                     wave: Dict, tj: int = 0, dyn_ipa: bool = False,
+                     dyn_ports: bool = False):
+    """One wave launch: the delta scattered into the resident inputs `x`
+    (donated), then a scan over the steps of `wave`, one preemptor
+    each, in plan order: _whatif_eval's dry run (always with the
+    nominated pass: with no nominees the two passes agree), the pick
+    (_wave_pick) and the claim applied to `x` at the picked lane only
+    (_wave_claim). A step whose `active` is False leaves `x` as it is.
+    Returns ({fits [T], pick [T] planner row or -1, victims [T, L] the
+    picked slots}, the updated inputs).
+
+    `wave` holds, per step, `active` [T] and `nom_req` [T, R] (the
+    preemptor's request row, added as nominated load where it claims);
+    for the launch, `lanes` [n] (planner row -> lane), `limit` (the
+    candidate cut), `offset` (pickOneNodeForPreemption's
+    PRIORITY_OFFSET) and the pick's per-slot tallies in planner rows
+    [n, L]: `prio`, `priosum` and `latest` (exact integer ranks of the
+    latest highest-priority start)."""
+    x = _apply_delta(x, delta)
+    L = x["v_valid"].shape[1]
+
+    def plan(x, nom_row):
+        ys = _whatif_eval(S, c_static, carry, x, tj, dyn_ipa, dyn_ports,
+                          True)
+        fits, row, take, picked = _wave_pick(ys, x, wave)
+        x = _wave_claim(x, wave["lanes"][row], take, picked, nom_row)
+        return x, (fits, jnp.where(take, row, -1).astype(jnp.int32), picked)
+
+    def idle(x, nom_row):
+        return x, (jnp.zeros((), bool), jnp.full((), -1, jnp.int32),
+                   jnp.zeros(L, bool))
+
+    def step(x, s):
+        active, nom_row = s
+        return jax.lax.cond(active, plan, idle, x, nom_row)
+
+    x, (fits, pick, victims) = jax.lax.scan(
+        step, x, (wave["active"], wave["nom_req"]))
+    return {"fits": fits, "pick": pick, "victims": victims}, x
+
+
+def _wave_pick(ys: Dict, x: Dict, wave: Dict):
+    """The planner's per-preemptor epilogue on the device, in its order
+    (preemption_device._plan_one_device): a preemptor that fits on some
+    node claims nothing; otherwise the candidates are the first `limit`
+    rows (planner order) that are feasible with every victim gone and
+    hold a victim, each tallied over its victim mask, and the pick is
+    FastPreemptionPlanner._pick_index's ladder as a lexicographic masked
+    argmin in exact integers — fewest PDB violations (none exist where
+    a wave launch runs), lowest highest victim priority, lowest
+    priority sum + `offset` * victims, fewest victims, latest start of
+    the highest-priority victims — the first row on the final tie.
+    Returns (fits, row, take, picked slots [L])."""
+    lanes = wave["lanes"]
+    valid = x["v_valid"][lanes].astype(bool)                 # [n, L]
+    fits = jnp.any(ys["fits_now"][lanes])
+    feasible = ys["base"][lanes] & jnp.any(valid, axis=1)
+    cand = feasible & (jnp.cumsum(feasible.astype(jnp.int32))
+                       <= wave["limit"])
+    vmask = ys["victims"][lanes] & valid
+    n_vict = jnp.sum(jnp.where(vmask, x["v_cnt"][lanes], 0),
+                     axis=1).astype(_I64)
+    vprio = wave["prio"]
+    low = jnp.iinfo(_I64).min
+    max_prio = jnp.max(jnp.where(vmask, vprio, low), axis=1)
+    sum_prio = jnp.sum(jnp.where(vmask, wave["priosum"], 0), axis=1)
+    latest = jnp.max(jnp.where(vmask & (vprio == max_prio[:, None]),
+                               wave["latest"], -1), axis=1).astype(_I64)
+    best = cand & (n_vict > 0)
+    top = jnp.iinfo(_I64).max
+    for crit in (max_prio, sum_prio + wave["offset"] * n_vict, n_vict,
+                 -latest):
+        vals = jnp.where(best, crit, top)
+        best = best & (vals == jnp.min(vals))
+    row = jnp.argmax(best)
+    take = ~fits & best[row]
+    return fits, row, take, vmask[row] & take
+
+
+def _wave_claim(x: Dict, lane, take, picked, nom_row) -> Dict:
+    """A pick's claim applied to the inputs at its lane only: the picked
+    slots leave (an invalid, zeroed slot is inert in the reprieve walk,
+    and the rest of the row keeps its order), their requests and member
+    counts become drains, and the preemptor becomes nominated load.
+    With `take` False (`picked` then empty) nothing moves."""
+    x = dict(x)
+    freed_req = jnp.sum(jnp.where(picked[:, None], x["v_req"][lane], 0),
+                        axis=0)
+    freed_cnt = jnp.sum(jnp.where(picked, x["v_cnt"][lane], 0))
+    for k in ("v_valid", "v_cnt", "v_req", "v_mfs", "v_manti", "v_mall"):
+        old = x[k][lane]
+        keep = ~picked.reshape(picked.shape + (1,) * (old.ndim - 1))
+        x[k] = x[k].at[lane].set(jnp.where(keep, old, jnp.zeros_like(old)))
+    x["pre_req"] = x["pre_req"].at[lane].add(freed_req)
+    x["pre_cnt"] = x["pre_cnt"].at[lane].add(
+        freed_cnt.astype(x["pre_cnt"].dtype))
+    x["nom_req"] = x["nom_req"].at[lane].add(
+        jnp.where(take, nom_row, 0).astype(x["nom_req"].dtype))
+    x["nom_cnt"] = x["nom_cnt"].at[lane].add(take.astype(x["nom_cnt"].dtype))
+    return x
+
+
 @functools.partial(jax.jit, static_argnames=("tj", "dyn_ports"))
 def _gang_fits_run(S: Dict, c_static: Dict, carry: Dict, k,
                    tj: int = 0, dyn_ports: bool = False):
@@ -607,6 +726,7 @@ class WhatifContext:
         out = {
             "f_same_key": np.asarray(sess._S["f_same_key"])[tj],
             "f_pair_cn": np.asarray(sess._S["f_pair_cn"])[tj],
+            "f_valid": np.asarray(sess._S["f_valid"])[tj],
         }
         if self.dyn_ipa:
             for k in _TERM_SLICE_KEYS:
@@ -638,6 +758,18 @@ class WhatifContext:
             sess._S, sess._c_static, self.carry, x, delta,
             tj=tj, dyn_ipa=self.dyn_ipa, dyn_ports=self.dyn_ports,
             has_nom=has_nom,
+        )
+
+    def run_wave(self, tj: int, x: Dict, delta: np.ndarray, wave: Dict):
+        """Launch the wave program (_whatif_wave_run) over up to
+        WAVE_STEPS preemptors; `x` and `delta` as run() takes them,
+        `wave` as the program documents. Returns (results, inputs)."""
+        sess = self._sess
+        x = {k: a if isinstance(a, jax.Array) else jnp.array(a)
+             for k, a in x.items()}
+        return _whatif_wave_run(
+            sess._S, sess._c_static, self.carry, x, delta, wave,
+            tj=tj, dyn_ipa=self.dyn_ipa, dyn_ports=self.dyn_ports,
         )
 
     def gang_fits(self, tj: int, k: int) -> bool:

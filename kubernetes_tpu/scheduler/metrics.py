@@ -360,12 +360,32 @@ preemption_planner = legacy_registry.register(
 whatif_launches = legacy_registry.register(
     Counter(
         "scheduler_whatif_launches_total",
-        "Fused what-if device launches (one per device-planned "
-        "preemptor: base feasibility + the full reprieve walk across "
-        "all candidate nodes). Launches never touch the live session "
-        "carry — scheduler_session_rebuilds_total must not move with "
-        "this counter.",
+        "Fused what-if device launches: one per preemptor planned "
+        "alone (base feasibility + the full reprieve walk across all "
+        "candidate nodes), one per wave launch of up to 64 preemptors "
+        "(scheduler_whatif_planned_total{path=\"wave\"}). Launches "
+        "never touch the live session carry — "
+        "scheduler_session_rebuilds_total must not move with this "
+        "counter.",
         (),
+    )
+)
+whatif_planned = legacy_registry.register(
+    Counter(
+        "scheduler_whatif_planned_total",
+        "Preemptors planned by a what-if launch, by how: path=wave "
+        "(reason=lane-local) in a wave launch, whose program picks and "
+        "claims on the device for a run of preemptors of one view, "
+        "template and priority; path=single in a launch of their own, "
+        "the reason saying why they left the wave launch: pdb (a "
+        "victim of the wave is covered by a PDB: budgets move with "
+        "claims), pairs (a victim or the preemptor matches the "
+        "template's spread classes or required (anti-)affinity terms: "
+        "a claim writes topology-pair counts), gang (a gang unit among "
+        "the victims), fault (the wave launch they were in failed), "
+        "key (no run of their key could be formed), off (a planner "
+        "built without wave launches).",
+        ("path", "reason"),
     )
 )
 whatif_inputs = legacy_registry.register(

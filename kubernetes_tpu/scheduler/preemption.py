@@ -448,11 +448,13 @@ class FastPreemptionPlanner:
             self._books_span.set(kept=self.n - rebuilt, rebuilt=rebuilt)
         metrics.preemption_books_nodes.inc(self.n - rebuilt, path="kept")
         metrics.preemption_books_nodes.inc(rebuilt, path="rebuilt")
-        limit = self._num_candidates()
-        out: List[Optional[Candidate]] = []
-        for pod in wave:
-            out.append(self._plan_one(pod, limit))
-        return out
+        return self._plan_pods(wave, self._num_candidates())
+
+    def _plan_pods(self, wave: List[v1.Pod],
+                   limit: int) -> List[Optional[Candidate]]:
+        """The wave's pods planned in order, each seeing every earlier
+        claim."""
+        return [self._plan_one(pod, limit) for pod in wave]
 
     def _num_candidates(self) -> int:
         n = self.n * self.min_pct // 100

@@ -9,7 +9,13 @@ Three rungs, per failed pod:
             pod (anti-)affinity terms and topology-spread constraints —
             the classes the numpy envelope must reject — because the
             session kernels already compute the IPA/PTS count
-            interference the dry run needs.
+            interference the dry run needs. Where a run of consecutive
+            preemptors of one view, template and priority claims only
+            lane-local state (no PDB-covered, pair-matching or gang
+            victims, no pair-matching preemptor), one WAVE launch plans
+            up to 64 of them, the pick and the claim inside the
+            program, and the host replays its picks through the same
+            candidate build and claim.
   fast    — the numpy FastPreemptionPlanner (preemption.py): resource
             fit + static gates + vectorized PDB reprieve, host-side.
   oracle  — the DefaultPreemption plugin dry-run via the scheduler's
@@ -43,7 +49,7 @@ from ..api import types as v1
 from ..utils import tracing
 from . import metrics
 from .degradation import DeviceFault
-from .plugins.defaultpreemption import Candidate
+from .plugins.defaultpreemption import PRIORITY_OFFSET, Candidate
 from .preemption import (
     FastPreemptionPlanner,
     WaveAntiTerms,
@@ -113,6 +119,18 @@ class _Inputs:
         self.pdb_allowed: Optional[np.ndarray] = None
 
 
+class _Key:
+    """A preemptor's launch key: its what-if view, template and
+    priority, with the template's host-side slices."""
+
+    __slots__ = ("ctx", "nps", "tj", "prio", "key", "same_key")
+
+    def __init__(self, ctx, nps: Dict, tj: int, prio: int):
+        self.ctx, self.nps, self.tj, self.prio = ctx, nps, tj, prio
+        self.key = (id(ctx), tj, prio)
+        self.same_key = nps["f_same_key"].astype(np.int32)  # [C, C]
+
+
 class DevicePreemptionPlanner(FastPreemptionPlanner):
     """FastPreemptionPlanner books + a device what-if rung.
 
@@ -123,7 +141,12 @@ class DevicePreemptionPlanner(FastPreemptionPlanner):
     A launch's inputs stay on the device for the wave: the first launch
     of a (view, template, priority) uploads them whole, each later one
     only the lanes the claims since changed (`resident_inputs=False`
-    uploads every launch whole: the parity control)."""
+    uploads every launch whole: the parity control).
+
+    A run of preemptors of one launch key whose claims are lane-local
+    is planned by wave launches, pick and claim on the device, and
+    replayed on the host; `wave_launch=False` launches every preemptor
+    alone: the parity control of tests/test_whatif_wave.py."""
 
     def __init__(self, snapshot, nominator, backend, framework=None,
                  args: Optional[dict] = None,
@@ -131,13 +154,15 @@ class DevicePreemptionPlanner(FastPreemptionPlanner):
                  pdbs: Optional[Sequence[v1.PodDisruptionBudget]] = None,
                  eligibility: Optional[Dict[str, Tuple[bool, bool]]] = None,
                  resident_inputs: bool = True,
-                 books: Optional[WaveBooks] = None):
+                 books: Optional[WaveBooks] = None,
+                 wave_launch: bool = True):
         super().__init__(snapshot, nominator, framework=framework,
                          args=args, claimed_victims=claimed_victims,
                          pdbs=pdbs, books=books)
         self.backend = backend
         self.eligibility = eligibility or {}
         self.resident_inputs = resident_inputs
+        self.wave_launch = wave_launch
         self.planner_paths: List[str] = []
         # the last launch's attributes of the `whatif` span, and when its
         # results reached the host
@@ -177,6 +202,7 @@ class DevicePreemptionPlanner(FastPreemptionPlanner):
         # rows whose victims each claim took, in claim order
         self._inputs: Dict[Tuple[int, int, int], _Inputs] = {}
         self._claimed_at: List[int] = []
+        self._lane_map = None  # _enc_idx on the device, for wave launches
         # planner (snapshot) node order -> encoding lane
         self._enc_idx = np.array(
             [enc.node_index.get(ni.node.metadata.name, -1)
@@ -285,7 +311,34 @@ class DevicePreemptionPlanner(FastPreemptionPlanner):
 
     # -- per-pod rung routing ----------------------------------------------
 
-    def _plan_one(self, pod: v1.Pod, limit: int):
+    def _plan_pods(self, wave: List[v1.Pod], limit: int):
+        """The wave in order. A run of consecutive device-eligible
+        preemptors of one launch key (view, template, priority) is
+        planned by wave launches of up to WAVE_STEPS preemptors each,
+        where its claims are lane-local; every other preemptor on its
+        own rung (_plan_one), the reason counted."""
+        from ..ops.whatif import WAVE_STEPS
+
+        why = self._wave_reason()
+        if why is not None:
+            return [self._plan_one(pod, limit, single=why) for pod in wave]
+        out: List = []
+        k = 0
+        while k < len(wave):
+            run, head = self._next_run(wave, k, WAVE_STEPS)
+            why = "key" if head is None else self._single_reason(head, run)
+            if why is None:
+                out.extend(self._plan_run(run, head, limit))
+            else:
+                out.extend(self._plan_one(pod, limit, single=why)
+                           for pod in run)
+            k += len(run)
+        return out
+
+    def _plan_one(self, pod: v1.Pod, limit: int, single: str = "key"):
+        """One preemptor on its rung: a launch of its own on the device
+        rung (`single`: why it is not in a wave launch), else one rung
+        down."""
         dev_ok, fast_ok = self.eligibility.get(v1.pod_key(pod),
                                                (False, True))
         if dev_ok:
@@ -306,24 +359,35 @@ class DevicePreemptionPlanner(FastPreemptionPlanner):
                         # pick: results on the host -> candidate claimed
                         sp.set(pick_s=_time.perf_counter() - self._t_results,
                                **self._launch_attrs)
-                self.fits_now.append(fits)
-                self.planner_paths.append("device")
-                metrics.preemption_planner.inc(path="device")
+                self._planned(fits, "single", single)
                 return cand
             except Exception as e:  # noqa: BLE001 — any device/prep
                 # failure falls one rung; the wave must keep planning
-                from ..ops.whatif import WhatifUnavailable
+                self._fell(e)
+        return self._lower_rung(pod, limit, fast_ok)
 
-                if isinstance(e, DeviceFault):
-                    reason = "fault"
-                    self.backend.record_whatif_fault(e.kind)
-                elif isinstance(e, WhatifUnavailable):
-                    reason = e.reason
-                else:
-                    reason = "error"
-                    logger.warning("what-if planning failed; falling back",
-                                   exc_info=True)
-                metrics.whatif_fallbacks.inc(reason=reason)
+    def _planned(self, fits: bool, path: str, reason: str) -> None:
+        self.fits_now.append(fits)
+        self.planner_paths.append("device")
+        metrics.preemption_planner.inc(path="device")
+        metrics.whatif_planned.inc(path=path, reason=reason)
+
+    def _fell(self, e: Exception) -> None:
+        """Count why a device launch could not plan its preemptor."""
+        from ..ops.whatif import WhatifUnavailable
+
+        if isinstance(e, DeviceFault):
+            reason = "fault"
+            self.backend.record_whatif_fault(e.kind)
+        elif isinstance(e, WhatifUnavailable):
+            reason = e.reason
+        else:
+            reason = "error"
+            logger.warning("what-if planning failed; falling back",
+                           exc_info=True)
+        metrics.whatif_fallbacks.inc(reason=reason)
+
+    def _lower_rung(self, pod: v1.Pod, limit: int, fast_ok: bool):
         if fast_ok:
             self.planner_paths.append("fast")
             return super()._plan_one(pod, limit)
@@ -331,16 +395,14 @@ class DevicePreemptionPlanner(FastPreemptionPlanner):
         self.fits_now.append(False)
         return ORACLE_FALLBACK
 
-    # -- the device rung ---------------------------------------------------
+    # -- the device rung: launch keys --------------------------------------
 
-    def _plan_one_device(self, pod: v1.Pod, limit: int):
-        """One fused what-if launch for this preemptor; returns
-        (fits_now, Candidate | None). Raises WhatifUnavailable /
-        DeviceFault to fall a rung."""
+    def _launch_key(self, pod: v1.Pod) -> "_Key":
+        """The view, template and priority this preemptor launches
+        under. Raises WhatifUnavailable to fall a rung."""
+        from ..ops.hoisted import template_fingerprint
         from ..ops.whatif import WhatifUnavailable
         from .volume_device import VolumeResolutionChanged
-
-        from ..ops.hoisted import template_fingerprint
 
         backend = self.backend
         try:
@@ -352,17 +414,12 @@ class DevicePreemptionPlanner(FastPreemptionPlanner):
         ctx = self._ctx.get(fp)
         if ctx is None:
             ctx = self._ctx[fp] = backend.whatif_context(pa)
-        t_prep = _time.perf_counter()
         tj = ctx.template_index(pa)
-        nps = ctx.np_slices(tj)
-        prio = _prio(pod)
-        req = self._req_vec(pod)
         lanes = self._enc_idx
-        Ncap = ctx.n_lanes
         if (
             self.n == 0
             or (lanes < 0).any()
-            or int(lanes.max()) >= Ncap
+            or int(lanes.max()) >= ctx.n_lanes
             # the lane map must describe the SAME encoding epoch the
             # context snapshotted: concurrent churn reorders lanes
             # in-range (capacities are pow2 buckets), so the version
@@ -371,21 +428,29 @@ class DevicePreemptionPlanner(FastPreemptionPlanner):
         ):
             raise WhatifUnavailable("node table skew vs the encoding",
                                     reason="node-skew")
+        return _Key(ctx, ctx.np_slices(tj), tj, _prio(pod))
 
-        key = (id(ctx), tj, prio)
-        same_key = nps["f_same_key"].astype(np.int32)      # [C, C]
-        C_n = same_key.shape[0]
-        taa = nps["ipaaa_valid"].shape[0]
-        inp = self._inputs.get(key)
+    def _key_inputs(self, k: "_Key") -> _Inputs:
+        inp = self._inputs.get(k.key)
         if inp is None:
-            inp = self._inputs[key] = _Inputs(
-                Ncap, self._enc_r, C_n, taa, ctx.vnp)
+            inp = self._inputs[k.key] = _Inputs(
+                k.ctx.n_lanes, self._enc_r, k.same_key.shape[0],
+                k.nps["ipaaa_valid"].shape[0], k.ctx.vnp)
+        return inp
+
+    def _launch_inputs(self, k: "_Key", inp: _Inputs):
+        """The next launch's inputs for key `k`: the claims since its
+        last launch taken into the running totals, then the device's
+        copy (donated to the launch: a raise from here on leaves none,
+        and the next launch uploads whole) with a delta, or a whole
+        upload. Returns (x, delta, why whole or None, lanes the delta
+        touches, bytes uploaded)."""
+        ctx, nps, tj, prio, same_key = k.ctx, k.nps, k.tj, k.prio, k.same_key
         nom_new = self._nom_take(ctx, nps, tj, prio, inp, same_key)
         pre_new = self._pre_take(ctx, nps, tj, inp, same_key)
         why = self._full_reason(inp)
-        # the device's copy goes to this launch (donated): a raise from
-        # here on leaves none, and the next launch uploads whole
         x, inp.x, inp.lost = inp.x, None, True
+        n_delta = 0
         if why is None:
             try:
                 delta, n_delta = self._delta(ctx, nps, tj, prio, inp,
@@ -400,24 +465,49 @@ class DevicePreemptionPlanner(FastPreemptionPlanner):
                               if why is not None else 0)
         metrics.whatif_inputs.inc(path="full" if why else "delta",
                                   reason=why or "resident")
+        return x, delta, why, n_delta, h2d
+
+    def _launched(self, ys, names: Sequence[str], what: str):
+        """Wait for a launch's results under the watchdog and bring
+        `names` of them to the host; a wedge or a raise is a fault."""
+        backend = self.backend
+        try:
+            if not backend._wait_ready(ys, backend.watchdog_timeout):
+                raise DeviceFault(f"{what} exceeded the watchdog",
+                                  kind="timeout")
+            return [np.asarray(ys[n]) for n in names]
+        except DeviceFault:
+            raise
+        except Exception as e:  # noqa: BLE001 — launch-path raise = fault
+            raise DeviceFault(f"{what} raised: {e}", kind="raise") from e
+
+    # -- the device rung: one preemptor a launch ---------------------------
+
+    def _plan_one_device(self, pod: v1.Pod, limit: int):
+        """One fused what-if launch for this preemptor; returns
+        (fits_now, Candidate | None). Raises WhatifUnavailable /
+        DeviceFault to fall a rung."""
+        backend = self.backend
+        k = self._launch_key(pod)
+        t_prep = _time.perf_counter()
+        req = self._req_vec(pod)
+        lanes = self._enc_idx
+        inp = self._key_inputs(k)
+        x, delta, why, n_delta, h2d = self._launch_inputs(k, inp)
 
         # -- the launch ----------------------------------------------------
         try:
             backend.check_whatif_fault()
             metrics.whatif_launches.inc()
-            ys, x = ctx.run(tj, x, delta, inp.nom["n"] > 0)
-            t_wait = _time.perf_counter()
-            if not backend._wait_ready(ys, backend.watchdog_timeout):
-                raise DeviceFault("what-if launch exceeded the watchdog",
-                                  kind="timeout")
-            fits_now = np.asarray(ys["fits_now"])
-            base = np.asarray(ys["base"])
-            victims_dev = np.asarray(ys["victims"])
+            ys, x = k.ctx.run(k.tj, x, delta, inp.nom["n"] > 0)
         except DeviceFault:
             raise
         except Exception as e:  # noqa: BLE001 — launch-path raise = fault
             raise DeviceFault(f"what-if launch raised: {e}",
                               kind="raise") from e
+        t_wait = _time.perf_counter()
+        fits_now, base, victims_dev = self._launched(
+            ys, ("fits_now", "base", "victims"), "what-if launch")
         if self.resident_inputs:
             inp.x, inp.lost = x, False
         self._t_results = _time.perf_counter()
@@ -426,7 +516,7 @@ class DevicePreemptionPlanner(FastPreemptionPlanner):
             "wait_s": self._t_results - t_wait,
             "inputs": "full" if why else "delta",
             "h2d_bytes": h2d,
-            "delta_lanes": 0 if why else n_delta,
+            "delta_lanes": n_delta,
         }
         L, slot_j, slot_valid, slot_vio = (
             inp.L, inp.slot_j, inp.slot_valid, inp.slot_vio)
@@ -465,17 +555,192 @@ class DevicePreemptionPlanner(FastPreemptionPlanner):
         if ci is None:
             return False, None
         i = int(Cc[ci])
-        victims = [
-            vp
-            for s in range(L) if vmask[ci, s]
-            for vp in self._vpods[i][int(sj[ci, s])]
-        ]
-        cand = Candidate(
-            self.nodes[i].node.metadata.name, victims,
-            num_pdb_violations=int(n_pdbv[ci]),
-        )
-        self._claim(cand, pod, prio, req)
+        cand = self._candidate(i, vmask[ci], slot_j[i], slot_vio[i], L)
+        self._claim(cand, pod, k.prio, req)
         return False, cand
+
+    def _candidate(self, i: int, picked: np.ndarray, slot_j: np.ndarray,
+                   slot_vio: np.ndarray, L: int) -> Candidate:
+        """Planner row i's victims: the members of the picked slots, in
+        slot (reprieve) order."""
+        sj = slot_j.astype(np.int64)
+        victims = [vp for s in range(L) if picked[s]
+                   for vp in self._vpods[i][int(sj[s])]]
+        n_pdbv = int(np.where(picked & slot_vio, self._vsize[i, sj], 0).sum())
+        return Candidate(self.nodes[i].node.metadata.name, victims,
+                         num_pdb_violations=n_pdbv)
+
+    # -- the device rung: wave launches ------------------------------------
+
+    def _wave_reason(self) -> Optional[str]:
+        """Why no preemptor of this wave may take a wave launch, or
+        None."""
+        if not self.wave_launch:
+            return "off"
+        if self._pdb_match.any():
+            # a budget moves with every claim and re-splits every node's
+            # victims into violating and not: host bookkeeping
+            return "pdb"
+        return None
+
+    def _next_run(self, wave: List[v1.Pod], k: int, steps: int):
+        """(run, its launch key): the preemptors from wave[k] on that
+        share one launch key, at most `steps`; ([wave[k]], None) where
+        wave[k] has no key (not device-eligible, or its launch would
+        fall a rung: _plan_one meets the same and counts it)."""
+        head = self._key_of(wave[k])
+        if head is None:
+            return wave[k:k + 1], None
+        end = k + 1
+        while end < len(wave) and end - k < steps:
+            nxt = self._key_of(wave[end])
+            if nxt is None or nxt.key != head.key:
+                break
+            end += 1
+        return wave[k:end], head
+
+    def _key_of(self, pod: v1.Pod) -> Optional["_Key"]:
+        if not self.eligibility.get(v1.pod_key(pod), (False, True))[0]:
+            return None
+        try:
+            return self._launch_key(pod)
+        except Exception:  # noqa: BLE001 — planned alone, which counts it
+            return None
+
+    def _single_reason(self, k: "_Key", run: List[v1.Pod]) -> Optional[str]:
+        """Why the run's claims are not lane-local, or None: a claim
+        must move only its own lane's victim slots, drains and
+        nominated load — no topology-pair count (a victim or a
+        preemptor matching the template's spread classes or required
+        (anti-)affinity terms) and no gang unit among the victims."""
+        valid = self._valive & (self._vprio < k.prio)
+        if (self._vsize[valid] > 1).any():
+            return "gang"
+        if not _reads_pairs(k.nps):
+            return None
+        mfs, manti, mall = self._slot_matches(k.ctx, k.nps, k.tj,
+                                              k.same_key)
+        if mfs[valid].any() or manti[valid].any() or mall[valid].any():
+            return "pairs"
+        mf, manti, mall = self._match_rows(
+            k.ctx, k.nps, k.tj,
+            [self.backend._pod_self_rows(pod) for pod in run])
+        if (mf @ k.same_key.T).any() or manti.any() or mall.any():
+            return "pairs"
+        return None
+
+    def _plan_run(self, run: List[v1.Pod], k: "_Key", limit: int):
+        """A run planned by one wave launch, then replayed on the host
+        in plan order. Where the launch fails, its first preemptor falls
+        a rung, as its own launch's fault would fall it, and the rest
+        launch alone."""
+        try:
+            inp, res = self._wave_launch(run, k, limit)
+        except Exception as e:  # noqa: BLE001 — as _plan_one's
+            self._fell(e)
+            fast_ok = self.eligibility[v1.pod_key(run[0])][1]
+            return ([self._lower_rung(run[0], limit, fast_ok)]
+                    + [self._plan_one(pod, limit, single="fault")
+                       for pod in run[1:]])
+        return self._replay(run, k, inp, res)
+
+    def _wave_launch(self, run: List[v1.Pod], k: "_Key", limit: int):
+        """One wave launch over the run (ops/whatif._whatif_wave_run):
+        the key's inputs as a single launch takes them, the run's
+        request rows and the pick's tallies. Returns (the key's inputs,
+        {fits, pick, victims} on the host)."""
+        from ..ops.whatif import WAVE_STEPS
+
+        backend = self.backend
+        sp = tracing.span(
+            "whatif-wave", "whatif-wave", n=len(run), steps=WAVE_STEPS,
+        ) if tracing.enabled() else tracing.NOOP_SPAN
+        with sp:
+            t_prep = _time.perf_counter()
+            inp = self._key_inputs(k)
+            x, delta, why, n_delta, h2d = self._launch_inputs(k, inp)
+            wave = self._wave_tensors(run, inp, limit, WAVE_STEPS)
+            try:
+                backend.check_whatif_fault()
+                metrics.whatif_launches.inc()
+                ys, x = k.ctx.run_wave(k.tj, x, delta, wave)
+            except DeviceFault:
+                raise
+            except Exception as e:  # noqa: BLE001 — launch-path raise
+                raise DeviceFault(f"what-if wave launch raised: {e}",
+                                  kind="raise") from e
+            t_wait = _time.perf_counter()
+            fits, pick, victims = self._launched(
+                ys, ("fits", "pick", "victims"), "what-if wave launch")
+            if self.resident_inputs:
+                inp.x, inp.lost = x, False
+            sp.set(prep_s=t_wait - t_prep,
+                   wait_s=_time.perf_counter() - t_wait,
+                   inputs="full" if why else "delta", h2d_bytes=h2d,
+                   delta_lanes=n_delta)
+        return inp, {"fits": fits, "pick": pick, "victims": victims}
+
+    def _wave_tensors(self, run: List[v1.Pod], inp: _Inputs, limit: int,
+                      steps: int) -> Dict:
+        """The wave program's `wave`: per step whether it is a preemptor
+        and its request row in encoding dims (what a claim adds as
+        nominated load, as _claim's entry does); the lane map, the
+        candidate cut, and the pick's tallies of every planner row's
+        slots in the key's slot order, start times as exact integer
+        ranks."""
+        R = self._enc_r
+        enc = self.backend.enc
+        active = np.zeros(steps, bool)
+        active[:len(run)] = True
+        nom = np.zeros((steps, R), np.int64)
+        for s, pod in enumerate(run):
+            vec, _nz = enc.pod_row_delta(pod)
+            if vec.shape[0] == R:
+                nom[s] = vec
+        at = (np.arange(self.n)[:, None], inp.slot_j)
+        _, rank = np.unique(self._vlatest_hi[at].ravel(),
+                            return_inverse=True)
+        if self._lane_map is None:
+            import jax.numpy as jnp
+
+            # once a wave: the planner is built for one
+            self._lane_map = jnp.asarray(self._enc_idx.astype(np.int32))
+        return {
+            "active": active, "nom_req": nom, "lanes": self._lane_map,
+            "limit": np.int32(limit), "offset": np.int64(PRIORITY_OFFSET),
+            "prio": self._vprio[at], "priosum": self._vpriosum[at],
+            "latest": rank.reshape(inp.slot_j.shape).astype(np.int32),
+        }
+
+    def _replay(self, run: List[v1.Pod], k: "_Key", inp: _Inputs,
+                res: Dict) -> List:
+        """The wave launch's steps replayed on the host in plan order:
+        each pick becomes its Candidate and is claimed in the books as
+        the per-preemptor path claims it. The device took the same
+        claims into its copy of the inputs, its slots left in place
+        (zeroed): the key's slot validity and running totals follow, so
+        no later launch applies them again."""
+        out = []
+        for s, pod in enumerate(run):
+            sp = tracing.span(
+                "whatif", "whatif", pod=v1.pod_key(pod), path="wave",
+            ) if tracing.enabled() else tracing.NOOP_SPAN
+            with sp:
+                t0 = _time.perf_counter()
+                fits, i, cand = bool(res["fits"][s]), int(res["pick"][s]), None
+                if not fits and i >= 0:
+                    picked = res["victims"][s]
+                    cand = self._candidate(i, picked, inp.slot_j[i],
+                                           inp.slot_vio[i], inp.L)
+                    self._claim(cand, pod, k.prio, self._req_vec(pod))
+                    inp.slot_valid[i, picked] = False
+                sp.set(pick_s=_time.perf_counter() - t0)
+            self._planned(fits, "wave", "lane-local")
+            out.append(cand)
+        self._nom_take(k.ctx, k.nps, k.tj, k.prio, inp, k.same_key)
+        self._pre_take(k.ctx, k.nps, k.tj, inp, k.same_key)
+        inp.claims = len(self._claimed_at)
+        return out
 
     # -- host tensor prep helpers ------------------------------------------
 
@@ -507,6 +772,12 @@ class DevicePreemptionPlanner(FastPreemptionPlanner):
         if got is not None:
             return got
         vm = max(self._vmax, 1)
+        if not _reads_pairs(nps):
+            got = self._slot_memo[key] = (
+                np.zeros((self.n, vm, same_key.shape[0]), np.int32),
+                np.zeros((self.n, vm, nps["ipaaa_valid"].shape[0]), np.int32),
+                np.zeros((self.n, vm), np.int32))
+            return got
         rows: List[Dict] = []
         at: List[int] = []
         term: List[bool] = []
@@ -547,7 +818,7 @@ class DevicePreemptionPlanner(FastPreemptionPlanner):
         mf = np.zeros((B, C_n), np.int32)
         manti = np.zeros((B, taa), np.int32)
         mall = np.zeros(B, np.int32)
-        if B == 0:
+        if B == 0 or not _reads_pairs(nps):
             return mf, manti, mall
         view = id(ctx)  # a template index names a row of one view only
         miss = [
@@ -793,6 +1064,15 @@ class DevicePreemptionPlanner(FastPreemptionPlanner):
         delta = pack_delta(parts, inp.L, self._enc_r, same_key.shape[0],
                            nps["ipaaa_valid"].shape[0])
         return delta, n_lanes
+
+
+def _reads_pairs(nps: Dict) -> bool:
+    """Does the template read a topology-pair count: a hard spread class
+    or a required (anti-)affinity term? Where it reads none, the match
+    rows of pods against it are never looked at by the dry run, and are
+    zero."""
+    return bool(nps["f_valid"].any() or nps["ipaaa_valid"].any()
+                or nps["ipaa_valid"].any())
 
 
 def _sum_by(idx: np.ndarray, vals: Dict[str, np.ndarray],

@@ -113,9 +113,14 @@ STAGES = (
     "whatif",       # per-pod fused what-if launches (nested inside a
                     # planner span — a separate stage so stage_stats
                     # never double-counts the wave's wall-clock); steps
-                    # prep / wait / pick as attrs prep_s, wait_s, pick_s
-    "whatif-context",  # one what-if view of the cluster built (inside
-                    # the first whatif span of a wave and template)
+                    # prep / wait / pick as attrs prep_s, wait_s, pick_s;
+                    # on the wave path (attr path="wave") one a
+                    # preemptor's host replay of its step, pick_s
+    "whatif-wave",  # one wave launch of up to 64 preemptors of one key
+                    # (inside the planner span): n, steps, prep_s,
+                    # wait_s, inputs, h2d_bytes, delta_lanes
+    "whatif-context",  # one what-if view of the cluster built, once
+                    # a wave and template (inside the planner span)
     "preemption-wave",  # completion worker, umbrella of one failure
                     # wave that has preemptable pods: batch, n, keys;
                     # steps snapshot / eligibility / plan / register /

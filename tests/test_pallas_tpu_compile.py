@@ -134,10 +134,50 @@ def test_whatif_launch_compiles_for_v5e_at_5000_nodes(one_chip, monkeypatch):
     planner = DevicePreemptionPlanner(
         Snapshot.from_objects(pods, nodes), PodNominator(),
         _mk_backend(nodes, pods),
-        eligibility={v1.pod_key(p): (True, False) for p in wave})
+        eligibility={v1.pod_key(p): (True, False) for p in wave},
+        wave_launch=False)
     assert all(planner.plan(wave))
     args, kw = launches[1]  # its inputs were donated: shapes only
     assert kw["has_nom"]
+    shapes = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        args)
+    compiled = run.lower(*shapes, **kw).compile()
+    assert "input_output_alias" in compiled.as_text()
+
+
+def test_whatif_wave_launch_compiles_for_v5e_at_5000_nodes(one_chip,
+                                                           monkeypatch):
+    """The bursts cell's wave launch (5000 nodes, four victims a node):
+    64 steps of dry run, pick and claim over inputs kept on the device.
+    The chip's compiler must take the inputs as donated, and the scan
+    must carry them in place."""
+    from kubernetes_tpu.api import types as v1
+    from kubernetes_tpu.ops import whatif
+    from kubernetes_tpu.scheduler.framework.snapshot import Snapshot
+    from kubernetes_tpu.scheduler.internal.nominator import PodNominator
+    from kubernetes_tpu.scheduler.preemption_device import (
+        DevicePreemptionPlanner,
+    )
+
+    from .test_preemption_fast import _mk_backend
+    from .test_whatif_resident import _burst
+
+    launches = []
+    run = whatif._whatif_wave_run
+
+    def capture(*args, **kw):
+        launches.append((args, kw))
+        return run(*args, **kw)
+
+    monkeypatch.setattr(whatif, "_whatif_wave_run", capture)
+    nodes, pods, wave = _burst(5000, 2)
+    planner = DevicePreemptionPlanner(
+        Snapshot.from_objects(pods, nodes), PodNominator(),
+        _mk_backend(nodes, pods),
+        eligibility={v1.pod_key(p): (True, False) for p in wave})
+    assert all(planner.plan(wave))
+    (args, kw), = launches  # its inputs were donated: shapes only
     shapes = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
         args)

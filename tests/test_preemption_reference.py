@@ -4,8 +4,9 @@ again): seeded small clusters of low-priority pods of mixed priorities and
 sizes, then bursts of preemptors, each burst of one priority and bound
 before the next is created. Every pod's node and the set of pods the
 program evicted are compared exactly, on each planner rung: the device
-what-if rung (KTPU_WHATIF=1, the TPUBackend's jnp session on the CPU) and
-the numpy fast rung."""
+what-if rung (KTPU_WHATIF=1, the TPUBackend's jnp session on the CPU),
+where every burst is planned by wave launches, and the numpy fast
+rung."""
 
 from __future__ import annotations
 
@@ -83,10 +84,20 @@ def _scenario(seed: int):
             return config, stages, binds, evicted
 
 
-def _planner_paths():
-    m = legacy_registry._metrics.get("scheduler_preemption_planner_total")
+def _counts(name: str):
+    m = legacy_registry._metrics.get(name)
     with m._lock:
-        return {k[0]: v for k, v in m._values.items()}
+        return dict(m._values)
+
+
+def _planner_paths():
+    return {k[0]: v for k, v in
+            _counts("scheduler_preemption_planner_total").items()}
+
+
+def _moved(after, before):
+    return {k: v - before.get(k, 0) for k, v in after.items()
+            if v != before.get(k, 0)}
 
 
 @pytest.mark.parametrize("rung,seed", [("device", 8), ("fast", 8),
@@ -100,6 +111,7 @@ def test_preemption_matches_the_reference(monkeypatch, rung, seed):
     cluster.build()
     try:
         before = _planner_paths()
+        launched = _counts("scheduler_whatif_planned_total")
         for stage in stages:
             cluster.stage(cluster.prebuild(
                 [cluster.pod_class(name) for name in stage]), timeout=120.0)
@@ -107,6 +119,8 @@ def test_preemption_matches_the_reference(monkeypatch, rung, seed):
         gone = set(cluster.deleted_t)
         classes, log = cluster.classes, cluster.log
         after = _planner_paths()
+        launched = _moved(_counts("scheduler_whatif_planned_total"),
+                          launched)
     finally:
         cluster.close()
     # the benchmark's classes are the configuration's templates in the
@@ -118,7 +132,10 @@ def test_preemption_matches_the_reference(monkeypatch, rung, seed):
         if i in gone:
             continue
         assert stored.get(i) == node_name(node), i
-    planned = {k: v - before.get(k, 0) for k, v in after.items()
-               if v != before.get(k, 0)}
+    planned = _moved(after, before)
     n_pre = sum(len(s) for s in stages[3:])
     assert planned == {rung: n_pre}
+    # the device rung's bursts took wave launches, the pick and the claim
+    # on the device
+    assert launched == ({("wave", "lane-local"): n_pre}
+                        if rung == "device" else {})

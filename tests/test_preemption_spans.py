@@ -39,7 +39,8 @@ CONFIG = {
 }
 NEW_STAGES = ("preemption-wave", "preemption-books", "evict",
               "preemption-wait", "nominated-place", "whatif-context",
-              "template-admit")
+              "template-admit", "whatif-wave")
+REPO = os.path.dirname(BENCH)
 
 
 def _builder():
@@ -176,12 +177,32 @@ def test_books_span_has_the_device_planners_steps(burst):
 
 
 def test_whatif_spans_carry_pick_s(burst):
+    """One `whatif` span a preemptor, with its `pod`. The burst's
+    preemptors are one run of one key: one wave launch plans them, and
+    each span is that preemptor's host replay of its step."""
     events, keys, _ = burst
     launches = _of(events, "whatif")
-    assert {a["pod"] for *_, a in launches} == keys
+    assert sorted(a["pod"] for *_, a in launches) == sorted(keys)
     for _, _, dur, a in launches:
-        assert a["pick_s"] > 0.0
-        assert a["prep_s"] + a["wait_s"] + a["pick_s"] <= dur
+        assert a["path"] == "wave"
+        assert 0.0 < a["pick_s"] <= dur
+
+
+def test_a_wave_launch_span_names_its_preemptors_and_steps(burst):
+    """`whatif-wave`, one a wave launch: `n` preemptors of `steps`, its
+    host preparation and its wait inside it, and it ends before the
+    replay of its first preemptor begins."""
+    from kubernetes_tpu.ops.whatif import WAVE_STEPS
+
+    events, keys, _ = burst
+    waves = _of(events, "whatif-wave")
+    assert sum(a["n"] for *_, a in waves) == len(keys)
+    first = min(t0 for _, t0, _, _ in _of(events, "whatif"))
+    for _, t0, dur, a in waves:
+        assert a["steps"] == WAVE_STEPS and 1 <= a["n"] <= WAVE_STEPS
+        assert a["prep_s"] >= 0.0 and a["wait_s"] > 0.0
+        assert a["prep_s"] + a["wait_s"] <= dur
+    assert min(t0 + dur for _, t0, dur, _ in waves) <= first
 
 
 def test_evict_names_the_preemptors_and_their_victims(burst):
@@ -214,3 +235,31 @@ def test_nominated_place_has_batch_and_keys(burst):
     assert sorted(placed) == sorted(keys)
     assert all(isinstance(a["batch"], int) and a["n"] >= len(a["keys"])
                for *_, a in places)
+
+
+def test_the_rehearsal_on_wave_launches_tiles_every_preemptor():
+    """The CPU rehearsal of the bursts cell, traced, on the device rung:
+    every preemptor planned in a wave launch, and benchlib/preemptpath.py
+    still tiles each one's wait by its own `whatif` span (on this path
+    its host replay), none left untiled by a segment that runs
+    backwards."""
+    import json
+    import subprocess
+
+    env = dict(os.environ, JAX_PLATFORMS="cpu", KTPU_WHATIF="1",
+               JAX_COMPILATION_CACHE_DIR=os.path.join(
+                   REPO, ".xla_cache", "rehearsal"))
+    p = subprocess.run(
+        [sys.executable, os.path.join(BENCH, "run.py"), "--workload",
+         "rehearsal-preemption-96n.rehearsal-bursts", "--seed",
+         str(2 ** 31 + 41), "--seconds", "3", "--trace", "1",
+         "--rehearse"],
+        cwd=REPO, env=env, capture_output=True, text=True, timeout=600)
+    assert p.returncode == 0, p.stderr[-2000:]
+    line = json.loads(p.stdout.strip().splitlines()[-1])
+    assert line["correct"] is True
+    assert line["metrics"]["whatif_wave_share"]["value"] == 1.0
+    path = line["detail"]["notes"]["preemptor_path"]
+    assert path["pods"] == line["attempted"] - line["failed"] > 0
+    assert path["out_of_order"] == {}
+    assert path["tiled"] == path["joined"] == path["pods"]

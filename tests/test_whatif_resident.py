@@ -9,6 +9,10 @@ tests/test_preemption_fast.py pins to the oracle. Randomised waves
 cover what a claim moves: PDB-covered victims, nominated load above and
 below the wave's priority, gang units, spread and affinity templates,
 victims an earlier wave claimed, and two priorities in one wave.
+
+These are the inputs of preemptors launched one at a time
+(`wave_launch=False`): tests/test_whatif_wave.py holds wave launches,
+whose inputs stay on the device the same way, to this path.
 """
 
 from __future__ import annotations
@@ -145,7 +149,7 @@ def _plan(nodes, pods, wave, kwargs, resident: bool, backend=None):
         Snapshot.from_objects(pods, nodes), nominator,
         backend or _mk_backend(nodes, pods),
         eligibility={v1.pod_key(p): (True, False) for p in wave},
-        resident_inputs=resident, **kw)
+        resident_inputs=resident, wave_launch=False, **kw)
     cands = planner.plan(wave)
     return planner, cands
 
@@ -262,7 +266,7 @@ def test_moved_pdb_budget_takes_the_full_path():
             Snapshot.from_objects(pods, nodes), PodNominator(),
             _mk_backend(nodes, pods), pdbs=[pdb],
             eligibility={v1.pod_key(p): (True, False) for p in wave},
-            resident_inputs=resident)
+            resident_inputs=resident, wave_launch=False)
         out.append(_summary(planner.plan(wave)))
     assert out[0] == out[1]
     assert all(c is not None for c in out[1])
@@ -336,7 +340,8 @@ def test_fault_mid_wave_falls_back_to_the_full_path():
     fault0 = _inputs("full", "fault")
     planner = DevicePreemptionPlanner(
         Snapshot.from_objects(pods, nodes), PodNominator(), backend,
-        eligibility={v1.pod_key(p): (True, True) for p in wave})
+        eligibility={v1.pod_key(p): (True, True) for p in wave},
+        wave_launch=False)
     cands = planner.plan(wave)
     assert inj.injected.get("raise-whatif") == 1
     assert planner.planner_paths == ["device"] * 4 + ["fast"] + \
