@@ -103,6 +103,13 @@ def _is_wildcard(ip: str) -> bool:
     return ip in _WILDCARD_IPS
 
 
+def _score_terms_of(pi: PodInfo) -> int:
+    """Rows a pod takes in the score-term table."""
+    return (len(pi.required_affinity_terms)
+            + len(pi.preferred_affinity_terms)
+            + len(pi.preferred_anti_affinity_terms))
+
+
 class _TermRows:
     """Growable stacked term-table arrays (per existing-pod affinity terms)."""
 
@@ -231,6 +238,11 @@ class ClusterEncoding:
         self._anti_reserve = 0
         self._score_reserve = 0
         self._node_reserve = 0
+        # score terms (required affinity, preferred affinity and
+        # anti-affinity) the pods seen at a rebuild carry, scaled to the
+        # pod reserve: a floor of the score-term table, sticky like the
+        # reserves
+        self._score_floor = 0
         # node-lane capacity quantum: the mesh backend sets this to the
         # shard count so padded capacity divides the mesh evenly and the
         # session's lane space aligns with the encoding's
@@ -263,7 +275,11 @@ class ClusterEncoding:
         of every kernel shape in flight. One reserve call up front
         collapses that to a single rebuild. The floors are sticky
         (max-accumulating) and apply to the pod table and the
-        anti/score affinity term tables."""
+        anti/score affinity term tables. The score-term table also takes
+        `pods` times the score terms the pods seen so far carry on
+        average: a reserve of pods is a reserve of the terms pods like
+        those carry, so the table steps once, at the first pod with
+        score terms, and not as such pods come."""
         self._pod_reserve = max(self._pod_reserve, pods)
         self._anti_reserve = max(self._anti_reserve, anti_terms)
         self._score_reserve = max(self._score_reserve, score_terms)
@@ -870,12 +886,10 @@ class ClusterEncoding:
 
         # term tables: size from observed maxima
         n_anti = sum(len(pi.required_anti_affinity_terms) for pi in pod_infos.values())
-        n_score = sum(
-            len(pi.required_affinity_terms)
-            + len(pi.preferred_affinity_terms)
-            + len(pi.preferred_anti_affinity_terms)
-            for pi in pod_infos.values()
-        )
+        n_score = sum(_score_terms_of(pi) for pi in pod_infos.values())
+        if pod_infos:
+            self._score_floor = max(self._score_floor, -(
+                -self._pod_reserve * n_score // len(pod_infos)))
         max_r, max_v, max_ns = 1, 1, 1
         for pi in pod_infos.values():
             for terms in (
@@ -895,7 +909,8 @@ class ClusterEncoding:
             bucket_capacity(max_v, 2), bucket_capacity(max_ns, 2), scored=False,
         )
         self._score_terms = _TermRows(
-            bucket_capacity(max(n_score, self._score_reserve, 1), minimum=16),
+            bucket_capacity(max(n_score, self._score_reserve,
+                                self._score_floor, 1), minimum=16),
             bucket_capacity(max_r, 2),
             bucket_capacity(max_v, 2), bucket_capacity(max_ns, 2), scored=True,
         )
@@ -1064,9 +1079,16 @@ class ClusterEncoding:
         if (before != after or not self._pod_free
                 or self.node_key_vocab.capacity > self._arrays["nkey"].shape[1]):
             return False
+        if (self._pod_reserve and not self._score_floor
+                and _score_terms_of(pod_info)):
+            return False  # the one step: sized for pods like this one
+        taken: Dict[str, int] = {}
         for which, table, ns_ids, _k, _kind, _w in term_rows:
             rows = self._anti_terms if which == "anti" else self._score_terms
-            if rows.needs_grow(table, len(ns_ids)):
+            # every term of the pod takes a free row of its table
+            taken[which] = taken.get(which, 0) + 1
+            if (rows.needs_grow(table, len(ns_ids))
+                    or len(rows.free) < taken[which]):
                 return False
         pidx = self._pod_free.pop()
         self.pod_index[key] = pidx

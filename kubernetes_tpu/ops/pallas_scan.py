@@ -67,8 +67,13 @@ Design notes (vs ops/hoisted.py _step, whose semantics this mirrors):
   (_least_wide, _balanced_wide), for every value below POS_BIG.
 - **gather-free spread counts**: per-node count rows; zone expansion and
   scored-set registration are MXU matvecs against a static one-hot.
-- float64 score math that remains (PTS topology weights, IPA
-  normalization) runs in float32; parity is pinned by tests.
+- **InterPodAffinity's normalization is exact**: upstream truncates
+  100 * float64((s - min) / (max - min)); the kernel takes the exact
+  floor in int32 and reads one less at the three whole values where
+  float64 does (ipa_normalize), for every difference below 2^30; a
+  session whose raw scores could pass that is refused (_score_bound).
+- float64 score math that remains (a per-node PTS weight) runs in
+  float32; parity is pinned by tests.
 - first-max tie-break (min index among maxima) is explicit — Mosaic's
   argmax lane order is unspecified.
 
@@ -146,6 +151,49 @@ PTS_LIMBS = 5
 PTS_BASE = 1 + 2 * MAX_QUIRKS          # where the limbs start in `bad`
 PTS_BASE_WIDE = 1 + 3 * MAX_QUIRKS
 PTS_MAX_COUNT = 1 << 18
+# InterPodAffinity's NormalizeScore is int64(100 * (float64(s - min) /
+# float64(max - min))). Where 100 * (s - min) / (max - min) is no whole
+# number it lies at least 1 / (max - min) from one, far above float64's
+# error: the truncation is the exact floor. Where it is a whole number k,
+# (s - min) / (max - min) == k / 100 exactly, so float64 reads what it
+# reads at k / 100 alone: one less at these k (0.29 * 100 = 28.99...).
+IPA_F64_LOW = tuple(k for k in range(MAX_NODE_SCORE + 1)
+                    if int(MAX_NODE_SCORE * (k / MAX_NODE_SCORE)) < k)
+# the exact form holds for differences below this (15-bit limbs)
+IPA_EXACT_MAX = POS_BIG
+
+
+def ipa_normalize(d, diff):
+    """InterPodAffinity's normalized score, upstream's float64 expression
+    bit for bit, in int32: 0 where diff <= 0, else for 0 <= d <= diff <
+    IPA_EXACT_MAX the floor k of 100 * d / diff, one less where float64
+    reads one less (IPA_F64_LOW). A float32 quotient gives k to within
+    one; the exact sign of 100 * d - q * diff, in 15-bit limbs, settles
+    it. Runs in the table kernel and in the mesh's (ops/sharded_scan.py)."""
+    i32, f32 = jnp.int32, jnp.float32
+    b = jnp.maximum(diff, i32(1))
+    est = (MAX_NODE_SCORE * (d.astype(f32) / b.astype(f32))).astype(i32)
+    est = jnp.clip(est, i32(0), i32(MAX_NODE_SCORE))
+    dh, dl = d >> 15, d & i32(0x7FFF)
+    bh, bl = b >> 15, b & i32(0x7FFF)
+
+    def excess(q):
+        """100 * d - q * b as (hi, lo), lo in [0, 2^15): it is >= 0 iff
+        hi >= 0, and 0 iff both are."""
+        lo = i32(MAX_NODE_SCORE) * dl - q * bl
+        hi = i32(MAX_NODE_SCORE) * dh - q * bh + (lo >> 15)
+        return hi, lo & i32(0x7FFF)
+
+    (dn_hi, dn_lo), (at_hi, at_lo), (up_hi, up_lo) = (
+        excess(est + i32(step)) for step in (-1, 0, 1))
+    k = est - 1 + (at_hi >= 0).astype(i32) + (up_hi >= 0).astype(i32)
+    whole = (((k < est) & (dn_hi == 0) & (dn_lo == 0))
+             | ((k == est) & (at_hi == 0) & (at_lo == 0))
+             | ((k > est) & (up_hi == 0) & (up_lo == 0)))
+    low = jnp.zeros(jnp.shape(k), jnp.bool_)
+    for v in IPA_F64_LOW:
+        low = low | (k == v)
+    return jnp.where(diff <= 0, i32(0), k - (whole & low).astype(i32))
 
 
 @functools.lru_cache(maxsize=None)
@@ -412,7 +460,7 @@ class _Layout(NamedTuple):
     CHK: int
     HAS: int
     NZ: int
-    IPAP: int
+    IPAP: int    # the spec reads an IPA score (static or a score row)
     STAT: int
     PF: int
     PS: int
@@ -425,9 +473,9 @@ class _Layout(NamedTuple):
 
 PF_W = 6   # valid, skew, self_match, count row, registered row, key-on-node row
 PS_W = 9   # valid, skew, first, key, perno, count row, zone-valid row, key-on-node row, zrow
-IPA_W = 13  # has_aff, self_match_all, aff_total, fail row, all-keys row,
+IPA_W = 12  # has_aff, self_match_all, aff_total, fail row, all-keys row,
 #             repel (off, n), anti terms (off, n), aff terms (off, n),
-#             score row, presence row
+#             score row
 (ST_MASK, ST_RAW_IPA, ST_TAINT, ST_NODEAFF, ST_IMAGE, ST_AVOID, ST_HAS_ALL,
  ST_SRC) = range(8)
 
@@ -586,13 +634,11 @@ def _build_kernel(cfg: _Cfg, Bp: int):
             lane_n = jax.lax.broadcasted_iota(i32, (1, Np), 1)
             valid_n = validn_ref[0:1, :]
             static_mask = srow(sp(L.STAT + ST_MASK))
-            raw_ipa = srow(sp(L.STAT + ST_RAW_IPA))
             cnt_taint = srow(sp(L.STAT + ST_TAINT))
             cnt_nodeaff = srow(sp(L.STAT + ST_NODEAFF))
             sc_image = srow(sp(L.STAT + ST_IMAGE))
             sc_avoid = srow(sp(L.STAT + ST_AVOID))
             shasall = srow(sp(L.STAT + ST_HAS_ALL))
-            ipa_present = sp(L.IPAP)
 
             # ---- NodeResourcesFit (exact int32 after GCD rescale) ----
             mask_fit = fit_row(sp)
@@ -768,23 +814,20 @@ def _build_kernel(cfg: _Cfg, Bp: int):
             norm = jnp.where(ignored, i32(0), norm)
             sc_pts = jnp.where(have_s != 0, norm, i32(0))
 
-            # ---- IPA score: static raw + the reader's score row (D4+D5) --
-            present = ipa_present != 0
-            if dyn_ipa:
-                raw_ipa = raw_ipa + crow(sp(L.IPA + 11))
-                present = present | any_lane(crow(sp(L.IPA + 12)))
+            # ---- IPA score: static raw + the reader's score row (D4+D5),
+            # normalized over the feasible nodes. A spec that reads no
+            # score has a raw of 0 on every lane, and so a score of 0:
+            # upstream's empty topology map, without a presence test ----
+            def ipa_score():
+                raw = srow(sp(L.STAT + ST_RAW_IPA))
+                if dyn_ipa:
+                    raw = raw + crow(sp(L.IPA + 11))
+                min_i = jnp.min(jnp.where(feasible, raw, i32(POS_BIG)))
+                max_i = jnp.max(jnp.where(feasible, raw, i32(NEG_BIG)))
+                return ipa_normalize(raw - min_i, max_i - min_i)
 
-            # ---- IPA normalize ----
-            min_i = jnp.min(jnp.where(feasible, raw_ipa, i32(POS_BIG)))
-            max_i = jnp.max(jnp.where(feasible, raw_ipa, i32(NEG_BIG)))
-            diff = (max_i - min_i).astype(f32)
-            ipa = jnp.where(
-                diff > 0,
-                (MAX_NODE_SCORE * ((raw_ipa - min_i).astype(f32)
-                                   / jnp.where(diff > 0, diff, f32(1.0))))
-                .astype(i32),
-                jnp.zeros((1, Np), i32))
-            ipa = jnp.where(present, ipa, jnp.zeros((1, Np), i32))
+            ipa = jax.lax.cond(sp(L.IPAP) != 0, ipa_score,
+                               lambda: jnp.zeros((1, Np), i32))
 
             # ---- default-normalized taint / node-affinity ----
             def norm_default(counts, reverse):
@@ -1131,6 +1174,10 @@ class PallasSession:
         self._uids: Dict[bytes, int] = {}
         self._zof: List[Dict[int, int]] = []
         self._pair_rows: Dict[int, int] = {}   # topology key -> pair row
+        # pair row -> the most pods one of its topology domains can hold
+        self._pair_cap: Dict[int, int] = {}
+        self._score_rows: set = set()   # every score row
+        self._score_pairs = 0           # D4/D5 touches wired so far
         self._n_cnt = 1   # row 0: all zero, never written (absent reads)
         self._pending: Dict[str, List] = {"srow": [], "zrow": [], "cnt": [],
                                           "onehot": []}
@@ -1352,9 +1399,12 @@ class PallasSession:
         if i is None:
             c = self._c
             ok = c["nkey"][:, key].astype(bool) & c["valid"].astype(bool)
-            i = self._intern_srow(self._node_row(
-                np.where(ok, c["pair_of_key"][:, key], -1), fill=-1))
+            pair = np.where(ok, c["pair_of_key"][:, key], -1)
+            i = self._intern_srow(self._node_row(pair, fill=-1))
             self._pair_rows[key] = i
+            pods = np.bincount(pair[ok], weights=c["allowed_pods"][ok]) \
+                if ok.any() else np.zeros(1)
+            self._pair_cap[i] = int(pods.max())
         return i
 
     def _zone_key(self, column: np.ndarray) -> Tuple[int, int]:
@@ -1450,6 +1500,10 @@ class PallasSession:
     def count_rows(self) -> int:
         return self._n_cnt
 
+    @property
+    def static_rows(self) -> int:
+        return len(self._srow_ids)
+
     def has(self, fp) -> bool:
         return fp in self._fps
 
@@ -1465,14 +1519,21 @@ class PallasSession:
         on the device alone: the snapshot would miss them). Raises
         TableFull / PallasUnsupported where the specs do not fit: the
         session is then half-written and DEAD, the caller rebuilds.
-        Returns {"n", "rows"}."""
+        Returns {"n", "rows", "score_pairs", "flush_s"} (flush_s: the
+        seconds flush() took, 0.0 where it was not called)."""
         new = self._unknown(template_arrays_list)
         if not new:
-            return {"n": 0, "rows": 0}
+            return {"n": 0, "rows": 0, "score_pairs": 0, "flush_s": 0.0}
+        flush_s = 0.0
         if flush is not None and self._read_by_new(new):
+            t0 = _time.perf_counter()
             flush()
+            flush_s = _time.perf_counter() - t0
+        pairs0 = self._score_pairs
         out = self._admit(cluster_fn(), new)
         self.admits += 1
+        out["score_pairs"] = self._score_pairs - pairs0
+        out["flush_s"] = flush_s
         return out
 
     def _unknown(self, arrays_list: List[Dict]) -> List[Dict]:
@@ -1547,9 +1608,15 @@ class PallasSession:
             own = tab["self_ns"].astype(np.int64)
             hit = (self._match(tab, "ptsf", pp, pk, ns, own).any()
                    or self._match(tab, "ptss", pp, pk, ns, own).any())
-            return bool(hit or any(
-                self._match(tab, f, pp, pk, ns).any()
-                for f in _TERM_FAMILIES))
+            if hit or any(self._match(tab, f, pp, pk, ns).any()
+                          for f in _TERM_FAMILIES):
+                return True
+            # ... or a term of a live spec select a new spec's labels
+            # (its pods repel the new spec, or move its score: D1, D4, D5)
+            live = {k: v[: self.T] for k, v in self._stack.items()}
+            npp, npk, nns = self._label_batch(new)
+            return bool(any(self._match(live, f, npp, npk, nns).any()
+                            for f in _TERM_FAMILIES))
         except (ValueError, IndexError):
             return True   # shapes moved: _admit will say so; be safe
 
@@ -1670,8 +1737,12 @@ class PallasSession:
             rec[L.STAT + j] = self._intern_srow(self._node_row(S[k][i]))
         valid_nodes = self._c["valid"].astype(bool)
         rows = {"f": [0] * C, "s": [0] * C, "f_pair": [0] * C,
-                "s_pair": [0] * C, "s_src": [-1] * C,
-                "score": 0, "present": 0, "score_w": 0}
+                "s_pair": [0] * C, "s_src": [-1] * C, "score": 0,
+                # pair row -> writer -> weight a pod of it adds there,
+                # and what the snapshot counted (_score_bound)
+                "score_w": {}, "static_bound": int(np.abs(np.asarray(
+                    S["raw_ipa"][i], np.int64)).max(initial=0))}
+        self._score_bound(rows)
         for c in range(C):
             if S["f_valid"][i, c]:
                 column = S["f_pair_cn"][i][:, c]
@@ -1764,24 +1835,37 @@ class PallasSession:
     def _add_score(self, writer: int, reader: int, pair: int,
                    w: int) -> None:
         """D4/D5: a pod of `writer` moves `reader`'s raw IPA score by w
-        in its topology group, and makes the score present. The reader's
-        score and presence rows are created with their first writer."""
+        in its topology group. The reader's score row is created with its
+        first writer; no presence is kept: a score row no writer has
+        reached reads 0 on every lane, and so does its normalization."""
         rows = self._rows[reader]
+        L = self._L
         if not rows["score"]:
             rows["score"] = self._new_cnt()
-            rows["present"] = self._new_cnt()
-            base = reader * self._L.W + self._L.IPA
-            self._spec[base + 11] = rows["score"]
-            self._spec[base + 12] = rows["present"]
-        rows["score_w"] += abs(w)
-        if rows["score_w"] >= 2 ** 14:
-            # the int32 score row must keep clear of the 2^30 sentinel
-            # at 2^16 assumed pods
-            raise PallasUnsupported(
-                "IPA score weights too large for int32 score headroom",
-                reason="ipa-score-weights")
+            self._score_rows.add(rows["score"])
+            self._spec[reader * L.W + L.IPA + 11] = rows["score"]
+            self._spec[reader * L.W + L.IPAP] = 1
+        by_writer = rows["score_w"].setdefault(pair, {})
+        by_writer[writer] = by_writer.get(writer, 0) + abs(w)
+        self._score_bound(rows)
         self._add_touch(writer, rows["score"], pair, w)
-        self._add_touch(writer, rows["present"], pair, 1)
+        self._score_pairs += 1
+
+    def _score_bound(self, rows: Dict) -> None:
+        """The most a reader's raw IPA score can read on a lane: what the
+        snapshot counted at its admission, and for each topology key the
+        most pods a domain can hold times the most one pod of a writer
+        adds. Two lanes differ by at most twice that: from IPA_EXACT_MAX
+        on, ipa_normalize's exact form, and the kernel's sentinels, would
+        no longer hold (refused: the hoisted session takes the cluster)."""
+        bound = rows["static_bound"]
+        for pair, by_writer in rows["score_w"].items():
+            bound += self._pair_cap[pair] * max(by_writer.values())
+        rows["score_bound"] = bound
+        if 2 * bound >= IPA_EXACT_MAX:
+            raise PallasUnsupported(
+                "IPA scores could leave the exact int32 normalization",
+                reason="ipa-score-weights")
 
     def _wire(self, first: int) -> None:
         """Who counts toward whose rows, for every (reader, writer) pair
@@ -1911,13 +1995,17 @@ class PallasSession:
         # bucket rides the result so a harvest-side device fault can
         # retire exactly the executable that produced the bad payload
         # (tpu_backend.py retry path)
-        specs = np.unique(tm)
+        specs = np.unique(tm).tolist()
+        written = {e[0] for t in specs for e in self._touch[t]}
+        read = {self._rows[t]["score"] for t in specs}
+        score = self._spec[tm * self._L.W + self._L.IPAP] != 0
         return {"rows": out, "n": B, "bucket": Bp,
                 # what the launch carried, for its dispatch span
-                "templates": int(specs.size),
+                "templates": len(specs),
                 "term_pods": int(self._has_terms[tm].sum()),
-                "count_rows": len({e[0] for t in specs
-                                   for e in self._touch[t]})}
+                "count_rows": len(written),
+                "score_pods": int(score.sum()),
+                "score_rows": len((written | read) & self._score_rows)}
 
     @staticmethod
     # ktpu: allow-sync(harvest decode: host consumes batch verdicts after the launch completes)

@@ -72,6 +72,7 @@ from .pallas_scan import (
     SUB as SUB_IPA,
     PallasUnsupported,
     _ceil,
+    ipa_normalize,
 )
 
 _CARRY_KEYS = ("requested", "nzpc", "cnt_fn", "cnt_sn")
@@ -348,13 +349,7 @@ def _eval_fn(cfg, statics, tables, carry, x):
     min_i = pmin(jnp.min(jnp.where(feasible, raw_ipa, jnp.int32(POS_BIG))))
     max_i = pmax(jnp.max(jnp.where(feasible, raw_ipa,
                                    jnp.int32(-POS_BIG))))
-    diff = (max_i - min_i).astype(f32)
-    ipa = jnp.where(
-        diff > 0,
-        (MAX_NODE_SCORE * ((raw_ipa - min_i).astype(f32)
-                           / jnp.where(diff > 0, diff, f32(1.0))))
-        .astype(jnp.int32),
-        jnp.zeros((1, Npl), jnp.int32))
+    ipa = ipa_normalize(raw_ipa - min_i, max_i - min_i)
     ipa = jnp.where(present, ipa, jnp.zeros((1, Npl), jnp.int32))
 
     # ---- default-normalized taint / node-affinity ----

@@ -288,7 +288,10 @@ session_templates = legacy_registry.register(
         "The live device session's table of pod specs (TPU-build "
         "metric): what=specs the specs admitted, what=capacity the "
         "room for them, what=rows / what=row_capacity the count rows "
-        "their constraints own. A spec that does not fit is a rebuild "
+        "their constraints own, what=static_rows / "
+        "what=static_row_capacity the interned per-node static rows "
+        "(masks, raw scores, pair ids). A spec that does not fit is a "
+        "rebuild "
         "(scheduler_session_rebuilds_total{reason=table-*}); watch "
         "specs against capacity to see one coming.",
         ("what",),

@@ -1818,7 +1818,9 @@ class TPUBackend(CacheListener):
                             if isinstance(ys, dict) and "templates" in ys:
                                 sp.set(templates=ys["templates"],
                                        term_pods=ys["term_pods"],
-                                       rows=ys["count_rows"])
+                                       rows=ys["count_rows"],
+                                       score_pods=ys["score_pods"],
+                                       score_rows=ys["score_rows"])
                         if devtime.enabled():
                             # submit stamps at the enqueue; harvest
                             # stamps ready after the pipeline's own
@@ -2236,7 +2238,8 @@ class TPUBackend(CacheListener):
                               n=len(new)) as sp:
                 out = sess.admit(self.enc.host_state, new,
                                  flush=self._flush_pending)
-                sp.set(rows=out["rows"])
+                sp.set(rows=out["rows"], score_pairs=out["score_pairs"],
+                       flush_s=out["flush_s"])
         except PallasUnsupported as e:
             # the table is used up (table-*), the spec needs what this
             # session was built without, or it cannot ride the kernel
@@ -2258,6 +2261,8 @@ class TPUBackend(CacheListener):
         session_templates.set(float(sess.Tcap), what="capacity")
         session_templates.set(float(sess.count_rows), what="rows")
         session_templates.set(float(sess.RC), what="row_capacity")
+        session_templates.set(float(sess.static_rows), what="static_rows")
+        session_templates.set(float(sess.SRc), what="static_row_capacity")
         balanced_quirk_states.set(float(sess.quirk_states), what="listed")
         balanced_quirk_states.set(float(MAX_QUIRKS), what="capacity")
 

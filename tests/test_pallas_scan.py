@@ -12,7 +12,11 @@ import pytest
 
 from kubernetes_tpu.api import types as v1
 from kubernetes_tpu.ops.hoisted import HoistedSession, template_fingerprint
-from kubernetes_tpu.ops.pallas_scan import PallasSession, PallasUnsupported
+from kubernetes_tpu.ops.pallas_scan import (
+    IPA_EXACT_MAX,
+    PallasSession,
+    PallasUnsupported,
+)
 from kubernetes_tpu.testing.synth import synth_cluster, synth_pending_pods
 
 from .test_hoisted import _encode_all, _presized_encoding
@@ -335,11 +339,14 @@ class TestPallasTerms:
                              interpret=True)
         assert sess.dyn_ipa
         # every preferred pod's score row takes weight 100 from each
-        # writer, inside the int32 headroom guard
+        # writer; the most a raw score can read (weights times the pods a
+        # domain holds) stays inside the exact normalization
         readers = [r for r in sess._rows if r["score"]]
-        assert readers and all(0 < r["score_w"] < 2 ** 14 for r in readers)
-        assert {w for touch in sess._touch for _, _, w, _ in touch} >= {
-            -100 if anti else 100, 1}
+        assert readers and all(
+            0 < 2 * r["score_bound"] < IPA_EXACT_MAX for r in readers)
+        # the score touches alone: no presence row is kept beside them
+        assert {w for touch in sess._touch for _, _, w, _ in touch} == {
+            -100 if anti else 100}
 
     def test_cross_template_anti(self):
         # template A's anti terms must repel template B pods assumed in

@@ -294,6 +294,10 @@ class TestSpansAndMetrics:
         admits = [e for e in ev if e["name"] == "template-admit"]
         assert [(e["n"], e["rows"] > 0)
                 for e in admits] == [(2, True)]
+        # the seconds spent landing batches in flight first, 0.0 where
+        # no batch could count toward the new specs' rows (a number, as
+        # every span attribute named *_s: readers sum them)
+        assert all(isinstance(e["flush_s"], float) for e in admits)
         g = sched_metrics.session_templates
         assert g.value(what="specs") == 5
         assert g.value(what="capacity") == be._session.Tcap
